@@ -13,10 +13,11 @@ CHECK_PATHS = raft_tpu tests bench.py benches docs README.md CHANGES.md
 
 all: native test
 
-native: cpp/libmultiraft.so
-
-cpp/libmultiraft.so: cpp/multiraft_engine.cpp
-	g++ -O3 -std=c++17 -shared -fPIC -o $@ $<
+# The library is keyed on the source's content hash
+# (cpp/libmultiraft.<hash>.so, raft_tpu/multiraft/native.py) and built on
+# first use; this target forces the build.
+native:
+	$(PY) -c "from raft_tpu.multiraft import native; native.load_library(); print(native.library_path())"
 
 test:
 	$(PY) -m pytest tests/ -q
@@ -79,5 +80,5 @@ examples:
 	$(PY) examples/five_mem_node.py
 
 clean:
-	rm -f cpp/libmultiraft.so
+	rm -f cpp/libmultiraft*.so
 	find . -name __pycache__ -type d -exec rm -rf {} +

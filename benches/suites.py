@@ -202,10 +202,9 @@ def bench_fused_instrumented(G=100_000, P=5):
     cfg = SimConfig(
         n_groups=G, n_peers=P, election_tick=64, collect_health=True
     )
-    interpret = jax.default_backend() == "cpu"
     k = 32
     kstep = pallas_step.fast_multi_round(
-        cfg, k=k, with_health=True, with_chaos=True, interpret=interpret
+        cfg, k=k, with_health=True, with_chaos=True
     )
     st = sim.init_state(cfg)
     h = sim.init_health(cfg)
@@ -273,10 +272,9 @@ def bench_fused_damped(G=100_000, P=5):
         n_groups=G, n_peers=P, election_tick=64, collect_health=True,
         collect_counters=True, check_quorum=True, pre_vote=True,
     )
-    interpret = jax.default_backend() == "cpu"
     k = 32
     kstep = pallas_step.fast_multi_round(
-        cfg, k=k, with_health=True, with_counters=True, interpret=interpret
+        cfg, k=k, with_health=True, with_counters=True
     )
     st = sim.init_state(cfg)
     h = sim.init_health(cfg)
@@ -542,6 +540,20 @@ def main():
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
     iters = 50 if args.quick else 300
+
+    # The one backend decision: no TPU and no explicit CPU pin is an
+    # error, and the table names the device its numbers came from.
+    from raft_tpu import platform
+
+    try:
+        device = platform.device_fields()
+    except RuntimeError as e:
+        raise SystemExit(f"ERROR: {e}")
+    platform.enable_compile_cache()
+    print(
+        f"device: {device['platform']} {device['device_kind']} "
+        f"x{device['n_devices']}"
+    )
 
     results = []
     bench_raft_new(results, iters)

@@ -6,10 +6,17 @@ This is the full TiKV topology in miniature (SURVEY.md §5.8b): per-process
 device-batched ticking, per-destination message batching, and the binary
 codec on the wire (frame = u32 len | u32 group | codec message).
 
+A codec/transport demo, not a chip workload: a TPU belongs to one process
+at a time, so the three children pin themselves to the CPU
+(JAX_PLATFORMS=cpu) before they import jax and never open the chip.  The
+on-chip shape of this topology is three drivers in ONE process
+(examples/multiraft_node.py).
+
 Run: python examples/multiraft_tcp.py
 """
 
 import multiprocessing as mp
+import os
 import queue
 import socket
 import struct
@@ -26,6 +33,9 @@ PROPOSALS_PER_GROUP = 3
 
 
 def node_main(node_id, result_q):
+    # Before anything imports jax: this child must never open the chip.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
     from raft_tpu import Config, MemStorage, StateRole
     from raft_tpu.codec import decode_message, encode_message
     from raft_tpu.multiraft.driver import MultiRaft

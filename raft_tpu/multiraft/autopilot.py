@@ -155,7 +155,6 @@ def make_cadence_runner(
     chaos_compiled: Optional[chaos_mod.CompiledChaos],
     rounds: int,
     fused: bool = False,
-    interpret: bool = False,
 ):
     """One jitted cadence segment: `rounds` scan iterations of
     reconfig._runner_body (chaos masks + op protocol + MTTR/safety folds)
@@ -188,8 +187,7 @@ def make_cadence_runner(
     from . import runner as runner_mod
 
     return runner_mod.make_runner(
-        cfg, (compiled, chaos_compiled), cadence=rounds, fused=fused,
-        interpret=interpret,
+        cfg, (compiled, chaos_compiled), cadence=rounds, fused=fused
     )
 
 
@@ -213,7 +211,6 @@ class Autopilot:
         monitor=None,
         metrics=None,
         fused: bool = False,
-        interpret: Optional[bool] = None,
     ):
         self.sim = sim
         self.cfg = cfg.validate()
@@ -224,9 +221,6 @@ class Autopilot:
         )
         self.metrics = metrics
         self.fused = fused
-        self.interpret = (
-            jax.default_backend() == "cpu" if interpret is None else interpret
-        )
         self._cooldown_until: Dict[int, int] = {}
         # Per-group retry counter shared by kicks AND transfers: the
         # policy cannot see liveness, so repeated attempts on the same
@@ -537,7 +531,6 @@ class Autopilot:
             r = make_cadence_runner(
                 self.sim.cfg, compiled, chaos_compiled, rounds,
                 fused=self.fused and rounds == self.cfg.cadence,
-                interpret=self.interpret,
             )
             self._runners[key] = r
         return r

@@ -2,12 +2,19 @@
 (cpp/multiraft_engine.cpp) — the framework's native scalar runtime and the
 CPU anchor for bench.py.
 
-The shared library is built lazily with g++ on first use and cached next to
-the source (no pybind11 in the image; plain C ABI via ctypes)."""
+The shared library is built with g++ on first use (no pybind11 in the image;
+plain C ABI via ctypes) and keyed on the SOURCE'S CONTENT: it lives at
+cpp/libmultiraft.<sha256[:16] of multiraft_engine.cpp>.so, so a binary built
+from any other source — a stale one, or one copied in from another machine —
+has a different name and is never loaded.  A missing g++ is a loud error:
+the engine is a parity reference, not an optional accelerator."""
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -15,28 +22,48 @@ from typing import Optional
 
 import numpy as np
 
-_CPP_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "cpp")
-_SO_PATH = os.path.abspath(os.path.join(_CPP_DIR, "libmultiraft.so"))
-_SRC_PATH = os.path.abspath(os.path.join(_CPP_DIR, "multiraft_engine.cpp"))
+_CPP_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "cpp")
+)
+_SRC_PATH = os.path.join(_CPP_DIR, "multiraft_engine.cpp")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> None:
-    subprocess.run(
-        [
-            "g++",
-            "-O3",
-            "-std=c++17",
-            "-shared",
-            "-fPIC",
-            "-o",
-            _SO_PATH,
-            _SRC_PATH,
-        ],
-        check=True,
-        capture_output=True,
-    )
+def library_path() -> str:
+    """Where the library built from the current source lives."""
+    with open(_SRC_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_CPP_DIR, f"libmultiraft.{digest}.so")
+
+
+def _build(so_path: str) -> None:
+    # Build under a private name and rename into place: a concurrent
+    # process never loads a half-written library.
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
+             _SRC_PATH],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
+    except FileNotFoundError as e:
+        raise RuntimeError(
+            "g++ not found: the native engine (cpp/multiraft_engine.cpp) "
+            "cannot be built, and nothing substitutes for it"
+        ) from e
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"g++ failed building {_SRC_PATH}:\n{e.stderr}"
+        ) from e
+    os.replace(tmp, so_path)
+    # Libraries of other sources are dead weight from here on.
+    for stale in glob.glob(os.path.join(_CPP_DIR, "libmultiraft*.so")):
+        if stale != so_path:
+            with contextlib.suppress(FileNotFoundError):
+                os.remove(stale)
 
 
 def load_library() -> ctypes.CDLL:
@@ -44,11 +71,10 @@ def load_library() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_SO_PATH) or os.path.getmtime(
-            _SO_PATH
-        ) < os.path.getmtime(_SRC_PATH):
-            _build()
-        lib = ctypes.CDLL(_SO_PATH)
+        so_path = library_path()
+        if not os.path.exists(so_path):
+            _build(so_path)
+        lib = ctypes.CDLL(so_path)
         lib.mr_create.restype = ctypes.c_void_p
         lib.mr_create.argtypes = [ctypes.c_int32] * 4
         lib.mr_destroy.argtypes = [ctypes.c_void_p]

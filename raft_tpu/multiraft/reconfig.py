@@ -1106,7 +1106,6 @@ def make_split_runner(
     k: int = 8,
     window: int = 4,
     with_counters: bool = False,
-    interpret: bool = False,
 ):
     """Build the SPLIT-HORIZON scenario runner (ISSUE 11): the same
     protocol as make_runner — bit-identical end state, health planes,
@@ -1163,7 +1162,7 @@ def make_split_runner(
 
     return runner_mod.make_runner(
         cfg, (compiled, chaos_compiled), split=True, k=k, window=window,
-        with_counters=with_counters, interpret=interpret,
+        with_counters=with_counters,
     )
 
 

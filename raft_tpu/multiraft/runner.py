@@ -296,7 +296,6 @@ def _make_reconfig_split(
     k: int,
     window: int,
     with_counters: bool,
-    interpret: bool,
 ):
     """The split-horizon reconfig runner (reconfig.make_split_runner's
     contract): planned general segments scan _runner_body; planned fused
@@ -332,7 +331,7 @@ def _make_reconfig_split(
     ) == n_rounds, "split_plan must tile the horizon exactly"
     fused_fn = pallas_step.steady_round(
         cfg, rounds=k, with_health=True, with_counters=with_counters,
-        with_chaos=chaos_on, interpret=interpret,
+        with_chaos=chaos_on,
     )
     n_carry = 7 if with_counters else 6  # ... + fused accumulator below
 
@@ -599,7 +598,6 @@ def _make_workload_split(
     k: int,
     chaos_compiled,
     reconfig_compiled,
-    interpret: bool,
 ):
     """The fused client-workload runner (workload.make_split_runner's
     contract): k-round blocks behind the steady + provably-servable-lease
@@ -641,9 +639,7 @@ def _make_workload_split(
     P, G = cfg.n_peers, cfg.n_groups
     n_blocks, tail = n_rounds // k, n_rounds % k
     n_client = len(schedules_mod.array_fields("client"))
-    fused_fn = pallas_step.steady_round(
-        cfg, rounds=k, with_health=True, interpret=interpret
-    )
+    fused_fn = pallas_step.steady_round(cfg, rounds=k, with_health=True)
 
     def _rebuild_client(sched_args):
         csched = rebuild("client", client, sched_args)
@@ -788,7 +784,6 @@ def _make_cadence(
     chaos_compiled: Optional[chaos_mod.CompiledChaos],
     rounds: int,
     fused: bool,
-    interpret: bool,
 ):
     """One jitted autopilot cadence segment (make_cadence_runner's
     contract): `rounds` scan iterations of _runner_body with the action
@@ -806,7 +801,7 @@ def _make_cadence(
 
         fused_fn = pallas_step.steady_round(
             cfg, rounds=rounds, with_health=True,
-            with_chaos=chaos_compiled is not None, interpret=interpret,
+            with_chaos=chaos_compiled is not None,
         )
 
     with_bb = cfg.blackbox
@@ -960,7 +955,6 @@ def make_runner(
     window: int = 4,
     with_counters: bool = False,
     fused: bool = False,
-    interpret: bool = False,
 ):
     """Build a compiled whole-scenario runner from compiled schedules.
 
@@ -995,20 +989,18 @@ def make_runner(
             )
         if client_c is not None:
             raise ValueError("cadence runners do not thread a client plan")
-        return _make_cadence(
-            cfg, reconfig_c, chaos_c, cadence, fused, interpret
-        )
+        return _make_cadence(cfg, reconfig_c, chaos_c, cadence, fused)
     if split:
         if client_c is not None:
             return _make_workload_split(
-                cfg, client_c, k, chaos_c, reconfig_c, interpret
+                cfg, client_c, k, chaos_c, reconfig_c
             )
         if reconfig_c is None:
             raise ValueError(
                 "split runners need a reconfig or client schedule"
             )
         return _make_reconfig_split(
-            cfg, reconfig_c, chaos_c, k, window, with_counters, interpret
+            cfg, reconfig_c, chaos_c, k, window, with_counters
         )
     if client_c is not None:
         return _make_workload(cfg, client_c, chaos_c, reconfig_c)

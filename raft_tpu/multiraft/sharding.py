@@ -47,12 +47,20 @@ def make_mesh(
     n_devices: Optional[int] = None, axis: str = "groups", devices=None
 ) -> Mesh:
     """1-D device mesh over the group axis.  Pass `devices` explicitly to
-    pin the backend (e.g. jax.devices("cpu") for a virtual dryrun mesh)."""
+    pin the backend (e.g. jax.devices("cpu") for a virtual dryrun mesh).
+
+    The axis is an Auto axis: every graph here is written for the
+    partitioner to split along G from the operand shardings (jit with
+    shardings), not for sharding-in-types — under jax's default Explicit
+    axis a pallas_call refuses to trace outside shard_map."""
     if devices is None:
         devices = jax.devices()
     if n_devices is not None:
         devices = devices[:n_devices]
-    return jax.make_mesh((len(devices),), (axis,), devices=list(devices))
+    return jax.make_mesh(
+        (len(devices),), (axis,), devices=list(devices),
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def _row_sharding(mesh: Mesh, axis: str, row) -> NamedSharding:
@@ -259,10 +267,7 @@ def global_status(cfg: SimConfig, mesh: Mesh, axis: str = "groups"):
     itself stays on ICI.  The underlying jitted fn is exposed as `.jitted`
     for the graftcheck trace audit (GC015 pins this graph's collective
     set to exactly its psum/pmin reductions)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps shard_map under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if cfg.n_groups * 255 >= 2**31:
         raise ValueError(

@@ -4408,7 +4408,6 @@ class ClusterSim:
             runner = runner_mod.make_runner(
                 self.cfg, (compiled, chaos_compiled), split=split,
                 k=split_k, window=split_window, with_counters=wc,
-                interpret=jax.default_backend() == "cpu",
             )
             self._reconfig_runner = (
                 plan, chaos_plan, compiled, runner, mode,
@@ -4585,7 +4584,6 @@ class ClusterSim:
             runner = runner_mod.make_runner(
                 self.cfg, (compiled, chaos_compiled, reconfig_compiled),
                 split=split, k=split_k,
-                interpret=jax.default_backend() == "cpu",
             )
             self._read_runner = (
                 plan, chaos_plan, reconfig_plan, compiled, runner, mode,

@@ -417,7 +417,6 @@ def make_split_runner(
     k: int = 8,
     chaos_compiled=None,
     reconfig_compiled=None,
-    interpret: bool = False,
 ):
     """Build the FUSED client-workload runner (the ISSUE 13 perf
     satellite): the same protocol and accounting as make_runner —
@@ -460,7 +459,7 @@ def make_split_runner(
 
     return runner_mod.make_runner(
         cfg, (client, chaos_compiled, reconfig_compiled), split=True,
-        k=k, interpret=interpret,
+        k=k,
     )
 
 

@@ -17,6 +17,7 @@ from raft_tpu.platform import (  # noqa: E402
 
 force_virtual_cpu(8)
 require_virtual_cpu(8)
-# Persistent XLA compile cache (opt-in via RAFT_TPU_COMPILE_CACHE; CI caches
-# the directory between runs): compile seconds are tier-1 budget.
+# Persistent XLA compile cache (JAX_COMPILATION_CACHE_DIR, else the fixed
+# .jax_cache/ in the checkout; CI caches the directory between runs):
+# compile seconds are tier-1 budget.
 enable_compile_cache()

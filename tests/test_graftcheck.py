@@ -1361,7 +1361,7 @@ def test_gc013_io_callback_in_graph_flags():
     vs, _ = _trace_run([_trace_spec("callback@fixture", build)])
     assert ids(vs) == ["GC013", "GC013"]
     prims = " ".join(v.message for v in vs)
-    assert "io_callback" in prims and "debug_callback" in prims
+    assert "io_callback" in prims and "debug_print" in prims
 
 
 def test_trace_build_failure_is_a_finding():

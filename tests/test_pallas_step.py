@@ -2,8 +2,8 @@
 step whenever the steady predicate holds, and the fast_step dispatcher must
 match sim.step on full schedules including elections and crashes.
 
-Runs in interpret mode on CPU (the TPU compile path is exercised by
-bench.py when RAFT_TPU_PALLAS=1)."""
+Runs in interpret mode on the pinned CPU (raft_tpu.platform decides);
+the Mosaic compile path is exercised on the chip by chip_smoke.py."""
 
 import numpy as np
 import jax
@@ -12,21 +12,6 @@ import pytest
 
 from raft_tpu.multiraft import ClusterSim, SimConfig
 from raft_tpu.multiraft import pallas_step, sim
-
-
-@pytest.fixture(autouse=True)
-def _interpret_pallas(monkeypatch):
-    # CPU test environment: run pallas in interpreter mode.
-    from jax.experimental import pallas as pl
-
-    orig = pl.pallas_call
-
-    def patched(*args, **kwargs):
-        kwargs.setdefault("interpret", True)
-        return orig(*args, **kwargs)
-
-    monkeypatch.setattr(pl, "pallas_call", patched)
-    yield
 
 
 def settle(cfg, rounds=30):

@@ -34,21 +34,6 @@ from raft_tpu.multiraft import chaos, kernels, reconfig
 from raft_tpu.multiraft import sim as sim_mod
 
 
-@pytest.fixture(autouse=True)
-def _interpret_pallas(monkeypatch):
-    # CPU test environment: run pallas in interpreter mode.
-    from jax.experimental import pallas as pl
-
-    orig = pl.pallas_call
-
-    def patched(*args, **kwargs):
-        kwargs.setdefault("interpret", True)
-        return orig(*args, **kwargs)
-
-    monkeypatch.setattr(pl, "pallas_call", patched)
-    yield
-
-
 def seg(start, rounds, fused):
     return reconfig.HorizonSegment(start, rounds, fused)
 
@@ -232,9 +217,7 @@ def test_split_runner_matches_unsplit_g8():
         return st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st)
 
     out1 = reconfig.make_runner(cfg, compiled)(*fresh())
-    runner = reconfig.make_split_runner(
-        cfg, compiled, k=4, window=4, interpret=True
-    )
+    runner = reconfig.make_split_runner(cfg, compiled, k=4, window=4)
     out2 = runner(*fresh())
     _assert_run_equal(out1, out2, "g8-split")
     fused = int(out2[6])
@@ -295,7 +278,6 @@ def test_split_runner_prod_composition_g32():
     out1 = reconfig.make_runner(cfg, compiled, ccompiled)(*fresh())
     runner = reconfig.make_split_runner(
         cfg, compiled, ccompiled, k=4, window=4, with_counters=True,
-        interpret=True,
     )
     st0, hl0, rst0 = fresh()
     out2 = runner(st0, hl0, rst0, kernels.zero_counters())

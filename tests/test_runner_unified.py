@@ -125,11 +125,10 @@ def _run_reconfig(G, split):
 
     if split:
         out_legacy = reconfig.make_split_runner(
-            cfg, compiled, ccompiled, k=4, window=4, interpret=True
+            cfg, compiled, ccompiled, k=4, window=4
         )(*fresh())
         out_unified = runner_mod.make_runner(
-            cfg, (compiled, ccompiled), split=True, k=4, window=4,
-            interpret=True,
+            cfg, (compiled, ccompiled), split=True, k=4, window=4
         )(*fresh())
     else:
         out_legacy = reconfig.make_runner(cfg, compiled, ccompiled)(*fresh())
@@ -154,11 +153,9 @@ def _run_workload(G, split):
         )
 
     if split:
-        out_legacy = workload.make_split_runner(
-            cfg, client, k=4, interpret=True
-        )(*fresh())
+        out_legacy = workload.make_split_runner(cfg, client, k=4)(*fresh())
         out_unified = runner_mod.make_runner(
-            cfg, (client,), split=True, k=4, interpret=True
+            cfg, (client,), split=True, k=4
         )(*fresh())
     else:
         out_legacy = workload.make_runner(cfg, client)(*fresh())

@@ -404,7 +404,7 @@ def test_split_runner_bit_identical_and_fuses():
     compiled = workload.compile_plan(plan, cfg.n_groups)
     k = 8
     general = workload.make_runner(cfg, compiled)
-    split = workload.make_split_runner(cfg, compiled, k=k, interpret=True)
+    split = workload.make_split_runner(cfg, compiled, k=k)
 
     def fresh():
         return (

@@ -113,13 +113,10 @@ def run_config_fused(doc: dict, groups: int) -> dict:
             check_quorum=True,
             pre_vote=True,
         )
-        interpret = jax.default_backend() == "cpu"
         _FUSED_CACHE[key] = (
             cfg,
             jax.jit(
-                pallas_step.fast_multi_round(
-                    cfg, k=1, with_health=True, interpret=interpret
-                )
+                pallas_step.fast_multi_round(cfg, k=1, with_health=True)
             ),
             jax.jit(functools.partial(sim_mod.step, cfg)),
         )

@@ -72,8 +72,8 @@ _COLLECTIVE_RE = re.compile(
 )
 
 # Primitives that move control or data across the host boundary (or pin a
-# transfer) inside a traced graph.  `debug_print` is jax.debug.print's
-# pre-0.4.31 spelling; kept so an old-jax trace still fails loudly.
+# transfer) inside a traced graph.  jax.debug.print traces to a
+# `debug_print` equation, jax.debug.callback to `debug_callback`.
 HOST_SYNC_PRIMITIVES: Set[str] = {
     "pure_callback",
     "io_callback",
@@ -461,9 +461,9 @@ def trace_inventory(
         specs = REGISTRY
     _pin_audit_mesh()
     try:
-        # GC011 pays real XLA compiles; the opt-in persistent cache
-        # (RAFT_TPU_COMPILE_CACHE — same cache CI shares with the tier-1
-        # job) makes repeated trace runs cheap.  Best-effort by design.
+        # GC011 pays real XLA compiles; the persistent cache (the same one
+        # the tier-1 job fills) makes repeated trace runs cheap.
+        # Best-effort by design.
         from raft_tpu import platform
 
         platform.enable_compile_cache()

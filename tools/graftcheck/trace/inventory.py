@@ -257,13 +257,11 @@ def _dispatcher_builder(damping: dict, with_health: bool):
 
         sim = _sim()
         cfg = sim.SimConfig(n_groups=G, n_peers=P, **damping)
-        # interpret-mode pallas off-TPU: the pallas_call wrapping differs
-        # but the kernel jaxpr inside (what GC014 counts) does not.
+        # On the audit's CPU mesh pallas builds in interpret mode: the
+        # pallas_call wrapping differs from the Mosaic one but the kernel
+        # jaxpr inside (what GC014 counts) does not.
         fn = pallas_step.fast_multi_round(
-            cfg,
-            k=DISPATCH_K,
-            with_health=with_health,
-            interpret=jax.default_backend() != "tpu",
+            cfg, k=DISPATCH_K, with_health=with_health
         )
         st, crashed, append_n = _base_args(cfg)
         args: tuple = (st, crashed, append_n)
@@ -390,7 +388,6 @@ def _reconfig_runner_builder(
 
 def _split_runner_builder():
     def build() -> Built:
-        import jax
         import jax.numpy as jnp
 
         from raft_tpu.multiraft import chaos, kernels, reconfig
@@ -423,7 +420,6 @@ def _split_runner_builder():
         runner = reconfig.make_split_runner(
             cfg, compiled, chaos_compiled, k=DISPATCH_K, window=4,
             with_counters=True,
-            interpret=jax.default_backend() != "tpu",
         )
         # The fused-block jit is the split runner's hot graph: the
         # steady-predicate + pending guard, the fused kernel, AND the
@@ -592,7 +588,6 @@ def _workload_runner_builder():
 
 def _workload_split_builder():
     def build() -> Built:
-        import jax
         import jax.numpy as jnp
 
         from raft_tpu.multiraft import chaos, kernels, reconfig, workload
@@ -603,10 +598,7 @@ def _workload_split_builder():
             check_quorum=True, lease_read=True,
         )
         compiled = workload.compile_plan(_client_plan(), G)
-        runner = workload.make_split_runner(
-            cfg, compiled, k=DISPATCH_K,
-            interpret=jax.default_backend() != "tpu",
-        )
+        runner = workload.make_split_runner(cfg, compiled, k=DISPATCH_K)
         st, _, _ = _base_args(cfg)
         # The fused-block jit is the split runner's hot graph: the
         # steady/read-pending/lease-provable predicate, the fused damped
@@ -743,10 +735,7 @@ def _sharded_dispatch_builder():
         cfg = sim.SimConfig(n_groups=G_SHARDED, n_peers=P, spmd=True)
         mesh = _sharded_mesh()
         st, crashed, append_n = _sharded_args(cfg, mesh)
-        fn = pallas_step.fast_multi_round(
-            cfg, k=DISPATCH_K,
-            interpret=jax.default_backend() != "tpu",
-        )
+        fn = pallas_step.fast_multi_round(cfg, k=DISPATCH_K)
         return Built(jax.jit(fn), (st, crashed, append_n))
 
     return build
