@@ -1,0 +1,217 @@
+"""The one general traffic generator: `traffic/<mix>.json` + (G, P, seed)
+-> one *segment*, the schedule the run replays call after call.
+
+A mix is data only (see README.md "Adding a traffic mix").  Its keys:
+
+  segment_rounds | segment_rounds_per_peer   length of the segment
+  phase_rounds            rounds per client phase (update loads are drawn
+                          once per phase; equal to split_k so a fused block
+                          spans one phase)
+  ops_per_round_per_group operations offered per round, as a share of G
+                          (open loop: offered whatever the fleet's state)
+  read_share              share of the operations that are reads
+  read_mode               "lease" | "safe"
+  distribution            {"kind": "zipfian", "constant": c} — YCSB's
+                          Zipfian over the G regions, ranks scattered over
+                          the region ids by a seeded permutation; or
+                          {"kind": "every_region"} — every region takes the
+                          same share (hashed keys)
+  split                   whether run_reads is called with split=True
+  chaos                   optional {"for_each_peer": [phase, ...], "then":
+                          [phase, ...]}: chaos phases in the program's plan
+                          grammar ("rounds", "crash", "partition"), the first
+                          list repeated for peer s = 1..P with "@peer"
+                          standing for s, the second appended once
+
+Operations of one kind on one region in one round coalesce: reads into one
+read fire, updates into one append of n entries.  Reads are drawn as the
+exact per-region marginal of n draws from the distribution (a region fires
+with probability 1 - (1 - p)^n, independently), which costs one pass over
+G per round instead of a search per operation; updates are n exact draws
+per phase, applied in each round of the phase.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+from typing import NamedTuple, Optional
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+MODE_CODES = {"safe": 1, "lease": 2}  # sim.READ_SAFE / sim.READ_LEASE
+
+
+class Segment(NamedTuple):
+    """Host arrays of one segment, in the layout of the program's
+    `workload.CompiledClient`."""
+
+    phase_of_round: np.ndarray  # int32[R]
+    read_fire_packed: np.ndarray  # uint32[R, ceil(G/32)], bit j of word w = group 32w+j
+    read_mode: np.ndarray  # int32[NPH, G]
+    append: np.ndarray  # int32[NPH, G]
+    chaos: Optional[dict]  # a chaos plan document, or None
+    n_groups: int
+    n_peers: int
+    split: bool
+    split_k: int
+    read_fires: int  # fires in the segment (after coalescing)
+    read_ops: int  # expected read operations offered (before coalescing)
+    update_entries: int  # entries offered over the segment
+    write_batches: int  # (group, round) pairs that offer an append
+    touched_share: float  # mean share of regions with any operation in a round
+
+    @property
+    def n_rounds(self) -> int:
+        return int(self.phase_of_round.shape[0])
+
+
+def load_mix(name: str) -> dict:
+    path = os.path.join(HERE, "traffic", f"{name}.json")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def pack_bits(mask: np.ndarray) -> np.ndarray:
+    """bool[..., G] -> uint32[..., ceil(G/32)], little-endian bit order
+    (the program's `kernels.pack_bits_g` layout)."""
+    g = mask.shape[-1]
+    pad = (-g) % 32
+    if pad:
+        mask = np.concatenate(
+            [mask, np.zeros(mask.shape[:-1] + (pad,), bool)], axis=-1
+        )
+    by = np.packbits(mask, axis=-1, bitorder="little")
+    return np.ascontiguousarray(by).view("<u4")
+
+
+def unpack_bits(words: np.ndarray, g: int) -> np.ndarray:
+    by = np.ascontiguousarray(words.astype("<u4")).view(np.uint8)
+    return np.unpackbits(by, axis=-1, bitorder="little")[..., :g].astype(bool)
+
+
+def region_weights(dist: dict, n_groups: int, rng) -> np.ndarray:
+    """float64[G]: the probability that one operation goes to each region."""
+    kind = dist["kind"]
+    if kind == "every_region":
+        return np.full(n_groups, 1.0 / n_groups)
+    if kind == "zipfian":
+        rank = np.arange(1, n_groups + 1, dtype=np.float64)
+        w = rank ** -float(dist["constant"])
+        w /= w.sum()
+        out = np.empty(n_groups)
+        out[rng.permutation(n_groups)] = w  # hot regions scattered
+        return out
+    raise ValueError(f"unknown distribution kind {kind!r}")
+
+
+def segment_rounds(mix: dict, n_peers: int) -> int:
+    doc = chaos_document(mix, n_peers, "")
+    if doc is not None:
+        return sum(int(ph["rounds"]) for ph in doc["phases"])
+    return int(mix["segment_rounds"])
+
+
+def chaos_document(mix: dict, n_peers: int, name: str) -> Optional[dict]:
+    spec = mix.get("chaos")
+    if not spec:
+        return None
+    def bind(ph: dict, s) -> dict:
+        ph = dict(ph)
+        if "crash" in ph:
+            ph["crash"] = [s if p == "@peer" else int(p) for p in ph["crash"]]
+        if "partition" in ph:
+            ph["partition"] = [
+                [s if p == "@peer" else int(p) for p in side]
+                for side in ph["partition"]
+            ]
+        return ph
+
+    phases = [
+        bind(ph, s)
+        for s in range(1, n_peers + 1)
+        for ph in spec.get("for_each_peer", [])
+    ]
+    phases += [bind(ph, None) for ph in spec.get("then", [])]
+    return {"name": name, "peers": n_peers, "phases": phases}
+
+
+def generate(mix: dict, n_groups: int, n_peers: int, seed: int,
+             name: str = "mix") -> Segment:
+    G = n_groups
+    R = segment_rounds(mix, n_peers)
+    pr = int(mix["phase_rounds"])
+    nph = -(-R // pr)
+    phase_of_round = (np.arange(R) // pr).astype(np.int32)
+    rng = np.random.Generator(np.random.PCG64(int(seed)))
+    p = region_weights(mix["distribution"], G, rng)
+
+    ops = float(mix["ops_per_round_per_group"]) * G
+    read_share = float(mix["read_share"])
+    n_reads = ops * read_share  # read operations per round
+    n_updates = ops * (1.0 - read_share)  # update entries per round
+
+    words = (G + 31) // 32
+    fire_packed = np.zeros((R, words), dtype="<u4")
+    read_mode = np.zeros((nph, G), np.int32)
+    fires = 0
+    q_read = np.zeros(G)
+    if n_reads > 0:
+        read_mode[:] = MODE_CODES[mix["read_mode"]]
+        # P(at least one of n draws lands on the region).
+        q_read = -np.expm1(n_reads * np.log1p(-p))
+        q32 = q_read.astype(np.float32)
+        for r in range(R):
+            mask = rng.random(G, dtype=np.float32) < q32
+            fires += int(mask.sum())
+            fire_packed[r] = pack_bits(mask)
+
+    append = np.zeros((nph, G), np.int32)
+    entries = batches = 0
+    q_upd = np.zeros(G)
+    if n_updates > 0:
+        if mix["distribution"]["kind"] == "every_region":
+            per = n_updates / G
+            if per != int(per):
+                raise ValueError("every_region needs a whole load per region")
+            append[:] = int(per)
+        else:
+            cdf = np.cumsum(p)
+            cdf[-1] = 1.0
+            m = int(round(n_updates))
+            for i in range(nph):
+                hit = np.searchsorted(cdf, rng.random(m), side="right")
+                append[i] = np.bincount(hit, minlength=G)[:G]
+        q_upd = -np.expm1(n_updates * np.log1p(-p)) if n_updates < G else np.ones(G)
+        rounds_in_phase = np.bincount(phase_of_round, minlength=nph)
+        entries = int((append.sum(axis=1) * rounds_in_phase).sum())
+        batches = int(((append > 0).sum(axis=1) * rounds_in_phase).sum())
+
+    touched = float(np.mean(1.0 - (1.0 - q_read) * (1.0 - q_upd)))
+    return Segment(
+        phase_of_round=phase_of_round,
+        read_fire_packed=fire_packed,
+        read_mode=read_mode,
+        append=append,
+        chaos=chaos_document(mix, n_peers, name),
+        n_groups=G,
+        n_peers=n_peers,
+        split=bool(mix.get("split", False)),
+        split_k=pr,
+        read_fires=fires,
+        read_ops=int(round(n_reads * R)),
+        update_entries=entries,
+        write_batches=batches,
+        touched_share=touched,
+    )
+
+
+def sample_rows(seg: Segment, gids: np.ndarray):
+    """(fire bool[R, n], mode int[R, n], append int[R, n]) of the sampled
+    groups, gathered by phase: what the reference replays."""
+    gids = np.asarray(gids)
+    w, b = gids // 32, gids % 32
+    fire = ((seg.read_fire_packed[:, w] >> b.astype(np.uint32)) & 1).astype(bool)
+    ph = seg.phase_of_round
+    return fire, seg.read_mode[:, gids][ph], seg.append[:, gids][ph]
