@@ -993,12 +993,12 @@ def bench_reads(
     _st, _hl, _rst, stats, _rstats, safety, _rcar, rdstats, lat_hist = (
         out[:9]
     )
-    lat_p = workload.latency_percentiles(lat_hist)
-    rdstats_h, lat_p_h, safety_h, stats_h = jax.device_get(
-        (rdstats, lat_p, safety, stats)
+    lat_p, recover_p = workload.report_percentiles(lat_hist, stats)
+    rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h = jax.device_get(
+        (rdstats, lat_p, safety, stats, recover_p)
     )
     report = workload.read_report(
-        rdstats_h, lat_p_h, safety_h, stats_h, plan.n_rounds
+        rdstats_h, lat_p_h, safety_h, stats_h, plan.n_rounds, recover_p_h
     )
     report["plan"] = plan.name
     report["groups"] = groups

@@ -1,0 +1,230 @@
+"""The loader of what the program itself writes into a capture, and the four
+reducers that read it: on a small recording from the chip WITH stats and
+name stacks (tests/data/program_trace.json: TPU v5 lite, a 2048 x 3 fleet,
+two `run_reads(split=True)` calls of two fused blocks and a general tail of
+one round, PR 26), and on a capture made here on the CPU for the wire
+format."""
+
+import glob
+import json
+import os
+
+import pytest
+
+from benchmark import program_trace as pt
+from benchmark import reducers, run, trace
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+RECORDED = os.path.join(HERE, "data", "program_trace.json")
+METRICS = os.path.join(os.path.dirname(HERE), "metrics")
+NEW = sorted(
+    os.path.basename(p)[:-len(".json")]
+    for p in glob.glob(os.path.join(METRICS, "*.json"))
+    if json.load(open(p, encoding="utf-8")).get("loader") == "program_trace"
+)
+
+
+def spec_of(name):
+    with open(os.path.join(METRICS, name + ".json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cap():
+    return pt.load_recorded(RECORDED)
+
+
+@pytest.fixture(scope="module")
+def facts(cap):
+    """What run.py would hand a reader, plus the capture."""
+    return pt.facts_of(cap)
+
+
+def read(name, facts):
+    spec = spec_of(name)
+    return reducers.load(spec["reducer"]).read(facts, spec["args"])
+
+
+def test_the_loader_looks_where_run_py_traces():
+    assert pt.TRACE_DIR == run.TRACE_DIR
+
+
+def test_the_ten_metric_files_are_there():
+    assert NEW == sorted([
+        "append_drop_share", "block_guard_share", "damped_kernel_share",
+        "idle_dispatch_ms", "idle_prepare_ms", "idle_report_ms",
+        "leaderless_rounds_share", "programs_per_segment",
+        "quorum_commit_share", "recover_p99_rounds",
+    ])
+
+
+def test_metric_files_spell_names_from_the_programs_catalogue():
+    from raft_tpu import profiling
+
+    for name in NEW:
+        args = spec_of(name)["args"]
+        if "span" in args:
+            assert args["span"] in profiling.SPANS, name
+        if "scope" in args:
+            assert args["scope"] in profiling.SCOPES, name
+        if "kernel" in args:
+            assert args["kernel"] in profiling.KERNELS, name
+    assert pt.RUN_SPAN in profiling.SPANS
+    assert pt.PROGRAM_PREFIX == profiling.SPAN_PREFIX
+
+
+def test_window_is_reduce_events_window(cap, facts):
+    lo, hi = pt.window(cap)
+    assert (hi - lo) / 1e9 == facts["trace"]["window_s"]
+    busy = trace.union_seconds(pt.op_intervals(cap, pt.planes(cap)[0]), lo, hi)
+    assert busy == pytest.approx(facts["trace"]["busy_s"], rel=1e-12)
+
+
+def test_the_recording_holds_the_span_tree_with_its_counts(cap):
+    calls = pt.spans_named(cap, pt.RUN_SPAN)
+    assert [c.stats["call"] for c in calls] == [2, 3]  # call 1 was the warm-up
+    for c in calls:
+        assert c.stats["rounds"] == 17 and c.stats["groups"] == 2048
+    reports = pt.spans_named(cap, "raft.run_reads.report")
+    assert [r.stats["call"] for r in reports] == [2, 3]
+    for r in reports:
+        assert r.stats["fused_rounds"] == 16 * 2048
+        assert r.stats["total_rounds"] == 17 * 2048
+        assert r.stats["appends_offered"] == 17 * 2048
+        assert r.stats["appends_dropped"] == 0
+        assert r.stats["safety.dual_leader"] == 0
+    blocks = pt.spans_named(cap, "raft.runner.blocks")
+    assert [b.stats for b in blocks] == [{"blocks": 2, "tail": 1}] * 2
+
+
+def test_name_stack_rules():
+    path = "jit(block_run)/cond/branch_1_fun/runner.fused_arm/raft_steady_damped/pallas_call:"
+    assert pt.has_kernel(path, "raft_steady_damped")
+    assert not pt.has_kernel(path, "raft_steady")
+    assert pt.has_scope(path, "runner.fused_arm")
+    assert not pt.has_scope(path, "runner.fused")  # whole components only
+    inner = "jit(f)/while/body/round.damped/quorum_commit/jit(take_along_axis)/gather:"
+    assert pt.has_scope(inner, "quorum_commit") and pt.has_scope(inner, "round.damped")
+    assert not pt.has_kernel(inner, "quorum_commit")
+    assert not pt.has_scope("", "round")
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_reader_finds_its_number_on_the_recording(name, facts):
+    value = read(name, facts)
+    assert isinstance(value, float)
+    unit = spec_of(name)["unit"]
+    if unit == "%":
+        assert 0.0 <= value <= 100.0
+    if name == "recover_p99_rounds":
+        assert value == -1.0  # nothing was lost, so no episode ended
+    elif name in ("leaderless_rounds_share", "append_drop_share"):
+        assert value == 0.0
+    elif name != "idle_prepare_ms":
+        assert value > 0.0
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_reader_returns_none_for_a_program_without_names(name, cap, facts):
+    """The parent of PR 26: no `raft.` span, no scope, no kernel name."""
+    bare = pt.Capture(
+        [s for s in cap.spans if not s.name.startswith(pt.PROGRAM_PREFIX)],
+        [o._replace(path="") for o in cap.ops],
+        cap.modules,
+    )
+    assert read(name, {**facts, "capture": bare}) is None
+
+
+def test_span_counter_returns_none_where_a_stat_is_missing(cap, facts):
+    older = pt.Capture(
+        [s._replace(stats={k: v for k, v in s.stats.items() if k != "appends_offered"})
+         for s in cap.spans],
+        cap.ops, cap.modules,
+    )
+    assert read("append_drop_share", {**facts, "capture": older}) is None
+    assert read("recover_p99_rounds", {**facts, "capture": older}) is not None
+
+
+def test_damped_kernel_share_is_the_custom_calls_share(facts):
+    """On a capture whose only kernel is the damped one, the share found by
+    the kernel's NAME equals the share found by `tpu_custom_call`."""
+    by_target = reducers.load("op_share").read(facts, spec_of("fused_kernel_share")["args"])
+    assert read("damped_kernel_share", facts) == pytest.approx(by_target, rel=1e-9)
+
+
+def test_idle_in_spans_sums_to_the_gaps_inside_the_calls(cap, facts):
+    calls = pt.spans_named(cap, pt.RUN_SPAN)
+    inside_ms = 1e3 * pt.idle_seconds_in(cap, calls) / len(calls)
+    parts = [read(n, facts) for n in ("idle_prepare_ms", "idle_dispatch_ms", "idle_report_ms")]
+    assert sum(parts) <= inside_ms * (1 + 1e-9)
+    assert sum(parts) >= 0.95 * inside_ms
+    # ... and the calls' idle time is what host_ms_per_segment reads, less
+    # the sliver of the benchmark's own span around each call.
+    host_ms = reducers.load("host_per_segment").read(facts, {})
+    assert inside_ms <= host_ms
+    assert inside_ms >= 0.95 * host_ms
+
+
+def test_programs_per_segment_counts_modules_inside_the_calls(cap, facts):
+    value = read("programs_per_segment", facts)
+    # At least the two block programs and the tail program of each call,
+    # and no program of the capture counted twice.
+    assert 3 <= value <= len(cap.modules) / 2
+    assert value * 2 == int(value * 2)  # a mean over the two calls
+
+
+def test_scopes_partition_no_more_than_busy(cap, facts):
+    shares = [
+        100.0 * pt.self_seconds_where(cap, lambda p, s=s: pt.has_scope(p, s))[0]
+        / facts["trace"]["busy_s"]
+        for s in ("runner.block_guard", "runner.fused_arm", "runner.general_arm")
+    ]
+    assert shares[0] > 0 and shares[1] > 0
+    assert shares[2] == 0.0  # every block fused: the fallback never ran
+    assert sum(shares) <= 100.0 + 1e-6
+
+
+def test_read_xplane_agrees_with_profiledata(tmp_path):
+    """The wire-format reader on a capture made here: the same spans, the
+    same whole-ns times and the same stats as jax's own reader gives."""
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    with jax.profiler.TraceAnnotation(trace.SEGMENT_SPAN):
+        with jax.profiler.TraceAnnotation("raft.run_reads", call=7, rounds=24) as whole:
+            whole.set_metadata(groups=64)
+            with jax.profiler.TraceAnnotation("raft.run_reads.report", neg=-3):
+                pass
+        with jax.profiler.TraceAnnotation("other.span"):
+            pass
+    jax.profiler.stop_trace()
+    path = trace.newest_xplane(str(tmp_path))
+    want = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("raft.", "bench.")):
+                    want.append((ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats)))
+    got = pt.read_xplane(path)
+    assert [tuple(s) for s in got.spans] == sorted(want, key=lambda s: (s[1], -s[2]))
+    assert [s.name for s in got.spans] == [
+        trace.SEGMENT_SPAN, "raft.run_reads", "raft.run_reads.report"]
+    assert got.spans[1].stats == {"call": 7, "rounds": 24, "groups": 64}
+    assert got.spans[2].stats == {"neg": -3}
+    assert got.ops == [] and got.modules == []
+    assert pt.load(str(tmp_path)) is pt.load(str(tmp_path))  # read once
+    for name in NEW:  # no device plane here: nothing to read
+        if spec_of(name)["source"] != "program_counter":
+            assert read(name, {"trace": {"busy_s": 1.0}, "capture": got}) is None
+
+
+def test_export_round_trips(cap, tmp_path):
+    out = tmp_path / "again.json"
+    pt.export(cap, str(out), per_line=10 ** 6)
+    again = pt.load_recorded(str(out))
+    assert again.spans == cap.spans
+    assert len(again.ops) == len([o for o in cap.ops if o.end_ns >= pt.window(cap)[0]])
+    assert pt.metrics(again).keys() == set(NEW)

@@ -388,36 +388,86 @@ class HostSchedule:
 
 # Chaos-stats accumulator indices ([N_CHAOS_STATS] int32; time-to-reelect /
 # MTTR off the PR 3 health planes — health.chaos_report formats them).
+# Every slot grows by at most G per round (CS_MAX_STREAK is a max), and
+# every compiled plan bounds rounds x G < 2**31: no slot can wrap.
 CS_REELECTIONS = 0  # leaderless episodes that ended (leader regained)
 CS_HEALED_ROUNDS = 1  # summed length of ended episodes (MTTR numerator)
 CS_MAX_STREAK = 2  # longest leaderless streak observed anywhere
 CS_LEADERLESS_ROUNDS = 3  # total leaderless (group, round) pairs
-N_CHAOS_STATS = 4
+CS_APPENDS_OFFERED = 4  # (group, round) pairs the schedule offered entries
+CS_APPENDS_DROPPED = 5  # ... of which no acting leader took them
+# The ended episodes by length: slot CS_RECOVER_HIST + i counts episodes
+# that lasted i rounds, the last slot every length >= RECOVER_CAP (capped
+# like workload.lat_hist) — so recovery has a p99, not only a mean.
+CS_RECOVER_HIST = 6
+RECOVER_CAP = 64
+N_RECOVER_BUCKETS = RECOVER_CAP + 1
+N_CHAOS_STATS = CS_RECOVER_HIST + N_RECOVER_BUCKETS
 
 CHAOS_STAT_NAMES = (
     "reelections",
     "healed_rounds",
     "max_leaderless_streak",
     "leaderless_group_rounds",
+    "appends_offered",
+    "appends_dropped",
 )
+
+
+def recover_hist(stats):
+    """The [N_RECOVER_BUCKETS] histogram of ended leaderless episodes by
+    length in rounds, out of a chaos-stats vector (device or host)."""
+    return stats[CS_RECOVER_HIST:CS_RECOVER_HIST + N_RECOVER_BUCKETS]
 
 
 def update_chaos_stats(
     stats: jnp.ndarray,  # gc: int32[S]
     prev_leaderless: jnp.ndarray,  # gc: int32[G]
     new_leaderless: jnp.ndarray,  # gc: int32[G]
+    offered: Optional[jnp.ndarray] = None,  # gc: bool[G]
+    dropped: Optional[jnp.ndarray] = None,  # gc: bool[G]
+    rounds: int = 1,
 ) -> jnp.ndarray:
-    """Fold one round's leaderless-plane transition into the stats."""
+    """Fold one round's leaderless-plane transition into the stats, and
+    the round's append offers: `offered` marks the groups the schedule
+    offered entries, `dropped` those of them whose batch no acting leader
+    took (sim.ReconfigProposal.dropped).  A fused block folds its `rounds`
+    rounds at once: the plane is 0 at every round's end (a leader held),
+    every offer was taken (dropped=None), and `offered` counts `rounds`
+    times."""
     healed = (prev_leaderless > 0) & (new_leaderless == 0)
     # dtype= on the sums: bare reductions widen to int64 under x64 (GC007).
-    delta = jnp.stack(
-        [
-            jnp.sum(healed, dtype=jnp.int32),
-            jnp.sum(jnp.where(healed, prev_leaderless, 0), dtype=jnp.int32),
-            jnp.int32(0),
-            jnp.sum(new_leaderless > 0, dtype=jnp.int32),
-        ]
+    n_healed = jnp.sum(healed, dtype=jnp.int32)
+
+    def count(mask):
+        if mask is None:
+            return jnp.int32(0)
+        return jnp.sum(mask, dtype=jnp.int32)
+
+    # The ended episodes by length: compare-and-sum over [buckets, G] —
+    # no scatter, and no cond around it (PERF.md §6, PR 26, has what a
+    # conditional in the scan body did to the round on the chip).
+    length = jnp.minimum(prev_leaderless, RECOVER_CAP)
+    bucket = jnp.arange(N_RECOVER_BUCKETS, dtype=jnp.int32)[:, None]
+    hist = jnp.sum(
+        healed[None, :] & (length[None, :] == bucket),
+        axis=1, dtype=jnp.int32,
     )
+    delta = jnp.concatenate([
+        jnp.stack(
+            [
+                n_healed,
+                jnp.sum(
+                    jnp.where(healed, prev_leaderless, 0), dtype=jnp.int32
+                ),
+                jnp.int32(0),
+                jnp.sum(new_leaderless > 0, dtype=jnp.int32),
+                count(offered) * jnp.int32(rounds),
+                count(dropped),
+            ]
+        ),
+        hist,
+    ])
     out = stats + delta
     return out.at[CS_MAX_STREAK].set(
         jnp.maximum(stats[CS_MAX_STREAK], jnp.max(new_leaderless))

@@ -316,6 +316,14 @@ class HealthMonitor:
                 degraded_serves=report.get("degraded_serves", 0),
                 read_p50=report.get("read_p50", -1),
                 read_p99=report.get("read_p99", -1),
+                leaderless_group_rounds=report.get(
+                    "leaderless_group_rounds", 0
+                ),
+                appends_offered=report.get("appends_offered", 0),
+                appends_dropped=report.get("appends_dropped", 0),
+                recover_p50_rounds=report.get("recover_p50_rounds", -1),
+                recover_p90_rounds=report.get("recover_p90_rounds", -1),
+                recover_p99_rounds=report.get("recover_p99_rounds", -1),
             )
             if any(report.get("safety", {}).values()):
                 m.trace("reads.safety", **report["safety"])

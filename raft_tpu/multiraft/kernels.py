@@ -159,6 +159,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
+
 INF = jnp.int32(2**31 - 1)
 
 # Vote results as int codes matching quorum.VoteResult.
@@ -172,6 +174,7 @@ def majority_of(count: jnp.ndarray) -> jnp.ndarray:  # gc: int32[...]
     return count // 2 + 1
 
 
+@profiling.scope("quorum_commit")
 def committed_index(
     matched: jnp.ndarray,  # gc: int32[..., P]
     voter_mask: jnp.ndarray,  # gc: bool[..., P]
@@ -602,6 +605,7 @@ def lease_read(
     return holder, served, index
 
 
+@profiling.scope("safety_audit")
 def check_safety(
     state: jnp.ndarray,  # gc: int32[P, G]
     term: jnp.ndarray,  # gc: int32[P, G]
@@ -1385,6 +1389,7 @@ def zero_health(n_groups: int) -> jnp.ndarray:
     return jnp.zeros((N_HEALTH_PLANES, n_groups), jnp.int32)
 
 
+@profiling.scope("health_fold")
 def update_health(
     planes: jnp.ndarray,  # gc: int32[H, G]
     window_pos: jnp.ndarray,  # gc: int32[]

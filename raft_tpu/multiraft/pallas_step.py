@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import platform
+from .. import platform, profiling
 from . import kernels as kernels_mod
 from . import planes
 from . import sim as sim_mod
@@ -307,6 +307,7 @@ def _agree_event(agree, in_set, value, lead_f):
     )
 
 
+@profiling.scope("quorum_commit")
 def _quorum_tile(matched, voter, qpos, P):
     """Majority index of a [P, B] matched tile over its voter rows: the
     same odd-even transposition network as the plain steady kernel (the
@@ -657,6 +658,7 @@ def steady_round(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=platform.pallas_interpret(),
+        name=profiling.kernel("raft_steady"),
     )
 
     def _run(
@@ -815,6 +817,7 @@ def _build_chaos_round(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=platform.pallas_interpret(),
+        name=profiling.kernel("raft_steady_chaos"),
     )
 
     def _run(
@@ -1247,6 +1250,7 @@ def _build_damped_round(
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=platform.pallas_interpret(),
+        name=profiling.kernel("raft_steady_damped"),
     )
 
     def _run(

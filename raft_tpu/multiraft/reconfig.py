@@ -95,6 +95,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
 from . import chaos as chaos_mod
 from . import kernels
 from . import sim as sim_mod
@@ -575,12 +576,14 @@ RECONFIG_STAT_NAMES = (
 )
 
 
+@profiling.scope("op_gather")
 def _gather_peer(plane: jnp.ndarray, owner: jnp.ndarray) -> jnp.ndarray:
     """plane[P, G], owner int32[G] (1-based, 0-safe) -> plane[owner-1, g]."""
     o = jnp.clip(owner - 1, 0, plane.shape[0] - 1)
     return jnp.take_along_axis(plane, o[None, :], axis=0)[0]
 
 
+@profiling.scope("op_gather")
 def _gather_op(plane: jnp.ndarray, op_ptr: jnp.ndarray) -> jnp.ndarray:
     """plane[K, ..., G], op_ptr int32[G] -> plane[op_ptr[g], ..., g]."""
     k = jnp.clip(op_ptr, 0, plane.shape[0] - 1)
@@ -903,9 +906,10 @@ def _runner_body(
         active = (rst.op_ptr < sched.n_ops) & (r >= start)
         want_prop = active & (rst.stage == 0)
         prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
+        offered = append + want_prop.astype(jnp.int32)
         step_out = sim_mod.step(
             cfg, st, crashed,
-            append + want_prop.astype(jnp.int32),
+            offered,
             counters=ctrs, health=hl, link=link,
             reconfig_propose=want_prop,
             transfer_propose=transfer_propose,
@@ -1000,7 +1004,8 @@ def _runner_body(
             learner_mask=lm3, recent_active=ra3, transferee=tr3,
         )
         stats = chaos_mod.update_chaos_stats(
-            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS]
+            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
+            offered=offered > 0, dropped=prop.dropped,
         )
         # dtype= on the counts: bare bool sums widen to int64 under x64
         # (GC007) and these feed the int32 accumulator.

@@ -44,6 +44,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
 from . import chaos as chaos_mod
 from . import kernels
 from . import reconfig as reconfig_mod
@@ -398,7 +399,8 @@ def _make_reconfig_split(
             # health fold pins HP_LEADERLESS to 0 every round (a leader
             # held), so k per-round folds telescope to this single one.
             stats2 = chaos_mod.update_chaos_stats(
-                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS]
+                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS],
+                offered=append > 0, rounds=k,
             )
             # No op proposed/gated/applied and no mask moved (predicate):
             # the op-protocol carry is unchanged except the transition-
@@ -654,6 +656,8 @@ def _make_workload_split(
     ):
         csched, sched = _rebuild_client(sched_args)
         body = reconfig_mod._runner_body(cfg, sched, None, client=csched)
+        guard = profiling.Sections()
+        guard.at("runner.block_guard")
         crashed = jnp.zeros((P, G), bool)
         cph = csched.phase_of_round[r0]
         append = sched.append[sched.phase_of_round[r0]] + csched.append[cph]
@@ -680,13 +684,18 @@ def _make_workload_split(
             cfg, st, crashed, horizon=k, read_pending=read_block
         )
         pred = jnp.all(mask & lease_prov) & same_phase
+        guard.end()
 
+        @profiling.scope("runner.fused_arm")
         def fast(args):
             st, hl, rst, stats, rstats, safety, rcar, rdstats, lat = args
             prev_ll = hl.planes[kernels.HP_LEADERLESS]
             st2, hl2 = fused_fn(st, crashed, append, hl)
+            # The predicate proves a standing leader with no transfer
+            # pending: every one of the k offers was taken.
             stats2 = chaos_mod.update_chaos_stats(
-                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS]
+                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS],
+                offered=append > 0, rounds=k,
             )
             # The op protocol provably never moves (no-op schedule); only
             # the transition-audit anchors refresh, like the reconfig
@@ -706,6 +715,7 @@ def _make_workload_split(
                 lat,
             )
 
+        @profiling.scope("runner.general_arm")
         def slow(args):
             carry, _ = jax.lax.scan(
                 body, args, r0 + jnp.arange(k, dtype=jnp.int32)
@@ -745,10 +755,11 @@ def _make_workload_split(
             st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
             jnp.int32(0),
         )
-        for b in range(n_blocks):
-            carry = fused_jit(
-                *carry, jnp.int32(b * k), *sched_args
-            )
+        with profiling.span("raft.runner.blocks", blocks=n_blocks, tail=tail):
+            for b in range(n_blocks):
+                carry = fused_jit(
+                    *carry, jnp.int32(b * k), *sched_args
+                )
         if tail_jit is not None:
             carry = tail_jit(
                 *carry, jnp.int32(n_blocks * k), *sched_args
@@ -908,7 +919,8 @@ def _make_cadence(
                 fargs = fargs + (loss, r0)
             st2, hl2 = fused_fn(*fargs, hl)
             stats2 = chaos_mod.update_chaos_stats(
-                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS]
+                stats, prev_ll, hl2.planes[kernels.HP_LEADERLESS],
+                offered=append > 0, rounds=rounds,
             )
             # No op, no action, commits flow every round (append > 0 on a
             # steady horizon): the op carry only refreshes its transition

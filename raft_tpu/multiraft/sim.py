@@ -110,6 +110,7 @@ from typing import List, NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
 from . import kernels, planes
 from .kernels import ROLE_CANDIDATE, ROLE_FOLLOWER, ROLE_LEADER
 
@@ -347,11 +348,16 @@ class ReconfigProposal(NamedTuple):
     appended last), term the owner's term at propose time.  The reconfig
     runner (raft_tpu/multiraft/reconfig.py) records these as the pending
     joint log position whose commit under BOTH majorities gates the mask
-    swap."""
+    swap.  `dropped` marks the groups whose whole batch of this round
+    (workload entries and conf entry alike) was offered and taken by
+    nobody — no alive leader, or a transfer pending at the acting leader
+    (raft-rs ProposalDropped); the runners count it
+    (chaos.CS_APPENDS_DROPPED)."""
 
     owner: jnp.ndarray  # gc: int32[G]
     index: jnp.ndarray  # gc: int32[G]
     term: jnp.ndarray  # gc: int32[G]
+    dropped: jnp.ndarray  # gc: bool[G]
 
 
 # Read-request modes for step(..., read_propose=) — int32[G] per-group
@@ -632,6 +638,7 @@ def _sort_rows_desc(rows: List[jnp.ndarray]) -> List[jnp.ndarray]:
     return rows
 
 
+@profiling.scope("quorum_commit")
 def _quorum_index(matched: jnp.ndarray, voter_mask: jnp.ndarray) -> jnp.ndarray:
     """Per-group majority commit index over the peer axis of [P, G] planes
     (the scalar oracle: quorum.MajorityConfig.committed_index, reference:
@@ -1065,6 +1072,7 @@ def _transfer_phase(
     return out, cg, won_t
 
 
+@profiling.scope("round")
 def step(
     cfg: SimConfig,
     st: SimState,
@@ -1782,6 +1790,7 @@ def step(
                 owner=jnp.where(prop_mask, first_l + 1, 0),
                 index=jnp.where(prop_mask, lead_last, 0),
                 term=jnp.where(prop_mask, lead_term, 0),
+                dropped=(append_n > 0) & (n_app == 0),
             ),
         )
     if read_extra is not None:
@@ -1789,6 +1798,7 @@ def step(
     return (out,) + extras
 
 
+@profiling.scope("round.linked")
 def _linked_step(
     cfg: SimConfig,
     st: SimState,
@@ -2441,6 +2451,7 @@ def _linked_step(
                 owner=jnp.where(prop_mask, first_l + 1, 0),
                 index=jnp.where(prop_mask, lead_last, 0),
                 term=jnp.where(prop_mask, lead_term, 0),
+                dropped=(append_n > 0) & (n_app == 0),
             ),
         )
     if read_extra is not None:
@@ -2448,6 +2459,7 @@ def _linked_step(
     return (out,) + extras
 
 
+@profiling.scope("round.damped")
 def _damped_linked_step(
     cfg: SimConfig,
     st: SimState,
@@ -2504,6 +2516,10 @@ def _damped_linked_step(
         )
     G, P = cfg.n_groups, cfg.n_peers
     st_in = st
+    # The parts of the round, each under its profiling scope (the names
+    # the device ops carry in a trace; they change no equation).
+    sec = profiling.Sections()
+    sec.at("damped.read_probe")
     # Client-read phase (ISSUE 13): pure probe on the round-entry state —
     # the lease gate plus the damped (nudge-cutoff) ReadIndex fallback —
     # BEFORE the transfer pump and the ticks, where the scalar oracle
@@ -2513,6 +2529,7 @@ def _damped_linked_step(
         if read_propose is None
         else _read_phase(cfg, st, crashed, read_propose, link)
     )
+    sec.at("damped.tick")
     t_extra = None
     if st.transferee is not None:
         # The transfer pre-tick pump, link-gated and lease-exempt (the
@@ -2649,6 +2666,7 @@ def _damped_linked_step(
         c = jnp.cumsum(eff.astype(jnp.int32), axis=axis)
         return (c - eff.astype(jnp.int32)) > 0
 
+    sec.at("damped.wave1")
     # ---- wave 1: heartbeats + (pre-)vote requests, per receiver in
     # sender order.  Mirrors _linked_step's wave 1 plus the damping
     # branches: lease ignores, lower-term nudges, and pre-vote's
@@ -2747,6 +2765,7 @@ def _damped_linked_step(
     else:
         (grants, resps, rej_snap, hb_accs, hb_ndg, hb_ndg_t) = w1_ys
 
+    sec.at("damped.wave2")
     # ---- wave 2a: heartbeat responses + nudges back at each leader, in
     # receiver order.  Closed form: the first nudge whose term beats the
     # leader's cuts off every later response (handle_heartbeat_response
@@ -2773,6 +2792,7 @@ def _damped_linked_step(
     HB = jnp.where(hdep, 0, HB)
     RT = jnp.where(hdep, draw(T), RT)
 
+    sec.at("damped.tally")
     # ---- real-election tally (the _linked_step wave-2 machinery): used
     # at wave 2 without pre-vote, at wave 4 with it.
     def _tally_inner(carry, xs):
@@ -3010,6 +3030,7 @@ def _damped_linked_step(
     LT = lt2
     C_send = C  # commit snapshots for wave-3 sends
 
+    sec.at("damped.wave3")
     # ---- wave 3: appends (winner noops + catch-ups) and — with pre-vote
     # — the REAL vote requests, per receiver in sender order.  Acks and
     # nudges are collected for the wave-4 fold; grants/rejects for the
@@ -3120,6 +3141,7 @@ def _damped_linked_step(
     # ---- generic ack/nudge stage fold (waves 4 and 6): per sender, acks
     # and nudge responses interleave in receiver order; the first
     # effective nudge deposes the sender and drops every later ack.
+    @profiling.scope("damped.stage_fold")
     def _stage_fold(T, V, St, Ld, EE, HB, RT, RA, matched3, C, ack, ndg,
                     ndg_t, sent_term, sent_idx):
         eff_n = ndg & Erev & (ndg_t > T[:, None, :])
@@ -3171,6 +3193,7 @@ def _damped_linked_step(
         adv = c_new > C
         return T, V, St, Ld, EE, HB, RT, RA, matched3, c_new, adv
 
+    sec.at("damped.tally")
     # ---- wave 4: with pre-vote, the REAL tally (plus its winner
     # effects); both modes run the stage fold over the wave-3 acks.
     (T, V, St, Ld, EE, HB, RT, RA, matched3, C, adv) = _stage_fold(
@@ -3199,6 +3222,7 @@ def _damped_linked_step(
         LI = jnp.where(won, li2, LI)
         LT = jnp.where(won, lt2, LT)
 
+    sec.at("damped.wave3")
     # ---- retry resends (the maybe_decr/fast-reject chain): a surviving
     # sender's resend carries prev at the receiver's conflict point, so it
     # lands as wholesale adoption one wave after the reject.  Applied
@@ -3244,6 +3268,7 @@ def _damped_linked_step(
         )
     )
 
+    sec.at("damped.wave5")
     # ---- wave 5: commit-advance re-broadcasts (pass 2) and — with
     # pre-vote — the winners' noop broadcasts, one sender-ordered scan.
     C_send5 = C
@@ -3368,6 +3393,7 @@ def _damped_linked_step(
     HB = jnp.where(dep6, 0, HB)
     RT = jnp.where(dep6, draw(T), RT)
 
+    sec.at("damped.workload")
     # ---- the round's append workload at the acting leader, with the
     # same nudge cutoffs on its ack stream.
     is_leader = (St == ROLE_LEADER) & alive
@@ -3466,6 +3492,7 @@ def _damped_linked_step(
     EE = jnp.where(dw, 0, EE)
     HB = jnp.where(dw, 0, HB)
     RT = jnp.where(dw, draw(T), RT)
+    sec.end()
 
     if transferee is not None:
         # reset-abort invariant (see step()): only standing leaders keep
@@ -3563,6 +3590,7 @@ def _damped_linked_step(
                 owner=jnp.where(prop_mask, first_l + 1, 0),
                 index=jnp.where(prop_mask, lead_last, 0),
                 term=jnp.where(prop_mask, lead_term, 0),
+                dropped=(append_n > 0) & (n_app == 0),
             ),
         )
     if read_extra is not None:
@@ -3748,6 +3776,7 @@ class ClusterSim:
                             link=link)
 
             self._step_counted = jax.jit(_counted, donate_argnums=(0, 3))
+        self._read_calls = 0  # run_reads' sequence number (profiling spans)
         if cfg.collect_health:
             self._health = init_health(cfg)
             if mesh is not None:
@@ -4531,108 +4560,139 @@ class ClusterSim:
         general per-round body — bit-identical either way, with the
         measured `fused_frac` added to the report.  Only a bare plan
         (no chaos/reconfig composition) supports the split mode."""
-        from . import chaos as chaos_mod
         from . import reconfig as reconfig_mod
         from . import workload as workload_mod
 
         health = self._require_health()
-        fused_zero = False
-        if split and self.cfg.blackbox:
-            # Conservative v1 (ISSUE 15): blackbox-on horizons never fuse
-            # (steady_mask rejects them), so run the general scan and
-            # report fused_frac 0 instead of spinning the split machinery.
-            split = False
-            fused_zero = True
-        cached = getattr(self, "_read_runner", None)
-        mode = ("split", split_k) if split else "scan"
-        if (
-            cached is None
-            or cached[0] is not plan
-            or cached[1] is not chaos_plan
-            or cached[2] is not reconfig_plan
-            or cached[5] != mode
-        ):
-            if isinstance(plan, workload_mod.CompiledClient):
-                compiled = plan
-            else:
-                compiled = workload_mod.compile_plan(
-                    plan, self.cfg.n_groups
+        # Host spans on the trace's clock (raft_tpu/profiling.py: no-ops
+        # unless a jax.profiler trace is being captured); `call` ties the
+        # report span's counts to this call.
+        self._read_calls += 1
+        with profiling.span("raft.run_reads") as whole:
+            with profiling.span("raft.run_reads.prepare") as prepare:
+                fused_zero = False
+                if split and self.cfg.blackbox:
+                    # Conservative v1 (ISSUE 15): blackbox-on horizons
+                    # never fuse (steady_mask rejects them), so run the
+                    # general scan and report fused_frac 0 instead of
+                    # spinning the split machinery.
+                    split = False
+                    fused_zero = True
+                cached = getattr(self, "_read_runner", None)
+                mode = ("split", split_k) if split else "scan"
+                if (
+                    cached is None
+                    or cached[0] is not plan
+                    or cached[1] is not chaos_plan
+                    or cached[2] is not reconfig_plan
+                    or cached[5] != mode
+                ):
+                    prepare.set_metadata(miss=1)
+                    compiled, runner = self._build_read_runner(
+                        plan, chaos_plan, reconfig_plan, split, split_k
+                    )
+                    self._read_runner = (
+                        plan, chaos_plan, reconfig_plan, compiled, runner,
+                        mode,
+                    )
+                else:
+                    compiled, runner = cached[3], cached[4]
+                whole.set_metadata(
+                    call=self._read_calls, rounds=compiled.n_rounds,
+                    groups=self.cfg.n_groups,
                 )
-            compiled = self._shard_client_schedule(compiled)
-            if chaos_plan is None or isinstance(
-                chaos_plan, chaos_mod.CompiledChaos
-            ):
-                chaos_compiled = chaos_plan
-            else:
-                chaos_compiled = chaos_mod.compile_plan(
-                    chaos_plan, self.cfg.n_groups
+                rst = self._place_reconfig_state(
+                    reconfig_mod.init_reconfig_state(self.state)
                 )
-            chaos_compiled = self._shard_chaos_schedule(chaos_compiled)
-            if reconfig_plan is None or isinstance(
-                reconfig_plan, reconfig_mod.CompiledReconfig
-            ):
-                reconfig_compiled = reconfig_plan
-            else:
-                reconfig_compiled = reconfig_mod.compile_plan(
-                    reconfig_plan, self.cfg.n_groups
+                rcar = jax.tree.map(
+                    lambda x: self._put(x, True),
+                    workload_mod.init_read_carry(self.cfg.n_groups),
                 )
-            reconfig_compiled = self._shard_reconfig_schedule(
-                reconfig_compiled
-            )
-            from . import runner as runner_mod
-
-            runner = runner_mod.make_runner(
-                self.cfg, (compiled, chaos_compiled, reconfig_compiled),
-                split=split, k=split_k,
-            )
-            self._read_runner = (
-                plan, chaos_plan, reconfig_plan, compiled, runner, mode,
-            )
-        else:
-            compiled, runner = cached[3], cached[4]
-        rst = self._place_reconfig_state(
-            reconfig_mod.init_reconfig_state(self.state)
-        )
-        rcar = jax.tree.map(
-            lambda x: self._put(x, True),
-            workload_mod.init_read_carry(self.cfg.n_groups),
-        )
-        args = [self.state, health, rst, rcar]
-        if self._blackbox is not None:
-            args.append(self._blackbox)
-        out = runner(*args)
-        (
-            self.state, self._health, _rst, stats, rstats, safety,
-            self._read_carry, rdstats, lat_hist,
-        ) = out[:9]
-        i = 9
-        if self._blackbox is not None:
-            self._blackbox = out[i]
-            i += 1
-        fused = out[i] if split else None
-        lat_p = workload_mod.latency_percentiles(lat_hist)
-        # graftcheck: allow-no-host-sync-in-jit — deliberate end-of-run
-        # download of fixed-size stat vectors, outside the jitted scan.
-        rdstats_h, lat_p_h, safety_h, stats_h = jax.device_get(
-            (rdstats, lat_p, safety, stats)
-        )
-        report = workload_mod.read_report(
-            rdstats_h, lat_p_h, safety_h, stats_h, compiled.n_rounds
-        )
-        if fused is not None:
-            total = compiled.n_rounds * self.cfg.n_groups
-            # graftcheck: allow-no-host-sync-in-jit — one int32 scalar,
-            # downloaded with the report, outside the jitted segments.
-            report["fused_rounds"] = int(jax.device_get(fused))
-            report["total_rounds"] = total
-            report["fused_frac"] = round(report["fused_rounds"] / total, 4)
-        elif fused_zero:
-            report["fused_rounds"] = 0
-            report["total_rounds"] = compiled.n_rounds * self.cfg.n_groups
-            report["fused_frac"] = 0.0
+                args = [self.state, health, rst, rcar]
+                if self._blackbox is not None:
+                    args.append(self._blackbox)
+            with profiling.span("raft.run_reads.dispatch"):
+                out = runner(*args)
+            (
+                self.state, self._health, _rst, stats, rstats, safety,
+                self._read_carry, rdstats, lat_hist,
+            ) = out[:9]
+            i = 9
+            if self._blackbox is not None:
+                self._blackbox = out[i]
+                i += 1
+            fused = (out[i],) if split else ()  # the fused group-rounds
+            with profiling.span("raft.run_reads.report") as reporting:
+                lat_p, recover_p = workload_mod.report_percentiles(
+                    lat_hist, stats
+                )
+                with profiling.span("raft.run_reads.download"):
+                    # graftcheck: allow-no-host-sync-in-jit — deliberate
+                    # end-of-run download of fixed-size stat vectors (and
+                    # the fused group-round scalar), outside the jitted
+                    # scan.
+                    got = jax.device_get(
+                        (rdstats, lat_p, safety, stats, recover_p, *fused)
+                    )
+                rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h = got[:5]
+                report = workload_mod.read_report(
+                    rdstats_h, lat_p_h, safety_h, stats_h, compiled.n_rounds,
+                    recover_p_h,
+                )
+                if split or fused_zero:
+                    total = compiled.n_rounds * self.cfg.n_groups
+                    report["fused_rounds"] = int(got[5]) if split else 0
+                    report["total_rounds"] = total
+                    report["fused_frac"] = round(
+                        report["fused_rounds"] / total, 4
+                    )
+                reporting.set_metadata(
+                    call=self._read_calls, groups=self.cfg.n_groups,
+                    **workload_mod.report_counts(report),
+                )
         if self.health_monitor is not None:
             self.health_monitor.record_reads(report)
         return report
+
+    def _build_read_runner(
+        self, plan, chaos_plan, reconfig_plan, split: bool, split_k: int
+    ):
+        """(compiled client schedule, runner) of one run_reads plan
+        triple: compile whatever is not compiled yet, place the schedules,
+        build the runner (the runner-cache miss of run_reads)."""
+        from . import chaos as chaos_mod
+        from . import reconfig as reconfig_mod
+        from . import runner as runner_mod
+        from . import workload as workload_mod
+
+        if isinstance(plan, workload_mod.CompiledClient):
+            compiled = plan
+        else:
+            compiled = workload_mod.compile_plan(plan, self.cfg.n_groups)
+        compiled = self._shard_client_schedule(compiled)
+        if chaos_plan is None or isinstance(
+            chaos_plan, chaos_mod.CompiledChaos
+        ):
+            chaos_compiled = chaos_plan
+        else:
+            chaos_compiled = chaos_mod.compile_plan(
+                chaos_plan, self.cfg.n_groups
+            )
+        chaos_compiled = self._shard_chaos_schedule(chaos_compiled)
+        if reconfig_plan is None or isinstance(
+            reconfig_plan, reconfig_mod.CompiledReconfig
+        ):
+            reconfig_compiled = reconfig_plan
+        else:
+            reconfig_compiled = reconfig_mod.compile_plan(
+                reconfig_plan, self.cfg.n_groups
+            )
+        reconfig_compiled = self._shard_reconfig_schedule(reconfig_compiled)
+        runner = runner_mod.make_runner(
+            self.cfg, (compiled, chaos_compiled, reconfig_compiled),
+            split=split, k=split_k,
+        )
+        return compiled, runner
 
     def counters(self) -> dict:
         """Download the device event-counter plane as {name: count}.

@@ -22,8 +22,8 @@ on-device histogram (`N_LAT_BUCKETS` buckets, overflow-capped), and
 ``kernels.check_safety``'s linearizability slots (SV_STALE_READ /
 SV_DUAL_LEASE) audit the lease-holder mask every round.  The histogram
 reduces ON DEVICE to p50/p90/p99 via :func:`latency_percentiles` — the
-nearest-rank rule of profiling.RoundTimer._percentile — so only a
-fixed-size report ever crosses to the host.
+nearest-rank rule of :func:`nearest_rank` — so only a fixed-size report
+ever crosses to the host.
 
 Plan JSON (see docs/OBSERVABILITY.md "Reads" and examples/reads/)::
 
@@ -49,6 +49,7 @@ the jaxprs).
 from __future__ import annotations
 
 import json
+import math
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -59,6 +60,7 @@ import jax.numpy as jnp
 from . import chaos as chaos_mod
 from . import kernels
 from . import sim as sim_mod
+from .. import profiling
 from .chaos import GroupSel, _group_mask
 
 
@@ -319,15 +321,17 @@ def init_read_carry(n_groups: int) -> ReadCarry:
     )
 
 
+@profiling.scope("read_latency")
 def latency_percentiles(
     hist: jnp.ndarray,  # gc: int32[L]
     qs: Tuple[int, ...] = (50, 90, 99),
 ) -> jnp.ndarray:
-    """Nearest-rank percentiles of the latency histogram, ON DEVICE: the
-    smallest bucket with at least ceil(q/100 * N) of the N served reads
-    at or below it — exactly profiling.RoundTimer._percentile's rule
-    lifted from a sorted sample list to the histogram.  Returns
-    int32[len(qs)], -1 everywhere when no read was served.
+    """Nearest-rank percentiles of a histogram of rounds (read latency;
+    the lengths of leaderless episodes — chaos.recover_hist), ON DEVICE:
+    the smallest bucket with at least ceil(q/100 * N) of the N samples
+    at or below it — exactly :func:`nearest_rank`'s rule lifted from a
+    sorted sample list to the histogram.  Returns int32[len(qs)], -1
+    everywhere when the histogram is empty.
 
     The rank math decomposes n = 100a + b so a*q + ceil(b*q/100) never
     leaves int32 (n < 2**31 by compile_plan's bound, q <= 100; a naive
@@ -343,17 +347,34 @@ def latency_percentiles(
     return jnp.stack(out)
 
 
-def host_latency_percentile(samples, q: int) -> int:
-    """Host twin of latency_percentiles for the tests: delegates to THE
-    nearest-rank rule (profiling.RoundTimer._percentile) over the raw
-    latency sample list, so the device reduction is pinned against the
-    single source of the formula."""
-    from ..profiling import RoundTimer
+@jax.jit
+def report_percentiles(
+    lat_hist: jnp.ndarray,  # gc: int32[L]
+    stats: jnp.ndarray,  # gc: int32[S]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(read p50/p90/p99, recover p50/p90/p99) of a run's accumulators as
+    ONE program: the report's second histogram must not add some twenty
+    eager dispatches to every call."""
+    return (
+        latency_percentiles(lat_hist),
+        latency_percentiles(chaos_mod.recover_hist(stats)),
+    )
 
+
+def nearest_rank(xs, q: float):
+    """Nearest-rank percentile (the smallest sample with at least q of
+    the distribution at or below it): xs sorted, 0 < q <= 1."""
+    return xs[math.ceil(q * len(xs)) - 1]
+
+
+def host_latency_percentile(samples, q: int) -> int:
+    """Host twin of latency_percentiles for the tests: THE nearest-rank
+    rule (:func:`nearest_rank`) over the raw sample list, so the device
+    reduction is pinned against the single source of the formula."""
     xs = sorted(samples)
     if not xs:
         return -1
-    return RoundTimer._percentile(xs, q / 100)
+    return nearest_rank(xs, q / 100)
 
 
 def _validate(cfg, client, chaos_compiled, reconfig_compiled):
@@ -515,12 +536,23 @@ def lease_fires_in_block(
 
 
 def read_report(
-    rdstats, lat_p, safety, stats, rounds: int
+    rdstats, lat_p, safety, stats, rounds: int, recover_p=(-1, -1, -1)
 ) -> dict:
     """The per-scenario read-workload summary off the device accumulators
     (host-side formatter; bench.py --reads and ClusterSim.run_reads emit
-    it).  `lat_p` is latency_percentiles' (p50, p90, p99) vector."""
-    from .chaos import CS_HEALED_ROUNDS, CS_MAX_STREAK, CS_REELECTIONS
+    it).  `lat_p` is latency_percentiles' (p50, p90, p99) vector of the
+    read histogram, `recover_p` the same of chaos.recover_hist(stats) —
+    the lengths in rounds of the leaderless episodes that ended (-1: none
+    did)."""
+    from .chaos import (
+        CS_APPENDS_DROPPED,
+        CS_APPENDS_OFFERED,
+        CS_HEALED_ROUNDS,
+        CS_LEADERLESS_ROUNDS,
+        CS_MAX_STREAK,
+        CS_REELECTIONS,
+        recover_hist,
+    )
     from .kernels import SAFETY_NAMES
 
     reelections = int(stats[CS_REELECTIONS])
@@ -535,8 +567,28 @@ def read_report(
             round(healed / reelections, 3) if reelections else None
         ),
         "reelections": reelections,
+        "healed_rounds": healed,
         "max_leaderless_streak": int(stats[CS_MAX_STREAK]),
+        "leaderless_group_rounds": int(stats[CS_LEADERLESS_ROUNDS]),
+        "appends_offered": int(stats[CS_APPENDS_OFFERED]),
+        "appends_dropped": int(stats[CS_APPENDS_DROPPED]),
+        "recover_hist": [int(v) for v in recover_hist(stats)],
+        "recover_p50_rounds": int(recover_p[0]),
+        "recover_p90_rounds": int(recover_p[1]),
+        "recover_p99_rounds": int(recover_p[2]),
         "safety": {
             name: int(v) for name, v in zip(SAFETY_NAMES, safety)
         },
     }
+
+
+def report_counts(report: dict) -> Dict[str, int]:
+    """Every integer of a report, flat — what the `raft.run_reads.report`
+    span is closed with (the safety slots as `safety.<name>`)."""
+    out = {
+        k: v for k, v in report.items()
+        if isinstance(v, int) and not isinstance(v, bool)
+    }
+    for name, v in report.get("safety", {}).items():
+        out[f"safety.{name}"] = v
+    return out

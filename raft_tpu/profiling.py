@@ -1,29 +1,179 @@
-"""Profiling hooks (SURVEY.md §5.1: the reference's observability is
-structured slog logging + Criterion; our device path adds JAX profiler
-traces so kernel time is inspectable in TensorBoard/Perfetto).
+"""Profiling hooks: what the program writes into a `jax.profiler` trace, and
+the one catalogue of the names it writes (SURVEY.md §5.1: the reference's
+observability is structured slog logging + Criterion; the device path adds
+JAX profiler traces so kernel time is inspectable in TensorBoard/Perfetto).
 
-Usage:
+There is no switch.  "Tracing on" means a `jax.profiler` trace is being
+captured (`device_trace` below, `bench.py --profile`, the benchmark's
+`--trace 1`); otherwise a span is the profiler's own no-op (under 1 us)
+and a scope or a kernel name costs nothing at run time: both only label
+the compiled program.
 
-    from raft_tpu.profiling import device_trace, RoundTimer
+    from raft_tpu.profiling import device_trace
 
     with device_trace("/tmp/raft-trace"):      # xprof/perfetto trace
-        sim.run(100, crashed, append)
+        report = sim.run_reads(plan)
 
-    timer = RoundTimer()
-    with timer.round():
-        state = step(state, crashed, append)
-        jax.block_until_ready(state)
-    print(timer.summary())
+Three kinds of name, each spelled in ONE place — the catalogues below —
+so that code, tests, docs and the benchmark's metric files cannot drift:
+
+  SPANS    host spans (`span`): `jax.profiler.TraceAnnotation`s on the
+           trace's clock, their counts readable as the event's stats.
+  SCOPES   `jax.named_scope`s (`scope`, `Sections`): every device op
+           traced under one carries it in its op name
+           (`jit(run)/while/body/round.damped/damped.tally/...`); a fusion
+           is named after its root instruction, so it belongs to the
+           scope of its root.
+  KERNELS  `name=` of the `pl.pallas_call` sites (`kernel`).
+
+A name that is not in its catalogue is a KeyError where it is used;
+tests/test_profiling_spans.py proves the other direction (every catalogue
+name is used).
 """
 
 from __future__ import annotations
 
 import contextlib
-import math
-import time
-from typing import Dict, List
+from typing import Dict, Optional
 
 import jax
+
+# --- the catalogue: name -> one line on what it covers -----------------------
+
+SPANS: Dict[str, str] = {
+    "raft.run_reads": (
+        "one ClusterSim.run_reads call, entry to returned report; stats: "
+        "call (sequence number of this sim's calls), rounds, groups"
+    ),
+    "raft.run_reads.prepare": (
+        "runner cache look-up (schedule compile + make_runner on a miss: "
+        "stat miss=1), init_reconfig_state, init_read_carry, placement"
+    ),
+    "raft.run_reads.dispatch": (
+        "runner(*args): the eager zero carries, the block loop or the "
+        "scan's dispatch, the tail audit"
+    ),
+    "raft.runner.blocks": (
+        "the split runner's Python loop of fused_jit dispatches, inside "
+        "dispatch; stats: blocks, tail (rounds left to the tail program)"
+    ),
+    "raft.run_reads.report": (
+        "everything dispatched for the report (latency_percentiles), the "
+        "download, formatting; closed with the report's integer counts"
+    ),
+    "raft.run_reads.download": (
+        "the device_get of the report's vectors, inside report: the host's "
+        "wait for the device to finish the call"
+    ),
+}
+
+SCOPES: Dict[str, str] = {
+    "round": "sim.step's undamped all-links-up round body",
+    "round.linked": "sim._linked_step: the undamped round under a link plane",
+    "round.damped": (
+        "sim._damped_linked_step: check-quorum / pre-vote / lease round — "
+        "the only body the benchmark's cells run"
+    ),
+    "damped.read_probe": "the round-entry read probe (lease gate, ReadIndex)",
+    "damped.tick": "timers, the check-quorum boundary, campaign local effects",
+    "damped.wave1": "heartbeats + (pre-)vote requests, per receiver",
+    "damped.wave2": "heartbeat responses + nudges back at each leader",
+    "damped.tally": "the (pre-)vote tallies and post-election bookkeeping",
+    "damped.wave3": "appends: winner noops, catch-ups, their retry chains",
+    "damped.stage_fold": "_stage_fold: the ack/nudge fold of waves 4 and 6",
+    "damped.wave5": "commit-advance re-broadcasts and their retry chains",
+    "damped.workload": (
+        "the round's append workload at the acting leader; dropped "
+        "appends are counted here"
+    ),
+    "quorum_commit": (
+        "the quorum position of the acked indexes: kernels.committed_index, "
+        "sim._quorum_index, pallas_step._quorum_tile (one thing, written "
+        "three times)"
+    ),
+    "op_gather": (
+        "reconfig._gather_peer / _gather_op: the op protocol's per-group "
+        "look-ups in every _runner_body round, a plan scheduled or not"
+    ),
+    "safety_audit": "kernels.check_safety: the per-round safety slots",
+    "health_fold": "kernels.update_health: the fleet-health planes",
+    "read_latency": "workload.latency_percentiles over a histogram",
+    "runner.block_guard": (
+        "everything a split block computes before its lax.cond: schedule "
+        "look-ups, reads_pending_in_horizon, lease_fires_in_block, "
+        "lease_read, steady_mask"
+    ),
+    "runner.fused_arm": "the cond's fused branch: kernel + closed-form folds",
+    "runner.general_arm": "the cond's fallback: k general rounds",
+}
+
+KERNELS: Dict[str, str] = {
+    "raft_steady": "pallas_step: k undamped steady rounds",
+    "raft_steady_chaos": "pallas_step: k steady rounds with in-kernel link loss",
+    "raft_steady_damped": (
+        "pallas_step: k damped steady rounds — the kernel the benchmark's "
+        ".load cell runs"
+    ),
+}
+
+SPAN_PREFIX = "raft."
+assert all(name.startswith(SPAN_PREFIX) for name in SPANS)
+
+
+# --- host spans ---------------------------------------------------------------
+
+
+def _known(catalogue: Dict[str, str], name: str) -> str:
+    if name not in catalogue:
+        raise KeyError(f"{name!r} is not in raft_tpu.profiling's catalogue")
+    return name
+
+
+def span(name: str, **counts: int) -> jax.profiler.TraceAnnotation:
+    """A host span `name` (a key of SPANS) on the trace's clock, `counts`
+    as its metadata; they come back as the event's stats from
+    `jax.profiler.ProfileData`.  Counts known only at the end go on with
+    `.set_metadata(**counts)` before the span closes.  Parentage is
+    containment on one thread.  With no trace running this is the
+    profiler's no-op."""
+    return jax.profiler.TraceAnnotation(_known(SPANS, name), **counts)
+
+
+# --- names on the device ------------------------------------------------------
+
+
+def scope(name: str):
+    """`jax.named_scope(name)` for a key of SCOPES: a context manager, or a
+    decorator for a whole function body.  Changes no equation."""
+    return jax.named_scope(_known(SCOPES, name))
+
+
+class Sections:
+    """Consecutive scopes over one long straight-line body, where a `with`
+    per part would re-indent hundreds of lines: `at(name)` closes the part
+    before it and opens `name`; `end()` closes the last.  Functions traced
+    while a part is open (a scan body, a helper) land in it."""
+
+    def __init__(self) -> None:
+        self._open: Optional[contextlib.AbstractContextManager] = None
+
+    def at(self, name: str) -> None:
+        self.end()
+        self._open = scope(name)
+        self._open.__enter__()
+
+    def end(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def kernel(name: str) -> str:
+    """`name` (a key of KERNELS), for `pl.pallas_call(..., name=)`."""
+    return _known(KERNELS, name)
+
+
+# --- capturing a trace --------------------------------------------------------
 
 
 def start_trace(log_dir: str, host_profiler: bool = False) -> None:
@@ -50,44 +200,3 @@ def device_trace(log_dir: str, host_profiler: bool = False):
         yield
     finally:
         stop_trace()
-
-
-def annotate(name: str):
-    """Named region inside a trace (shows up on the host timeline)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-class RoundTimer:
-    """Lightweight wall-clock histogram for protocol rounds — the host-side
-    equivalent of the reference's Criterion loops."""
-
-    def __init__(self) -> None:
-        self.samples: List[float] = []
-
-    @contextlib.contextmanager
-    def round(self):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.samples.append(time.perf_counter() - t0)
-
-    @staticmethod
-    def _percentile(xs: List[float], q: float) -> float:
-        """Nearest-rank percentile (the smallest sample with at least q of
-        the distribution at or below it): xs sorted, 0 < q <= 1."""
-        return xs[math.ceil(q * len(xs)) - 1]
-
-    def summary(self) -> Dict[str, float]:
-        if not self.samples:
-            return {"count": 0}
-        xs = sorted(self.samples)
-        n = len(xs)
-        return {
-            "count": n,
-            "mean_ms": sum(xs) / n * 1e3,
-            "p50_ms": self._percentile(xs, 0.50) * 1e3,
-            "p90_ms": self._percentile(xs, 0.90) * 1e3,
-            "p99_ms": self._percentile(xs, 0.99) * 1e3,
-            "max_ms": xs[-1] * 1e3,
-        }

@@ -66,8 +66,9 @@ def make_round_fn(cfg, compiled, ccompiled):
         active = (rst.op_ptr < compiled.n_ops) & (r >= start)
         want_prop = active & (rst.stage == 0)
         prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
+        offered = append + want_prop.astype(jnp.int32)
         st2, hl2, prop = sim_mod.step(
-            cfg, st, crashed, append + want_prop.astype(jnp.int32),
+            cfg, st, crashed, offered,
             health=hl, link=link, reconfig_propose=want_prop,
         )
         got = want_prop & (prop.owner > 0)
@@ -111,7 +112,8 @@ def make_round_fn(cfg, compiled, ccompiled):
             learner_mask=lm3, recent_active=ra3,
         )
         stats = chaos.update_chaos_stats(
-            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS]
+            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
+            offered=offered > 0, dropped=prop.dropped,
         )
         rstats = rstats + jnp.stack([
             jnp.sum(got, dtype=jnp.int32),
