@@ -144,17 +144,21 @@ here is missing from it or untested under tests/.
                                break as health_summary; host-argsort
                                parity in tests/test_forensics.py
 
-TPU notes: P is tiny (<= 8 typical) and static, so the "sort" in
-committed_index is a fixed-width masked sort along the last axis that XLA
-lowers to a compare-exchange network on the VPU — no MXU involvement, no
-dynamic shapes, fully fusable with the surrounding elementwise ops.  All
-dtypes are int32/bool (indices < 2^31 in practice; the scalar oracle checks
-overflow), so no x64 dependency.
+TPU notes: P is tiny (<= 8 typical) and static, so the quorum position is
+taken from P static slices of the peer axis, a compare-exchange network over
+them (_sort_rows_desc) and P static selects (_quorum_of_rows) — elementwise
+int32 work with G on the lanes, no MXU, no dynamic shapes.  It used to be a
+`jnp.sort` along the last axis plus a `take_along_axis`, on the assumption
+that XLA lowers that to such a network: on the v5e it does not.  The gather
+ran at ~70 M elements/s and, eight calls a round, was 58 of a general
+round's 98 ms at 100k x 5 (PERF.md §6, PR 26 and PR 27).  All dtypes are
+int32/bool (indices < 2^31 in practice; the scalar oracle checks overflow),
+so no x64 dependency.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -174,6 +178,45 @@ def majority_of(count: jnp.ndarray) -> jnp.ndarray:  # gc: int32[...]
     return count // 2 + 1
 
 
+def _sort_rows_desc(rows: Sequence[jnp.ndarray]) -> List[jnp.ndarray]:
+    """Descending odd-even transposition sorting network over P same-shaped
+    rows: the TPU-friendly replacement for a variadic sort along the peer
+    axis (SURVEY.md §7 kernel k2).  P is static, so the network unrolls."""
+    n = len(rows)
+    rows = list(rows)
+    for pass_ in range(n):
+        for i in range(pass_ % 2, n - 1, 2):
+            hi = jnp.maximum(rows[i], rows[i + 1])
+            lo = jnp.minimum(rows[i], rows[i + 1])
+            rows[i], rows[i + 1] = hi, lo
+    return rows
+
+
+def _quorum_of_rows(
+    rows: Sequence[jnp.ndarray],  # P rows of int32[...]
+    masks: Sequence[jnp.ndarray],  # P rows of bool[...]
+) -> jnp.ndarray:
+    """The majority()-th largest of P per-peer rows among the voters the P
+    masks name; INF where no peer is a voter.  The one body under both
+    committed_index ([..., P] operands) and sim._quorum_index ([P, G] planes).
+
+    Padding argument: non-voters are masked to 0.  Since matched >= 0, the
+    k-th largest over (voters ∪ zero-padding) equals the k-th largest over
+    voters alone for k <= |voters| — zeros can only displace other zeros.
+    """
+    srt = _sort_rows_desc(
+        [jnp.where(m, r, 0) for r, m in zip(rows, masks)]
+    )
+    count = masks[0].astype(jnp.int32)
+    for m in masks[1:]:
+        count = count + m.astype(jnp.int32)
+    qpos = count // 2  # majority_of(count) - 1, counted from the largest
+    out = jnp.zeros_like(srt[0])
+    for p, row in enumerate(srt):
+        out = jnp.where(qpos == p, row, out)
+    return jnp.where(count == 0, INF, out)
+
+
 @profiling.scope("quorum_commit")
 def committed_index(
     matched: jnp.ndarray,  # gc: int32[..., P]
@@ -188,19 +231,15 @@ def committed_index(
     for an empty config (so joint min() ignores it), exactly the reference's
     empty-config convention (majority.rs:71-75).
 
-    Padding argument: non-voters are masked to 0.  Since matched >= 0, the
-    k-th largest over (voters ∪ zero-padding) equals the k-th largest over
-    voters alone for k <= |voters| — zeros can only displace other zeros.
+    The peer axis is read with P static slices, so a caller's
+    swapaxes(x[P_owner, P, G], 1, 2) folds back to x[:, p, :] and G stays
+    on the lanes: no sort, no gather, no operand whose minor dimension is P.
     """
-    masked = jnp.where(voter_mask, matched, 0)
-    srt = jnp.sort(masked, axis=-1)  # ascending
-    count = jnp.sum(voter_mask, axis=-1).astype(jnp.int32)
-    q = majority_of(count)
-    p = matched.shape[-1]
-    # k-th largest = srt[P - q] (ascending sort), guarded for empty configs.
-    idx = jnp.clip(p - q, 0, p - 1)
-    quorum_idx = jnp.take_along_axis(srt, idx[..., None], axis=-1)[..., 0]
-    return jnp.where(count == 0, INF, quorum_idx)
+    P = matched.shape[-1]
+    return _quorum_of_rows(
+        [matched[..., p] for p in range(P)],
+        [voter_mask[..., p] for p in range(P)],
+    )
 
 
 def committed_index_grouped(

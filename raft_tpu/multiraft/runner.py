@@ -129,6 +129,26 @@ def rebuild_scheds(compiled, chaos_compiled, sched_args):
 # entry points; the wrappers there delegate here) ----------------------------
 
 
+def _tail_audit(
+    stf: sim_mod.SimState, rstf: reconfig_mod.ReconfigState
+) -> jnp.ndarray:
+    """The one extra safety fold after a runner's last round: the scan body
+    checks each apply's mask transition one round later, so a final-round
+    apply needs this (prev_commit = final commit keeps the commit checks
+    inert).  The scan runners fold it inside their jit; the split runners,
+    whose blocks are separate dispatches, call it through a jit of their
+    own — eagerly its quorum networks alone are ~110 programs a call."""
+    return kernels.check_safety(
+        stf.state, stf.term, stf.commit, stf.last_index, stf.agree,
+        stf.commit,
+        voter_mask=stf.voter_mask,
+        outgoing_mask=stf.outgoing_mask,
+        matched=stf.matched,
+        prev_voter_mask=rstf.prev_voter,
+        prev_outgoing_mask=rstf.prev_outgoing,
+    )
+
+
 def _make_chaos(cfg: sim_mod.SimConfig, compiled: chaos_mod.CompiledChaos):
     """The chaos-only whole-scenario runner (chaos.make_runner's
     contract): its own lean scan body — no op protocol, no read carry —
@@ -266,15 +286,7 @@ def _make_reconfig(
             )
             bb = bb._replace(meta=meta, trip_round=trip)
             return stf, hlf, rstf, stats, rstats, safety, bb
-        safety = safety + kernels.check_safety(
-            stf.state, stf.term, stf.commit, stf.last_index, stf.agree,
-            stf.commit,
-            voter_mask=stf.voter_mask,
-            outgoing_mask=stf.outgoing_mask,
-            matched=stf.matched,
-            prev_voter_mask=rstf.prev_voter,
-            prev_outgoing_mask=rstf.prev_outgoing,
-        )
+        safety = safety + _tail_audit(stf, rstf)
         return stf, hlf, rstf, stats, rstats, safety
 
     jitted = jax.jit(
@@ -431,6 +443,7 @@ def _make_reconfig_split(
 
     donate = (0, 1, 2) + ((6,) if with_counters else ())
     fused_jit = jax.jit(fused_block_run, donate_argnums=donate)
+    tail_audit_jit = jax.jit(_tail_audit)
     general_jits: Dict[int, Callable] = {}
     for seg in segments:
         if not seg.fused and seg.rounds not in general_jits:
@@ -466,19 +479,7 @@ def _make_reconfig_split(
         stf, hlf, rstf, stats, rstats, safety = carry[:6]
         ctrs_f = carry[6] if with_counters else None
         fused = carry[n_carry]
-        # Tail audit — the same one extra fold the unsplit runner does:
-        # the scan body checks each apply's mask transition one round
-        # later, so a final-round apply needs this (prev_commit = final
-        # commit keeps the commit checks inert).
-        safety = safety + kernels.check_safety(
-            stf.state, stf.term, stf.commit, stf.last_index, stf.agree,
-            stf.commit,
-            voter_mask=stf.voter_mask,
-            outgoing_mask=stf.outgoing_mask,
-            matched=stf.matched,
-            prev_voter_mask=rstf.prev_voter,
-            prev_outgoing_mask=rstf.prev_outgoing,
-        )
+        safety = safety + tail_audit_jit(stf, rstf)
         out = (stf, hlf, rstf, stats, rstats, safety, fused)
         if with_counters:
             out = out + (ctrs_f,)
@@ -567,15 +568,7 @@ def _make_workload(
                 stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
                 lat_hist, bb,
             )
-        safety = safety + kernels.check_safety(
-            stf.state, stf.term, stf.commit, stf.last_index, stf.agree,
-            stf.commit,
-            voter_mask=stf.voter_mask,
-            outgoing_mask=stf.outgoing_mask,
-            matched=stf.matched,
-            prev_voter_mask=rstf.prev_voter,
-            prev_outgoing_mask=rstf.prev_outgoing,
-        )
+        safety = safety + _tail_audit(stf, rstf)
         return (
             stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
             lat_hist,
@@ -742,6 +735,7 @@ def _make_workload_split(
 
     donate = (0, 1, 2, 6)
     fused_jit = jax.jit(block_run, donate_argnums=donate)
+    tail_audit_jit = jax.jit(_tail_audit)
     tail_jit = jax.jit(tail_run, donate_argnums=donate) if tail else None
     sched_args = schedule_args(client, reconfig_sched)
 
@@ -768,17 +762,9 @@ def _make_workload_split(
             stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
             lat_hist, fused,
         ) = carry
-        # The unsplit runner's tail audit (a final-round apply transition
-        # — inert here with the no-op schedule, kept for bit-parity).
-        safety = safety + kernels.check_safety(
-            stf.state, stf.term, stf.commit, stf.last_index, stf.agree,
-            stf.commit,
-            voter_mask=stf.voter_mask,
-            outgoing_mask=stf.outgoing_mask,
-            matched=stf.matched,
-            prev_voter_mask=rstf.prev_voter,
-            prev_outgoing_mask=rstf.prev_outgoing,
-        )
+        # Inert here with the no-op schedule, kept for bit-parity with the
+        # unsplit runner.
+        safety = safety + tail_audit_jit(stf, rstf)
         return (
             stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
             lat_hist, fused,

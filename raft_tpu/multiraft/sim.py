@@ -105,7 +105,7 @@ tests/test_sim_fuzz.py for the schedules that originally exposed them.
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Optional, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -624,35 +624,16 @@ def unpack_ra_carry(
     )
 
 
-def _sort_rows_desc(rows: List[jnp.ndarray]) -> List[jnp.ndarray]:
-    """Descending odd-even transposition sorting network over P rows of [G]
-    vectors: the TPU-friendly replacement for a variadic sort along the peer
-    axis (SURVEY.md §7 kernel k2)."""
-    n = len(rows)
-    rows = list(rows)
-    for pass_ in range(n):
-        for i in range(pass_ % 2, n - 1, 2):
-            hi = jnp.maximum(rows[i], rows[i + 1])
-            lo = jnp.minimum(rows[i], rows[i + 1])
-            rows[i], rows[i + 1] = hi, lo
-    return rows
-
-
 @profiling.scope("quorum_commit")
 def _quorum_index(matched: jnp.ndarray, voter_mask: jnp.ndarray) -> jnp.ndarray:
     """Per-group majority commit index over the peer axis of [P, G] planes
     (the scalar oracle: quorum.MajorityConfig.committed_index, reference:
-    majority.rs:70-124).  Returns int32[G]."""
+    majority.rs:70-124): kernels._quorum_of_rows over the P rows, the body of
+    kernels.committed_index too.  Returns int32[G]."""
     P = matched.shape[0]
-    rows = _sort_rows_desc(
-        [jnp.where(voter_mask[p], matched[p], 0) for p in range(P)]
+    return kernels._quorum_of_rows(
+        [matched[p] for p in range(P)], [voter_mask[p] for p in range(P)]
     )
-    count = jnp.sum(voter_mask, axis=0).astype(jnp.int32)  # [G]
-    qpos = count // 2  # q - 1 = count//2+1-1
-    out = jnp.zeros_like(rows[0])
-    for p in range(P):
-        out = jnp.where(qpos == p, rows[p], out)
-    return jnp.where(count == 0, kernels.INF, out)
 
 
 def _transfer_phase(

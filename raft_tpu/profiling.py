@@ -87,9 +87,9 @@ SCOPES: Dict[str, str] = {
         "appends are counted here"
     ),
     "quorum_commit": (
-        "the quorum position of the acked indexes: kernels.committed_index, "
-        "sim._quorum_index, pallas_step._quorum_tile (one thing, written "
-        "three times)"
+        "the quorum position of the acked indexes: kernels.committed_index "
+        "and sim._quorum_index (one network, kernels._quorum_of_rows) and "
+        "pallas_step._quorum_tile (its twin on VMEM tiles)"
     ),
     "op_gather": (
         "reconfig._gather_peer / _gather_op: the op protocol's per-group "

@@ -6,9 +6,10 @@ import random
 
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from raft_tpu.quorum import AckIndexer, Index, JointConfig, MajorityConfig, U64_MAX, VoteResult
-from raft_tpu.multiraft import kernels
+from raft_tpu.multiraft import kernels, sim
 from raft_tpu.util import deterministic_timeout
 
 
@@ -25,14 +26,15 @@ def make_case(rng):
 
 
 def scalar_committed(mask, matched, groups=None, use_gc=False):
-    voters = [i + 1 for i in range(P) if mask[i]]
+    """quorum.MajorityConfig.committed_index over one peer row (any width)."""
+    voters = [i + 1 for i in range(len(mask)) if mask[i]]
     l = AckIndexer(
         {
             i + 1: Index(
                 index=int(matched[i]),
                 group_id=int(groups[i]) if groups is not None else 0,
             )
-            for i in range(P)
+            for i in range(len(mask))
         }
     )
     idx, flag = MajorityConfig(voters).committed_index(use_gc, l)
@@ -58,6 +60,69 @@ def test_committed_index_empty_config_is_inf():
         jnp.zeros((1, P), jnp.int32), jnp.zeros((1, P), bool)
     )
     assert int(got[0]) == 2**31 - 1
+
+
+def quorum_rows(kind, width, rng, n):
+    """n (mask[width], matched[width]) rows of one kind of config."""
+    rows = []
+    for _ in range(n):
+        matched = np.array(
+            [rng.randint(0, 100) for _ in range(width)], dtype=np.int32
+        )
+        mask = np.zeros(width, dtype=bool)
+        if kind == "random":
+            mask[rng.sample(range(width), rng.randint(0, width))] = True
+        elif kind == "single_voter":
+            mask[rng.randrange(width)] = True
+        elif kind == "all_equal":
+            mask[rng.sample(range(width), rng.randint(1, width))] = True
+            matched[:] = rng.randint(0, 100)
+        else:
+            assert kind == "empty"
+        rows.append((mask, matched))
+    return rows
+
+
+@pytest.mark.parametrize("kind", ["random", "empty", "single_voter", "all_equal"])
+@pytest.mark.parametrize("layout", ["rows", "owner_planes"])
+@pytest.mark.parametrize("width", [1, 3, 5, 7])
+def test_committed_index_is_the_oracle_in_every_layout(width, layout, kind):
+    """kernels.committed_index against the scalar oracle on [N, P] rows and
+    on the callers' swapaxes([P_owner, P, G], 1, 2) planes, and equal to
+    sim._quorum_index on each owner's [P, G] plane."""
+    rng = random.Random(f"{width}-{layout}-{kind}")
+    n_groups = 24
+    n = width * n_groups if layout == "owner_planes" else 120
+    rows = quorum_rows(kind, width, rng, n)
+    mask = np.stack([r[0] for r in rows])
+    matched = np.stack([r[1] for r in rows])
+    inf = int(kernels.INF)  # the kernel's spelling of the oracle's U64_MAX
+    want = np.array(
+        [min(scalar_committed(m, a)[0], inf) for m, a in rows], dtype=np.int32
+    )
+    if kind == "empty":
+        assert (want == inf).all()
+    if layout == "rows":
+        got = kernels.committed_index(jnp.asarray(matched), jnp.asarray(mask))
+        np.testing.assert_array_equal(np.asarray(got), want)
+        return
+    # [P_owner, P_target, G] as the damped round and the audits hold it.
+    matched3 = jnp.asarray(
+        matched.reshape(width, n_groups, width).transpose(0, 2, 1)
+    )
+    mask3 = jnp.asarray(mask.reshape(width, n_groups, width).transpose(0, 2, 1))
+    got = kernels.committed_index(
+        jnp.swapaxes(matched3, 1, 2), jnp.swapaxes(mask3, 1, 2)
+    )
+    assert got.shape == (width, n_groups)
+    np.testing.assert_array_equal(
+        np.asarray(got), want.reshape(width, n_groups)
+    )
+    for owner in range(width):
+        np.testing.assert_array_equal(
+            np.asarray(sim._quorum_index(matched3[owner], mask3[owner])),
+            np.asarray(got[owner]),
+        )
 
 
 def test_joint_committed_index_parity():
@@ -446,3 +511,40 @@ def test_pack_u16_pairs_roundtrip_and_numpy_twin():
         assert np.array_equal(np.asarray(words), twin)
         back = kernels.unpack_u16_pairs(words, k)
         assert np.array_equal(np.asarray(back), vals)
+
+
+def test_committed_index_attribute_is_what_the_round_and_the_audit_call(monkeypatch):
+    """The benchmark's commit-quorum control (benchmark/tests/weaken.py)
+    swaps `kernels.committed_index` by name and `(matched[..., P],
+    voter_mask[..., P])` signature: the damped round's stage folds and the
+    safety audit must read that attribute with peer-last operands, and the
+    network they share with sim._quorum_index sits below it."""
+    import jax
+
+    from raft_tpu.multiraft import SimConfig
+
+    n_groups, width = 8, 3
+    shapes = []
+    real = kernels.committed_index
+
+    def spy(matched, voter_mask):
+        shapes.append((matched.shape, voter_mask.shape))
+        return real(matched, voter_mask)
+
+    monkeypatch.setattr(kernels, "committed_index", spy)
+    cfg = SimConfig(n_groups, width, check_quorum=True, pre_vote=True)
+    st = sim.init_state(cfg)
+    crashed = jnp.zeros((width, n_groups), bool)
+    append = jnp.ones((n_groups,), jnp.int32)
+    jax.make_jaxpr(lambda s: sim.step(cfg, s, crashed, append))(st)
+    owner_planes = ((width, n_groups, width),) * 2
+    assert shapes == [owner_planes] * 4, "two stage folds, both majorities"
+    shapes.clear()
+    jax.make_jaxpr(
+        lambda s: kernels.check_safety(
+            s.state, s.term, s.commit, s.last_index, s.agree, s.commit,
+            voter_mask=s.voter_mask, outgoing_mask=s.outgoing_mask,
+            matched=s.matched,
+        )
+    )(st)
+    assert shapes == [owner_planes] * 2

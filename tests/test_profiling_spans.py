@@ -278,6 +278,27 @@ def test_scope_is_in_the_lowered_program(lowered_text, scope):
     assert re.search(rf'["/]{re.escape(scope)}["/]', text), scope
 
 
+@pytest.mark.parametrize(
+    "banned", ["gather", "sort", "take_along_axis", "dynamic_slice", "transpose"]
+)
+def test_quorum_commit_is_static_slices_and_selects(lowered_text, banned):
+    """The quorum position is P static slices, a compare-exchange network
+    and P selects (kernels._quorum_of_rows): no op under `quorum_commit` in
+    any lowered program is a sort, a gather or a relayout.  The v5e ran the
+    sort + take_along_axis form at 58 of a general round's 98 ms (PERF.md
+    §6, PR 27); a CPU run would not notice it coming back.  A jitted
+    helper's ops carry only their own name, so the name stack to read is
+    the call's: `.../quorum_commit/jit(take_along_axis)`."""
+    tails = []
+    for text in lowered_text.values():
+        tails += re.findall(r'"[^"]*/quorum_commit/([^"]*)"', text)
+    assert any(t in ("max", "min") for t in tails), "the network is there"
+    assert re.search(r'"[^"]*/round\.damped/[^"]*/quorum_commit/max"',
+                     lowered_text["block"]), "and in the damped round"
+    hits = sorted({t for t in tails if banned in t})
+    assert not hits, hits
+
+
 @pytest.mark.parametrize("kernel,kw", [
     ("raft_steady", {}),
     ("raft_steady_chaos", {"with_chaos": True}),

@@ -279,3 +279,44 @@ def test_make_runner_rejects_empty():
     cfg = SimConfig(n_groups=4, n_peers=3, collect_health=True)
     with pytest.raises(ValueError):
         runner_mod.make_runner(cfg, ())
+
+
+# --- the split runners' tail audit is one program --------------------------
+
+
+@pytest.mark.parametrize("family", ["workload", "reconfig"])
+def test_split_runner_tail_audit_runs_under_a_jit(family, monkeypatch):
+    """Outside a jit kernels.check_safety is one XLA program per jnp op —
+    its two quorum networks alone about 110 — dispatched after the last
+    block of every call (PERF.md §6, PR 27).  The split runners call it
+    through runner._tail_audit under a jit: every operand check_safety
+    ever sees from a runner is a tracer."""
+    G = 8
+    cfg = SimConfig(n_groups=G, n_peers=3, collect_health=True)
+    seen = []
+    real = kernels.check_safety
+
+    def spy(state, *args, **kw):
+        seen.append(isinstance(state, jax.core.Tracer))
+        return real(state, *args, **kw)
+
+    monkeypatch.setattr(kernels, "check_safety", spy)
+    if family == "workload":
+        run = runner_mod.make_runner(
+            cfg, (workload.compile_plan(_client_plan(), G),), split=True, k=4
+        )
+        st = sim_mod.init_state(cfg)
+        args = (
+            st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st),
+            workload.init_read_carry(G),
+        )
+    else:
+        plan = _reconfig_plan()
+        run = runner_mod.make_runner(
+            cfg, (reconfig.compile_plan(plan, G),), split=True, k=4, window=4
+        )
+        st = sim_mod.init_state(cfg, *reconfig.initial_masks(plan, G))
+        args = (st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st))
+    out = run(*args)
+    assert int(jnp.sum(out[5])) == 0, "safety slots"
+    assert seen and all(seen), seen
