@@ -7,7 +7,10 @@ traced run only, `breakdown`.  `metrics` holds exactly the metrics that
 end-to-end metrics; `--trace 1`: its per-layer metrics), each as
 `{"value": number, "unit": unit}` with the unit `BENCHMARK.json` gives.
 `device` holds `platform`, `kind`, `count`, `memory_peak_bytes` and, traced,
-`window_s` and `busy_s` with 0 < busy_s <= window_s.
+`window_s` and `busy_s` with 0 < busy_s <= window_s.  A per-layer metric may
+be absent only where the run said it left it out (`run.read_metrics`: the
+program under test does not carry a name the metric's reader asks it for);
+`validate` is told which.
 
 `build` is the only place a value is cast for JSON, and it refuses what
 JSON cannot say (NaN, infinities): a metric that cannot be computed is an
@@ -122,8 +125,10 @@ def _is_number(x) -> bool:
     return isinstance(x, (int, float)) and not isinstance(x, bool) and math.isfinite(x)
 
 
-def validate(stdout: str, bench: dict, workload: str, traced: bool) -> List[str]:
-    """Every way the captured stdout breaks the contract; [] if none."""
+def validate(stdout: str, bench: dict, workload: str, traced: bool,
+             left_out=()) -> List[str]:
+    """Every way the captured stdout breaks the contract; [] if none.
+    `left_out`: per-layer metrics the run said it left out of the line."""
     problems: List[str] = []
     if not stdout.endswith("\n"):
         problems.append("stdout does not end in a newline")
@@ -162,7 +167,8 @@ def validate(stdout: str, bench: dict, workload: str, traced: bool) -> List[str]
         return problems + ["metrics is not an object"]
     for name, unit in want.items():
         if name not in got:
-            problems.append(f"metric {name!r} is listed for {workload} but absent")
+            if not (traced and name in left_out):
+                problems.append(f"metric {name!r} is listed for {workload} but absent")
             continue
         m = got[name]
         if not isinstance(m, dict) or set(m) != {"value", "unit"}:
@@ -220,14 +226,15 @@ def validate(stdout: str, bench: dict, workload: str, traced: bool) -> List[str]
 
 
 if __name__ == "__main__":
-    # python3 benchmark/line.py <workload> <0|1> < captured-stdout
+    # python3 benchmark/line.py <workload> <0|1> [metric left out ...] < captured-stdout
     import os
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json"), encoding="utf-8") as f:
         bench_json = json.load(f)
-    found = validate(sys.stdin.read(), bench_json, sys.argv[1], sys.argv[2] == "1")
+    found = validate(sys.stdin.read(), bench_json, sys.argv[1], sys.argv[2] == "1",
+                     left_out=sys.argv[3:])
     for p in found:
         print(f"INVALID: {p}")
     print("line ok" if not found else f"{len(found)} problem(s)")

@@ -1,13 +1,13 @@
 """What the PROGRAM wrote into the run's capture: its host spans with their
 counts, and its names on the device ops.
 
-`trace.py` reduces a capture to the facts the first seven metrics read; it
-keeps only the `bench.` spans and drops every stat, and `run.py` hands the
-readers a fixed `facts`.  The readers of what the program names itself
-(`reducers/scope_share.py`, `idle_in_span.py`, `modules_per_segment.py`,
-`span_counter.py`) therefore find the capture here: `load()` reads the newest
-`.xplane.pb` under `TRACE_DIR` (the directory `run.py` traces into; a test
-pins the two equal), once per process.
+This is the one read of a run's capture: `run.py` calls `read_xplane` on
+the `.xplane.pb` it traced and hands every reader `facts_of(capture)` — the
+reduction of `trace.py` (busy, self time per op, idle gaps) made from these
+events, plus the capture itself under `facts["capture"]`, which the readers
+of what the program names (`reducers/scope_share.py`, `idle_in_span.py`,
+`modules_per_segment.py`, `span_counter.py`) work on.  `load()` is for the
+command line at the foot of this file only.
 
 Where a name lands in the chip's trace (TPU v5 lite, jax 0.9.0, looked at by
 hand in PR 26 with `describe`):
@@ -295,16 +295,9 @@ def read_xplane(path: str) -> Capture:
     return Capture(spans, ops, modules)
 
 
-_LOADED: Dict[str, Capture] = {}
-
-
 def load(trace_dir: str = TRACE_DIR) -> Capture:
-    """The newest capture under `trace_dir`, read once per process."""
-    path = trace.newest_xplane(trace_dir)
-    if path not in _LOADED:
-        _LOADED.clear()
-        _LOADED[path] = read_xplane(path)
-    return _LOADED[path]
+    """The newest capture under `trace_dir` (by hand: the foot of this file)."""
+    return read_xplane(trace.newest_xplane(trace_dir))
 
 
 # --- what the reducers share ---------------------------------------------------
@@ -354,8 +347,9 @@ def path_events(cap: Capture, plane: str) -> List[trace.Event]:
 
 
 def facts_of(cap: Capture) -> dict:
-    """The `facts` `run.py` would hand a reader for this capture (its
-    trace part), plus the capture itself."""
+    """The trace part of the `facts` a reader gets: `trace.py`'s reduction
+    of this capture's events (idle gaps named after the program's spans,
+    which are among them) and the capture itself."""
     events = [trace.Event(o.plane, o.line, o.name, o.start_ns, o.dur_ns)
               for o in cap.ops]
     events += [trace.Event(trace.HOST_PLANE, "", s.name, s.start_ns, s.dur_ns)
@@ -459,8 +453,9 @@ def load_recorded(path: str) -> Capture:
 
 
 def metrics(cap: Capture, names: Optional[List[str]] = None) -> Dict[str, Optional[float]]:
-    """Every metric file whose reducer reads this loader, computed on `cap`
-    with the trace facts `run.py` would hand it (None: nothing to read)."""
+    """Every metric file whose reducer reads names of the program (it has a
+    `names` function), computed on `cap` with the trace facts `run.py` would
+    hand it (None: nothing to read)."""
     from benchmark import reducers
 
     facts = facts_of(cap)
@@ -469,15 +464,39 @@ def metrics(cap: Capture, names: Optional[List[str]] = None) -> Dict[str, Option
         with open(os.path.join(HERE, "metrics", fname), encoding="utf-8") as f:
             spec = json.load(f)
         name = fname[:-len(".json")]
-        if spec.get("loader") == "program_trace" and (not names or name in names):
-            out[name] = reducers.load(spec["reducer"]).read(facts, spec["args"])
+        reducer = reducers.load(spec["reducer"])
+        if hasattr(reducer, "names") and (not names or name in names):
+            out[name] = reducer.read(facts, spec["args"])
     return out
 
 
-def capture_of(facts: dict) -> Capture:
-    """The capture a reader works on: the one a test or `metrics` put into
-    `facts`, else the run's own."""
-    return facts.get("capture") or load()
+def op_label(name: str, path: str, known) -> str:
+    """What the breakdown calls a device op: where its name stack `path`
+    passes a scope or kernel the program named (`known`), that name, the
+    stack's last component, the instruction and its result type —
+    `op_gather/.../gather fusion.514 pred[500000]`; else `trace.short_name`,
+    the label of a program without names."""
+    parts = [c for c in path.rstrip(":").split("/") if c]
+    at = max((i for i, c in enumerate(parts) if c in known), default=None)
+    if at is None:
+        return trace.short_name(name)
+    tail = parts[at:]
+    where = tail[0] if len(tail) == 1 else tail[0] + ("/" if len(tail) == 2 else "/.../") + tail[-1]
+    head, _sep, rest = name.partition(" = ")
+    result = rest.split("{")[0].split(" ")[0][:24]
+    if result.startswith("("):  # a tuple: its first element stands for it
+        result = result.rstrip(",") + ",..)"
+    return " ".join(x for x in (where, head.lstrip("%")[:32], result) if x)
+
+
+def top_ops(facts: dict, known, n: int = 10) -> List[Tuple[str, float]]:
+    """The `n` device ops with most self time, each under its `op_label`
+    (an HLO instruction has one name stack, so the instruction text is the
+    key)."""
+    paths = {op.name: op.path for op in facts["capture"].ops}
+    rows = sorted(facts["trace"]["op_seconds"].items(), key=lambda kv: -kv[1][0])
+    return [(op_label(name, paths.get(name, ""), known), sec)
+            for name, (sec, _calls) in rows[:n]]
 
 
 if __name__ == "__main__":

@@ -3,11 +3,23 @@
 
     read(facts: dict, args: dict) -> float | None
 
-`facts` holds what a traced run gathered: `counters` (exact counts summed
-over the traced segments' reports), `trace` (trace.TraceFacts as a dict),
-`shape` (n_groups, n_peers) and `peaks` (this device's row of peaks.json).
-A reader that finds nothing to read returns None and the harness leaves the
-metric out of the line; it never invents a value.
+`facts` holds what a traced run gathered, from one read of its capture:
+`counters` (exact counts summed over the traced segments' reports), `trace`
+(trace.TraceFacts as a dict), `capture` (program_trace.Capture: the
+program's host spans with their stats, every `XLA Ops` / `XLA Modules` event
+with its name stack), `shape` (n_groups, n_peers) and `peaks` (this device's
+row of peaks.json).  A reader that finds nothing to read returns None; it
+never invents a value.
+
+A reader of what the PROGRAM names also has
+
+    names(args: dict) -> {"spans" | "scopes" | "kernels" | "counts": [name, ...]}
+
+— the names its `args` ask the program for.  `lacking` holds them against
+what the program under test carries (`run.program_names`): a None from a
+reader whose names the program lacks leaves the metric out of the line (an
+older or newer program is no fault of the run); a None although the program
+carries every name stops the run.
 """
 
 import importlib
@@ -17,6 +29,15 @@ from typing import Dict, List
 
 def load(name: str):
     return importlib.import_module(f"{__name__}.{name}")
+
+
+def lacking(reducer, args: dict, program: Dict[str, set]) -> List[str]:
+    """The names `reducer` asks the program for that `program` lacks."""
+    ask = getattr(reducer, "names", None)
+    if ask is None:
+        return []
+    return [f"{kind} {name!r}" for kind, names in ask(args).items()
+            for name in names if name not in program.get(kind, ())]
 
 
 def matching(op_seconds: Dict[str, List[float]], pattern: str):

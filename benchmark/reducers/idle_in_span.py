@@ -5,8 +5,12 @@ in ms: which part of the entry call the device waited for."""
 from .. import program_trace as pt
 
 
+def names(args):
+    return {"spans": [pt.RUN_SPAN, args["span"]]}
+
+
 def read(facts, args):
-    cap = pt.capture_of(facts)
+    cap = facts["capture"]
     calls = pt.spans_named(cap, pt.RUN_SPAN)
     spans = pt.spans_named(cap, args["span"])
     if not calls or not spans or not pt.planes(cap):

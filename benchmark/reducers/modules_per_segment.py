@@ -5,8 +5,12 @@ entry call dispatches."""
 from .. import program_trace as pt
 
 
+def names(args):
+    return {"spans": [pt.RUN_SPAN]}
+
+
 def read(facts, args):
-    cap = pt.capture_of(facts)
+    cap = facts["capture"]
     calls = pt.spans_named(cap, pt.RUN_SPAN)
     planes = pt.planes(cap)
     if not calls or not planes:

@@ -9,12 +9,16 @@ import functools
 from .. import program_trace as pt
 
 
+def names(args):
+    return {"kernels": [args["kernel"]]} if "kernel" in args else {"scopes": [args["scope"]]}
+
+
 def read(facts, args):
     if "kernel" in args:
         keep = functools.partial(pt.has_kernel, kernel=args["kernel"])
     else:
         keep = functools.partial(pt.has_scope, scope=args["scope"])
-    seconds, calls = pt.self_seconds_where(pt.capture_of(facts), keep)
+    seconds, calls = pt.self_seconds_where(facts["capture"], keep)
     if not calls:
         return None
     return 100.0 * seconds / facts["trace"]["busy_s"]

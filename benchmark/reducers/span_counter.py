@@ -11,9 +11,17 @@ import statistics
 from .. import program_trace as pt
 
 
+def _stats(args):
+    return [args["median"]] if "median" in args else [args["num"], *args["den"]]
+
+
+def names(args):
+    return {"spans": [args["span"]], "counts": _stats(args)}
+
+
 def read(facts, args):
-    spans = pt.spans_named(pt.capture_of(facts), args["span"])
-    wanted = [args["median"]] if "median" in args else [args["num"], *args["den"]]
+    spans = pt.spans_named(facts["capture"], args["span"])
+    wanted = _stats(args)
     if not spans or any(k not in s.stats for s in spans for k in wanted):
         return None
     if "median" in args:

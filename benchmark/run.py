@@ -23,12 +23,15 @@ import shutil
 import sys
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import check, line, reducers, trace, traffic  # noqa: E402
+from benchmark import check, line, program_trace, reducers, trace, traffic  # noqa: E402
+from benchmark.reference import membership  # noqa: E402
 
 TRACE_DIR = os.path.join(HERE, ".trace")  # fixed, inside the checkout
 TRACE_SECONDS = 2.0  # a traced window closes at the first segment end after this
@@ -61,12 +64,53 @@ def find_cell(bench: dict, workload: str) -> Tuple[dict, dict, dict]:
 
 
 def metric_readers(bench: dict, workload: str) -> Dict[str, Tuple[str, object, dict]]:
-    """{per-layer metric of this cell: (unit, reader function, args)}."""
+    """{per-layer metric of this cell: (unit, reducer module, args)}."""
     out = {}
     for name, unit in line.expected_metrics(bench, workload, traced=True).items():
         spec = load_json(HERE, "metrics", f"{name}.json")
-        out[name] = (unit, reducers.load(spec["reducer"]).read, spec.get("args", {}))
+        out[name] = (unit, reducers.load(spec["reducer"]), spec.get("args", {}))
     return out
+
+
+def program_names(report: dict) -> Dict[str, set]:
+    """What the program under test can be asked for by name: the spans,
+    scopes and kernels of `raft_tpu.profiling`'s catalogue (none, on a
+    program from before it had one) and the counts its report span is
+    closed with — `call`, `groups`, every integer of the report, the safety
+    slots as `safety.<slot>`."""
+    try:
+        from raft_tpu import profiling
+    except ImportError:
+        profiling = None
+    counts = {"call", "groups"} | {f"safety.{k}" for k in report.get("safety", {})} | {
+        k for k, v in report.items() if isinstance(v, int) and not isinstance(v, bool)}
+    return {
+        "spans": set(getattr(profiling, "SPANS", ())),
+        "scopes": set(getattr(profiling, "SCOPES", ())),
+        "kernels": set(getattr(profiling, "KERNELS", ())),
+        "counts": counts,
+    }
+
+
+def read_metrics(readers, facts: dict, program: Dict[str, set], workload: str, say):
+    """({metric: (value, unit)}, [metrics left out]).  A reader that finds
+    nothing: left out where the program lacks a name the reader asks it for,
+    an error where it carries them all."""
+    metrics, left_out = {}, []
+    for name, (unit, reducer, args) in readers.items():
+        value = reducer.read(facts, args)
+        if value is not None:
+            metrics[name] = (value, unit)
+            continue
+        lacks = reducers.lacking(reducer, args, program)
+        if not lacks:
+            raise BenchError(
+                f"per-layer metric {name!r} is listed for {workload} but its "
+                "reader found nothing to read in this run"
+            )
+        say(f"metric {name} left out: the program has no {', '.join(lacks)}")
+        left_out.append(name)
+    return metrics, left_out
 
 
 # --- the device --------------------------------------------------------------
@@ -128,10 +172,19 @@ class Fleet:
     schedules in the program's own types, and the one call the window
     drives."""
 
+    PLANES = {"voter": "voter_mask", "outgoing": "outgoing_mask", "learner": "learner_mask"}
+
     def __init__(self, config: dict, n_groups: int):
+        import jax.numpy as jnp
+
         from raft_tpu.multiraft import ClusterSim, SimConfig
 
         self.config = config
+        P = config["n_peers"]
+        self.voters, self.learners = membership.of_config(config)
+        self.home = [  # the [P, G] voter, outgoing-voter and learner planes it boots in
+            membership.masks(m, P, n_groups) for m in (self.voters, [], self.learners)
+        ]
         self.cfg = SimConfig(
             n_groups, config["n_peers"],
             election_tick=config["election_tick"],
@@ -141,9 +194,13 @@ class Fleet:
             lease_read=config["lease_read"],
             collect_health=config["collect_health"],
         )
-        self.sim = ClusterSim(self.cfg)
+        if len(self.voters) == P:
+            self.sim = ClusterSim(self.cfg)  # every slot a voter: the program's default
+        else:
+            self.sim = ClusterSim(self.cfg, *(jnp.asarray(m) for m in self.home))
         self.client = None
         self.chaos = None
+        self.reconfig = None
         self.seg: Optional[traffic.Segment] = None
 
     def boot(self) -> None:
@@ -154,7 +211,7 @@ class Fleet:
     def load(self, seg: traffic.Segment) -> None:
         import jax.numpy as jnp
 
-        from raft_tpu.multiraft import chaos, workload
+        from raft_tpu.multiraft import chaos, reconfig, workload
 
         self.seg = seg
         self.client = workload.CompiledClient(
@@ -165,23 +222,25 @@ class Fleet:
             n_peers=seg.n_peers,
         )
         self.chaos = chaos.plan_from_dict(seg.chaos) if seg.chaos else None
+        self.reconfig = reconfig.plan_from_dict(seg.reconfig) if seg.reconfig else None
 
     def segment(self) -> dict:
         """One replay of the segment; returns when its report is on the
         host (run_reads ends in the download)."""
         return self.sim.run_reads(
-            self.client, self.chaos, split=self.seg.split, split_k=self.seg.split_k
+            self.client, self.chaos, self.reconfig,
+            split=self.seg.split, split_k=self.seg.split_k,
         )
 
     def rows(self, gids) -> dict:
-        """Cursor rows [n, P] and the read in flight [n] of some groups."""
+        """Cursor and membership rows [n, P] and the read in flight [n] of
+        some groups."""
         import jax
-        import numpy as np
 
         st = self.sim.state
         idx = np.asarray(gids)
         got = jax.device_get(
-            [getattr(st, k)[:, idx] for k in check.ref.FIELDS]
+            [getattr(st, self.PLANES.get(k, k))[:, idx] for k in check.ref.FIELDS]
             + [self.sim._read_carry.pending_mode[idx]]
         )
         out = {k: v.T for k, v in zip(check.ref.FIELDS, got)}
@@ -258,7 +317,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
     fleet = Fleet(config, G)
     fleet.boot()  # the device boots while the host draws the traffic
     t1 = time.monotonic()
-    seg = traffic.generate(mix, G, config["n_peers"], seed, name=workload)
+    seg = traffic.generate(mix, G, config["n_peers"], seed, name=workload,
+                           voters=fleet.voters, learners=fleet.learners)
     t2 = time.monotonic()
     fleet.load(seg)
     jax.block_until_ready(fleet.sim.state)
@@ -286,7 +346,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
             "read_fires": seg.read_fires, "read_ops": seg.read_ops,
             "update_entries": seg.update_entries, "write_batches": seg.write_batches,
             "share_of_regions_touched_per_round": seg.touched_share,
-            "split": seg.split,
+            "split": seg.split, "conf_ops": seg.conf_ops,
         },
         "warmup_report": warm,
     }))
@@ -317,15 +377,16 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
     # --- correctness, outside the window and outside set-up ---
     t_check = time.monotonic()
     st = fleet.sim.state
-    commit_end, agree, voter, pending = jax.device_get(
-        (st.commit, st.agree, st.voter_mask, fleet.sim._read_carry.pending_mode)
+    commit_end, agree, voter, outgoing, learner, pending = jax.device_get(
+        (st.commit, st.agree, st.voter_mask, st.outgoing_mask, st.learner_mask,
+         fleet.sim._read_carry.pending_mode)
     )
     findings = [
         check.safety([warm] + reports),
         check.fires([warm] + reports, seg),
         check.reads(reports[-1], pending),
         check.monotonic(commit_start, commit_end),
-        check.durability(commit_end, agree, voter),
+        check.durability(commit_end, agree, voter, outgoing),
         check.reference(config, seg, gids, sample_rows),
     ]
     for f in findings:
@@ -337,17 +398,24 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
         say(f"warning: {compiled_inside} program(s) compiled inside the window")
 
     counters = summed(reports, G)
-    attempted = len(reports) * (seg.read_fires + seg.write_batches)
+    attempted = len(reports) * (seg.read_fires + seg.write_batches + seg.conf_ops)
+    # Groups that are not back in the configuration's membership: an op of
+    # the last segment that did not land.
+    astray = int(np.any(
+        [(got != home).any(axis=0) for got, home in zip((voter, outgoing, learner), fleet.home)],
+        axis=0).sum())
     outstanding = sum(
         max(0, r["reads_issued"] - r["served_lease"] - r["served_quorum"])
         for r in reports
     )
-    failed = int(counters["dropped_fires"]) + outstanding + rejected
+    failed = int(counters["dropped_fires"]) + outstanding + rejected + astray
     say(json.dumps({"window": {
         "seconds": window_s, "segments": len(reports), "counters": counters,
         "read_p99_rounds": [r["read_p99"] for r in reports],
         "mttr_rounds": [r["mttr_rounds"] for r in reports],
         "max_leaderless_streak": [r["max_leaderless_streak"] for r in reports],
+        "conf_ops_offered": len(reports) * seg.conf_ops,
+        "groups_not_back_in_the_configuration": astray,
     }}))
 
     if not traced:
@@ -357,16 +425,21 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
             metrics=metrics, device=device,
         )
 
-    facts_trace = trace.reduce_events(trace.load_xplane(trace.newest_xplane(TRACE_DIR)))
+    # The one read of the capture: the program's spans, stats and name stacks
+    # and the reduction to busy, self times and idle gaps come from it.
+    capture = program_trace.read_xplane(trace.newest_xplane(TRACE_DIR))
+    trace_facts = program_trace.facts_of(capture)  # a CPU has no device plane: TraceError
     peaks = load_json(HERE, "peaks.json")
     if device["kind"] not in peaks:
         raise BenchError(f"device kind {device['kind']!r} is not in peaks.json")
     facts = {
+        **trace_facts,
         "counters": counters,
-        "trace": facts_trace._asdict(),
         "shape": {"n_groups": G, "n_peers": config["n_peers"]},
         "peaks": peaks[device["kind"]],
     }
+    facts_trace = trace.TraceFacts(**facts["trace"])
+    program = program_names(reports[-1])
     say(json.dumps({"trace": {
         "host_window_s": window_s, "window_s": facts_trace.window_s,
         "busy_s": facts_trace.busy_s, "chips": facts_trace.n_chips,
@@ -376,22 +449,14 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
         "custom_calls": [[k[:400], v[0], v[1]] for k, v in facts_trace.op_seconds.items()
                          if "custom-call(" in k or "custom_call" in k][:8],
     }}))
-    metrics = {}
-    for name, (unit, read, args) in readers.items():
-        value = read(facts, args)
-        if value is None:
-            raise BenchError(
-                f"per-layer metric {name!r} is listed for {workload} but its "
-                "reader found nothing to read in this run"
-            )
-        metrics[name] = (value, unit)
+    metrics, _left_out = read_metrics(readers, facts, program, workload, say)
     device["window_s"] = facts_trace.window_s
     device["busy_s"] = facts_trace.busy_s
     return line.build(
         correct=rejected == 0, attempted=attempted, failed=failed,
         metrics=metrics, device=device,
         breakdown={
-            "device_ops": trace.top_ops(facts_trace),
+            "device_ops": program_trace.top_ops(facts, program["scopes"] | program["kernels"]),
             "idle_gaps": facts_trace.idle_gaps,
         },
     )

@@ -6,6 +6,13 @@ and the device's own linearizability audit (stale_read / dual_lease) must
 trip, on every seed; the sound program on the same seeds is correct.  On the
 chip at the cell's own size this is `control_on_chip.py`.
 
+The control of a membership that changes: the program that commits on the
+incoming voters alone while a configuration is joint
+(weaken.joint_commit_on_incoming_only) drives the test deployment's
+`churn-crash` mix — two outgoing-only voters down inside the joint window —
+and the joint-window audit (`commit_no_quorum`) must trip on every seed; the
+sound program on the same seeds is correct.
+
 The broken paths: a segment that returns its state unchanged, and an answer
 altered where it is produced.
 """
@@ -21,12 +28,12 @@ G = 64
 SEEDS = [5, 3_000_000_017, 2**31 + 11]
 
 
-def drive(bench, cell, seed, seconds=0.3):
+def drive(bench, cell, seed, seconds=0.3, n_groups=G):
     import jax
 
     lines = []
     text = run.run_cell(bench, cell, seed=seed, seconds=seconds, traced=False,
-                        say=lines.append, n_groups=G, devices=jax.devices())
+                        say=lines.append, n_groups=n_groups, devices=jax.devices())
     checks = {l.split()[1].rstrip(":"): l for l in lines if l.startswith("check ")}
     return json.loads(text), checks
 
@@ -43,6 +50,26 @@ def test_control_weakened_lease_gate_is_not_correct(bench, seed):
 @pytest.mark.parametrize("seed", SEEDS)
 def test_sound_program_is_correct_on_the_same_seeds(bench, seed):
     out, checks = drive(bench, "fleet-100k-r5.outage", seed)
+    assert out["correct"] is True, checks
+    assert all("FAILED" not in c for c in checks.values())
+
+
+CHURN_CRASH = "fleet-64-r3of5.churn-crash"
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_control_commit_on_the_incoming_voters_alone_is_not_correct(churn_bench, seed):
+    with weaken.joint_commit_on_incoming_only() as replaced:
+        out, checks = drive(churn_bench, CHURN_CRASH, seed, n_groups=None)
+    assert replaced[0] >= 1  # the weakening found the quorum position it replaces
+    assert out["correct"] is False
+    assert "FAILED" in checks["safety"] and "commit_no_quorum" in checks["safety"]
+    assert out["failed"] >= 1
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_sound_program_is_correct_under_churn_and_crashes(churn_bench, seed):
+    out, checks = drive(churn_bench, CHURN_CRASH, seed, n_groups=None)
     assert out["correct"] is True, checks
     assert all("FAILED" not in c for c in checks.values())
 

@@ -132,3 +132,20 @@ def test_validate_rejects_a_roofline_share_over_the_ceiling(bench):
     text = line.build(correct=True, attempted=1, failed=0, metrics=m, device=TRACED,
                       breakdown=BREAKDOWN)
     assert any("roofline" in p for p in line.validate(text + "\n", bench, cell, True))
+
+
+def test_a_metric_the_run_left_out_may_be_absent_and_no_other(bench):
+    """`run.read_metrics` leaves a per-layer metric out where the program
+    lacks a name its reader asks for; the line validates when told so, and
+    only for that metric, and only in a traced line."""
+    metrics = metrics_for(bench, True)
+    del metrics["op_gather_share"]
+    text = line.build(correct=True, attempted=10, failed=1, metrics=metrics,
+                      device=TRACED, breakdown=BREAKDOWN) + "\n"
+    assert line.validate(text, bench, CELL, True, left_out=["op_gather_share"]) == []
+    assert any("op_gather_share" in p for p in line.validate(text, bench, CELL, True))
+    assert line.validate(text, bench, CELL, True, left_out=["quorum_commit_share"])
+    e2e = metrics_for(bench, False)
+    del e2e["recover_ms"]
+    text = line.build(correct=True, attempted=10, failed=1, metrics=e2e, device=DEVICE) + "\n"
+    assert line.validate(text, bench, CELL, False, left_out=["recover_ms"])

@@ -1,5 +1,6 @@
-"""The loader of what the program itself writes into a capture, and the four
-reducers that read it: on a small recording from the chip WITH stats and
+"""The one reader of a run's capture — what the program itself writes into
+it, merged with `trace.py`'s reduction into the `facts` every reducer gets —
+and the four reducers that read the program's names: on a small recording from the chip WITH stats and
 name stacks (tests/data/program_trace.json: TPU v5 lite, a 2048 x 3 fleet,
 two `run_reads(split=True)` calls of two fused blocks and a general tail of
 one round, PR 26), and on a capture made here on the CPU for the wire
@@ -17,11 +18,13 @@ from benchmark import reducers, run, trace
 HERE = os.path.dirname(os.path.abspath(__file__))
 RECORDED = os.path.join(HERE, "data", "program_trace.json")
 METRICS = os.path.join(os.path.dirname(HERE), "metrics")
-NEW = sorted(
-    os.path.basename(p)[:-len(".json")]
+SPECS = {
+    os.path.basename(p)[:-len(".json")]: json.load(open(p, encoding="utf-8"))
     for p in glob.glob(os.path.join(METRICS, "*.json"))
-    if json.load(open(p, encoding="utf-8")).get("loader") == "program_trace"
-)
+}
+NEW = sorted(n for n, s in SPECS.items() if hasattr(reducers.load(s["reducer"]), "names"))
+KNOWN = {"runner.block_guard", "runner.fused_arm", "runner.general_arm", "round.damped",
+         "quorum_commit", "op_gather", "raft_steady_damped"}
 
 
 def spec_of(name):
@@ -49,13 +52,18 @@ def test_the_loader_looks_where_run_py_traces():
     assert pt.TRACE_DIR == run.TRACE_DIR
 
 
-def test_the_ten_metric_files_are_there():
+def test_the_metric_files_that_read_the_programs_names_are_there():
     assert NEW == sorted([
         "append_drop_share", "block_guard_share", "damped_kernel_share",
         "idle_dispatch_ms", "idle_prepare_ms", "idle_report_ms",
-        "leaderless_rounds_share", "programs_per_segment",
+        "leaderless_rounds_share", "op_gather_share", "programs_per_segment",
         "quorum_commit_share", "recover_p99_rounds",
     ])
+    assert not any("loader" in s for s in SPECS.values())  # one way only: facts
+
+
+def test_every_metric_file_is_listed_in_benchmark_json(bench):
+    assert {m["name"] for m in bench["per_layer"]} == set(SPECS)
 
 
 def test_metric_files_spell_names_from_the_programs_catalogue():
@@ -215,7 +223,6 @@ def test_read_xplane_agrees_with_profiledata(tmp_path):
     assert got.spans[1].stats == {"call": 7, "rounds": 24, "groups": 64}
     assert got.spans[2].stats == {"neg": -3}
     assert got.ops == [] and got.modules == []
-    assert pt.load(str(tmp_path)) is pt.load(str(tmp_path))  # read once
     for name in NEW:  # no device plane here: nothing to read
         if spec_of(name)["source"] != "program_counter":
             assert read(name, {"trace": {"busy_s": 1.0}, "capture": got}) is None
@@ -228,3 +235,149 @@ def test_export_round_trips(cap, tmp_path):
     assert again.spans == cap.spans
     assert len(again.ops) == len([o for o in cap.ops if o.end_ns >= pt.window(cap)[0]])
     assert pt.metrics(again).keys() == set(NEW)
+
+
+# --- one read, merged facts; the None rule; the named breakdown ----------------
+
+
+def test_facts_hold_the_reduction_and_the_capture_from_one_read(cap, facts):
+    assert set(facts) == {"trace", "capture"} and facts["capture"] is cap
+    t = trace.TraceFacts(**facts["trace"])
+    assert 0.0 < t.busy_s <= t.window_s and t.n_chips == 1
+    # The op table is keyed by instruction text, as trace.py keys it, and the
+    # capture gives each instruction its name stack.
+    assert set(t.op_seconds) <= {o.name for o in cap.ops}
+    assert sum(v[0] for v in t.op_seconds.values()) == pytest.approx(t.busy_s, rel=1e-6)
+
+
+def test_idle_gaps_are_named_after_the_programs_spans(cap, facts):
+    labels = dict(facts["trace"]["idle_gaps"])
+    spans = {k.rsplit(".", 1)[0] for k in labels}
+    assert "raft.run_reads.dispatch" in spans
+    assert spans <= {"raft.run_reads", "raft.run_reads.prepare", "raft.run_reads.dispatch",
+                     "raft.runner.blocks", "raft.run_reads.report", "raft.run_reads.download",
+                     "segment.head", "segment.mid", "segment.tail", "between_segments"}
+    # Naming cuts gaps up; it neither makes nor loses idle time.
+    bare = [trace.Event(trace.HOST_PLANE, "", s.name, s.start_ns, s.dur_ns)
+            for s in cap.spans if s.name == trace.SEGMENT_SPAN]
+    bare += [trace.Event(o.plane, o.line, o.name, o.start_ns, o.dur_ns) for o in cap.ops]
+    old = dict(trace.reduce_events(bare, top_gaps=100).idle_gaps)
+    new = dict(trace.reduce_events(
+        bare + [trace.Event(trace.HOST_PLANE, "", s.name, s.start_ns, s.dur_ns)
+                for s in cap.spans if s.name.startswith(pt.PROGRAM_PREFIX)],
+        top_gaps=100).idle_gaps)
+    assert {k.split(".")[0] for k in old} <= {"segment", "between_segments"}
+    total = lambda d: sum(v for k, v in d.items() if k.endswith(".total"))
+    assert total(new) == pytest.approx(total(old), rel=1e-9)
+
+
+def test_the_breakdown_names_an_op_by_its_scope_path(facts):
+    rows = pt.top_ops(facts, KNOWN)
+    assert len(rows) == 10 and all(sec > 0 for _l, sec in rows)
+    labels = [l for l, _s in rows]
+    assert "op_gather/.../gather fusion.27 pred[6144]" in labels
+    assert sum(l.startswith("quorum_commit/.../gather fusion.") for l in labels) == 8
+    assert all(len(l) <= 120 and "%" not in l for l in labels)
+    # A program without names falls back to the old labels.
+    assert [l for l, _s in pt.top_ops(facts, set())] == [
+        trace.short_name(n) for n, _v in sorted(
+            facts["trace"]["op_seconds"].items(), key=lambda kv: -kv[1][0])[:10]]
+
+
+def test_op_label_forms():
+    text = "%fusion.514 = pred[500000]{0:T(1024)S(1)} fusion(pred[5,100000]{1,0} %p), kind=kLoop"
+    path = ("jit(scan_run)/while/body/runner.general_arm/op_gather/"
+            "jit(take_along_axis)/gather:")
+    assert pt.op_label(text, path, KNOWN) == "op_gather/.../gather fusion.514 pred[500000]"
+    assert pt.op_label(text, "jit(f)/op_gather/gather:", KNOWN) == "op_gather/gather fusion.514 pred[500000]"
+    assert pt.op_label(text, "jit(f)/while/body/add:", KNOWN) == "fusion.514 fusion"
+    assert pt.op_label(text, "", KNOWN) == "fusion.514 fusion"
+    kernel = ('%raft_steady_damped.1 = (s32[5,100000]{1,0:T(8,128)}, s32[5,100000]{1,0}) '
+              'custom-call(s32[5,100000]{1,0} %p), custom_call_target="tpu_custom_call"')
+    assert pt.op_label(kernel, "jit(b)/cond/runner.fused_arm/raft_steady_damped/pallas_call:",
+                       KNOWN) == "raft_steady_damped/pallas_call raft_steady_damped.1 (s32[5,100000],..)"
+
+
+PROGRAM = {"spans": {"raft.run_reads", "raft.run_reads.report", "raft.run_reads.dispatch"},
+           "scopes": {"quorum_commit", "op_gather"}, "kernels": {"raft_steady_damped"},
+           "counts": {"appends_offered", "appends_dropped"}}
+
+
+def readers_of(*names):
+    return {n: (SPECS[n]["unit"], reducers.load(SPECS[n]["reducer"]), SPECS[n]["args"])
+            for n in names}
+
+
+# A share of a scope no op of the recording carries.
+NO_SUCH = {"later_scope_share": ("%", reducers.load("scope_share"), {"scope": "later.scope"})}
+
+
+def test_a_metric_whose_name_the_program_lacks_is_left_out(facts):
+    """No op of the recording carries the scope `later.scope`, and the
+    program's catalogue has no such scope: the metric is left out, said so,
+    and the others are read."""
+    said = []
+    got, left_out = run.read_metrics(
+        {**NO_SUCH, **readers_of("damped_kernel_share")}, facts, PROGRAM, "a.cell", said.append)
+    assert left_out == ["later_scope_share"] and list(got) == ["damped_kernel_share"]
+    assert said == ["metric later_scope_share left out: the program has no scopes 'later.scope'"]
+
+
+def test_a_metric_whose_name_the_program_has_and_the_trace_lacks_stops_the_run(facts):
+    newer = {**PROGRAM, "scopes": PROGRAM["scopes"] | {"later.scope"}}
+    with pytest.raises(run.BenchError, match="later_scope_share.*found nothing to read"):
+        run.read_metrics(NO_SUCH, facts, newer, "a.cell", print)
+    # A reader that asks the program for no name stops the run whatever the program is.
+    bare = {**facts, "trace": {**facts["trace"], "op_seconds": {}}}
+    with pytest.raises(run.BenchError, match="fused_kernel_share"):
+        run.read_metrics(readers_of("fused_kernel_share"), bare, {}, "a.cell", print)
+
+
+def test_a_count_the_report_lacks_is_a_name_the_program_lacks(facts):
+    program = {**PROGRAM, "counts": {"appends_dropped"}}
+    cap = facts["capture"]
+    older = pt.Capture(
+        [s._replace(stats={k: v for k, v in s.stats.items() if k != "appends_offered"})
+         for s in cap.spans], cap.ops, cap.modules)
+    got, left_out = run.read_metrics(
+        readers_of("append_drop_share"), {**facts, "capture": older}, program, "a.cell",
+        lambda _t: None)
+    assert got == {} and left_out == ["append_drop_share"]
+
+
+def test_program_names_are_the_catalogue_and_the_reports_counts():
+    from raft_tpu import profiling
+
+    names = run.program_names({"rounds": 24, "read_p99": 0, "mttr_rounds": 21.5,
+                               "safety": {"dual_leader": 0}, "ok": True})
+    assert names["spans"] == set(profiling.SPANS) and "op_gather" in names["scopes"]
+    assert names["kernels"] == set(profiling.KERNELS)
+    assert names["counts"] == {"call", "groups", "rounds", "read_p99", "safety.dual_leader"}
+    for spec in SPECS.values():  # today's program carries every name the files ask for
+        reducer = reducers.load(spec["reducer"])
+        program = {**names, "counts": names["counts"] | set(
+            getattr(reducer, "names", lambda a: {})(spec["args"]).get("counts", []))}
+        assert reducers.lacking(reducer, spec["args"], program) == []
+
+
+def test_a_run_that_stops_over_a_listed_metric_exits_2_and_prints_no_line():
+    """What `read_metrics` raises reaches the command as exit code 2 and an
+    empty stdout, the reason on stderr."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from benchmark import run\n"
+        "def stop(*a, **k):\n"
+        "    run.read_metrics({'m': ('%', run.reducers.load('op_share'), {'pattern': 'x'})},\n"
+        "                     {'trace': {'op_seconds': {}, 'busy_s': 1.0}}, {}, 'a.cell', print)\n"
+        "run.run_cell = stop\n"
+        "sys.exit(run.main(['--workload', 'fleet-100k-r5.serve', '--seed', '1',\n"
+        "                   '--seconds', '1', '--trace', '1']))\n"
+    )
+    root = os.path.dirname(os.path.dirname(HERE))
+    done = subprocess.run([sys.executable, "-c", code], cwd=root, capture_output=True,
+                          text=True, timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 2 and done.stdout == ""
+    assert "BenchError" in done.stderr and "found nothing to read" in done.stderr

@@ -153,3 +153,33 @@ def test_recorded_kernel_is_found_by_the_metric_files_pattern_and_nests_in_its_c
                      "peaks": {"hbm_bytes_per_s": 819e9}},
                     {"pattern": pattern, "operands": {"pg": 15, "ppg": 2, "g": 5}})
     assert 0 < pct < 100
+
+
+def test_recorded_gaps_keep_their_old_labels_on_a_program_without_spans(recorded):
+    """chip_trace.json is PR 25's program: no `raft.` span in the capture,
+    so every gap is named by its place in the segment, as before."""
+    facts = trace.reduce_events(recorded)
+    assert facts.idle_gaps
+    assert {label.split(".")[0] for label, _s in facts.idle_gaps} <= {"segment", "between_segments"}
+
+
+def test_a_gap_is_cut_at_the_edges_of_the_programs_spans():
+    events = [
+        span(0, 100),
+        span(2, 96, name="raft.run_reads"),
+        span(2, 8, name="raft.run_reads.prepare"),
+        span(10, 60, name="raft.run_reads.dispatch"),
+        span(20, 30, name="raft.runner.blocks"),
+        span(70, 28, name="raft.run_reads.report"),
+        op("a fusion", 15, 10),   # idle 0-15: 2 outside, 8 in prepare, 5 in dispatch
+        op("b fusion", 40, 45),   # idle 25-40 in blocks; 85-100: 13 report, 2 outside
+    ]
+    labels = dict(trace.reduce_events(events, top_gaps=100).idle_gaps)
+    ms = lambda key: labels[key] * 1e3
+    assert ms("segment.head.total") == pytest.approx(2.0)
+    assert ms("raft.run_reads.prepare.total") == pytest.approx(8.0)
+    assert ms("raft.run_reads.dispatch.total") == pytest.approx(5.0)
+    assert ms("raft.runner.blocks.total") == pytest.approx(15.0)  # the innermost span
+    assert ms("raft.run_reads.report.total") == pytest.approx(13.0)
+    assert ms("segment.tail.total") == pytest.approx(2.0)
+    assert sum(v for k, v in labels.items() if k.endswith(".total")) == pytest.approx(0.045)

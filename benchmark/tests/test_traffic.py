@@ -1,6 +1,9 @@
 """The traffic generator: seeded, in the program's packed layout, and the
 mix it says it is."""
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -89,3 +92,101 @@ def test_sample_rows_match_the_unpacked_schedule():
     assert np.array_equal(fire, mask[:, gids])
     assert np.array_equal(append, seg.append[seg.phase_of_round][:, gids])
     assert mode.shape == fire.shape
+
+
+# --- a mix whose schedule changes memberships ---------------------------------
+
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+MOVE = [  # store 1's replica moves to store 4 ...
+    {"rounds": 8},
+    {"rounds": 8, "op": {"add_learner": 4}, "groups": {"mod": 4, "eq": 1}},
+    {"rounds": 8, "op": {"enter_joint": [{"add": 4}, {"remove": 1}]}, "groups": {"mod": 4, "eq": 1}},
+    {"rounds": 40, "op": {"leave_joint": True}, "groups": {"mod": 4, "eq": 1}, "append": 2},
+]
+BACK = [  # ... and back
+    {"rounds": 8, "op": {"add_learner": 1}, "groups": {"mod": 4, "eq": 1}},
+    {"rounds": 8, "op": {"enter_joint": [{"add": 1}, {"remove": 4}]}, "groups": {"mod": 4, "eq": 1}},
+    {"rounds": 40, "op": {"leave_joint": True}, "groups": {"mod": 4, "eq": 1}},
+]
+
+
+def churn_mix(phases, **more):
+    with open(os.path.join(DATA, "churn.json"), encoding="utf-8") as f:
+        mix = json.load(f)
+    return {**mix, "reconfig": {"phases": phases}, **more}
+
+
+def test_the_reconfig_key_passes_through_in_the_programs_grammar():
+    from raft_tpu.multiraft import reconfig
+
+    G = 256
+    seg = traffic.generate(churn_mix(MOVE + BACK), G, 5, 7, name="a.cell", voters=[1, 2, 3])
+    assert seg.n_rounds == 120 and seg.chaos is None and not seg.split
+    assert seg.reconfig == {"name": "a.cell", "peers": 5, "voters": [1, 2, 3], "learners": [],
+                            "phases": MOVE + BACK}
+    assert seg.conf_ops == 6 * (G // 4)
+    plan = reconfig.plan_from_dict(seg.reconfig)  # the program's grammar takes it
+    assert plan.n_rounds == seg.n_rounds and plan.voters == [1, 2, 3]
+    compiled = reconfig.compile_plan(plan, G)
+    assert int(compiled.n_ops.sum()) == seg.conf_ops
+    # The reconfig phase's load goes to every region, on top of the client's.
+    fire, mode, append = traffic.sample_rows(seg, np.array([0, 1]))
+    client = seg.append[:, [0, 1]][seg.phase_of_round]
+    assert np.array_equal(append[24:64] - client[24:64], np.full((40, 2), 2))
+    assert np.array_equal(append[:24], client[:24]) and np.array_equal(append[64:], client[64:])
+    plain = traffic.generate(churn_mix(MOVE + BACK, reconfig=None, segment_rounds=120), G, 5, 7)
+    assert seg.update_entries == plain.update_entries + 40 * 2 * G
+    assert seg.write_batches > plain.write_batches and plain.conf_ops == 0
+    assert np.array_equal(seg.read_fire_packed, plain.read_fire_packed)  # same draws
+
+
+def test_a_mix_without_the_key_means_what_it_meant():
+    seg = gen("outage", G=256)
+    assert seg.reconfig is None and seg.conf_ops == 0
+
+
+def test_chaos_and_reconfig_schedules_must_agree_in_length():
+    chaos = {"then": [{"rounds": 60}, {"rounds": 59, "crash": [1]}]}
+    with pytest.raises(ValueError, match="differ in length.*119.*120|differ in length.*120.*119"):
+        traffic.generate(churn_mix(MOVE + BACK, chaos=chaos), 64, 5, 7, voters=[1, 2, 3])
+    chaos["then"][1]["rounds"] = 60
+    seg = traffic.generate(churn_mix(MOVE + BACK, chaos=chaos), 64, 5, 7, voters=[1, 2, 3])
+    assert seg.n_rounds == 120 and seg.chaos["phases"][1]["crash"] == [1]
+
+
+def test_a_schedule_that_does_not_end_where_it_began_is_refused():
+    with pytest.raises(ValueError) as e:
+        traffic.generate(churn_mix(MOVE), 64, 5, 7, name="a.cell", voters=[1, 2, 3])
+    text = str(e.value)
+    assert "16 groups" in text and "phases [1, 2, 3]" in text and "group 1)" in text
+    assert "end at voters [2, 3, 4]" in text and "not at the configuration's voters [1, 2, 3]" in text
+    # ... and one that ends inside a joint window, and one the Changer refuses.
+    with pytest.raises(ValueError, match=r"outgoing \[2, 3, 4\]"):
+        traffic.generate(churn_mix(MOVE + BACK[:2]), 64, 5, 7, voters=[1, 2, 3])
+    with pytest.raises(ValueError, match="phase 1.*refused"):
+        traffic.generate(churn_mix([{"rounds": 8}, {"rounds": 8, "op": {"leave_joint": True}}]),
+                         64, 5, 7, voters=[1, 2, 3])
+    # The same schedule from another starting membership: store 4 holds a voter already.
+    with pytest.raises(ValueError):
+        traffic.generate(churn_mix(MOVE + BACK), 64, 5, 7, voters=[2, 3, 4])
+
+
+def test_classes_of_groups_are_walked_apart():
+    other = [dict(ph, groups={"mod": 4, "eq": 3}) if "op" in ph else ph for ph in MOVE]
+    with pytest.raises(ValueError, match=r"phases \[9, 10, 11\].*group 3\)"):
+        traffic.generate(churn_mix(MOVE + BACK + [{"rounds": 8}] + other), 64, 5, 7,
+                         voters=[1, 2, 3])
+
+
+def test_at_least_half_of_the_sample_are_groups_the_schedule_changes():
+    seg = traffic.generate(churn_mix(MOVE + BACK), 4096, 5, 7, voters=[1, 2, 3])
+    for seed in range(8):
+        gids = check.pick_sample(seg, seed, 6)
+        assert len(set(gids.tolist())) == 6
+        assert (gids % 4 == 1).sum() >= 3
+    chains = check.conf_chains(seg, np.array([1, 2]))
+    steps, starts = chains[0]
+    assert starts == [8, 16, 24, 64, 72, 80] and chains[1] == ([], [])
+    assert [sorted(s.voters) for s in steps] == [[1, 2, 3], [2, 3, 4], [2, 3, 4],
+                                                 [2, 3, 4], [1, 2, 3], [1, 2, 3]]
+    assert [sorted(s.outgoing) for s in steps][1::3] == [[1, 2, 3], [2, 3, 4]]

@@ -8,6 +8,13 @@ current recent-active quorum (it saves reading a [P, P, G] plane every
 round).  raft-rs's LeaseBased reads are only safe under check-quorum; with
 the gate gone a deposed leader that has not yet heard of its successor still
 holds a "lease" and reads stop being linearizable.
+
+`joint_commit_on_incoming_only` is the control of a membership that changes:
+the nearest weaker guarantee there — commit on the majority of the incoming
+voters alone while a configuration is joint (it saves the second quorum
+position every round).  raft-rs commits at the MIN of both halves
+(quorum/joint.rs); with the outgoing half gone, a write is acknowledged that
+a majority of the old voters never held.
 """
 
 import contextlib
@@ -37,6 +44,45 @@ def lease_without_quorum_gate():
         yield
     finally:
         kernels.lease_read = real
+
+
+@contextlib.contextmanager
+def joint_commit_on_incoming_only():
+    """Every round's commit looks at the incoming voters alone: the
+    outgoing half of `min(_quorum_index(row, st.voter_mask),
+    _quorum_index(row, st.outgoing_mask))` reads "no voters" (INF, as an
+    empty configuration does).  The outgoing plane is known by identity: it
+    is the `outgoing_mask` of the state `sim.step` was called with.  Yields
+    a list that holds the number of quorum positions replaced so far — 0
+    after a run means the program no longer computes them this way and the
+    control controls nothing."""
+    import jax.numpy as jnp
+
+    from raft_tpu.multiraft import kernels
+    from raft_tpu.multiraft import sim
+
+    real_step, real_quorum = sim.step, sim._quorum_index
+    outgoing = []  # the outgoing planes of the steps being traced, innermost last
+    replaced = [0]
+
+    def step(cfg, st, *args, **kw):
+        outgoing.append(st.outgoing_mask)
+        try:
+            return real_step(cfg, st, *args, **kw)
+        finally:
+            outgoing.pop()
+
+    def quorum(matched, voter_mask):
+        if outgoing and voter_mask is outgoing[-1]:
+            replaced[0] += 1
+            return jnp.full(matched.shape[1:], kernels.INF, jnp.int32)
+        return real_quorum(matched, voter_mask)
+
+    sim.step, sim._quorum_index = step, quorum
+    try:
+        yield replaced
+    finally:
+        sim.step, sim._quorum_index = real_step, real_quorum
 
 
 @contextlib.contextmanager

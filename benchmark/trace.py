@@ -25,6 +25,11 @@ Rules, each there because the simple way is wrong:
   it on the same line, so that containers do not swallow their bodies.
 - no device plane, no op line, or no op event inside the window is an
   error.  A CPU run has no device plane: its traced path fails here.
+- an idle gap is named after the innermost host span of the PROGRAM that
+  covers it (`raft.run_reads.dispatch`; a gap that straddles spans is cut at
+  their edges); where the events hold no such span — an older program, or a
+  loader that keeps only the `bench.` spans — by its place in the segment
+  (`segment.head` / `.mid` / `.tail`), as before the program drew spans.
 """
 
 from __future__ import annotations
@@ -163,6 +168,22 @@ def self_seconds(events: Sequence[Event], lo: float, hi: float) -> Dict[str, Lis
     return out
 
 
+def name_gap(s: float, e: float, spans: Sequence[Event], fallback: str):
+    """[(label, ns)] of the gap [s, e): cut at the edges of the host spans
+    `spans`, each piece named after the innermost span that covers it
+    (the one that started last), or `fallback` where none does."""
+    over = [sp for sp in spans if sp.start_ns < e and sp.end_ns > s]
+    if not over:
+        return [(fallback, e - s)]
+    edges = sorted({s, e} | {t for sp in over for t in (sp.start_ns, sp.end_ns) if s < t < e})
+    out = []
+    for lo, hi in zip(edges, edges[1:]):
+        inside = [sp for sp in over if sp.start_ns <= lo and sp.end_ns >= hi]
+        label = max(inside, key=lambda sp: (sp.start_ns, -sp.dur_ns)).name if inside else fallback
+        out.append((label, hi - lo))
+    return out
+
+
 class TraceFacts(NamedTuple):
     window_s: float
     busy_s: float  # union of op intervals in the window, mean over chips
@@ -180,6 +201,8 @@ def reduce_events(events: Sequence[Event], top_gaps: int = 10) -> TraceFacts:
     if not spans:
         raise TraceError(f"no {SEGMENT_SPAN!r} host span in the trace")
     lo, hi = spans[0].start_ns, max(e.end_ns for e in spans)
+    program_spans = [e for e in events
+                     if e.plane == HOST_PLANE and not e.name.startswith(SPAN_PREFIX)]
 
     planes = sorted({e.plane for e in events if DEVICE_PLANE.match(e.plane)})
     if not planes:
@@ -216,7 +239,8 @@ def reduce_events(events: Sequence[Event], top_gaps: int = 10) -> TraceFacts:
                         where = "tail"  # report download and host work after the last
                     else:
                         where = "mid"
-                    gap_list.append((f"segment.{where}", (e - s) / 1e9))
+                    for label, ns in name_gap(s, e, program_spans, f"segment.{where}"):
+                        gap_list.append((label, ns / 1e9))
                 if i + 1 < len(spans) and spans[i + 1].start_ns > sp.end_ns:
                     gap_list.append(("between_segments", (spans[i + 1].start_ns - sp.end_ns) / 1e9))
     n = len(planes)
@@ -251,11 +275,6 @@ def short_name(name: str) -> str:
         return head[:64]
     m = _OPCODE.search(rest)
     return f"{head} {m.group(1)}"[:64] if m else head[:64]
-
-
-def top_ops(facts: TraceFacts, n: int = 10) -> List[Tuple[str, float]]:
-    rows = sorted(facts.op_seconds.items(), key=lambda kv: -kv[1][0])
-    return [(short_name(name), sec) for name, (sec, _calls) in rows[:n]]
 
 
 def export_events(xplane: str, out_json: str, per_line: int = 400) -> None:

@@ -22,6 +22,19 @@ A mix is data only (see README.md "Adding a traffic mix").  Its keys:
                           grammar ("rounds", "crash", "partition"), the first
                           list repeated for peer s = 1..P with "@peer"
                           standing for s, the second appended once
+  reconfig                optional {"phases": [phase, ...]}: a membership-
+                          change schedule in the program's plan grammar
+                          ("rounds", "op", "groups", "append"; the ops are
+                          listed in reference/membership.py), its name,
+                          peers, voters and learners filled in from the cell
+                          and its configuration.  The segment is as long as
+                          its phases (and as the chaos phases, where the mix
+                          has both).  A mix is one segment replayed with
+                          state carried over, so every class of groups has to
+                          end, outside any joint window, at the
+                          configuration's voters and learners: `generate`
+                          walks each class through the reference's Changer
+                          and refuses a schedule that does not
 
 Operations of one kind on one region in one round coalesce: reads into one
 read fire, updates into one append of n entries.  Reads are drawn as the
@@ -33,13 +46,17 @@ per phase, applied in each round of the phase.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from .reference import membership
+
 HERE = os.path.dirname(os.path.abspath(__file__))
+MIX_DIR = os.path.join(HERE, "traffic")
 MODE_CODES = {"safe": 1, "lease": 2}  # sim.READ_SAFE / sim.READ_LEASE
 
 
@@ -61,6 +78,8 @@ class Segment(NamedTuple):
     update_entries: int  # entries offered over the segment
     write_batches: int  # (group, round) pairs that offer an append
     touched_share: float  # mean share of regions with any operation in a round
+    reconfig: Optional[dict] = None  # a reconfig plan document, or None
+    conf_ops: int = 0  # (op, group) pairs the schedule enqueues in one segment
 
     @property
     def n_rounds(self) -> int:
@@ -68,7 +87,7 @@ class Segment(NamedTuple):
 
 
 def load_mix(name: str) -> dict:
-    path = os.path.join(HERE, "traffic", f"{name}.json")
+    path = os.path.join(MIX_DIR, f"{name}.json")
     with open(path, encoding="utf-8") as f:
         return json.load(f)
 
@@ -107,9 +126,16 @@ def region_weights(dist: dict, n_groups: int, rng) -> np.ndarray:
 
 
 def segment_rounds(mix: dict, n_peers: int) -> int:
-    doc = chaos_document(mix, n_peers, "")
-    if doc is not None:
-        return sum(int(ph["rounds"]) for ph in doc["phases"])
+    lengths = {
+        key: sum(int(ph["rounds"]) for ph in doc["phases"])
+        for key, doc in (("chaos", chaos_document(mix, n_peers, "")),
+                         ("reconfig", mix.get("reconfig")))
+        if doc
+    }
+    if len(set(lengths.values())) > 1:
+        raise ValueError(f"the mix's schedules differ in length: {lengths} rounds")
+    if lengths:
+        return next(iter(lengths.values()))
     return int(mix["segment_rounds"])
 
 
@@ -137,10 +163,57 @@ def chaos_document(mix: dict, n_peers: int, name: str) -> Optional[dict]:
     return {"name": name, "peers": n_peers, "phases": phases}
 
 
+def reconfig_document(mix: dict, n_groups: int, n_peers: int, name: str,
+                      voters, learners) -> Tuple[Optional[dict], int]:
+    """(the mix's membership-change schedule as a plan document of the
+    program's grammar, the (op, group) pairs it enqueues), or (None, 0).
+    Refuses a schedule some class of groups does not end, outside a joint
+    window, at `voters` / `learners`."""
+    spec = mix.get("reconfig")
+    if not spec:
+        return None, 0
+    phases = [dict(ph) for ph in spec["phases"]]
+    home = (frozenset(voters), frozenset(), frozenset(learners), frozenset())
+    conf_ops = 0
+    for chain, groups in membership.classes(phases, n_groups).items():
+        last = membership.walk(phases, chain, n_peers, voters, learners)[-1]
+        if (last.voters, last.outgoing, last.learners, last.learners_next) != home:
+            raise ValueError(
+                f"reconfig schedule of {name!r}: the {int(groups.sum())} groups that "
+                f"follow the ops of phases {list(chain)} (first: group "
+                f"{int(np.flatnonzero(groups)[0])}) end at voters {sorted(last.voters)} "
+                f"outgoing {sorted(last.outgoing)} learners {sorted(last.learners)} "
+                f"learners_next {sorted(last.learners_next)}, not at the configuration's "
+                f"voters {sorted(voters)} learners {sorted(learners)}: a mix is one "
+                "segment replayed, so it has to end where it began"
+            )
+        conf_ops += len(chain) * int(groups.sum())
+    if not conf_ops:
+        raise ValueError(f"reconfig schedule of {name!r} has no op")
+    doc = {"name": name, "peers": n_peers, "voters": sorted(voters),
+           "learners": sorted(learners), "phases": phases}
+    return doc, conf_ops
+
+
+def conf_append_by_round(seg_reconfig: Optional[dict], n_rounds: int) -> np.ndarray:
+    """int32[R]: the entries a reconfig phase offers every group each round."""
+    out = np.zeros(n_rounds, np.int32)
+    r = 0
+    for ph in (seg_reconfig or {}).get("phases", []):
+        out[r:r + int(ph["rounds"])] = int(ph.get("append", 0))
+        r += int(ph["rounds"])
+    return out
+
+
 def generate(mix: dict, n_groups: int, n_peers: int, seed: int,
-             name: str = "mix") -> Segment:
+             name: str = "mix", voters=None, learners=()) -> Segment:
+    """`voters` / `learners`: the configuration's membership (1-based peer
+    slots; default every slot a voter), which a `reconfig` schedule starts
+    from and has to return to."""
     G = n_groups
     R = segment_rounds(mix, n_peers)
+    voters = list(range(1, n_peers + 1)) if voters is None else list(voters)
+    reconfig, conf_ops = reconfig_document(mix, G, n_peers, name, voters, list(learners))
     pr = int(mix["phase_rounds"])
     nph = -(-R // pr)
     phase_of_round = (np.arange(R) // pr).astype(np.int32)
@@ -184,11 +257,15 @@ def generate(mix: dict, n_groups: int, n_peers: int, seed: int,
                 hit = np.searchsorted(cdf, rng.random(m), side="right")
                 append[i] = np.bincount(hit, minlength=G)[:G]
         q_upd = -np.expm1(n_updates * np.log1p(-p)) if n_updates < G else np.ones(G)
-        rounds_in_phase = np.bincount(phase_of_round, minlength=nph)
-        entries = int((append.sum(axis=1) * rounds_in_phase).sum())
-        batches = int(((append > 0).sum(axis=1) * rounds_in_phase).sum())
+    # Rounds by (client phase, entries the reconfig phase adds for every group).
+    extra = conf_append_by_round(reconfig, R)
+    for (i, x), n in collections.Counter(zip(phase_of_round.tolist(), extra.tolist())).items():
+        entries += n * (int(append[i].sum()) + x * G)
+        batches += n * (G if x else int((append[i] > 0).sum()))
 
     touched = float(np.mean(1.0 - (1.0 - q_read) * (1.0 - q_upd)))
+    loaded = float(np.mean(extra > 0))  # rounds whose reconfig phase loads every region
+    touched = loaded + (1.0 - loaded) * touched
     return Segment(
         phase_of_round=phase_of_round,
         read_fire_packed=fire_packed,
@@ -204,6 +281,8 @@ def generate(mix: dict, n_groups: int, n_peers: int, seed: int,
         update_entries=entries,
         write_batches=batches,
         touched_share=touched,
+        reconfig=reconfig,
+        conf_ops=conf_ops,
     )
 
 
@@ -214,4 +293,5 @@ def sample_rows(seg: Segment, gids: np.ndarray):
     w, b = gids // 32, gids % 32
     fire = ((seg.read_fire_packed[:, w] >> b.astype(np.uint32)) & 1).astype(bool)
     ph = seg.phase_of_round
-    return fire, seg.read_mode[:, gids][ph], seg.append[:, gids][ph]
+    append = seg.append[:, gids][ph] + conf_append_by_round(seg.reconfig, seg.n_rounds)[:, None]
+    return fire, seg.read_mode[:, gids][ph], append
