@@ -377,16 +377,26 @@ def _compile_schedule(plan: ReconfigPlan, n_groups: int):
         raise ValueError("plan has no reconfig ops (use a ChaosPlan for "
                          "pure fault scenarios)")
     # Per-group op signature -> Changer chain (validated once per
-    # distinct sequence, shared across the groups that follow it).
-    sig_of_group: List[Tuple[int, ...]] = []
-    for g in range(G):
-        sig_of_group.append(
-            tuple(i for i in op_phases if gsel_by_phase[i][g])
-        )
-    chains: Dict[Tuple[int, ...], List[_OpSlot]] = {}
-    for sig in set(sig_of_group):
-        chains[sig] = _walk_chain(plan, sig)
-    K = max(1, max(len(s) for s in sig_of_group))
+    # distinct sequence, shared across the groups that follow it).  The
+    # signatures are the distinct columns of the ops' [n_op_phases, G]
+    # selection plane — numbered by refining the classes one op at a
+    # time, so the ids stay below G however many ops there are — and
+    # every plane below is filled per signature: a plan has a handful of
+    # them whatever G is.
+    sel = np.stack([gsel_by_phase[i] for i in op_phases])
+    sig_idx = np.zeros(G, dtype=np.int64)
+    for row in sel:
+        _, sig_idx = np.unique(2 * sig_idx + row, return_inverse=True)
+    firsts = np.unique(sig_idx, return_index=True)[1]
+    sigs: List[Tuple[int, ...]] = [
+        tuple(i for i, on in zip(op_phases, sel[:, g]) if on)
+        for g in firsts
+    ]
+    sig_of_group = [sigs[i] for i in sig_idx.tolist()]
+    chains: Dict[Tuple[int, ...], List[_OpSlot]] = {
+        sig: _walk_chain(plan, sig) for sig in sigs
+    }
+    K = max(1, max(len(s) for s in sigs))
     op_start = np.full((K, G), NO_ROUND, dtype=np.int32)
     n_ops = np.zeros(G, dtype=np.int32)
     tgt_voter = np.zeros((K, P, G), dtype=bool)
@@ -394,21 +404,25 @@ def _compile_schedule(plan: ReconfigPlan, n_groups: int):
     tgt_learner = np.zeros((K, P, G), dtype=bool)
     added = np.zeros((K, P, G), dtype=bool)
     removed = np.zeros((K, P, G), dtype=bool)
-    for g in range(G):
-        sig = sig_of_group[g]
-        n_ops[g] = len(sig)
+    pids = np.arange(1, P + 1)
+
+    def column(members: frozenset) -> np.ndarray:
+        """bool[P, 1]: the peers of one set, to broadcast over a mask."""
+        return np.isin(pids, list(members))[:, None]
+
+    for i, sig in enumerate(sigs):
+        mask = sig_idx == i
+        n_ops[mask] = len(sig)
         for k, slot in enumerate(chains[sig]):
-            op_start[k, g] = phase_start[slot.phase]
-            for p in range(P):
-                pid = p + 1
-                tgt_voter[k, p, g] = pid in slot.voters_inc
-                tgt_outgoing[k, p, g] = pid in slot.voters_out
-                # learners_next stay outgoing voters until leave-joint
-                # materializes them (tracker.rs:50-83) — the device
-                # learner plane carries only the ACTIVE learners.
-                tgt_learner[k, p, g] = pid in slot.learners
-                added[k, p, g] = pid in slot.added
-                removed[k, p, g] = pid in slot.removed
+            op_start[k, mask] = phase_start[slot.phase]
+            tgt_voter[k][:, mask] = column(slot.voters_inc)
+            tgt_outgoing[k][:, mask] = column(slot.voters_out)
+            # learners_next stay outgoing voters until leave-joint
+            # materializes them (tracker.rs:50-83) — the device
+            # learner plane carries only the ACTIVE learners.
+            tgt_learner[k][:, mask] = column(slot.learners)
+            added[k][:, mask] = column(slot.added)
+            removed[k][:, mask] = column(slot.removed)
     return (
         phase_of_round, append, op_start, n_ops,
         tgt_voter, tgt_outgoing, tgt_learner, added, removed,
@@ -559,6 +573,33 @@ def init_reconfig_state(st: sim_mod.SimState) -> ReconfigState:
     )
 
 
+def resume_state(
+    rst: ReconfigState,
+    n_ops: jnp.ndarray,  # gc: int32[G]
+) -> ReconfigState:
+    """The carry a call starts from, given the carry the last call of the
+    SAME schedule ended with (or a fresh one: for it this is the
+    identity).  A replayed schedule is a cycle: a group whose chain is
+    complete (every op applied, nothing in flight) starts again at op 0;
+    a group with an op in flight or ops left keeps its pointer and its
+    pending entry, and finishes its chain in order — late, by `op_start`,
+    never from op 0.  Everything else goes on as if the two calls were
+    one scan: the transition-audit anchors are the last round's step
+    masks, so the first round audits the last call's final apply once
+    more (its tail audit already did)."""
+    done = (rst.op_ptr >= n_ops) & (rst.stage == 0)
+    return rst._replace(op_ptr=jnp.where(done, 0, rst.op_ptr))
+
+
+@jax.jit
+def unfinished_groups(
+    op_ptr: jnp.ndarray,  # gc: int32[G]
+    n_ops: jnp.ndarray,  # gc: int32[G]
+) -> jnp.ndarray:
+    """int32[]: groups with ops of their chain still to apply."""
+    return jnp.sum(op_ptr < n_ops, dtype=jnp.int32)
+
+
 # Reconfig stats accumulator indices ([N_RECONFIG_STATS] int32; each slot
 # grows by at most G per round, and compile_plan bounds rounds x G < 2**31
 # — the GC008 no-wrap argument).
@@ -572,6 +613,15 @@ RECONFIG_STAT_NAMES = (
     "proposals",
     "ops_applied",
     "retries",
+    "joint_group_rounds",
+)
+
+# The same four under the names ClusterSim.run_reads reports them by
+# (workload.read_report), beside `conf_unfinished`.
+READ_REPORT_CONF_NAMES = (
+    "conf_proposals",
+    "conf_applied",
+    "conf_retries",
     "joint_group_rounds",
 )
 
@@ -902,9 +952,10 @@ def _runner_body(
             lease_holder = None
             lease_fire = None
         # Op eligibility: the next unapplied op, once its phase starts.
-        start = _gather_op(sched.op_start, rst.op_ptr)
-        active = (rst.op_ptr < sched.n_ops) & (r >= start)
-        want_prop = active & (rst.stage == 0)
+        with profiling.scope("reconfig.gate"):
+            start = _gather_op(sched.op_start, rst.op_ptr)
+            active = (rst.op_ptr < sched.n_ops) & (r >= start)
+            want_prop = active & (rst.stage == 0)
         prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
         offered = append + want_prop.astype(jnp.int32)
         step_out = sim_mod.step(
@@ -924,29 +975,31 @@ def _runner_body(
         else:
             st2, hl2, prop = step_out
             ctrs2 = None
-        # Record where the conf entry landed (owner 0 = no alive leader
-        # this round; the op stays at stage 0 and retries).
-        got = want_prop & (prop.owner > 0)
-        stage = jnp.where(got, 1, rst.stage)
-        powner = jnp.where(got, prop.owner, rst.prop_owner)
-        pindex = jnp.where(got, prop.index, rst.prop_index)
-        pterm = jnp.where(got, prop.term, rst.prop_term)
-        # The dual-majority commit gate, off the post-round planes: the
-        # owner still leads at its propose term (its log cannot have been
-        # overwritten — a leader only appends) and is not crashed (a
-        # frozen isolated owner can never advance), and its commit
-        # covers the entry.  Commit advancement itself already required
-        # BOTH majorities of the joint config (joint.rs min-of-halves in
-        # every step path), so `commit >= index` IS the dual-quorum gate.
-        own_lead = (
-            (_gather_peer(st2.state, powner) == kernels.ROLE_LEADER)
-            & (_gather_peer(st2.term, powner) == pterm)
-            & ~_gather_peer(crashed, powner)
-        )
-        committed = _gather_peer(st2.commit, powner) >= pindex
-        apply_mask = (stage == 1) & own_lead & committed
-        retry = (stage == 1) & ~own_lead
-        stage = jnp.where(apply_mask | retry, 0, stage)
+        with profiling.scope("reconfig.gate"):
+            # Record where the conf entry landed (owner 0 = no alive
+            # leader this round; the op stays at stage 0 and retries).
+            got = want_prop & (prop.owner > 0)
+            stage = jnp.where(got, 1, rst.stage)
+            powner = jnp.where(got, prop.owner, rst.prop_owner)
+            pindex = jnp.where(got, prop.index, rst.prop_index)
+            pterm = jnp.where(got, prop.term, rst.prop_term)
+            # The dual-majority commit gate, off the post-round planes:
+            # the owner still leads at its propose term (its log cannot
+            # have been overwritten — a leader only appends) and is not
+            # crashed (a frozen isolated owner can never advance), and its
+            # commit covers the entry.  Commit advancement itself already
+            # required BOTH majorities of the joint config (joint.rs
+            # min-of-halves in every step path), so `commit >= index` IS
+            # the dual-quorum gate.
+            own_lead = (
+                (_gather_peer(st2.state, powner) == kernels.ROLE_LEADER)
+                & (_gather_peer(st2.term, powner) == pterm)
+                & ~_gather_peer(crashed, powner)
+            )
+            committed = _gather_peer(st2.commit, powner) >= pindex
+            apply_mask = (stage == 1) & own_lead & committed
+            retry = (stage == 1) & ~own_lead
+            stage = jnp.where(apply_mask | retry, 0, stage)
         # Joint-window safety invariants on the post-step (pre-apply)
         # state under the masks that governed the step; the mask
         # TRANSITION pair (prev round's step masks -> this round's) audits
@@ -981,51 +1034,55 @@ def _runner_body(
                 lease_holder=lease_holder,
                 lease_fire=lease_fire,
             )
-        # The gated swap: target masks of the op being applied, the
-        # reference's apply-time reactions on the batched planes.
-        (
-            state3, leader3, commit3, matched3, vm3, om3, lm3, ra3, tr3,
-        ) = kernels.apply_confchange(
-            st2.state, st2.leader_id, st2.commit, st2.term_start_index,
-            st2.matched, st2.voter_mask, st2.outgoing_mask,
-            st2.learner_mask,
-            _gather_op(sched.tgt_voter, rst.op_ptr),
-            _gather_op(sched.tgt_outgoing, rst.op_ptr),
-            _gather_op(sched.tgt_learner, rst.op_ptr),
-            _gather_op(sched.added, rst.op_ptr),
-            _gather_op(sched.removed, rst.op_ptr),
-            apply_mask,
-            st2.recent_active,
-            st2.transferee,
-        )
-        st3 = st2._replace(
-            state=state3, leader_id=leader3, commit=commit3,
-            matched=matched3, voter_mask=vm3, outgoing_mask=om3,
-            learner_mask=lm3, recent_active=ra3, transferee=tr3,
-        )
+        with profiling.scope("reconfig.apply"):
+            # The gated swap: target masks of the op being applied, the
+            # reference's apply-time reactions on the batched planes.
+            (
+                state3, leader3, commit3, matched3, vm3, om3, lm3, ra3,
+                tr3,
+            ) = kernels.apply_confchange(
+                st2.state, st2.leader_id, st2.commit,
+                st2.term_start_index,
+                st2.matched, st2.voter_mask, st2.outgoing_mask,
+                st2.learner_mask,
+                _gather_op(sched.tgt_voter, rst.op_ptr),
+                _gather_op(sched.tgt_outgoing, rst.op_ptr),
+                _gather_op(sched.tgt_learner, rst.op_ptr),
+                _gather_op(sched.added, rst.op_ptr),
+                _gather_op(sched.removed, rst.op_ptr),
+                apply_mask,
+                st2.recent_active,
+                st2.transferee,
+            )
+            st3 = st2._replace(
+                state=state3, leader_id=leader3, commit=commit3,
+                matched=matched3, voter_mask=vm3, outgoing_mask=om3,
+                learner_mask=lm3, recent_active=ra3, transferee=tr3,
+            )
         stats = chaos_mod.update_chaos_stats(
             stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
             offered=offered > 0, dropped=prop.dropped,
         )
-        # dtype= on the counts: bare bool sums widen to int64 under x64
-        # (GC007) and these feed the int32 accumulator.
-        rstats = rstats + jnp.stack(
-            [
-                jnp.sum(got, dtype=jnp.int32),
-                jnp.sum(apply_mask, dtype=jnp.int32),
-                jnp.sum(retry, dtype=jnp.int32),
-                jnp.sum(jnp.any(om3, axis=0), dtype=jnp.int32),
-            ]
-        )
-        rst2 = ReconfigState(
-            stage=stage,
-            op_ptr=jnp.where(apply_mask, rst.op_ptr + 1, rst.op_ptr),
-            prop_owner=powner,
-            prop_index=pindex,
-            prop_term=pterm,
-            prev_voter=st2.voter_mask,
-            prev_outgoing=st2.outgoing_mask,
-        )
+        with profiling.scope("reconfig.apply"):
+            # dtype= on the counts: bare bool sums widen to int64 under
+            # x64 (GC007) and these feed the int32 accumulator.
+            rstats = rstats + jnp.stack(
+                [
+                    jnp.sum(got, dtype=jnp.int32),
+                    jnp.sum(apply_mask, dtype=jnp.int32),
+                    jnp.sum(retry, dtype=jnp.int32),
+                    jnp.sum(jnp.any(om3, axis=0), dtype=jnp.int32),
+                ]
+            )
+            rst2 = ReconfigState(
+                stage=stage,
+                op_ptr=jnp.where(apply_mask, rst.op_ptr + 1, rst.op_ptr),
+                prop_owner=powner,
+                prop_index=pindex,
+                prop_term=pterm,
+                prev_voter=st2.voter_mask,
+                prev_outgoing=st2.outgoing_mask,
+            )
         out = (st3, hl2, rst2, stats, rstats, safety)
         if with_counters:
             out = out + (ctrs2,)

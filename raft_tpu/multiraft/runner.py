@@ -522,6 +522,9 @@ def _make_workload(
         sched, chaos_sched = rebuild_scheds(
             reconfig_compiled, chaos_compiled, sched_args[n_client:]
         )
+        # A replayed schedule is a cycle: finished chains start again,
+        # unfinished ones go on (identity on a fresh carry).
+        rst = reconfig_mod.resume_state(rst, sched.n_ops)
         stats = jnp.zeros((chaos_mod.N_CHAOS_STATS,), jnp.int32)
         rstats = jnp.zeros((reconfig_mod.N_RECONFIG_STATS,), jnp.int32)
         safety = jnp.zeros((kernels.N_SAFETY,), jnp.int32)

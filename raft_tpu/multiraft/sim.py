@@ -3758,6 +3758,11 @@ class ClusterSim:
 
             self._step_counted = jax.jit(_counted, donate_argnums=(0, 3))
         self._read_calls = 0  # run_reads' sequence number (profiling spans)
+        # The op protocol's carry as the last run_reconfig / run_reads call
+        # left it (checkpoint.save_reconfig_state), and the run_reads
+        # runner it belongs to: only that runner's next call resumes it.
+        self._reconfig_state = None
+        self._reconfig_state_of = None
         if cfg.collect_health:
             self._health = init_health(cfg)
             if mesh is not None:
@@ -4427,6 +4432,7 @@ class ClusterSim:
         rst = self._place_reconfig_state(
             reconfig_mod.init_reconfig_state(self.state)
         )
+        self._reconfig_state_of = None  # the carry below is no run_reads'
         fused = None
         if split:
             if wc:
@@ -4533,6 +4539,17 @@ class ClusterSim:
         scan are cached per plan triple, so repeated calls pay one
         compile.
 
+        A reconfig plan replayed call after call is a cycle: the op
+        protocol's carry is kept between calls of one plan triple
+        (`self._reconfig_state`, what checkpoint.save_reconfig_state
+        saves; reconfig.resume_state) — a group whose chain is complete
+        starts again at op 0, a group with an op in flight or ops left
+        finishes its chain first, never from op 0 — and its counts are in
+        the report (`conf_proposals`, `conf_applied`, `conf_retries`,
+        `joint_group_rounds`, `conf_unfinished`: all 0 with no reconfig
+        plan).  Plans apply absolute target masks, so a plan meant to be
+        replayed has to end in the configuration it starts from.
+
         `split=True` (the ISSUE 13 fused satellite) executes the plan
         through workload.make_split_runner: steady stretches whose reads
         are pure lease serves ride the fused Pallas kernel in
@@ -4569,22 +4586,28 @@ class ClusterSim:
                     or cached[5] != mode
                 ):
                     prepare.set_metadata(miss=1)
-                    compiled, runner = self._build_read_runner(
+                    compiled, runner, n_ops = self._build_read_runner(
                         plan, chaos_plan, reconfig_plan, split, split_k
                     )
                     self._read_runner = (
                         plan, chaos_plan, reconfig_plan, compiled, runner,
-                        mode,
+                        mode, n_ops,
                     )
                 else:
-                    compiled, runner = cached[3], cached[4]
+                    compiled, runner, n_ops = cached[3], cached[4], cached[6]
                 whole.set_metadata(
                     call=self._read_calls, rounds=compiled.n_rounds,
                     groups=self.cfg.n_groups,
                 )
-                rst = self._place_reconfig_state(
-                    reconfig_mod.init_reconfig_state(self.state)
-                )
+                # The op protocol's carry: the one the last call of this
+                # plan triple ended with (the runner resumes it: finished
+                # chains start again, unfinished ones go on), else fresh.
+                if self._reconfig_state_of is runner:
+                    rst = self._reconfig_state
+                else:
+                    rst = self._place_reconfig_state(
+                        reconfig_mod.init_reconfig_state(self.state)
+                    )
                 rcar = jax.tree.map(
                     lambda x: self._put(x, True),
                     workload_mod.init_read_carry(self.cfg.n_groups),
@@ -4595,9 +4618,10 @@ class ClusterSim:
             with profiling.span("raft.run_reads.dispatch"):
                 out = runner(*args)
             (
-                self.state, self._health, _rst, stats, rstats, safety,
-                self._read_carry, rdstats, lat_hist,
+                self.state, self._health, self._reconfig_state, stats,
+                rstats, safety, self._read_carry, rdstats, lat_hist,
             ) = out[:9]
+            self._reconfig_state_of = runner
             i = 9
             if self._blackbox is not None:
                 self._blackbox = out[i]
@@ -4607,22 +4631,33 @@ class ClusterSim:
                 lat_p, recover_p = workload_mod.report_percentiles(
                     lat_hist, stats
                 )
+                # Groups with ops of their chain left (none without a plan).
+                unfinished = (
+                    0 if n_ops is None
+                    else reconfig_mod.unfinished_groups(
+                        self._reconfig_state.op_ptr, n_ops
+                    )
+                )
                 with profiling.span("raft.run_reads.download"):
                     # graftcheck: allow-no-host-sync-in-jit — deliberate
                     # end-of-run download of fixed-size stat vectors (and
                     # the fused group-round scalar), outside the jitted
                     # scan.
                     got = jax.device_get(
-                        (rdstats, lat_p, safety, stats, recover_p, *fused)
+                        (rdstats, lat_p, safety, stats, recover_p, rstats,
+                         unfinished, *fused)
                     )
-                rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h = got[:5]
+                (
+                    rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h,
+                    rstats_h, unfinished_h,
+                ) = got[:7]
                 report = workload_mod.read_report(
                     rdstats_h, lat_p_h, safety_h, stats_h, compiled.n_rounds,
-                    recover_p_h,
+                    recover_p_h, rstats_h, unfinished_h,
                 )
                 if split or fused_zero:
                     total = compiled.n_rounds * self.cfg.n_groups
-                    report["fused_rounds"] = int(got[5]) if split else 0
+                    report["fused_rounds"] = int(got[7]) if split else 0
                     report["total_rounds"] = total
                     report["fused_frac"] = round(
                         report["fused_rounds"] / total, 4
@@ -4638,9 +4673,10 @@ class ClusterSim:
     def _build_read_runner(
         self, plan, chaos_plan, reconfig_plan, split: bool, split_k: int
     ):
-        """(compiled client schedule, runner) of one run_reads plan
-        triple: compile whatever is not compiled yet, place the schedules,
-        build the runner (the runner-cache miss of run_reads)."""
+        """(compiled client schedule, runner, the reconfig schedule's
+        n_ops plane or None) of one run_reads plan triple: compile
+        whatever is not compiled yet, place the schedules, build the
+        runner (the runner-cache miss of run_reads)."""
         from . import chaos as chaos_mod
         from . import reconfig as reconfig_mod
         from . import runner as runner_mod
@@ -4673,7 +4709,8 @@ class ClusterSim:
             self.cfg, (compiled, chaos_compiled, reconfig_compiled),
             split=split, k=split_k,
         )
-        return compiled, runner
+        n_ops = None if reconfig_compiled is None else reconfig_compiled.n_ops
+        return compiled, runner, n_ops
 
     def counters(self) -> dict:
         """Download the device event-counter plane as {name: count}.

@@ -792,6 +792,24 @@ class ReconfigOracle(HealthOracle):
         self.prop_owner = np.zeros(G, dtype=np.int64)
         self.prop_index = np.zeros(G, dtype=np.int64)
         self.prop_term = np.zeros(G, dtype=np.int64)
+        # The runner's rstats vector (reconfig.RC_* order: proposals,
+        # applies, retries, joint group-rounds), since the last resume().
+        self.rstats = np.zeros(4, dtype=np.int64)
+
+    def resume(self) -> None:
+        """Start a replay of the same schedule from the carry the last
+        one ended with — the scalar twin of reconfig.resume_state: a
+        group whose chain is complete starts again at op 0, a group with
+        an op in flight or ops left keeps its pointer and its pending
+        entry; the round index and the counts start over."""
+        done = (self.op_ptr >= self.schedule.n_ops) & (self.stage == 0)
+        self.op_ptr[done] = 0
+        self.round_idx = 0
+        self.rstats[:] = 0
+
+    def unfinished(self) -> int:
+        """Groups with ops of their chain still to apply."""
+        return int((self.op_ptr < self.schedule.n_ops).sum())
 
     @staticmethod
     def _regime_start(raft) -> int:
@@ -882,6 +900,7 @@ class ReconfigOracle(HealthOracle):
                     self.prop_index[g],
                     self.prop_term[g],
                 ) = props[g]
+                self.rstats[0] += 1
         for g in range(G):
             if self.stage[g] != 1:
                 continue
@@ -896,6 +915,14 @@ class ReconfigOracle(HealthOracle):
                 self._apply_surgery(g, sch.slot(g, int(self.op_ptr[g])))
                 self.op_ptr[g] += 1
                 self.stage[g] = 0
+                self.rstats[1] += 1
             elif not own_lead:
                 self.stage[g] = 0  # retry at the next acting leader
+                self.rstats[2] += 1
+        # Every peer of a group holds the same configuration (the surgery
+        # is applied to all at once): peer 1's says whether it is joint.
+        self.rstats[3] += sum(
+            bool(net.peers[1].raft.prs.conf.voters.outgoing.ids())
+            for net in self.cluster.networks
+        )
         self.round_idx += 1

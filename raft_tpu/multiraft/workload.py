@@ -536,14 +536,19 @@ def lease_fires_in_block(
 
 
 def read_report(
-    rdstats, lat_p, safety, stats, rounds: int, recover_p=(-1, -1, -1)
+    rdstats, lat_p, safety, stats, rounds: int, recover_p=(-1, -1, -1),
+    rstats=(0, 0, 0, 0), conf_unfinished: int = 0,
 ) -> dict:
     """The per-scenario read-workload summary off the device accumulators
     (host-side formatter; bench.py --reads and ClusterSim.run_reads emit
     it).  `lat_p` is latency_percentiles' (p50, p90, p99) vector of the
     read histogram, `recover_p` the same of chaos.recover_hist(stats) —
     the lengths in rounds of the leaderless episodes that ended (-1: none
-    did)."""
+    did).  `rstats` is the op protocol's [reconfig.N_RECONFIG_STATS]
+    vector (conf entries proposed / ops applied / entries given up with
+    their owner / group-rounds in a joint configuration) and
+    `conf_unfinished` the groups with ops of their chain left at the end;
+    all zero with no reconfig plan."""
     from .chaos import (
         CS_APPENDS_DROPPED,
         CS_APPENDS_OFFERED,
@@ -554,6 +559,7 @@ def read_report(
         recover_hist,
     )
     from .kernels import SAFETY_NAMES
+    from .reconfig import READ_REPORT_CONF_NAMES
 
     reelections = int(stats[CS_REELECTIONS])
     healed = int(stats[CS_HEALED_ROUNDS])
@@ -576,6 +582,8 @@ def read_report(
         "recover_p50_rounds": int(recover_p[0]),
         "recover_p90_rounds": int(recover_p[1]),
         "recover_p99_rounds": int(recover_p[2]),
+        **{name: int(v) for name, v in zip(READ_REPORT_CONF_NAMES, rstats)},
+        "conf_unfinished": int(conf_unfinished),
         "safety": {
             name: int(v) for name, v in zip(SAFETY_NAMES, safety)
         },

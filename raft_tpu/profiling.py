@@ -47,7 +47,8 @@ SPANS: Dict[str, str] = {
     ),
     "raft.run_reads.prepare": (
         "runner cache look-up (schedule compile + make_runner on a miss: "
-        "stat miss=1), init_reconfig_state, init_read_carry, placement"
+        "stat miss=1), the op protocol's carry (fresh on a miss, else the "
+        "last call's), init_read_carry, placement"
     ),
     "raft.run_reads.dispatch": (
         "runner(*args): the eager zero carries, the block loop or the "
@@ -58,8 +59,9 @@ SPANS: Dict[str, str] = {
         "dispatch; stats: blocks, tail (rounds left to the tail program)"
     ),
     "raft.run_reads.report": (
-        "everything dispatched for the report (latency_percentiles), the "
-        "download, formatting; closed with the report's integer counts"
+        "everything dispatched for the report (latency_percentiles, "
+        "unfinished_groups under a reconfig plan), the download, "
+        "formatting; closed with the report's integer counts"
     ),
     "raft.run_reads.download": (
         "the device_get of the report's vectors, inside report: the host's "
@@ -94,6 +96,16 @@ SCOPES: Dict[str, str] = {
     "op_gather": (
         "reconfig._gather_peer / _gather_op: the op protocol's per-group "
         "look-ups in every _runner_body round, a plan scheduled or not"
+    ),
+    "reconfig.gate": (
+        "the op protocol's first half in every _runner_body round: "
+        "eligibility, the propose record, the dual-majority commit gate "
+        "(its look-ups are op_gather, inside)"
+    ),
+    "reconfig.apply": (
+        "the op protocol's second half: kernels.apply_confchange on the "
+        "op's target planes (op_gather, inside), the pointer advance, the "
+        "rstats fold"
     ),
     "safety_audit": "kernels.check_safety: the per-round safety slots",
     "health_fold": "kernels.update_health: the fleet-health planes",
