@@ -43,6 +43,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import profiling
 from . import chaos as chaos_mod
@@ -646,20 +647,34 @@ def _make_workload_split(
         )
         return csched, sched
 
+    def tables_run(starts, *sched_args):
+        """Every block's workload.BlockRows and the write load of the
+        rounds `starts` (one block start per distinct load, below), each
+        as a list of rows."""
+        csched, sched = _rebuild_client(sched_args)
+        append = (
+            sched.append[sched.phase_of_round[starts]]
+            + csched.append[csched.phase_of_round[starts]]
+        )
+        return jax.tree.map(
+            list, (workload_mod.block_tables(csched, k), append)
+        )
+
     def block_run(
         st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
-        fused, r0, *sched_args,
+        fused, rows, append, *sched_args,
     ):
         csched, sched = _rebuild_client(sched_args)
         body = reconfig_mod._runner_body(cfg, sched, None, client=csched)
         guard = profiling.Sections()
         guard.at("runner.block_guard")
         crashed = jnp.zeros((P, G), bool)
-        cph = csched.phase_of_round[r0]
-        append = sched.append[sched.phase_of_round[r0]] + csched.append[cph]
-        same_phase = cph == csched.phase_of_round[r0 + k - 1]
-        read_block = workload_mod.reads_pending_in_horizon(csched, rcar, r0, k)
-        n_lease, any_lease = workload_mod.lease_fires_in_block(csched, r0, k)
+        # The schedule's half of the guard is the block's own rows
+        # (workload.BlockRows); only the fleet's half is computed here.
+        read_block = (rcar.pending_mode > 0) | kernels.unpack_bits_g(
+            rows.safe_fire, G
+        )
+        any_lease = kernels.unpack_bits_g(rows.lease_fire, G)
         _, lease_entry, _ = kernels.lease_read(
             st.state, st.term, st.leader_id, st.election_elapsed,
             st.commit, st.term_start_index, crashed, cfg.election_tick,
@@ -679,7 +694,7 @@ def _make_workload_split(
         mask = pallas_step.steady_mask(
             cfg, st, crashed, horizon=k, read_pending=read_block
         )
-        pred = jnp.all(mask & lease_prov) & same_phase
+        pred = jnp.all(mask & lease_prov) & rows.same_phase
         guard.end()
 
         @profiling.scope("runner.fused_arm")
@@ -702,7 +717,7 @@ def _make_workload_split(
             # Closed-form receipts: every in-block lease fire issues
             # fresh (the carry is provably empty — read_block rejected
             # otherwise) and serves the round it fires at latency 0.
-            n_served = jnp.sum(n_lease, dtype=jnp.int32)
+            n_served = rows.n_lease
             lat = lat.at[0].add(n_served)
             rdstats2 = rdstats.at[workload_mod.RS_ISSUED].add(n_served)
             rdstats2 = rdstats2.at[workload_mod.RS_SERVED_LEASE].add(n_served)
@@ -714,7 +729,7 @@ def _make_workload_split(
         @profiling.scope("runner.general_arm")
         def slow(args):
             carry, _ = jax.lax.scan(
-                body, args, r0 + jnp.arange(k, dtype=jnp.int32)
+                body, args, rows.r0 + jnp.arange(k, dtype=jnp.int32)
             )
             return carry
 
@@ -741,6 +756,20 @@ def _make_workload_split(
     tail_audit_jit = jax.jit(_tail_audit)
     tail_jit = jax.jit(tail_run, donate_argnums=donate) if tail else None
     sched_args = schedule_args(client, reconfig_sched)
+    # Blocks that start in one client phase share one append row (the
+    # template above has a single phase), so the rows never outgrow the
+    # schedule's own planes.
+    starts = np.arange(n_blocks, dtype=np.int32) * k
+    _, first, row_of = np.unique(
+        np.asarray(client.phase_of_round)[starts],
+        return_index=True, return_inverse=True,
+    )
+    tables, loads = jax.jit(tables_run)(starts[first], *sched_args)
+    block_args = [
+        (workload_mod.BlockRows(*(t[b] for t in tables)), loads[row_of[b]])
+        for b in range(n_blocks)
+    ]
+    tail_r0 = jnp.int32(n_blocks * k)
 
     def runner(st, hl, rst, rcar):
         stats = jnp.zeros((chaos_mod.N_CHAOS_STATS,), jnp.int32)
@@ -753,14 +782,10 @@ def _make_workload_split(
             jnp.int32(0),
         )
         with profiling.span("raft.runner.blocks", blocks=n_blocks, tail=tail):
-            for b in range(n_blocks):
-                carry = fused_jit(
-                    *carry, jnp.int32(b * k), *sched_args
-                )
+            for block in block_args:
+                carry = fused_jit(*carry, *block, *sched_args)
         if tail_jit is not None:
-            carry = tail_jit(
-                *carry, jnp.int32(n_blocks * k), *sched_args
-            )
+            carry = tail_jit(*carry, tail_r0, *sched_args)
         (
             stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
             lat_hist, fused,
@@ -774,6 +799,7 @@ def _make_workload_split(
         )
 
     runner.fused_jit = fused_jit  # type: ignore[attr-defined]
+    runner.block_args = block_args  # type: ignore[attr-defined]
     runner.schedule_args = sched_args  # type: ignore[attr-defined]
     return runner
 

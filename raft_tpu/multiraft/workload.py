@@ -469,8 +469,14 @@ def make_split_runner(
     --reads shape.  Returns a callable with make_runner's signature plus
     a trailing fused-group-rounds int32 scalar:
     (st, hl, rst, rcar) -> (..., lat_hist, fused_rounds).
-    ``runner.fused_jit`` / ``runner.schedule_args`` are exposed for the
-    graftcheck trace audit.
+
+    What the predicate and the closed-form fold need of the SCHEDULE is
+    computed once, when the runner is built (:func:`block_tables`), and
+    block b receives its own rows as operands (``runner.block_args[b]``:
+    a :class:`BlockRows` and the block's append row), so a block's cost
+    does not follow the schedule's length; only the general arm reads the
+    schedule's planes.  ``runner.fused_jit`` / ``runner.block_args`` /
+    ``runner.schedule_args`` are exposed for the graftcheck trace audit.
 
     Thin behavior-neutral wrapper since the runner-registry refactor:
     the construction lives in the unified factory
@@ -498,7 +504,10 @@ def reads_pending_in_horizon(
     quorum round, while pure LEASE fires are NOT pending — on a steady
     horizon the lease gate provably holds and the serve touches no
     message planes, so those fold closed-form (workload.make_split_runner
-    / bench --reads)."""
+    / bench --reads).  The per-round DEFINITION: the split runner
+    computes the carry half itself and takes the schedule half from
+    :func:`block_tables`, which tests/test_block_tables.py holds equal
+    to this."""
     G = rcar.pending_mode.shape[0]
     pending = rcar.pending_mode > 0
     safe_fire = jnp.zeros((G,), bool)
@@ -521,7 +530,9 @@ def lease_fires_in_block(
     """(n_lease int32[G], any bool[G]): scheduled LEASE-mode fires per
     group inside [r0, r0 + horizon) — the closed-form serve count a fused
     block folds into the latency histogram's zero bucket (a lease serve
-    on a steady horizon completes the round it fires)."""
+    on a steady horizon completes the round it fires).  The per-round
+    DEFINITION of BlockRows.n_lease / .lease_fire (see
+    reads_pending_in_horizon)."""
     G = client.read_mode.shape[1]
     n = jnp.zeros((G,), jnp.int32)
     R = client.n_rounds
@@ -533,6 +544,59 @@ def lease_fires_in_block(
             fire & (mode == sim_mod.READ_LEASE) & ((r0 + o) < R)
         ).astype(jnp.int32)
     return n, n > 0
+
+
+class BlockRows(NamedTuple):
+    """What ONE fused k-round block needs of the client schedule: every
+    field is a function of the schedule and the block's number alone, so
+    :func:`block_tables` computes them once per schedule and the split
+    runner hands block b its rows as operands — a block's guard reads no
+    [R, ...] or [NPH, ...] plane (on the v5e those planes are laid out
+    phase-minor, so ONE row read costs the whole plane: PERF.md, PR 31).
+
+    r0:         int32[]       the block's first round
+    same_phase: bool[]        its first and last round share a client phase
+    n_lease:    int32[]       LEASE-mode fires inside it, all groups
+                              (sum of lease_fires_in_block's count)
+    safe_fire:  uint32[Wg]    groups with a SAFE-mode fire inside it
+                              (reads_pending_in_horizon's schedule half),
+                              bit-packed along G like read_fire_packed
+    lease_fire: uint32[Wg]    groups with a LEASE-mode fire inside it
+                              (lease_fires_in_block's `any`), packed alike
+    """
+
+    r0: jnp.ndarray  # gc: int32[]
+    same_phase: jnp.ndarray  # gc: bool[]
+    n_lease: jnp.ndarray  # gc: int32[]
+    safe_fire: jnp.ndarray  # gc: uint32[WG]
+    lease_fire: jnp.ndarray  # gc: uint32[WG]
+
+
+def block_tables(client: CompiledClient, k: int) -> BlockRows:
+    """The :class:`BlockRows` of every whole k-round block of `client`,
+    stacked along a leading [n_rounds // k] axis.  One pass over the
+    schedule in PACKED space — a round's fire words AND its phase's packed
+    mode mask — so nothing of [n_blocks, k, G] is ever built."""
+    n_blocks = client.n_rounds // k
+    n = n_blocks * k
+    phase = client.phase_of_round[:n]
+    fire = client.read_fire_packed[:n]
+
+    def fires(mode: int) -> jnp.ndarray:
+        of_phase = kernels.pack_bits_g(client.read_mode == mode)
+        return (fire & of_phase[phase]).reshape(n_blocks, k, fire.shape[1])
+
+    lease = fires(sim_mod.READ_LEASE)
+    by_block = phase.reshape(n_blocks, k)
+    return BlockRows(
+        r0=jnp.arange(n_blocks, dtype=jnp.int32) * k,
+        same_phase=by_block[:, 0] == by_block[:, k - 1],
+        n_lease=jnp.sum(
+            jax.lax.population_count(lease), axis=(1, 2), dtype=jnp.int32
+        ),
+        safe_fire=jnp.bitwise_or.reduce(fires(sim_mod.READ_SAFE), axis=1),
+        lease_fire=jnp.bitwise_or.reduce(lease, axis=1),
+    )
 
 
 def read_report(

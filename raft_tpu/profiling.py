@@ -111,9 +111,9 @@ SCOPES: Dict[str, str] = {
     "health_fold": "kernels.update_health: the fleet-health planes",
     "read_latency": "workload.latency_percentiles over a histogram",
     "runner.block_guard": (
-        "everything a split block computes before its lax.cond: schedule "
-        "look-ups, reads_pending_in_horizon, lease_fires_in_block, "
-        "lease_read, steady_mask"
+        "everything a split block computes before its lax.cond: its "
+        "tabled schedule rows unpacked (workload.BlockRows), lease_read, "
+        "steady_mask"
     ),
     "runner.fused_arm": "the cond's fused branch: kernel + closed-form folds",
     "runner.general_arm": "the cond's fallback: k general rounds",

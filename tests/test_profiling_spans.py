@@ -249,7 +249,7 @@ def lowered_text():
         st, sim.init_health(cfg), rst, zeros(chaos.N_CHAOS_STATS),
         zeros(reconfig.N_RECONFIG_STATS), zeros(kernels.N_SAFETY),
         workload.init_read_carry(G), zeros(workload.N_READ_STATS),
-        zeros(workload.N_LAT_BUCKETS), jnp.int32(0), jnp.int32(0),
+        zeros(workload.N_LAT_BUCKETS), jnp.int32(0), *run.block_args[0],
         *run.schedule_args,
     )
     texts = {"block": run.fused_jit.lower(*args).as_text(debug_info=True)}

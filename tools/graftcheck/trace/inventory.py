@@ -613,8 +613,7 @@ def _workload_split_builder():
             jnp.zeros((workload.N_READ_STATS,), jnp.int32),
             jnp.zeros((workload.N_LAT_BUCKETS,), jnp.int32),
             jnp.int32(0),
-            jnp.int32(0),
-        ) + runner.schedule_args
+        ) + runner.block_args[0] + runner.schedule_args
         return Built(runner.fused_jit, args, (0, 1, 2, 6))
 
     return build
