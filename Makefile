@@ -6,9 +6,9 @@ PY ?= python
 # The exact file set the static-analysis gates run over — keep `make lint`,
 # `make typecheck`, CI, and docs/STATIC_ANALYSIS.md in sync by changing it
 # here only.
-CHECK_PATHS = raft_tpu tests bench.py benches docs README.md CHANGES.md
+CHECK_PATHS = raft_tpu tests docs README.md CHANGES.md
 
-.PHONY: all test test-fast bench bench-suites native examples clean \
+.PHONY: all test test-fast native examples clean \
 	lint typecheck check obligations jaxpr-budget
 
 all: native test
@@ -50,8 +50,8 @@ obligations:
 	$(PY) -m tools.graftcheck --emit-obligations \
 		tools/graftcheck/parity_obligations.json raft_tpu/multiraft tests
 
-# Regenerate the GC014 jaxpr-size budget after an intentional graph change
-# (the bench-gate workflow, for compile time): re-traces the whole graph
+# Regenerate the GC014 jaxpr-size budget after an intentional graph change:
+# re-traces the whole graph
 # inventory and rewrites tools/graftcheck/jaxpr_budget.json — commit the
 # result so the growth is paid visibly in review (docs/STATIC_ANALYSIS.md).
 jaxpr-budget:
@@ -68,12 +68,6 @@ check: lint typecheck test
 
 test-fast:
 	$(PY) -m pytest tests/ -q --ignore=tests/test_pallas_step.py
-
-bench:
-	$(PY) bench.py
-
-bench-suites:
-	$(PY) benches/suites.py
 
 examples:
 	$(PY) examples/single_mem_node.py

@@ -4,9 +4,9 @@ Drives the system's main path once on ONE TPU chip, through the entry
 points a user calls (ClusterSim and its run_* scenario runners,
 pallas_step.fast_multi_round / hybrid_multi_round, the MultiRaft driver), at
 the headline deployment's size — BASELINE config 3's shape, 100 000 groups
-x 5 peers, with bench.py's kernel parameters (K = 32 fused rounds,
-election_tick = 64 for the chaos and damped families) — and checks every
-answer by the repo's own means:
+x 5 peers, K = 32 fused rounds a block, election_tick = 64 for the chaos
+and damped families (the regime in which every fused family engages) —
+and checks every answer by the repo's own means:
 
   1. general path, undamped   == the C++ engine (NativeMultiRaft), full G
   2. general path, damped     == scalar raft-rs port (simref.ScalarCluster)
@@ -42,9 +42,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 G, P = 100_000, 5  # BASELINE config 3
 G_BIG, P_BIG = 1_000_000, 3  # BASELINE config 5
-K = 32  # fused rounds per block (bench.py)
-TICK_FUSED = 64  # election_tick of the chaos/damped families (bench.py)
-LOSS = 0.01  # uniform per-link loss of the chaos family (bench.py --lossy)
+K = 32  # fused rounds per block
+TICK_FUSED = 64  # election_tick of the chaos/damped families
+LOSS = 0.01  # uniform per-link loss of the chaos family
 SEED = 20230  # picks the block of groups leg 2 replays on the scalar port
 SAMPLE = 16  # groups in that block
 
@@ -387,7 +387,7 @@ def _settled_production_sim(
     G: int, P: int, election_tick: int, masks=(), **flags
 ):
     """The settled check-quorum + pre-vote fleet the split runners start
-    from (bench.py's regime: the boot storm is not part of the plan)."""
+    from (the boot storm is not part of the plan)."""
     from raft_tpu.multiraft import SimConfig
 
     cfg = SimConfig(

@@ -10,7 +10,7 @@ Modules:
   kernels   — pure jnp kernel functions (the scalar oracle lives in
               raft_tpu.quorum / raft_tpu.tracker)
   sim       — ClusterSim: closed-loop on-device simulation of G groups × P
-              peers (the bench workhorse; BASELINE configs 2-5)
+              peers (what the benchmark drives; BASELINE configs 2-5)
   simref    — ScalarCluster: the same lockstep protocol driven through real
               scalar Raft instances (the parity oracle)
   sharding  — mesh construction + shard_map'd step for multi-chip scale-out

@@ -1,7 +1,7 @@
-"""Fleet autopilot (ISSUE 12): the closed loop that ACTS on health.
+"""Fleet autopilot: the closed loop that ACTS on health.
 
 The fleet already elects, replicates, damps, reconfigures, and reports
-health under chaos; this module closes ROADMAP item 2's loop: a host-side
+health under chaos; this module closes the loop: a host-side
 DECLARATIVE policy (`AutopilotConfig`: thresholds, per-cadence action
 budgets, cooldowns) reads the device-reduced health summary at each drain
 cadence and emits batched actions whose ACTUATION is device-resident:
@@ -24,12 +24,13 @@ cadence and emits batched actions whose ACTUATION is device-resident:
              degraded-site framing.
 
 Execution shape: the chaos horizon runs as cadence-sized donated jitted
-segments (`make_cadence_runner` wraps reconfig._runner_body, so the op
-protocol, the MTTR/safety folds, and the chaos masks are the SAME code
-the reconfig runner uses); between segments the fixed-size health summary
-crosses to the host, the policy decides, and the next segment carries the
-action planes.  An evacuation decision swaps in the compiled evacuation
-schedule for the remaining horizon — reconfig + chaos in one scan.
+segments (`runner.make_runner(..., cadence=)` over reconfig._runner_body,
+so the op protocol, the MTTR/safety folds, and the chaos masks are the
+SAME code the reconfig runner uses); between segments the fixed-size
+health summary crosses to the host, the policy decides, and the next
+segment carries the action planes.  An evacuation decision swaps in the
+compiled evacuation schedule for the remaining horizon — reconfig + chaos
+in one scan.
 
 Determinism/replay: the loop is fully deterministic — identical plans,
 state, and policy knobs reproduce identical actions round-for-round (the
@@ -38,12 +39,6 @@ summaries only).  `tools/autopilot_report.py` exploits this for the
 before/after CI gate: the autopilot-on corpus replay must beat the
 autopilot-off replay on MTTR and commit-stall with zero safety
 violations.
-
-Since the runner-registry refactor the cadence segment is BUILT by the
-unified factory (raft_tpu/multiraft/runner.py) from the schedules.py
-registry — :func:`make_cadence_runner` here is a thin behavior-neutral
-wrapper, and the flat schedule-arg tuple comes from
-``runner.schedule_args`` (GC018 machine-checks both).
 """
 
 from __future__ import annotations
@@ -56,22 +51,19 @@ import jax.numpy as jnp
 
 from . import chaos as chaos_mod
 from . import kernels
-from . import sim as sim_mod
+from . import runner as runner_mod
 from .reconfig import (
     N_RECONFIG_STATS,
-    NO_ROUND,
-    CompiledReconfig,
     ReconfigPhase,
     ReconfigPlan,
     compile_plan,
+    empty_reconfig_schedule,
     init_reconfig_state,
 )
 
 __all__ = [
     "Autopilot",
     "AutopilotConfig",
-    "empty_reconfig_schedule",
-    "make_cadence_runner",
 ]
 
 
@@ -105,8 +97,8 @@ class AutopilotConfig(NamedTuple):
     evac_stall_ticks: int = 12
     evac_min_groups: int = 2
     # Leader-placement balancing against a skewed workload (the Zipf
-    # hot-region regime, benches/suites.py config 3): when on, each
-    # cadence ALSO spends up to max_balance_transfers moving the
+    # hot-region regime): when on, each cadence ALSO spends up to
+    # max_balance_transfers moving the
     # heaviest groups off the most-loaded leader peer onto each group's
     # least-loaded voter — "Paxos vs Raft" names leadership placement as
     # the production lever, and this is its closed-loop form.  Needs the
@@ -123,72 +115,6 @@ class AutopilotConfig(NamedTuple):
         if self.cooldown < 0:
             raise ValueError("cooldown must be >= 0")
         return self
-
-
-def empty_reconfig_schedule(
-    n_rounds: int, n_peers: int, n_groups: int
-) -> CompiledReconfig:
-    """A no-op CompiledReconfig spanning `n_rounds`: zero ops, zero extra
-    append — composing it with a chaos schedule through _runner_body
-    reproduces the plain chaos runner's protocol exactly (the op-protocol
-    carry provably never moves).  The autopilot starts every horizon on
-    this template and swaps in a real evacuation schedule only when the
-    policy fires."""
-    P, G = n_peers, n_groups
-    return CompiledReconfig(
-        phase_of_round=jnp.zeros((n_rounds,), jnp.int32),
-        append=jnp.zeros((1, G), jnp.int32),
-        op_start=jnp.full((1, G), NO_ROUND, jnp.int32),
-        n_ops=jnp.zeros((G,), jnp.int32),
-        tgt_voter=jnp.zeros((1, P, G), bool),
-        tgt_outgoing=jnp.zeros((1, P, G), bool),
-        tgt_learner=jnp.zeros((1, P, G), bool),
-        added=jnp.zeros((1, P, G), bool),
-        removed=jnp.zeros((1, P, G), bool),
-        n_peers=P,
-    )
-
-
-def make_cadence_runner(
-    cfg: sim_mod.SimConfig,
-    compiled: CompiledReconfig,
-    chaos_compiled: Optional[chaos_mod.CompiledChaos],
-    rounds: int,
-    fused: bool = False,
-):
-    """One jitted cadence segment: `rounds` scan iterations of
-    reconfig._runner_body (chaos masks + op protocol + MTTR/safety folds)
-    with the autopilot's action planes applied at the segment's FIRST
-    round, plus a per-round commit-stall fold (group-rounds at/over
-    SimConfig.commit_stall_ticks — the report's second headline metric).
-
-    `fused=True` adds the production fast path (the bench.py --autopilot
-    configuration): the whole segment rides the fused Pallas steady
-    kernel (pallas_step.steady_round with health + chaos) behind a
-    lax.cond whose guard is the steady predicate over the segment horizon
-    — which rejects pending transfers and scheduled reconfig ops — AND
-    this segment carrying no action (transfer plane all-zero, kick mask
-    all-false) with a positive append everywhere (so the closed-form
-    commit-stall fold is exactly zero).  Bit-identical to the general
-    scan when engaged, like the split runner's fused blocks.
-
-    Signature: (st, hl, rst, stats, rstats, safety, cs_rounds, r0,
-    transfer_plane, kick_plane, *schedule_args) with the whole protocol
-    carry donated; schedule arrays enter as runtime arguments (GC012).
-    Returns the advanced carry (with a trailing fused-group-rounds int32
-    scalar accumulated into cs_rounds' sibling position when `fused` —
-    callers get it via the returned tuple's last element).
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxpr (GC014 pins it).
-    """
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(
-        cfg, (compiled, chaos_compiled), cadence=rounds, fused=fused
-    )
 
 
 class Autopilot:
@@ -528,8 +454,8 @@ class Autopilot:
             # The fused fast path only pays off at the full cadence
             # length (a remainder segment would compile its own Pallas
             # kernel for one use).
-            r = make_cadence_runner(
-                self.sim.cfg, compiled, chaos_compiled, rounds,
+            r = runner_mod.make_runner(
+                self.sim.cfg, (compiled, chaos_compiled), cadence=rounds,
                 fused=self.fused and rounds == self.cfg.cadence,
             )
             self._runners[key] = r
@@ -543,9 +469,8 @@ class Autopilot:
         them — plus whatever healing the autopilot achieved.
 
         `append` (optional int32[G]) is a per-GROUP workload plane ADDED
-        to every round's chaos-phase append — the Zipf hot-region
-        workload of bench.py --autopilot; None keeps the plan's own
-        workload only."""
+        to every round's chaos-phase append (a Zipf hot-region
+        workload, say); None keeps the plan's own workload only."""
         sim = self.sim
         scfg = sim.cfg
         G, P = scfg.n_groups, scfg.n_peers
@@ -586,8 +511,6 @@ class Autopilot:
             # The flat runtime-arg tuple comes from the registry
             # (schedules.py via runner.schedule_args) — never hand-listed
             # (GC018).
-            from . import runner as runner_mod
-
             sched_args = runner_mod.schedule_args(compiled, chaos_compiled)
             out = runner(
                 st, hl, rst, stats, rstats, safety,
@@ -648,7 +571,7 @@ class Autopilot:
                         append=compiled.append + append[None, :]
                     )
                 rst = init_reconfig_state(st)
-        # Tail audit, exactly make_runner's: a final-round apply's mask
+        # Tail audit, exactly the scan runners': a final-round apply's mask
         # transition is checked one extra fold later.
         if bb is not None:
             viol = kernels.check_safety_groups(

@@ -11,14 +11,14 @@ group)`` so every run replays bit-exactly).
 A :class:`ChaosPlan` is a list of phases — partitions, directed link
 overrides, loss rates, crashes, heals — each covering a round range and an
 optional group selector.  :func:`compile_plan` lowers it host-side into
-dense per-phase schedule arrays; :func:`run_plan` then executes the whole
-multi-phase scenario inside ONE jitted ``lax.scan`` with zero host round
-trips: per-round masks are gathered from the schedule by phase index, the
-loss plane is drawn on device, the link-gated step advances every group,
+dense per-phase schedule arrays; ``runner.make_runner`` then executes the
+whole multi-phase scenario inside ONE jitted ``lax.scan`` with zero host
+round trips: per-round masks are gathered from the schedule by phase index,
+the loss plane is drawn on device, the link-gated step advances every group,
 ``kernels.check_safety`` folds the safety invariants (election safety,
 committed-prefix agreement, commit monotonicity) into a violation
-accumulator, and the health planes feed a time-to-reelect / MTTR
-accumulator (``health.chaos_report`` formats the host-side summary).
+accumulator, and the health planes feed a time-to-reelect / MTTR accumulator
+(``health.chaos_report`` formats the host-side summary).
 
 Plan JSON (see docs/OBSERVABILITY.md "Chaos" and tests/testdata/chaos/)::
 
@@ -37,10 +37,8 @@ per-edge drops — :func:`host_masks` / :func:`host_loss_draw` are the numpy
 mirrors of the device schedule and must stay bit-identical
 (tests/test_chaos_parity.py).
 
-Since the runner-registry refactor the compiled runner is BUILT by the
-unified factory (raft_tpu/multiraft/runner.py) from the schedules.py
-registry row set; :func:`make_runner` here is a thin behavior-neutral
-wrapper (GC018 machine-checks the closure, GC014 pins the jaxpr).
+The compiled runner is built by ``runner.make_runner`` from the
+schedules.py registry rows; this module knows nothing of the runner.
 """
 
 from __future__ import annotations
@@ -54,7 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from . import kernels
-from . import sim as sim_mod
 
 
 # Group selectors: "all", an explicit id list, or {"mod": m, "eq": r}.
@@ -131,7 +128,7 @@ def plan_from_dict(doc: Dict[str, object]) -> ChaosPlan:
 
 
 def load_plan(path: str) -> ChaosPlan:
-    """Load a ChaosPlan from a JSON file (the bench.py --chaos input)."""
+    """Load a ChaosPlan from a JSON file (examples/chaos/)."""
     with open(path, "r", encoding="utf-8") as f:
         return plan_from_dict(json.load(f))
 
@@ -182,7 +179,8 @@ class CompiledChaos(NamedTuple):
     traffic shrinks ~6x at P = 5 (byte-per-bool [P, P, G] planes become
     ceil(P*P/32) uint32 words per group).  schedule_masks returns the
     planes UNPACKED — the step sees bit-identical masks either way
-    (pinned by tests/test_chaos_parity.py's run_plan-vs-stepping case).
+    (pinned by tests/test_chaos_parity.py's run_plan-vs-stepping case,
+    ClusterSim.run_plan).
 
     phase_of_round: int32[R]                round -> phase index
     link_packed:    uint32[NPH, Wl, G]      per-phase base link plane,
@@ -252,8 +250,8 @@ def _compile_arrays(
             crashed[i, _peer_index(pid, P, "crash", i)] = gsel
         append[i] = np.where(gsel, ph.append, 0)
     # The chaos-stats accumulator sums per-group indicators over the run in
-    # int32 (see run_plan); bound the schedule so it provably cannot wrap
-    # (the GC008 discipline, derived in docs/STATIC_ANALYSIS.md).
+    # int32 (update_chaos_stats); bound the schedule so it provably cannot
+    # wrap (the GC008 discipline, derived in docs/STATIC_ANALYSIS.md).
     if plan.n_rounds * max(1, G) >= 2**31:
         raise ValueError(
             f"plan spans {plan.n_rounds} rounds x {G} groups >= 2**31 "
@@ -298,7 +296,7 @@ def schedule_planes(
     """Device-side (base_link, loss_rate, crashed, append) for one round:
     the round's phase row gathered and unpacked WITHOUT the loss sample
     knocked out.  schedule_masks is the per-round consumer; the split
-    fused dispatch (reconfig.make_split_runner) needs the base plane for
+    fused dispatch (the reconfig split runner) needs the base plane for
     its steady predicate and the raw rates for the in-kernel draw — both
     constant across a phase, so one gather covers a whole fused block."""
     P = compiled.n_peers
@@ -472,58 +470,3 @@ def update_chaos_stats(
     return out.at[CS_MAX_STREAK].set(
         jnp.maximum(stats[CS_MAX_STREAK], jnp.max(new_leaderless))
     )
-
-
-def make_runner(cfg: sim_mod.SimConfig, compiled: CompiledChaos):
-    """Build the jitted whole-scenario runner: one lax.scan over every
-    round of the compiled schedule with zero host round trips inside —
-    per-round masks gathered on device, the link-gated step, the safety
-    fold, and the MTTR stats fold all fuse into the scan body.
-
-    The schedule arrays enter the jit as RUNTIME ARGUMENTS, not closure
-    captures: a closed-over schedule is baked into the jaxpr as consts
-    (GC012 constant-capture — the whole packed schedule duplicated into
-    the executable, defeating the compile cache per plan).  Only the
-    schedule SHAPES (n_rounds, phase count) specialize the compile.
-
-    Returns a callable (state, health) -> (state', health',
-    stats[N_CHAOS_STATS], safety[N_SAFETY]); state and health are
-    donated, the schedule arrays are not (bench reps reuse them).  With
-    SimConfig(blackbox=True) the signature gains a sim.BlackboxState —
-    (state, health, blackbox) -> (state', health', blackbox', stats,
-    safety) — and each round folds kernels.check_safety_groups instead,
-    summing the per-group indicators into the identical safety counts
-    while the black box records the offending (group, round) pairs; the
-    blackbox=False graph is byte-identical to the pre-forensics build.
-    Build once and call repeatedly — each make_runner call compiles
-    afresh.  The underlying jit and its trailing schedule arguments are
-    exposed as ``runner.jitted`` / ``runner.schedule_args`` for the
-    graftcheck trace audit (tools/graftcheck/trace/inventory.py).
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxpr (GC014 pins it).
-    """
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(cfg, (compiled,))
-
-
-def run_plan(
-    cfg: sim_mod.SimConfig,
-    state: sim_mod.SimState,
-    compiled: CompiledChaos,
-    health: Optional[sim_mod.HealthState] = None,
-) -> Tuple[sim_mod.SimState, sim_mod.HealthState, jnp.ndarray, jnp.ndarray]:
-    """Execute a whole compiled scenario in one jitted lax.scan.
-
-    Returns (state', health', stats[N_CHAOS_STATS], safety[N_SAFETY]) —
-    all device arrays; nothing crosses to the host inside the run.  The
-    health planes are REQUIRED (the MTTR stats ride on HP_LEADERLESS):
-    pass an existing HealthState to continue its windows, or None to start
-    fresh.
-    """
-    if health is None:
-        health = sim_mod.init_health(cfg)
-    return make_runner(cfg, compiled)(state, health)

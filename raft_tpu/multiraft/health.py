@@ -68,7 +68,7 @@ class HealthMonitor:
     def summary_dict(counts, lag_hist, worst_ids, worst_scores) -> dict:
         """THE summary shape (module docstring) from the four reduction
         vectors, in kernels.health_summary's return order — the single
-        formatter every producer (ClusterSim, MultiRaft, bench.py) goes
+        formatter every producer (ClusterSim, MultiRaft) goes
         through so the consumers can never see a drifted shape."""
         from .kernels import HEALTH_COUNT_NAMES
 
@@ -128,8 +128,8 @@ class HealthMonitor:
                 harness asserts it.
         rounds: rounds executed (python int, from the compiled plan).
 
-        Returns the scenario-summary dict bench.py --chaos emits as a CI
-        artifact::
+        Returns the scenario-summary dict ClusterSim.run_plan returns
+        (tools/chaos_churn_report.py writes it as a CI artifact)::
 
             {"rounds": R,
              "mttr_rounds": mean leaderless-episode length (None when no
@@ -171,9 +171,7 @@ class HealthMonitor:
         group still inside a joint config (outgoing half non-empty)
         whose commit has been flat for `stall_timeouts * election_tick`
         rounds — the existing commit-stall health plane joined with the
-        joint bit, no new device plane.  Shared by
-        ClusterSim.run_reconfig and bench.py --reconfig so the threshold
-        and ranking cannot drift between the two surfaces.  Returns
+        joint bit, no new device plane.  Returns
         (stalled_count, worst_group_ids) with worst ranked by staleness,
         capped at `topk`."""
         import numpy as np
@@ -211,8 +209,8 @@ class HealthMonitor:
                  derived from the existing commit-stall health plane plus
                  the joint bit (no new device plane).
 
-        Returns the scenario-summary dict bench.py --reconfig and
-        tools/reconfig_report.py emit as CI artifacts.
+        Returns the scenario-summary dict ClusterSim.run_reconfig returns
+        and tools/reconfig_report.py writes as a CI artifact.
         """
         from .chaos import CS_MAX_STREAK, CS_REELECTIONS, CS_HEALED_ROUNDS
         from .kernels import SAFETY_NAMES

@@ -1,6 +1,5 @@
 """ctypes bindings for the native C++ multi-group Raft engine
-(cpp/multiraft_engine.cpp) — the framework's native scalar runtime and the
-CPU anchor for bench.py.
+(cpp/multiraft_engine.cpp) — the framework's native scalar runtime.
 
 The shared library is built with g++ on first use (no pybind11 in the image;
 plain C ABI via ctypes) and keyed on the SOURCE'S CONTENT: it lives at

@@ -13,7 +13,7 @@ per-call traffic (at k = 1 the general XLA step's own fusion is the
 competitor), the lane block is sized from each family's operand list to a
 scoped-VMEM budget (`_tiling`), and whether Pallas interprets or Mosaic
 compiles is raft_tpu.platform's decision, never a caller's.  Speeds come
-from `python bench.py` on the chip; none is quoted here.
+from `python3 benchmark/run.py` on the chip (PERF.md); none is quoted here.
 
 `steady_predicate(cfg, st, crashed, horizon=k)` decides whether the
 invariant provably holds for the next k rounds; `fast_multi_round` then
@@ -21,7 +21,7 @@ lax.cond's between the fused kernel and k sequential general steps, so the
 fast path is a pure optimization with IDENTICAL semantics
 (tests/test_pallas_step.py asserts bit-parity round by round; the crashed
 mask and per-round append workload are held constant across the k rounds,
-which is exactly the lockstep schedule ScalarCluster/bench drive).
+which is exactly the lockstep schedule ScalarCluster drives).
 
 Coverage matrix (docs/PERF.md): the INSTRUMENTED configurations ride the
 fused path too — `with_health` tracks ticks_since_commit in-kernel and
@@ -1432,7 +1432,7 @@ def steady_mask(
     whose entry gate passes (kernels.lease_read, heartbeat_tick == 1)
     provably serves every in-horizon lease fire at latency 0 and the
     workload split runner folds those receipts closed-form
-    (workload.make_split_runner; fused-vs-general bit-parity in
+    (the workload split runner; fused-vs-general bit-parity in
     tests/test_workload.py).  None keeps every existing graph
     unchanged."""
     for flag in planes.steady_defuse_flags():
@@ -1440,7 +1440,7 @@ def steady_mask(
         # today only `blackbox`, ISSUE 15): the fused kernel cannot fold
         # these rows' per-round wave-path writes (the black-box ring
         # trace), so configs enabling them reject every fused horizon and
-        # ride the general path; bench.py --blackbox measures the cost,
+        # ride the general path,
         # and graphs with every defuse flag off are untouched (this is a
         # python-level branch on static config fields).
         if getattr(cfg, flag):  # graftcheck: allow-no-python-branch-on-traced — `flag` names a static SimConfig bool (registry steady == "defuse"; GC016 pins the field's existence), so this getattr is a trace-time constant
@@ -1653,10 +1653,10 @@ def fast_multi_round(
     With `count_fused`, the fn takes ONE extra trailing int32[] argument —
     the fused GROUP-round accumulator — and returns it (appended last)
     incremented by k * n_groups when the fused branch ran, unchanged
-    otherwise.  This is the measured fused-fraction metric (bench.py
-    `fused_frac`): an exact in-graph count, not a log line.  int32 bound:
-    the caller keeps total group-rounds below 2**31 (bench.py drains it
-    per run).  count_fused=False leaves every existing graph unchanged."""
+    otherwise.  This is the measured fused-fraction metric
+    (`fused_frac`): an exact in-graph count, not a log line.  int32 bound:
+    the caller keeps total group-rounds below 2**31 (draining it per
+    run).  count_fused=False leaves every existing graph unchanged."""
     pallas_fn = steady_round(
         cfg,
         rounds=k,

@@ -1,7 +1,6 @@
 """Device-resident membership churn: declarative joint-consensus reconfig
 plans compiled into on-device schedules for the batched sim (BASELINE
-config 4; ROADMAP item 4 — compile reconfig the way chaos.py compiles
-fault schedules).
+config 4), the way chaos.py compiles fault schedules.
 
 A :class:`ReconfigPlan` is a list of phases; a phase may carry ONE
 conf-change op (add/remove voter, add/promote learner, explicit
@@ -11,9 +10,9 @@ driving the scalar ``confchange.Changer`` — every transition is validated
 and its target masks computed by the reference's own rules (one voter per
 simple step, outgoing := old incoming on joint-entry, ``learners_next``
 staging, materialized on leave) — into dense per-op schedule arrays;
-:func:`make_runner` then executes the whole multi-phase scenario inside
-ONE jitted ``lax.scan`` with zero host round trips, composable with a
-compiled :class:`chaos.ChaosPlan` of equal length in the SAME scan
+``runner.make_runner`` then executes the whole multi-phase scenario
+inside ONE jitted ``lax.scan`` with zero host round trips, composable
+with a compiled :class:`chaos.ChaosPlan` of equal length in the SAME scan
 (reconfig *during* partition/loss/crash — the Jepsen-style killer
 scenario).
 
@@ -77,12 +76,11 @@ per-round), and the stats accumulators count at most one event per
 int32 accumulators provably cannot wrap (the GC008 discipline,
 docs/STATIC_ANALYSIS.md).
 
-Since the runner-registry refactor the compiled runners are BUILT by the
-unified factory (raft_tpu/multiraft/runner.py) from the schedules.py
-registry; :func:`make_runner` / :func:`make_split_runner` here are thin
-behavior-neutral wrappers, while ``_runner_body`` — the one shared
-per-round scan body every runner variant closes over — STAYS in this
-module (GC018 machine-checks the closure, GC014 pins the jaxprs).
+The compiled runners are built by ``runner.make_runner`` from the
+schedules.py registry; this module knows nothing of the runner.
+``_runner_body`` — the one per-round scan body every runner variant
+closes over — lives here (GC018 machine-checks the closure, GC014 pins
+the jaxprs).
 """
 
 from __future__ import annotations
@@ -176,7 +174,7 @@ def plan_from_dict(doc: Dict[str, object]) -> ReconfigPlan:
 
 
 def load_plan(path: str) -> ReconfigPlan:
-    """Load a ReconfigPlan from a JSON file (bench.py --reconfig)."""
+    """Load a ReconfigPlan from a JSON file (examples/reconfig/)."""
     with open(path, "r", encoding="utf-8") as f:
         return plan_from_dict(json.load(f))
 
@@ -481,6 +479,30 @@ def compile_plan(plan: ReconfigPlan, n_groups: int) -> CompiledReconfig:
         added=jnp.asarray(added, dtype=bool),
         removed=jnp.asarray(removed, dtype=bool),
         n_peers=plan.n_peers,
+    )
+
+
+def empty_reconfig_schedule(
+    n_rounds: int, n_peers: int, n_groups: int
+) -> CompiledReconfig:
+    """A no-op CompiledReconfig spanning `n_rounds`: zero ops, zero extra
+    append — composing it with a chaos schedule through _runner_body
+    reproduces the plain chaos runner's protocol exactly (the op-protocol
+    carry provably never moves).  The workload runners run it when no
+    reconfig plan is given; the autopilot starts every horizon on it and
+    swaps in a real evacuation schedule only when the policy fires."""
+    P, G = n_peers, n_groups
+    return CompiledReconfig(
+        phase_of_round=jnp.zeros((n_rounds,), jnp.int32),
+        append=jnp.zeros((1, G), jnp.int32),
+        op_start=jnp.full((1, G), NO_ROUND, jnp.int32),
+        n_ops=jnp.zeros((G,), jnp.int32),
+        tgt_voter=jnp.zeros((1, P, G), bool),
+        tgt_outgoing=jnp.zeros((1, P, G), bool),
+        tgt_learner=jnp.zeros((1, P, G), bool),
+        added=jnp.zeros((1, P, G), bool),
+        removed=jnp.zeros((1, P, G), bool),
+        n_peers=P,
     )
 
 
@@ -826,8 +848,8 @@ def _validate_plans(
     compiled: CompiledReconfig,
     chaos_compiled: Optional[chaos_mod.CompiledChaos],
 ) -> None:
-    """The shared runner-input compatibility checks (make_runner and
-    make_split_runner): equal horizons, agreeing peer counts."""
+    """The runner-input compatibility checks of the reconfig scan and
+    split runners: equal horizons, agreeing peer counts."""
     if chaos_compiled is not None:
         if chaos_compiled.n_rounds != compiled.n_rounds:
             raise ValueError(
@@ -854,15 +876,15 @@ def _runner_body(
 ):
     """One general round of the compiled reconfig(+chaos) scenario as a
     lax.scan body over the absolute round index — the SINGLE source of the
-    op propose/gate/apply protocol, shared by make_runner's whole-horizon
-    scan, make_split_runner's general segments / fused-block fallback,
-    the autopilot's cadence segments (autopilot.make_cadence_runner), and
-    the client-workload runner (workload.make_runner).
+    op propose/gate/apply protocol, shared by every runner.make_runner
+    variant: the reconfig runner's whole-horizon scan, the split runners'
+    general segments / fused-block fallback, the autopilot's cadence
+    segment, and the client-workload runner.
 
     Carry: (state, health, rstate, stats, rstats, safety) with an
     [N_COUNTERS] int32 plane appended when `with_counters` (the split
-    runner's production configuration threads it; make_runner keeps the
-    historical carry and graph).
+    runner's production configuration threads it; the scan runner keeps
+    the historical carry and graph).
 
     `actions` (ISSUE 12, the autopilot's device-resident actuation) is an
     optional (action_round, transfer_plane int32[G], kick_plane
@@ -1126,125 +1148,3 @@ def _runner_body(
         return out, ()
 
     return body
-
-
-def make_runner(
-    cfg: sim_mod.SimConfig,
-    compiled: CompiledReconfig,
-    chaos_compiled: Optional[chaos_mod.CompiledChaos] = None,
-):
-    """Build the jitted whole-scenario runner: ONE lax.scan over every
-    round of the compiled reconfig schedule — per-round op eligibility,
-    the conf-entry propose/gate/apply protocol, the joint-window safety
-    fold, and the MTTR/reconfig stats folds all fuse into the scan body
-    with zero host round trips.  `chaos_compiled` (optional, equal
-    n_rounds/n_peers) threads a compiled fault schedule through the SAME
-    scan: the link/crash/loss masks gather exactly as chaos.make_runner's
-    (chaos.schedule_masks is shared), so membership changes run *during*
-    partitions.
-
-    Like the chaos runner, every schedule array enters the jit as a
-    RUNTIME ARGUMENT (GC012: a closed-over schedule would bake into the
-    jaxpr as consts); only the shapes specialize the compile.  Returns a
-    callable (state, health, rstate) -> (state', health', rstate',
-    stats[N_CHAOS_STATS], rstats[N_RECONFIG_STATS], safety[N_SAFETY]);
-    state/health/rstate are donated.  ``runner.jitted`` /
-    ``runner.schedule_args`` are exposed for the graftcheck trace audit.
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxpr (GC014 pins it).
-    """
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(cfg, (compiled, chaos_compiled))
-
-
-def make_split_runner(
-    cfg: sim_mod.SimConfig,
-    compiled: CompiledReconfig,
-    chaos_compiled: Optional[chaos_mod.CompiledChaos] = None,
-    k: int = 8,
-    window: int = 4,
-    with_counters: bool = False,
-):
-    """Build the SPLIT-HORIZON scenario runner (ISSUE 11): the same
-    protocol as make_runner — bit-identical end state, health planes,
-    op-protocol carry, and stats/safety accumulators — but the horizon is
-    split at reconfig op boundaries (`split_plan`) so the steady stretches
-    BETWEEN ops ride the fused Pallas kernel instead of the whole horizon
-    hard-rejecting because one op is scheduled somewhere.
-
-    Execution shape: planned general segments (op windows, joint
-    intervals, phase-cut remainders) run the per-round `_runner_body`
-    scan exactly like make_runner; planned fused segments run k-round
-    blocks, each a lax.cond between the fused steady kernel
-    (pallas_step.steady_round with health[, counters][, chaos loss]) and
-    the same k general rounds — guarded at runtime by
-    `steady_mask(reconfig_pending=pending_in_horizon(...),
-    loss_rate=...)` over the whole batch, so a retry tail that outlives
-    its planned window, an unsettled election, or a lossy chaos phase
-    falls back honestly.  A fused block provably cannot move the
-    op-protocol carry, the masks, the rstats, or the safety accumulator
-    (no op is eligible, the config is not joint, and every check_safety
-    slot is zero on a steady horizon — pinned by the split-vs-unsplit
-    parity suite), and its MTTR fold is the closed form of k leaderful
-    rounds; only `prev_voter`/`prev_outgoing` refresh so the next general
-    round's transition audit sees (unchanged -> current).
-
-    Dispatch is a short host loop over segments (a handful of jitted
-    calls with the carry donated end to end, schedule arrays as runtime
-    args per GC012) rather than make_runner's single scan: segment count
-    is O(ops), and async dispatch keeps the device busy across the
-    boundaries.
-
-    `with_counters` threads the [N_COUNTERS] int32 plane through both
-    branches (the production configuration); the caller drains it — the
-    GC008 bound is the caller's: n_rounds x G x events-per-group-round
-    must stay below 2**31 within one run (compile_plan already bounds
-    n_rounds x G).
-
-    Returns a callable runner(st, hl, rst[, counters]) ->
-    (st', hl', rst', stats, rstats, safety, fused_rounds[, counters']).
-    `fused_rounds` is an int32 scalar of fused GROUP-rounds (k x n_groups
-    per fused block that engaged); total group-rounds is
-    compiled.n_rounds x cfg.n_groups, so fused_frac = fused_rounds /
-    total — the measured number behind bench.py's `fused_frac` field.
-    st/hl/rst (and counters) are donated.  ``runner.segments``,
-    ``runner.fused_jit``, ``runner.general_jits`` and
-    ``runner.schedule_args`` are exposed for tests and the graftcheck
-    trace audit.
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxprs (GC014 pins it)."""
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(
-        cfg, (compiled, chaos_compiled), split=True, k=k, window=window,
-        with_counters=with_counters,
-    )
-
-
-def run_plan(
-    cfg: sim_mod.SimConfig,
-    state: sim_mod.SimState,
-    compiled: CompiledReconfig,
-    health: Optional[sim_mod.HealthState] = None,
-    rstate: Optional[ReconfigState] = None,
-    chaos_compiled: Optional[chaos_mod.CompiledChaos] = None,
-):
-    """Execute a whole compiled reconfig(+chaos) scenario in one jitted
-    lax.scan.  Returns (state', health', rstate', stats[N_CHAOS_STATS],
-    rstats[N_RECONFIG_STATS], safety[N_SAFETY]) — all device arrays;
-    nothing crosses to the host inside the run.  Health planes are
-    REQUIRED (MTTR stats ride on HP_LEADERLESS)."""
-    if health is None:
-        health = sim_mod.init_health(cfg)
-    if rstate is None:
-        rstate = init_reconfig_state(state)
-    return make_runner(cfg, compiled, chaos_compiled)(
-        state, health, rstate
-    )

@@ -1,17 +1,12 @@
-"""The unified compiled-runner factory (ROADMAP item 5, runner half):
-ONE :func:`make_runner` entry point instantiates every whole-scenario
-runner — chaos-only, reconfig(+chaos), client workload, the two
-split-horizon variants, and the autopilot cadence segment — from the
-schedule registry (schedules.py) over the shared scan body
-(``reconfig._runner_body``).
+"""The compiled-runner factory: ONE entry point, :func:`make_runner`,
+builds every whole-scenario runner — chaos-only, reconfig(+chaos), client
+workload, the two split-horizon variants, and the autopilot cadence
+segment — from the schedule registry (schedules.py) over the shared scan
+body (``reconfig._runner_body``).  Its docstring is the dispatch table
+and each variant's contract.
 
-The legacy entry points (``chaos.make_runner``, ``reconfig.make_runner``
-/ ``make_split_runner``, ``workload.make_runner`` /
-``make_split_runner``, ``autopilot.make_cadence_runner``) are thin
-behavior-neutral wrappers over this module: same signatures, same
-donation, same outputs, byte-identical jaxprs (the GC014 budget pins
-it; tests/test_runner_unified.py replays each wrapper against the
-descriptor-built runner bit-for-bit).
+The schedule modules (chaos, reconfig, workload) know nothing of this
+one; ``ClusterSim`` and the autopilot call it.
 
 Registry discipline (GC018): every schedule array crosses the jit
 boundary as a RUNTIME argument (GC012) in its family's registry order —
@@ -20,21 +15,6 @@ way schedule tuples are assembled or rebound here, so the flat arg
 order, the compiled NamedTuple field order, and the registry rows
 cannot drift apart.  Hand-listing a schedule tuple or reading a
 closed-over compiled schedule inside a jitted body fails the build.
-
-Dispatch shape::
-
-    make_runner(cfg, [chaos_c])                      -> chaos runner
-    make_runner(cfg, [reconfig_c, chaos_c])          -> reconfig runner
-    make_runner(cfg, [reconfig_c, chaos_c],
-                split=True, k=8, window=4)           -> reconfig split
-    make_runner(cfg, [client_c, chaos_c, reconfig_c]) -> workload runner
-    make_runner(cfg, [client_c], split=True, k=8)    -> workload split
-    make_runner(cfg, [reconfig_c, chaos_c],
-                cadence=rounds, fused=...)           -> cadence segment
-
-Compiled schedules are classified by type (chaos.CompiledChaos,
-reconfig.CompiledReconfig, workload.CompiledClient); ``None`` entries
-are skipped so call sites can pass optional schedules straight through.
 """
 
 from __future__ import annotations
@@ -126,8 +106,7 @@ def rebuild_scheds(compiled, chaos_compiled, sched_args):
     return sched, chaos_sched
 
 
-# --- the runner constructors (moved verbatim from the four legacy
-# entry points; the wrappers there delegate here) ----------------------------
+# --- the runner constructors (make_runner's docstring has each contract) ----
 
 
 def _tail_audit(
@@ -151,9 +130,9 @@ def _tail_audit(
 
 
 def _make_chaos(cfg: sim_mod.SimConfig, compiled: chaos_mod.CompiledChaos):
-    """The chaos-only whole-scenario runner (chaos.make_runner's
-    contract): its own lean scan body — no op protocol, no read carry —
-    so the chaos_runner@* jaxpr budgets stay at step + chaos gather."""
+    """The chaos-only whole-scenario runner: its own lean scan body — no
+    op protocol, no read carry — so the chaos_runner@* jaxpr budgets stay
+    at step + chaos gather."""
     n_rounds = compiled.n_rounds
     with_bb = cfg.blackbox
 
@@ -232,8 +211,8 @@ def _make_reconfig(
     compiled: reconfig_mod.CompiledReconfig,
     chaos_compiled: Optional[chaos_mod.CompiledChaos],
 ):
-    """The reconfig(+chaos) whole-scenario runner (reconfig.make_runner's
-    contract): one scan of _runner_body with the tail transition audit."""
+    """The reconfig(+chaos) whole-scenario runner: one scan of
+    _runner_body with the tail transition audit."""
     n_rounds = compiled.n_rounds
     reconfig_mod._validate_plans(cfg, compiled, chaos_compiled)
 
@@ -311,22 +290,22 @@ def _make_reconfig_split(
     window: int,
     with_counters: bool,
 ):
-    """The split-horizon reconfig runner (reconfig.make_split_runner's
-    contract): planned general segments scan _runner_body; planned fused
-    segments ride pallas_step.steady_round behind the steady predicate."""
+    """The split-horizon reconfig runner: planned general segments scan
+    _runner_body; planned fused segments ride pallas_step.steady_round
+    behind the steady predicate."""
     from . import pallas_step  # deferred: keeps the factory importable sans pallas
 
     n_rounds = compiled.n_rounds
     P, G = cfg.n_peers, cfg.n_groups
     if not cfg.collect_health:
         raise ValueError(
-            "make_split_runner needs SimConfig(collect_health=True) — the "
+            "a split runner needs SimConfig(collect_health=True) — the "
             "MTTR stats and the fused block's closed-form fold ride on the "
             "health planes"
         )
     if cfg.blackbox:
         raise ValueError(
-            "make_split_runner does not thread the black box (v1: "
+            "a split runner does not thread the black box (v1: "
             "steady_mask rejects blackbox-on horizons, so nothing would "
             "fuse) — use the unsplit runner; ClusterSim.run_reconfig"
             "(split=True) falls back automatically"
@@ -499,14 +478,12 @@ def _make_workload(
     chaos_compiled: Optional[chaos_mod.CompiledChaos],
     reconfig_compiled: Optional[reconfig_mod.CompiledReconfig],
 ):
-    """The client-workload whole-scenario runner (workload.make_runner's
-    contract): _runner_body with the read protocol threaded; a missing
-    reconfig plan runs the no-op schedule."""
+    """The client-workload whole-scenario runner: _runner_body with the
+    read protocol threaded; a missing reconfig plan runs the no-op
+    schedule."""
     workload_mod._validate(cfg, client, chaos_compiled, reconfig_compiled)
     if reconfig_compiled is None:
-        from .autopilot import empty_reconfig_schedule
-
-        reconfig_compiled = empty_reconfig_schedule(
+        reconfig_compiled = reconfig_mod.empty_reconfig_schedule(
             client.n_rounds, cfg.n_peers, cfg.n_groups
         )
     n_rounds = client.n_rounds
@@ -598,27 +575,27 @@ def _make_workload_split(
     chaos_compiled,
     reconfig_compiled,
 ):
-    """The fused client-workload runner (workload.make_split_runner's
-    contract): k-round blocks behind the steady + provably-servable-lease
-    predicate, lease receipts folded closed-form on the fast arm."""
+    """The fused client-workload runner: k-round blocks behind the
+    steady + provably-servable-lease predicate, lease receipts folded
+    closed-form on the fast arm."""
     from . import pallas_step
 
     if chaos_compiled is not None or reconfig_compiled is not None:
         raise ValueError(
-            "make_split_runner runs bare client plans; compose chaos/"
-            "reconfig schedules through the unsplit runner (or the "
-            "reconfig split machinery) instead"
+            "the workload split runner runs bare client plans; compose "
+            "chaos/reconfig schedules through the unsplit runner (or the "
+            "reconfig split runner) instead"
         )
     if cfg.blackbox:
         raise ValueError(
-            "make_split_runner does not thread the black box (v1: "
+            "a split runner does not thread the black box (v1: "
             "steady_mask rejects blackbox-on horizons, so nothing would "
             "fuse) — use the unsplit runner; ClusterSim.run_reads"
             "(split=True) falls back automatically"
         )
     if not cfg.collect_health:
         raise ValueError(
-            "make_split_runner needs SimConfig(collect_health=True) — "
+            "a split runner needs SimConfig(collect_health=True) — "
             "the MTTR stats and the fused block's closed-form fold ride "
             "on the health planes"
         )
@@ -629,9 +606,7 @@ def _make_workload_split(
             "at most one churn-window crossing per block"
         )
     workload_mod._validate(cfg, client, None, None)
-    from .autopilot import empty_reconfig_schedule
-
-    reconfig_sched = empty_reconfig_schedule(
+    reconfig_sched = reconfig_mod.empty_reconfig_schedule(
         client.n_rounds, cfg.n_peers, cfg.n_groups
     )
     n_rounds = client.n_rounds
@@ -811,10 +786,10 @@ def _make_cadence(
     rounds: int,
     fused: bool,
 ):
-    """One jitted autopilot cadence segment (make_cadence_runner's
-    contract): `rounds` scan iterations of _runner_body with the action
-    planes applied at the segment's first round, plus the commit-stall
-    fold; `fused=True` adds the steady fast path behind a cond."""
+    """One jitted autopilot cadence segment: `rounds` scan iterations of
+    _runner_body with the action planes applied at the segment's first
+    round, plus the commit-stall fold; `fused=True` adds the steady fast
+    path behind a cond."""
     if not cfg.collect_health:
         raise ValueError("the autopilot needs SimConfig(collect_health=True)")
     if not cfg.transfer:
@@ -986,15 +961,109 @@ def make_runner(
     """Build a compiled whole-scenario runner from compiled schedules.
 
     `schedules` is any mix of chaos.CompiledChaos,
-    reconfig.CompiledReconfig, and workload.CompiledClient (at most one
-    each; None entries skipped) — the variant is picked by what is
-    present plus the `split` / `cadence` selectors (see the module
-    docstring for the dispatch table and each legacy wrapper's docstring
-    for the variant's full contract).  `cadence=rounds` builds one
-    autopilot cadence segment and returns the bare jit; every other
-    variant returns the wrapped runner with ``.jitted`` /
-    ``.schedule_args`` (and the split runners' block jits) exposed for
-    the graftcheck trace audit.
+    reconfig.CompiledReconfig and workload.CompiledClient (at most one
+    each; None entries skipped, so optional schedules pass straight
+    through).  What is present, plus `split` / `cadence`, picks the
+    variant::
+
+        [chaos]                               chaos scan
+        [reconfig, chaos?]                    reconfig scan
+        [reconfig, chaos?], split=True        reconfig split (k, window,
+                                              with_counters)
+        [client, chaos?, reconfig?]           workload scan
+        [client], split=True                  workload split (k)
+        [reconfig, chaos?], cadence=rounds    autopilot cadence segment
+                                              (fused)
+
+    Common to all: every schedule array enters the jit as a RUNTIME
+    argument (GC012; only shapes specialize the compile, so one runner
+    serves every plan of the same shapes), the protocol carry is donated
+    and the schedule arrays are not, nothing crosses to the host inside a
+    run, and each call of make_runner compiles afresh — build once, call
+    repeatedly.  Schedules composed in one runner span equal rounds and
+    agree on peers.  Health planes are required (the MTTR stats ride on
+    HP_LEADERLESS).  Every variant but the cadence segment returns a
+    wrapped callable with ``.schedule_args`` (and ``.jitted`` on the scan
+    runners) exposed for the graftcheck trace audit.
+
+    chaos scan: (state, health) -> (state', health',
+    stats[N_CHAOS_STATS], safety[N_SAFETY]); one lax.scan — per-round
+    masks gathered on device, the link-gated sim.step, the safety fold
+    and the MTTR fold.  With SimConfig(blackbox=True) a BlackboxState
+    follows `health` in and out and each round folds
+    kernels.check_safety_groups (the same counts, per group).
+
+    reconfig scan: (state, health, rstate) -> (state', health', rstate',
+    stats, rstats[N_RECONFIG_STATS], safety); one scan of
+    reconfig._runner_body — op eligibility, the propose/gate/apply
+    protocol, the joint-window audit — with the chaos masks, when given,
+    gathered exactly as the chaos scan's, so membership changes run
+    during partitions; a tail audit covers a final-round apply.
+
+    reconfig split: the same protocol, bit-identical state, health,
+    op-protocol carry and accumulators, but the horizon is cut at op
+    boundaries (reconfig.split_plan, `window` rounds around each op).
+    Planned general segments scan _runner_body; planned fused segments
+    run `k`-round blocks, each a lax.cond between
+    pallas_step.steady_round (with health[, counters][, chaos loss]) and
+    the same k general rounds, guarded at run time by steady_mask(
+    reconfig_pending=pending_in_horizon(...), loss_rate=...) over the
+    whole batch — a retry tail that outlives its window, an unsettled
+    election or a lossy phase falls back.  A fused block cannot move the
+    op carry, the masks, the rstats or the safety accumulator (no op is
+    eligible, the config is not joint, every check_safety slot is zero
+    on a steady horizon); its MTTR fold is the closed form of k leaderful
+    rounds, and only prev_voter / prev_outgoing refresh.  A short host
+    loop dispatches the segments (O(ops) jitted calls, carry donated end
+    to end).  `with_counters` threads the [N_COUNTERS] plane through both
+    arms; the caller drains it and owns the GC008 bound (n_rounds x G x
+    events per group-round < 2**31 in one run).  (st, hl, rst[, ctrs]) ->
+    (st', hl', rst', stats, rstats, safety, fused_rounds[, ctrs']):
+    `fused_rounds` is the int32 count of fused GROUP-rounds, so
+    fused_frac = fused_rounds / (n_rounds x n_groups).  Also exposes
+    ``.segments``, ``.fused_jit``, ``.general_jits``.
+
+    workload scan: (state, health, rstate, read_carry) -> (state',
+    health', rstate', stats, rstats, safety, read_carry',
+    read_stats[N_READ_STATS], lat_hist[N_LAT_BUCKETS]); _runner_body with
+    the read protocol threaded — fires, retries, serves, the write skew,
+    the latency fold and the full audit including the linearizability
+    slots, every round.  A missing reconfig plan runs the no-op schedule
+    (reconfig.empty_reconfig_schedule), whose op protocol never moves.
+
+    workload split: the workload scan's outputs plus a trailing
+    fused_rounds, bit-identical, run as `k`-round blocks.  A block takes
+    the fused kernel when, at run time, the steady invariant holds for
+    the horizon (pallas_step.steady_mask, damping conditions included),
+    no quorum-round read work touches it (an outstanding read of any mode
+    or a scheduled Safe fire rejects: steady_mask(read_pending=
+    workload.reads_pending_in_horizon(...))), and every scheduled LEASE
+    fire is provably servable: the block spans one client phase, the
+    acting leader passes kernels.lease_read at block entry, and
+    heartbeat_tick == 1 re-saturates recent_active every round.  The
+    fused arm folds the receipts closed-form (every fire served in its
+    round: lat_hist[0], issued and served_lease += fires; the read carry
+    stays empty; every safety slot zero).  Bare client plans only.  What
+    a block needs of the schedule is tabled once, when the runner is
+    built (workload.block_tables; block b's operands are
+    ``.block_args[b]``), so a block's cost does not follow the schedule's
+    length; only the general arm reads the planes.  Also exposes
+    ``.fused_jit``.
+
+    cadence segment: returns the bare jit (st, hl, rst, stats, rstats,
+    safety[, blackbox], cs_rounds, r0, transfer_plane, kick_plane,
+    *schedule_args) -> the advanced carry + a trailing fused group-rounds
+    scalar, the carry donated: `cadence` scan rounds of _runner_body from
+    absolute round r0 with the action planes applied at the first, plus
+    the per-round commit-stall fold (group-rounds at or over
+    SimConfig.commit_stall_ticks).  `fused=True` puts the whole segment
+    behind a lax.cond, bit-identical to the scan when taken: the steady
+    predicate over its horizon (which rejects pending transfers and
+    scheduled ops) AND no action this segment AND one schedule phase AND
+    an alive voter quorum on a loss-free horizon with a positive append
+    everywhere (so the closed-form commit-stall fold is exactly zero).
+    Needs SimConfig(collect_health=True, transfer=True) and a reconfig
+    schedule (the no-op one at rest).
     """
     by_family: Dict[str, object] = {}
     for s in schedules:

@@ -1,17 +1,11 @@
 """The schedule registry: one declarative row per compiled schedule
 array, one family per schedule pipeline, one variant per compiled
-runner graph — the single source of truth the unified runner
-(raft_tpu/multiraft/runner.py), the host twins, and the graftcheck
-closure rules all read (ROADMAP item 5, runner half; the plane half is
-planes.py).
-
-Before this registry, the four runner entry points (chaos.make_runner,
-reconfig.make_runner / make_split_runner, workload.make_runner,
-autopilot.make_cadence_runner) each hand-assembled the same scan: a
-hand-listed flat tuple of schedule arrays threaded as runtime jit args
-(GC012), a hand-spelled `_replace` rebuild inside the jit, a hand-listed
-trace-inventory row (tools/graftcheck/trace/inventory.py), and a
-hand-paired host twin.  Every copy was a drift surface.  Now:
+runner graph — the single source of truth that ``runner.make_runner``,
+the host twins, and the graftcheck closure rules all read (the plane
+half is planes.py).  Without it every runner variant would hand-list
+the same things — the flat tuple of schedule arrays threaded as runtime
+jit args (GC012), the `_replace` rebuild inside the jit, the
+trace-inventory row, the host twin — and every copy is a drift surface.
 
 * ``SCHEDULES`` holds one :class:`ScheduleSpec` per device schedule
   array, in the exact field order of the family's compiled NamedTuple

@@ -32,7 +32,7 @@ traffic (DCN) terminates in the host driver, not here.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -463,28 +463,3 @@ def shard_client(compiled, rcar, mesh: Mesh, axis: str = "groups"):
         else jax.tree.map(jax.device_put, rcar, rcar_sh)
     )
     return placed_sched, placed_rcar
-
-
-def run_sharded(
-    cfg: SimConfig,
-    mesh: Mesh,
-    rounds: int,
-    axis: str = "groups",
-) -> Tuple[SimState, dict]:
-    """Initialize, shard, and advance `rounds` steps on the mesh; returns
-    (final_state, global status dict).
-
-    Thin compat wrapper (ISSUE 14): the per-round host dispatch loop this
-    function used to run is retired — the rounds now execute as ONE
-    donated lax.scan under jit-with-shardings through
-    ClusterSim(mesh=).run_compiled, the same fast path every other mesh
-    entry point uses (zero per-round host dispatches, double-buffered
-    carry, SPMD-friendly graphs).  Signature and results are unchanged;
-    the MULTICHIP smoke keeps passing against the scan path."""
-    cs = sim.ClusterSim(cfg, mesh=mesh, mesh_axis=axis)
-    append = jax.device_put(
-        jnp.ones((cfg.n_groups,), jnp.int32), NamedSharding(mesh, P(axis))
-    )
-    cs.run_compiled(rounds, append_n=append)
-    status = global_status(cs.cfg, mesh, axis)(cs.state)
-    return cs.state, jax.tree.map(lambda x: int(x), status)

@@ -4026,8 +4026,8 @@ class ClusterSim:
         """Jitted `rounds`-round lax.scan with the WHOLE carry donated —
         state (and counter/health extras) double-buffer in place instead of
         paying a fresh allocation + host dispatch per round, the same shape
-        the compiled scenario runners use (runner.make_runner, behind the
-        chaos.make_runner wrapper).  Cached per (rounds, link-threading).
+        the compiled scenario runners use (runner.make_runner).  Cached per
+        (rounds, link-threading).
 
         "Donated" here is verified, not assumed: XLA can silently decline
         a donation it cannot alias, so the GC011 trace audit checks every
@@ -4095,7 +4095,7 @@ class ClusterSim:
     ) -> SimState:
         """Advance `rounds` lockstep rounds as donated jitted lax.scan(s):
         zero per-round host dispatches and a double-buffered carry, for
-        constant crashed/append/link planes (the bench schedule).  With
+        constant crashed/append/link planes.  With
         counters enabled the scan is chunked to the GC008 drain cap (a
         residual window carried in from prior run_round calls is drained
         up front, so the undrained window provably never exceeds the cap)
@@ -4335,7 +4335,7 @@ class ClusterSim:
         commit-stall plane joined with the joint bit.
 
         `split=True` (ISSUE 11) executes the plan through the
-        SPLIT-HORIZON runner (reconfig.make_split_runner): the steady
+        SPLIT-HORIZON runner (runner.make_runner, split=True): the steady
         stretches between ops ride the fused Pallas kernel in
         `split_k`-round blocks while the op windows (planned by
         reconfig.split_plan with `split_window` rounds around each op)
@@ -4447,11 +4447,9 @@ class ClusterSim:
                         f"plan spans {compiled.n_rounds} rounds but the "
                         f"GC008 drain cap at this batch size is "
                         f"{self._drain_cap} rounds per undrained window; "
-                        "run the plan through the unified factory "
-                        "(runner.make_runner with split=True, or its "
-                        "reconfig.make_split_runner wrapper) directly, "
-                        "managing the counter plane yourself — or split "
-                        "the plan"
+                        "run the plan through runner.make_runner with "
+                        "split=True directly, managing the counter "
+                        "plane yourself — or split the plan"
                     )
             out = runner(
                 self.state, health, rst,
@@ -4551,7 +4549,7 @@ class ClusterSim:
         replayed has to end in the configuration it starts from.
 
         `split=True` (the ISSUE 13 fused satellite) executes the plan
-        through workload.make_split_runner: steady stretches whose reads
+        through the workload split runner: steady stretches whose reads
         are pure lease serves ride the fused Pallas kernel in
         `split_k`-round blocks (the lease receipts fold closed-form),
         while quorum-round reads, chaos, and reconfig rounds run the
@@ -4916,7 +4914,7 @@ class ClusterSim:
         SimConfig(lease_read=True, check_quorum=True) for a non-trivial
         answer; zero message rounds either way.  For the full in-round
         read path (serve + ReadIndex degrade + latency accounting) use
-        step(read_propose=) / workload.make_runner."""
+        step(read_propose=) / ClusterSim.run_reads."""
         if crashed is None:
             crashed = jnp.zeros(
                 (self.cfg.n_peers, self.cfg.n_groups), bool

@@ -1,5 +1,5 @@
 """Compiled client workloads: Zipf-skewed read/write mixes driven through
-the batched sim as ONE jitted lax.scan (ISSUE 13).
+the batched sim as ONE jitted lax.scan.
 
 A :class:`ClientPlan` is the client-side twin of a chaos.ChaosPlan: a list
 of phases, each covering a round range and a group selector, declaring the
@@ -9,11 +9,12 @@ TiKV-style hot-region skew) and its READ traffic (a read issued every
 ReadIndex quorum round — or "lease" — the LeaseBased local serve under the
 check-quorum leader lease).  :func:`compile_plan` lowers it host-side into
 dense schedule arrays (per-round read-fire masks bit-packed 32:1 along G —
-GC008 PACKED_PLANES `bits_g`); :func:`make_runner` then executes the whole
-scenario inside one ``lax.scan`` with zero host round trips, composable
-with a ``chaos.CompiledChaos`` AND a ``reconfig.CompiledReconfig`` in the
-SAME scan (reads during partitions, reads during joint config —
-``reconfig._runner_body`` is the shared round body).
+GC008 PACKED_PLANES `bits_g`); ``runner.make_runner`` then executes the
+whole scenario inside one ``lax.scan`` with zero host round trips,
+composable with a ``chaos.CompiledChaos`` AND a
+``reconfig.CompiledReconfig`` in the SAME scan (reads during partitions,
+reads during joint config — ``reconfig._runner_body`` is the shared round
+body).
 
 Each round: outstanding reads retry through ``sim.step(read_propose=)``
 (one read in flight per group; a fire landing on an outstanding read is
@@ -39,11 +40,8 @@ LeaseBased/Safe pumps); :class:`HostClientSchedule` is the numpy half the
 oracle-driven tests walk — built by the SAME `_compile_arrays` walk as the
 device schedule, so the two cannot drift.
 
-Since the runner-registry refactor the compiled runners are BUILT by the
-unified factory (raft_tpu/multiraft/runner.py) from the schedules.py
-registry; :func:`make_runner` / :func:`make_split_runner` here are thin
-behavior-neutral wrappers (GC018 machine-checks the closure, GC014 pins
-the jaxprs).
+The compiled runners are built by ``runner.make_runner`` from the
+schedules.py registry; this module knows nothing of the runner.
 """
 
 from __future__ import annotations
@@ -51,7 +49,7 @@ from __future__ import annotations
 import json
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Tuple
 
 import numpy as np
 import jax
@@ -102,8 +100,8 @@ class ClientPhase:
                 leader (ignored when write_zipf > 0).
     write_zipf: Zipf skew parameter (> 1); when set, each selected group
                 draws its per-round write load once for the phase from
-                numpy's zipf(a), clipped to write_max — the hot-region
-                skew of benches/suites.py config 3.
+                numpy's zipf(a), clipped to write_max — TiKV-style
+                hot-region skew.
     write_max:  clip bound for the Zipf draw.
     read_every: issue a read every N rounds per selected group (0 = no
                 reads this phase).
@@ -172,7 +170,7 @@ def plan_from_dict(doc: Dict[str, object]) -> ClientPlan:
 
 
 def load_plan(path: str) -> ClientPlan:
-    """Load a ClientPlan from a JSON file (the bench.py --reads input)."""
+    """Load a ClientPlan from a JSON file (examples/reads/)."""
     with open(path, "r", encoding="utf-8") as f:
         return plan_from_dict(json.load(f))
 
@@ -396,100 +394,6 @@ def _validate(cfg, client, chaos_compiled, reconfig_compiled):
         )
 
 
-def make_runner(
-    cfg: sim_mod.SimConfig,
-    client: CompiledClient,
-    chaos_compiled: Optional[chaos_mod.CompiledChaos] = None,
-    reconfig_compiled=None,
-):
-    """Build the jitted whole-scenario client-workload runner: ONE
-    lax.scan over every round — read fires/retries/serves, the Zipf write
-    skew, the latency-histogram fold, the MTTR stats, and the FULL safety
-    audit (joint-window + linearizability slots, every round) — with zero
-    host round trips, optionally composed with a chaos schedule and/or a
-    reconfig schedule of equal length in the SAME scan
-    (reconfig._runner_body is the shared round body; a missing reconfig
-    plan runs the no-op schedule, whose op protocol provably never moves).
-
-    Like every compiled runner, the schedule arrays enter the jit as
-    RUNTIME ARGUMENTS (GC012) — only shapes specialize the compile.
-    Returns a callable (state, health, rstate, read_carry) ->
-    (state', health', rstate', stats[N_CHAOS_STATS],
-    rstats[N_RECONFIG_STATS], safety[N_SAFETY], read_carry',
-    read_stats[N_READ_STATS], lat_hist[N_LAT_BUCKETS]);
-    state/health/rstate/read_carry are donated.  ``runner.jitted`` /
-    ``runner.schedule_args`` are exposed for the graftcheck trace audit.
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxpr (GC014 pins it).
-    """
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(
-        cfg, (client, chaos_compiled, reconfig_compiled)
-    )
-
-
-def make_split_runner(
-    cfg: sim_mod.SimConfig,
-    client: CompiledClient,
-    k: int = 8,
-    chaos_compiled=None,
-    reconfig_compiled=None,
-):
-    """Build the FUSED client-workload runner (the ISSUE 13 perf
-    satellite): the same protocol and accounting as make_runner —
-    bit-identical end state, health planes, read stats, latency
-    histogram, and safety accumulators (tests/test_workload.py pins it) —
-    but executed as k-round blocks, each a lax.cond between the fused
-    Pallas steady kernel and the same k general rounds.
-
-    A block rides the fused kernel when, at runtime: the steady invariant
-    holds for the whole horizon (pallas_step.steady_mask, including the
-    damping conditions when check_quorum is on) AND no quorum-round read
-    work touches it (`steady_mask(read_pending=
-    reads_pending_in_horizon(...))` — an outstanding read of any mode or
-    a scheduled Safe-mode fire rejects) AND every scheduled LEASE fire is
-    provably servable — the block spans one client phase, the group's
-    acting leader passes the lease gate at block entry
-    (kernels.lease_read), and heartbeat_tick == 1 re-saturates the
-    recent_active row every round, so the gate provably holds at every
-    round entry of a steady horizon.  The fused arm then folds the lease
-    receipts CLOSED-FORM: every fire serves the round it fires (latency
-    0 — lat_hist[0] += fires; issued/served_lease += fires), the
-    outstanding-read carry provably stays empty, and every safety slot —
-    including the linearizability pair — is provably zero (one leader,
-    one lease holder, serve index = the group max commit).
-
-    Composition with chaos/reconfig schedules is NOT supported here
-    (pass them to make_runner; the reconfig split machinery is
-    reconfig.make_split_runner) — a bare plan is exactly the bench
-    --reads shape.  Returns a callable with make_runner's signature plus
-    a trailing fused-group-rounds int32 scalar:
-    (st, hl, rst, rcar) -> (..., lat_hist, fused_rounds).
-
-    What the predicate and the closed-form fold need of the SCHEDULE is
-    computed once, when the runner is built (:func:`block_tables`), and
-    block b receives its own rows as operands (``runner.block_args[b]``:
-    a :class:`BlockRows` and the block's append row), so a block's cost
-    does not follow the schedule's length; only the general arm reads the
-    schedule's planes.  ``runner.fused_jit`` / ``runner.block_args`` /
-    ``runner.schedule_args`` are exposed for the graftcheck trace audit.
-
-    Thin behavior-neutral wrapper since the runner-registry refactor:
-    the construction lives in the unified factory
-    (raft_tpu/multiraft/runner.py), instantiated from the schedules.py
-    registry — byte-identical jaxprs (GC014 pins it)."""
-    from . import runner as runner_mod
-
-    return runner_mod.make_runner(
-        cfg, (client, chaos_compiled, reconfig_compiled), split=True,
-        k=k,
-    )
-
-
 def reads_pending_in_horizon(
     client: CompiledClient,
     rcar: ReadCarry,
@@ -503,8 +407,8 @@ def reads_pending_in_horizon(
     `read_pending=`): the fused kernel can serve neither arm of the
     quorum round, while pure LEASE fires are NOT pending — on a steady
     horizon the lease gate provably holds and the serve touches no
-    message planes, so those fold closed-form (workload.make_split_runner
-    / bench --reads).  The per-round DEFINITION: the split runner
+    message planes, so those fold closed-form (the workload split
+    runner).  The per-round DEFINITION: the split runner
     computes the carry half itself and takes the schedule half from
     :func:`block_tables`, which tests/test_block_tables.py holds equal
     to this."""
@@ -604,15 +508,14 @@ def read_report(
     rstats=(0, 0, 0, 0), conf_unfinished: int = 0,
 ) -> dict:
     """The per-scenario read-workload summary off the device accumulators
-    (host-side formatter; bench.py --reads and ClusterSim.run_reads emit
-    it).  `lat_p` is latency_percentiles' (p50, p90, p99) vector of the
-    read histogram, `recover_p` the same of chaos.recover_hist(stats) —
-    the lengths in rounds of the leaderless episodes that ended (-1: none
-    did).  `rstats` is the op protocol's [reconfig.N_RECONFIG_STATS]
-    vector (conf entries proposed / ops applied / entries given up with
-    their owner / group-rounds in a joint configuration) and
-    `conf_unfinished` the groups with ops of their chain left at the end;
-    all zero with no reconfig plan."""
+    (host-side formatter; ClusterSim.run_reads emits it).  `lat_p` is
+    latency_percentiles' (p50, p90, p99) vector of the read histogram,
+    `recover_p` the same of chaos.recover_hist(stats) — the lengths in
+    rounds of the leaderless episodes that ended (-1: none did).  `rstats`
+    is the op protocol's [reconfig.N_RECONFIG_STATS] vector (conf entries
+    proposed / ops applied / entries given up with their owner /
+    group-rounds in a joint configuration) and `conf_unfinished` the groups
+    with ops of their chain left at the end; all zero with no reconfig plan."""
     from .chaos import (
         CS_APPENDS_DROPPED,
         CS_APPENDS_OFFERED,

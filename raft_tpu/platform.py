@@ -85,20 +85,6 @@ def pallas_interpret() -> bool:
     return backend() == "cpu"
 
 
-def device_fields() -> dict:
-    """The device a result was produced on, as JAX reports it — carried by
-    every JSON line the benches print."""
-    import jax
-
-    backend()
-    devices = jax.devices()
-    return {
-        "platform": devices[0].platform,
-        "device_kind": devices[0].device_kind,
-        "n_devices": len(devices),
-    }
-
-
 def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache and return its directory.
 

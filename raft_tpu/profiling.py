@@ -4,10 +4,9 @@ observability is structured slog logging + Criterion; the device path adds
 JAX profiler traces so kernel time is inspectable in TensorBoard/Perfetto).
 
 There is no switch.  "Tracing on" means a `jax.profiler` trace is being
-captured (`device_trace` below, `bench.py --profile`, the benchmark's
-`--trace 1`); otherwise a span is the profiler's own no-op (under 1 us)
-and a scope or a kernel name costs nothing at run time: both only label
-the compiled program.
+captured (`device_trace` below, the benchmark's `--trace 1`); otherwise a
+span is the profiler's own no-op (under 1 us) and a scope or a kernel name
+costs nothing at run time: both only label the compiled program.
 
     from raft_tpu.profiling import device_trace
 
@@ -192,8 +191,7 @@ def start_trace(log_dir: str, host_profiler: bool = False) -> None:
     """Begin a JAX profiler (XLA) trace writing into `log_dir`.
 
     The imperative twin of `device_trace` for callers whose start/stop
-    points do not nest lexically (bench.py --profile brackets its timed
-    region across loop iterations this way).  Must be paired with
+    points do not nest lexically.  Must be paired with
     `stop_trace`; traces do not nest."""
     jax.profiler.start_trace(log_dir, create_perfetto_trace=host_profiler)
 

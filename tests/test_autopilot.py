@@ -4,8 +4,8 @@ Tier-1 covers the host-side policy as pure functions (no jit), one small
 end-to-end healing run, and the observability wiring; the heavier claims
 — cadence-runner protocol identity vs the plain chaos scan, the fused
 fast path's bit-identity, evacuation through the reconfig protocol, and
-the corpus report tool — are @pytest.mark.slow (the 870s tier-1 gate is
-saturated)."""
+the corpus report tool — are @pytest.mark.slow: the long cases (no gate is
+saturated: tier-1 takes 247 s of its 1470 s limit under xdist -n 6 at PR 32)."""
 
 import json
 
@@ -16,13 +16,9 @@ import jax.numpy as jnp
 
 from raft_tpu.metrics import Metrics
 from raft_tpu.multiraft import ClusterSim, SimConfig, chaos
-from raft_tpu.multiraft.autopilot import (
-    Autopilot,
-    AutopilotConfig,
-    empty_reconfig_schedule,
-)
+from raft_tpu.multiraft.autopilot import Autopilot, AutopilotConfig
 from raft_tpu.multiraft.health import HealthMonitor
-from raft_tpu.multiraft.reconfig import NO_ROUND
+from raft_tpu.multiraft.reconfig import NO_ROUND, empty_reconfig_schedule
 
 CRASH_PLAN = {
     "name": "crash-heal",
@@ -158,7 +154,7 @@ def test_policy_leader_from_role_columns_not_stale_views():
 
 
 def test_balance_transfers_spread_leaders_by_weight():
-    """The Zipf load-balance policy (benches/suites.py config 3's
+    """The Zipf load-balance policy (the TiKV-style hot-region
     regime): heavy groups move off the overloaded leader peer onto their
     least-loaded voter, strictly improving the weighted load gap, within
     budget."""

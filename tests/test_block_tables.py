@@ -24,6 +24,7 @@ import pytest
 
 from raft_tpu.multiraft import ClusterSim, SimConfig, sim
 from raft_tpu.multiraft import chaos, kernels, reconfig, workload
+from raft_tpu.multiraft import runner as runner_mod
 from raft_tpu.multiraft.simref import host_pack_bits_g
 
 P = 3
@@ -80,7 +81,7 @@ def test_tabled_rows_equal_the_per_round_definitions(name, k):
     client = schedule(G, phase_rounds, fires, stray_bits=G % 32 != 0)
     R = client.n_rounds
     cfg = SimConfig(n_groups=G, n_peers=P, collect_health=True)
-    run = workload.make_split_runner(cfg, client, k=k)
+    run = runner_mod.make_runner(cfg, (client,), split=True, k=k)
     assert len(run.block_args) == R // k
     idle = workload.init_read_carry(G)
 
@@ -125,7 +126,7 @@ def test_tables_stay_under_the_schedules_own_bytes(phase_rounds):
     all blocks are smaller than the schedule they were computed from."""
     client = schedule(96, phase_rounds, {})
     cfg = SimConfig(n_groups=96, n_peers=P, collect_health=True)
-    run = workload.make_split_runner(cfg, client, k=8)
+    run = runner_mod.make_runner(cfg, (client,), split=True, k=8)
     rows = {id(x): x.nbytes for x in jax.tree.leaves(run.block_args)}
     own = sum(
         x.nbytes for x in jax.tree.leaves(client._replace(n_peers=None))
@@ -213,7 +214,7 @@ def test_the_guard_does_not_grow_with_the_schedule():
     guards = {}
     for R in (64, 640):
         client = schedule(G, [phase_len] * (R // phase_len), {})
-        run = workload.make_split_runner(cfg, client, k=k)
+        run = runner_mod.make_runner(cfg, (client,), split=True, k=k)
         args = (
             st, sim.init_health(cfg), reconfig.init_reconfig_state(st),
             zeros(chaos.N_CHAOS_STATS), zeros(reconfig.N_RECONFIG_STATS),

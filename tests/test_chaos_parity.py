@@ -16,7 +16,8 @@ Four claims are pinned here (ISSUE 5 acceptance criteria):
 
 Tier-1 cost: the link-path jit is ~9s on CPU, so the tier-1 cases share
 ONE module-scoped ClusterSim (G=8 short schedules); everything at G>=32
-or >=100 rounds is marked slow (the 870s gate is saturated — ROADMAP.md).
+or >=100 rounds is marked slow (ROADMAP.md's standing constraint; time is
+not scarce: tier-1 takes 247 s of its 1470 s limit under xdist -n 6 at PR 32).
 """
 
 import functools

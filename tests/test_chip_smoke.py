@@ -1,7 +1,7 @@
 """chip_smoke.py must not rot between chip runs: its legs run here at a
 tiny size on the pinned CPU (Pallas interpret mode, by the one decision
-point in raft_tpu.platform), and the commands that need a chip — the smoke
-and the benches — refuse to run without one unless explicitly pinned."""
+point in raft_tpu.platform), and the command itself refuses to run without
+a chip, pinned to the CPU or fallen back to it."""
 
 import os
 import subprocess
@@ -73,27 +73,18 @@ def test_smoke_refuses_the_pinned_cpu():
     assert "no TPU" in proc.stderr
 
 
-# One subprocess for every unpinned refusal: without JAX_PLATFORMS the
-# installed jax spends ~20 s failing to find a TPU before it falls back to
-# the CPU, and that fallback is exactly the case under test.
+# Without JAX_PLATFORMS the installed jax spends ~20 s failing to find a
+# TPU before it falls back to the CPU, and that fallback is exactly the
+# case under test.
 _UNPINNED = """
-import runpy, sys
+import sys
 import jax
 if jax.default_backend() == "tpu":
     print("HAS_TPU")
     sys.exit(0)
 import chip_smoke
 assert chip_smoke.main() != 0
-for script in ("bench.py", "benches/suites.py"):
-    sys.argv = [script]
-    try:
-        runpy.run_path(script, run_name="__main__")
-    except SystemExit as e:
-        assert e.code not in (0, None), script
-        assert "no TPU" in str(e.code), (script, e.code)
-    else:
-        raise AssertionError(script + " ran without a chip")
-print("ALL_REFUSED")
+print("REFUSED")
 """
 
 
@@ -103,5 +94,5 @@ def test_nothing_runs_on_a_fallback_cpu():
     if "HAS_TPU" in proc.stdout:
         pytest.skip("this machine has a TPU")
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "ALL_REFUSED" in proc.stdout
-    assert '"ok"' not in proc.stdout and '"metric"' not in proc.stdout
+    assert "REFUSED" in proc.stdout
+    assert '"ok"' not in proc.stdout

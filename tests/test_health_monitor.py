@@ -4,7 +4,7 @@ health planes + health()/explain(), the ClusterSim monitor wiring, and the
 ready-scan short-circuit satellite (dirty-set scan + skip-ratio counters).
 
 Everything here is host-only or reuses shapes compiled elsewhere — cheap by
-construction (the tier-1 gate is saturated)."""
+construction."""
 
 import numpy as np
 import pytest

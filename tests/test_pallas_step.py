@@ -218,7 +218,7 @@ def test_hybrid_storm_overflow_falls_back():
             )
 
 
-@pytest.mark.slow  # ~8s of interpret-mode compile: the tier-1 gate is full
+@pytest.mark.slow  # ~8s of interpret-mode compile
 def test_steady_round_health_matches_general_steps():
     """The fused health fold (in-kernel ticks_since_commit + closed-form
     window math) must be bit-identical to threading sim.step's health
@@ -550,9 +550,9 @@ def test_plain_jaxpr_unchanged_by_new_flags():
 # The damped kernel family (_steady_damped_kernel) must be bit-identical —
 # per-round state AND health planes AND the recent_active plane — to k
 # general damped wave rounds (sim._damped_linked_step) per configuration:
-# plain / health / counters / chaos, each under cq and cq+pv.  Tier-1 keeps
-# one small case per flag mode sharing the module-scoped settles below; the
-# rest of the matrix is slow (the 870s gate is saturated — ROADMAP.md).
+# plain / health / counters / chaos, each under cq and cq+pv — the bodies
+# every benchmark cell runs, so the whole matrix is tier-1 (which takes
+# 247 s of its 1470 s under xdist -n 6, /root/TESTS_LAST_RUN.json at PR 32).
 
 DK = 4  # fused horizon for the damped cases
 
@@ -755,7 +755,6 @@ def test_damped_build_leaves_undamped_graphs_unchanged():
         assert str(base) == str(explicit)
 
 
-@pytest.mark.slow  # the remaining flag-mode cross product (two compiles)
 def test_damped_fused_parity_matrix_plain_health(cq_settled, cq_pv_settled):
     """health × cq and plain × cq+pv — the other half of the
     plain/health matrix, off the shared settles."""
@@ -792,7 +791,6 @@ def test_damped_fused_parity_matrix_plain_health(cq_settled, cq_pv_settled):
     _assert_state_equal(want, got, "plain-cq+pv")
 
 
-@pytest.mark.slow  # its own pv-only settle + two fresh damped compiles
 def test_damped_fused_parity_pv_only():
     """plain × pre-vote-only: SimConfig(pre_vote=True) alone routes to
     _steady_damped_kernel(with_cq=False) in production (steady_mask's
@@ -816,7 +814,6 @@ def test_damped_fused_parity_pv_only():
         _assert_state_equal(want[blk], got, f"pv-only block {blk}")
 
 
-@pytest.mark.slow  # two counter-threaded damped compiles
 def test_damped_fused_counters_closed_form(cq_settled, cq_pv_settled):
     """counters × cq and counters × cq+pv: the closed-form CTR_* fold
     (campaigns/wins provably 0, heartbeat fires arithmetic — incl. any
@@ -850,7 +847,6 @@ def test_damped_fused_counters_closed_form(cq_settled, cq_pv_settled):
         _assert_state_equal(want_st, got_st, note)
 
 
-@pytest.mark.slow  # chaos-on damped compiles at election_tick=60
 def test_damped_fused_chaos_both_branches():
     """chaos × cq and chaos(+health) × cq+pv through the dispatcher: 18
     k=4 blocks cross the election_tick=60 boundary window, so the
@@ -1020,11 +1016,6 @@ def test_steady_mask_loss_rate_per_group(cq_settled):
     assert not old.any()
 
 
-@pytest.mark.slow  # ~20s of counted-dispatch compiles; the count_fused
-# accounting is exercised every CI build by the chaos-churn --fused gate
-# and the bench --fused-floor gates (fused_frac is a hard-gated number),
-# so tier-1 demotes this to pay for the ISSUE 15 forensics e2e case
-# (the standing 870s-gate constraint: new tier-1 time must be paid for).
 def test_fast_multi_round_count_fused_plain():
     """count_fused: the trailing int32 accumulator counts k * n_groups
     group-rounds per fused block, 0 per fallback block, and the counted

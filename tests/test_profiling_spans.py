@@ -343,10 +343,9 @@ def crash_run():
     cfg = s.cfg
     client = workload.compile_plan(plan, G)
     cc = chaos.compile_plan(cplan, G)
-    from raft_tpu.multiraft.autopilot import empty_reconfig_schedule
-
     body = jax.jit(reconfig._runner_body(
-        cfg, empty_reconfig_schedule(ROUNDS, P, G), cc, client=client))
+        cfg, reconfig.empty_reconfig_schedule(ROUNDS, P, G), cc,
+        client=client))
     zeros = lambda n: jnp.zeros((n,), jnp.int32)  # noqa: E731
     carry = (
         s.state, s._health, reconfig.init_reconfig_state(s.state),

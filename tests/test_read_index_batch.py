@@ -174,10 +174,8 @@ def test_read_index_no_leader():
         assert nat[g] == -1
 
 
-@pytest.mark.slow  # ~18s of 3-seed lockstep storm: ISSUE 11 paid the
-# saturated tier-1 gate for its split-runner parity case with this one
-# (tools/tier1_budget.py top-N); the mixed/joint/learners/even-P storm
-# variants keep the probe-schedule shape in tier-1.
+@pytest.mark.slow  # ~18s of 3-seed lockstep storm; the mixed/joint/
+# learners/even-P storm variants keep the probe-schedule shape in tier-1.
 def test_read_index_storm_plain():
     for seed in (11, 23, 37):
         run_probe_schedule(seed, 3, 5, 60)

@@ -30,6 +30,7 @@ from raft_tpu.multiraft import (
     SimConfig,
 )
 from raft_tpu.multiraft import chaos, reconfig, workload
+from raft_tpu.multiraft import runner as runner_mod
 
 G, P = 96, 5
 VOTERS = [1, 2, 3]
@@ -279,9 +280,11 @@ def test_without_a_plan_the_report_is_what_it_was(split):
     client = client_of(seg)
     carried, fresh = booted_sim(cfg), booted_sim(cfg)
     if split:
-        runner = workload.make_split_runner(cfg, client, k=seg.split_k)
+        runner = runner_mod.make_runner(
+            cfg, (client,), split=True, k=seg.split_k
+        )
     else:
-        runner = workload.make_runner(cfg, client)
+        runner = runner_mod.make_runner(cfg, (client,))
     for call in (1, 2):
         report = carried.run_reads(client, split=split, split_k=seg.split_k)
         # The same call as it was before the carry: a fresh op-protocol

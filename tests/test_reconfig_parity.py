@@ -10,7 +10,7 @@ Five claims are pinned here (ISSUE 10 acceptance criteria):
      propose/gate/retry rules and the scalar surgery mirror of
      kernels.apply_confchange — across multi-phase schedules composed
      with link chaos, undamped AND damped (cq+pv), plus a seeded fuzz;
-  3. the one-shot compiled scan (reconfig.make_runner / run_plan) ends
+  3. the one-shot compiled scan (runner.make_runner) ends
      bit-identical to stepping the same schedule round by round;
   4. zero joint-window safety violations on every correct schedule, and
      each joint-window invariant CAN fire (negative tests per slot);
@@ -21,8 +21,9 @@ Five claims are pinned here (ISSUE 10 acceptance criteria):
 Tier-1 cost: the reconfig round body jit is the link-path step plus the
 gate/apply tail (~10-15s on CPU), so tier-1 keeps ONE undamped composed
 schedule and ONE damped (cq+pv) schedule at G=8; the seeded fuzz battery,
-the G=32 corpus replays, and the 5-peer cases are marked slow (the 870s
-gate is saturated — ROADMAP.md).
+the G=32 corpus replays, and the 5-peer cases are marked slow (ROADMAP.md's
+standing constraint; tier-1 takes 247 s of its 1470 s limit under xdist -n 6
+at PR 32).
 """
 
 import functools
@@ -39,6 +40,7 @@ from raft_tpu.multiraft import (
     SimConfig,
 )
 from raft_tpu.multiraft import chaos, kernels, reconfig
+from raft_tpu.multiraft import runner as runner_mod
 from raft_tpu.multiraft import sim as sim_mod
 
 FIELDS = ("term", "state", "commit", "last_index", "last_term")
@@ -350,8 +352,8 @@ def test_run_plan_matches_stepping():
 
     # one-shot compiled scan
     st2 = sim_mod.init_state(cfg, vm, om, lm)
-    out = reconfig.run_plan(
-        cfg, st2, compiled, chaos_compiled=ccompiled
+    out = runner_mod.make_runner(cfg, (compiled, ccompiled))(
+        st2, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st2)
     )
     stf, hlf, rstf, stats_f, rstats_f, safety_f = out
     for f in sim_mod.SimState._fields:
@@ -642,15 +644,14 @@ def test_compiled_schedule_shapes_and_selectors():
     slot = host.slot(1, 0)
     assert slot.voters_out == frozenset({1, 2, 3})
     with pytest.raises(ValueError, match="rounds"):
-        reconfig.make_runner(
+        runner_mod.make_runner(
             SimConfig(n_groups=4, n_peers=3, collect_health=True),
-            c,
-            chaos.compile_plan(
+            (c, chaos.compile_plan(
                 chaos.plan_from_dict(
                     {"name": "x", "peers": 3,
                      "phases": [{"rounds": 5}]}
                 ), 4,
-            ),
+            )),
         )
 
 

@@ -8,20 +8,20 @@ Three claims are pinned here:
      joint-entering ops to their leave), cuts fused spans at schedule
      phase starts, degrades remainders to general rounds, and yields ONE
      full fused segment for an op-free horizon;
-  2. `reconfig.make_split_runner` is bit-identical to the unsplit
-     `make_runner` scan — state, health planes, op-protocol carry, and
-     every stats/safety accumulator — while actually engaging the fused
+  2. the reconfig split runner (`runner.make_runner(split=True)`) is
+     bit-identical to the unsplit scan — state, health planes,
+     op-protocol carry, and every stats/safety accumulator — while
+     actually engaging the fused
      kernel (fused_rounds > 0) on the steady stretches between ops;
   3. the ClusterSim.run_reconfig(split=True) wiring reports the measured
      fused fraction.
 
-Tier-1 keeps the planner battery (pure host, no compiles) and ONE
-undamped G=8 split-vs-unsplit parity case; the G=32 production
-composition (health + counters + chaos + cq + pv) and the ClusterSim
-wiring case are @pytest.mark.slow per the saturated 870s gate — paid for
-by slow-marking the 3-seed plain read-index storm (see
-tools/tier1_budget.py top-N; its mixed/joint/learners/even-P siblings
-keep the storm shape in tier-1).
+Tier-1 keeps the planner battery (pure host, no compiles) and ONE undamped
+G=8 split-vs-unsplit parity case; the G=32 production composition (health +
+counters + chaos + cq + pv) and the ClusterSim wiring case are
+@pytest.mark.slow (long cases; tier-1 takes 247 s of its 1470 s limit under
+xdist -n 6 at PR 32; tests/test_example_plans.py runs the shipped production
+plan through ClusterSim.run_reconfig(split=True) in tier-1).
 """
 
 import jax
@@ -31,6 +31,7 @@ import pytest
 
 from raft_tpu.multiraft import ClusterSim, SimConfig
 from raft_tpu.multiraft import chaos, kernels, reconfig
+from raft_tpu.multiraft import runner as runner_mod
 from raft_tpu.multiraft import sim as sim_mod
 
 
@@ -216,8 +217,10 @@ def test_split_runner_matches_unsplit_g8():
         st = sim_mod.init_state(cfg, *reconfig.initial_masks(plan, G))
         return st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st)
 
-    out1 = reconfig.make_runner(cfg, compiled)(*fresh())
-    runner = reconfig.make_split_runner(cfg, compiled, k=4, window=4)
+    out1 = runner_mod.make_runner(cfg, (compiled,))(*fresh())
+    runner = runner_mod.make_runner(
+        cfg, (compiled,), split=True, k=4, window=4
+    )
     out2 = runner(*fresh())
     _assert_run_equal(out1, out2, "g8-split")
     fused = int(out2[6])
@@ -275,9 +278,10 @@ def test_split_runner_prod_composition_g32():
         st = sim_mod.init_state(cfg, *reconfig.initial_masks(plan, G))
         return st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st)
 
-    out1 = reconfig.make_runner(cfg, compiled, ccompiled)(*fresh())
-    runner = reconfig.make_split_runner(
-        cfg, compiled, ccompiled, k=4, window=4, with_counters=True,
+    out1 = runner_mod.make_runner(cfg, (compiled, ccompiled))(*fresh())
+    runner = runner_mod.make_runner(
+        cfg, (compiled, ccompiled), split=True, k=4, window=4,
+        with_counters=True,
     )
     st0, hl0, rst0 = fresh()
     out2 = runner(st0, hl0, rst0, kernels.zero_counters())

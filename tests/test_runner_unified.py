@@ -1,19 +1,19 @@
-"""Wrapper-vs-unified runner bit-identity (the runner-registry refactor).
+"""ClusterSim's entry points against the runner factory called directly.
 
-Every legacy entry point — chaos.make_runner, reconfig.make_runner,
-reconfig.make_split_runner, workload.make_runner,
-workload.make_split_runner, autopilot.make_cadence_runner — is now a
-thin wrapper over the one descriptor-built factory
-(raft_tpu/multiraft/runner.make_runner, instantiated from the
-schedules.py registry).  These tests pin the wrapper contract the hard
-way: one golden scenario per schedule family, run through BOTH the
-legacy symbol and the unified factory from identical fresh inputs, with
-every output leaf compared bit-for-bit.  G=8 covers tier-1; the same
-scenarios at G=32 are slow-marked (ISSUE 19's budget satellite).
+``runner.make_runner`` is the one way a compiled scenario runner is built
+(raft_tpu/multiraft/runner.py, instantiated from the schedules.py
+registry).  What sits between a user and it is the facade: ClusterSim's
+``run_plan`` / ``run_reconfig`` / ``run_reads`` and the autopilot's
+cadence loop compile and place the schedules, cache the runner, thread
+the carry from call to call and format the report.  These tests pin that
+layer: one golden scenario per schedule family, run through the facade
+and through the factory on fresh state, every state leaf compared
+bit-for-bit and the report against the same formatter over the factory's
+own outputs.  G=8 covers tier-1; the same scenarios at G=32 are
+slow-marked.
 
-The jaxpr-level identity is separately machine-checked (GC014 holds the
-committed budgets byte-identical; GC019 pins the phase decomposition) —
-this file is the end-to-end behavioral half of that argument.
+The jaxpr-level identity of the graphs is separately machine-checked
+(GC014 holds the committed budgets; GC019 pins the phase decomposition).
 """
 
 import jax
@@ -21,10 +21,12 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from raft_tpu.multiraft import SimConfig
-from raft_tpu.multiraft import autopilot, chaos, kernels, reconfig, workload
+from raft_tpu.multiraft import ClusterSim, SimConfig
+from raft_tpu.multiraft import chaos, kernels, reconfig, workload
 from raft_tpu.multiraft import runner as runner_mod
 from raft_tpu.multiraft import sim as sim_mod
+from raft_tpu.multiraft.autopilot import Autopilot, AutopilotConfig
+from raft_tpu.multiraft.health import HealthMonitor
 
 
 def _assert_tree_equal(out1, out2, note):
@@ -89,146 +91,193 @@ def _client_plan():
 
 
 def _run_chaos(G):
+    """run_plan twice on one sim (the second call takes the cached
+    runner from where the first left the fleet) == the factory's runner
+    called twice with the carry threaded by hand."""
     cfg = SimConfig(n_groups=G, n_peers=3, collect_health=True)
-    compiled = chaos.compile_plan(_chaos_plan(), G)
-
-    def fresh():
-        return sim_mod.init_state(cfg), sim_mod.init_health(cfg)
-
-    out_legacy = chaos.make_runner(cfg, compiled)(*fresh())
-    out_unified = runner_mod.make_runner(cfg, (compiled,))(*fresh())
-    _assert_tree_equal(out_legacy, out_unified, f"chaos g{G}")
+    plan = _chaos_plan()
+    cs = ClusterSim(cfg, chaos=plan)
+    run = runner_mod.make_runner(cfg, (chaos.compile_plan(plan, G),))
+    st, hl = sim_mod.init_state(cfg), sim_mod.init_health(cfg)
+    for call in (1, 2):
+        report = cs.run_plan()
+        st, hl, stats, safety = run(st, hl)
+        _assert_tree_equal((cs.state, cs._health), (st, hl), f"chaos g{G}")
+        assert report == HealthMonitor.chaos_report(
+            *jax.device_get((stats, safety)), plan.n_rounds
+        ), f"call {call}"
 
 
 def _run_reconfig(G, split):
     plan = _reconfig_plan()
     cfg = SimConfig(n_groups=G, n_peers=3, collect_health=True)
-    compiled = reconfig.compile_plan(plan, G)
-    ccompiled = chaos.compile_plan(
-        chaos.plan_from_dict(
-            {
-                "name": "unified-overlay",
-                "peers": 3,
-                "phases": [
-                    {"rounds": 32},
-                    {"rounds": 8, "loss_all": 0.03},
-                    {"rounds": 8},
-                ],
-            }
-        ),
-        G,
+    cplan = chaos.plan_from_dict(
+        {
+            "name": "unified-overlay",
+            "peers": 3,
+            "phases": [
+                {"rounds": 32},
+                {"rounds": 8, "loss_all": 0.03},
+                {"rounds": 8},
+            ],
+        }
+    )
+    vm, om, lm = reconfig.initial_masks(plan, G)
+    cs = ClusterSim(cfg, voter_mask=vm, outgoing_mask=om, learner_mask=lm)
+    report = cs.run_reconfig(
+        plan, chaos_plan=cplan, split=split, split_k=4, split_window=4
     )
 
-    def fresh():
-        st = sim_mod.init_state(cfg, *reconfig.initial_masks(plan, G))
-        return st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st)
-
-    if split:
-        out_legacy = reconfig.make_split_runner(
-            cfg, compiled, ccompiled, k=4, window=4
-        )(*fresh())
-        out_unified = runner_mod.make_runner(
-            cfg, (compiled, ccompiled), split=True, k=4, window=4
-        )(*fresh())
-    else:
-        out_legacy = reconfig.make_runner(cfg, compiled, ccompiled)(*fresh())
-        out_unified = runner_mod.make_runner(cfg, (compiled, ccompiled))(
-            *fresh()
-        )
+    run = runner_mod.make_runner(
+        cfg,
+        (reconfig.compile_plan(plan, G), chaos.compile_plan(cplan, G)),
+        split=split, k=4, window=4,
+    )
+    st = sim_mod.init_state(cfg, *reconfig.initial_masks(plan, G))
+    out = run(st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st))
     tag = "split" if split else "plain"
-    _assert_tree_equal(out_legacy, out_unified, f"reconfig-{tag} g{G}")
+    _assert_tree_equal(
+        (cs.state, cs._health, cs._reconfig_state), out[:3],
+        f"reconfig-{tag} g{G}",
+    )
+    stats, rstats, safety, om_h, since = jax.device_get(
+        out[3:6]
+        + (out[0].outgoing_mask, out[1].planes[kernels.HP_SINCE_COMMIT])
+    )
+    want = HealthMonitor.reconfig_report(
+        stats, rstats, safety, plan.n_rounds,
+        *HealthMonitor.reconfig_stall_groups(
+            om_h, since, cfg.election_tick, stall_timeouts=4,
+            topk=min(cfg.health_topk, G),
+        ),
+    )
+    if split:
+        total = plan.n_rounds * G
+        want.update(
+            fused_rounds=int(out[6]), total_rounds=total,
+            fused_frac=round(int(out[6]) / total, 4),
+        )
+        assert want["fused_rounds"] > 0
+    assert report == want
 
 
 def _run_workload(G, split):
+    """run_reads twice on one sim: state, health, the op-protocol carry
+    kept between the calls and the read carry, against the factory's
+    runner with a fresh read carry a call."""
     cfg = SimConfig(n_groups=G, n_peers=3, collect_health=True)
-    client = workload.compile_plan(_client_plan(), G)
-
-    def fresh():
-        st = sim_mod.init_state(cfg)
-        return (
-            st,
-            sim_mod.init_health(cfg),
-            reconfig.init_reconfig_state(st),
-            workload.init_read_carry(G),
-        )
-
-    if split:
-        out_legacy = workload.make_split_runner(cfg, client, k=4)(*fresh())
-        out_unified = runner_mod.make_runner(
-            cfg, (client,), split=True, k=4
-        )(*fresh())
-    else:
-        out_legacy = workload.make_runner(cfg, client)(*fresh())
-        out_unified = runner_mod.make_runner(cfg, (client,))(*fresh())
+    plan = _client_plan()
+    cs = ClusterSim(cfg)
+    run = runner_mod.make_runner(
+        cfg, (workload.compile_plan(plan, G),), split=split, k=4
+    )
+    st, hl = sim_mod.init_state(cfg), sim_mod.init_health(cfg)
+    rst = reconfig.init_reconfig_state(st)
     tag = "split" if split else "plain"
-    _assert_tree_equal(out_legacy, out_unified, f"workload-{tag} g{G}")
+    for call in (1, 2):
+        report = cs.run_reads(plan, split=split, split_k=4)
+        out = run(st, hl, rst, workload.init_read_carry(G))
+        st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist = out[:9]
+        _assert_tree_equal(
+            (cs.state, cs._health, cs._reconfig_state, cs._read_carry),
+            (st, hl, rst, rcar), f"workload-{tag} g{G} call {call}",
+        )
+        lat_p, recover_p = workload.report_percentiles(lat_hist, stats)
+        want = workload.read_report(
+            *jax.device_get((rdstats, lat_p, safety, stats)), plan.n_rounds,
+            *jax.device_get((recover_p, rstats)),
+        )
+        if split:
+            total = plan.n_rounds * G
+            want.update(
+                fused_rounds=int(out[9]), total_rounds=total,
+                fused_frac=round(int(out[9]) / total, 4),
+            )
+        assert report == want, f"call {call}"
 
 
 def _run_cadence(G):
-    """One whole-horizon cadence segment with live action planes (one
-    transfer target, two kicks) — the actions family's golden scenario."""
+    """The autopilot's loop over a crash window at cadence 8 (four
+    segments, the policy's kicks and transfers live) == the factory's
+    cadence segment driven by hand with the action planes the policy
+    chose, then the tail audit."""
     cfg = SimConfig(
         n_groups=G, n_peers=3, collect_health=True, transfer=True
     )
-    P = cfg.n_peers
-    ccompiled = chaos.compile_plan(_chaos_plan(), G)
-    R = ccompiled.n_rounds
-    compiled = autopilot.empty_reconfig_schedule(R, P, G)
+    P, cadence = cfg.n_peers, 8
+    plan = _chaos_plan()
+    cs = ClusterSim(cfg)
+    ap = Autopilot(cs, AutopilotConfig(cadence=cadence))
+    decided = []
+    decide = ap._decide
 
-    def fresh_args():
-        st = sim_mod.init_state(cfg)
-        transfer = np.zeros((G,), np.int32)
-        transfer[0] = 2
-        kick = np.zeros((P, G), bool)
-        kick[0, 1] = True
-        kick[1, 2 % G] = True
-        return (
-            st,
-            sim_mod.init_health(cfg),
-            reconfig.init_reconfig_state(st),
-            jnp.zeros((chaos.N_CHAOS_STATS,), jnp.int32),
-            jnp.zeros((reconfig.N_RECONFIG_STATS,), jnp.int32),
-            jnp.zeros((kernels.N_SAFETY,), jnp.int32),
-            jnp.int32(0),
-            jnp.int32(0),
+    def spy(summary, round_idx):
+        planes = decide(summary, round_idx)
+        decided.append(planes[:2])
+        return planes
+
+    ap._decide = spy
+    report = ap.run_plan(plan)
+    assert sum(report["actions"].values()) > 0, report["actions"]
+
+    ccompiled = chaos.compile_plan(plan, G)
+    R = ccompiled.n_rounds
+    compiled = reconfig.empty_reconfig_schedule(R, P, G)
+    run = runner_mod.make_runner(cfg, (compiled, ccompiled), cadence=cadence)
+    st = sim_mod.init_state(cfg)
+    carry = (
+        st,
+        sim_mod.init_health(cfg),
+        reconfig.init_reconfig_state(st),
+        jnp.zeros((chaos.N_CHAOS_STATS,), jnp.int32),
+        jnp.zeros((reconfig.N_RECONFIG_STATS,), jnp.int32),
+        jnp.zeros((kernels.N_SAFETY,), jnp.int32),
+        jnp.int32(0),
+    )
+    actions = [(np.zeros((G,), np.int32), np.zeros((P, G), bool))] + decided
+    assert len(actions) == R // cadence
+    for i, (transfer, kick) in enumerate(actions):
+        *carry, _fused = run(
+            *carry,
+            jnp.int32(i * cadence),
             jnp.asarray(transfer, dtype=jnp.int32),
             jnp.asarray(kick, dtype=bool),
             *runner_mod.schedule_args(compiled, ccompiled),
         )
-
-    out_legacy = autopilot.make_cadence_runner(cfg, compiled, ccompiled, R)(
-        *fresh_args()
+    st, hl, rst, stats, _rstats, safety, csr = carry
+    _assert_tree_equal((cs.state, cs._health), (st, hl), f"cadence g{G}")
+    safety = safety + runner_mod._tail_audit(st, rst)
+    want = HealthMonitor.chaos_report(
+        *jax.device_get((stats, safety)), R
     )
-    out_unified = runner_mod.make_runner(
-        cfg, (compiled, ccompiled), cadence=R
-    )(*fresh_args())
-    _assert_tree_equal(out_legacy, out_unified, f"cadence g{G}")
+    assert {k: report[k] for k in want} == want
+    assert report["commit_stall_group_rounds"] == int(csr)
 
 
 # --- tier-1: G=8 ----------------------------------------------------------
 
 
-def test_chaos_wrapper_bit_identical_g8():
+def test_run_plan_is_the_chaos_runner_g8():
     _run_chaos(8)
 
 
-def test_reconfig_wrapper_bit_identical_g8():
+def test_run_reconfig_is_the_reconfig_runner_g8():
     _run_reconfig(8, split=False)
 
 
-def test_reconfig_split_wrapper_bit_identical_g8():
+def test_run_reconfig_split_is_the_reconfig_split_runner_g8():
     _run_reconfig(8, split=True)
 
 
-def test_workload_wrapper_bit_identical_g8():
+def test_run_reads_is_the_workload_runner_g8():
     _run_workload(8, split=False)
 
 
-def test_workload_split_wrapper_bit_identical_g8():
+def test_run_reads_split_is_the_workload_split_runner_g8():
     _run_workload(8, split=True)
 
 
-def test_cadence_wrapper_bit_identical_g8():
+def test_autopilot_loop_is_the_cadence_runner_g8():
     _run_cadence(8)
 
 
@@ -236,32 +285,32 @@ def test_cadence_wrapper_bit_identical_g8():
 
 
 @pytest.mark.slow
-def test_chaos_wrapper_bit_identical_g32():
+def test_run_plan_is_the_chaos_runner_g32():
     _run_chaos(32)
 
 
 @pytest.mark.slow
-def test_reconfig_wrapper_bit_identical_g32():
+def test_run_reconfig_is_the_reconfig_runner_g32():
     _run_reconfig(32, split=False)
 
 
 @pytest.mark.slow
-def test_reconfig_split_wrapper_bit_identical_g32():
+def test_run_reconfig_split_is_the_reconfig_split_runner_g32():
     _run_reconfig(32, split=True)
 
 
 @pytest.mark.slow
-def test_workload_wrapper_bit_identical_g32():
+def test_run_reads_is_the_workload_runner_g32():
     _run_workload(32, split=False)
 
 
 @pytest.mark.slow
-def test_workload_split_wrapper_bit_identical_g32():
+def test_run_reads_split_is_the_workload_split_runner_g32():
     _run_workload(32, split=True)
 
 
 @pytest.mark.slow
-def test_cadence_wrapper_bit_identical_g32():
+def test_autopilot_loop_is_the_cadence_runner_g32():
     _run_cadence(32)
 
 

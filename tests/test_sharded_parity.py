@@ -15,11 +15,13 @@ is bit-identical to the cond form on and off campaign rounds.
 Tier-1 keeps the spmd-identity unit, the plain-scan parity case, the
 drain-overlap/counter parity case (the multichip CI tool replays the
 corpora but not the instrumented run_compiled path), and the
-total_commit overflow regression; the golden chaos AND reconfig
-corpora, the damped packed-carry scan at mesh-tiling width, the
-client-read workload, and the split-fused production plan are
-slow-marked (870s gate — ROADMAP.md) and replayed by the multichip CI
-job via tools/sharded_parity_report.py.
+total_commit overflow regression and, since PR 32, the damped
+packed-carry scan at mesh-tiling width and the client-read workload (the
+damped bodies every benchmark cell runs); the golden chaos AND reconfig
+corpora and the split-fused production plan are slow-marked (23-40 s
+each; tier-1 takes 247 s of its 1470 s limit under xdist -n 6 at PR 32) and the
+corpora are replayed by the multichip CI job via
+tools/sharded_parity_report.py.
 """
 
 import functools
@@ -95,7 +97,6 @@ def test_sharded_scan_parity_plain():
     assert_state_equal(a.state, b.state, "scan")
 
 
-@pytest.mark.slow  # damped scan compile x2 at the mesh-tiling width
 def test_sharded_damped_scan_parity_packed_carry():
     """The damped mesh scan: the bits_g packed recent_active carry rides
     the donated segments sharded on its group-minor word axis (G=256:
@@ -181,7 +182,6 @@ def test_sharded_golden_reconfig_corpus():
         assert ra == rb, f"{plan.name}: report diverged"
 
 
-@pytest.mark.slow  # workload-runner compile x2 (damped + lease)
 def test_sharded_reads_parity():
     """The compiled client workload (Zipf writes + lease/safe reads) with
     a chaos overlay in the SAME scan replays bit-identically on the
@@ -241,9 +241,9 @@ def test_sharded_split_fused_prod_plan():
     G = 256
     # collect_counters stays off: ClusterSim.run_reconfig(split=True)
     # refuses plans longer than the GC008 per-window drain cap (256
-    # rounds > 128) — the counters-threaded split path is bench
-    # --prod-fused's direct make_split_runner drive, and mesh counter
-    # parity is pinned by test_sharded_drain_overlap_counter_parity.
+    # rounds > 128) — the counters-threaded split path is pinned by
+    # tests/test_reconfig_split.py, and mesh counter parity by
+    # test_sharded_drain_overlap_counter_parity.
     cfg = SimConfig(
         n_groups=G, n_peers=plan.n_peers, election_tick=64,
         collect_health=True,

@@ -51,7 +51,9 @@ def SimState_fields():
 def test_global_status_collectives():
     cfg = SimConfig(n_groups=16, n_peers=3)
     mesh = sharding.make_mesh()
-    st, status = sharding.run_sharded(cfg, mesh, rounds=30)
+    cs = ClusterSim(cfg, mesh=mesh)
+    cs.run_compiled(30, append_n=jnp.ones((cfg.n_groups,), jnp.int32))
+    status = jax.tree.map(int, sharding.global_status(cfg, mesh)(cs.state))
     # After 30 quiet rounds every group has elected a leader and committed
     # its noop + 1 append per round.
     assert status["n_leaders"] == cfg.n_groups
@@ -60,8 +62,7 @@ def test_global_status_collectives():
     assert status["total_commit"] >= cfg.n_groups
 
 
-@pytest.mark.slow  # ~74s: the P=5 step + sharded-barrier compiles dominate
-# the tier-1 budget (870s gate saturated — ROADMAP.md); the unsharded
+@pytest.mark.slow  # ~74s of P=5 step + sharded-barrier compiles; the unsharded
 # read_index semantics stay tier-1 in test_read_index_batch.py and the
 # sharding mechanics in this file's shard-invariance cases.
 def test_sharded_read_index_matches_local():

@@ -155,7 +155,7 @@ def test_initial_state():
 
 
 # --- ArrayStorage: the dense SoA twin must behave exactly like MemStorage
-# through the public surface (the VERDICT "Missing #4" satellite) ---
+# through the public surface ---
 
 
 def _drive(store):

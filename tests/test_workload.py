@@ -24,6 +24,7 @@ import pytest
 
 from raft_tpu.multiraft import ClusterSim, ScalarCluster, SimConfig, sim
 from raft_tpu.multiraft import chaos, kernels, reconfig, workload
+from raft_tpu.multiraft import runner as runner_mod
 from raft_tpu.multiraft.simref import ReadOracle
 
 TESTDATA = os.path.join(os.path.dirname(__file__), "testdata")
@@ -197,7 +198,7 @@ def run_workload_vs_replay(cfg, client_plan, chaos_plan=None):
         else None
     )
     compiled = workload.compile_plan(client_plan, cfg.n_groups)
-    runner = workload.make_runner(cfg, compiled, compiled_chaos)
+    runner = runner_mod.make_runner(cfg, (compiled, compiled_chaos))
     rst = reconfig.init_reconfig_state(cs.state)
     rcar = workload.init_read_carry(cfg.n_groups)
     out = runner(cs.state, cs._health, rst, rcar)
@@ -390,7 +391,7 @@ def split_plan_fixture():
 
 
 def test_split_runner_bit_identical_and_fuses():
-    """workload.make_split_runner vs make_runner from one settled state:
+    """The workload split runner vs the scan runner from one settled state:
     every output — end state, health planes, op carry, stats, safety,
     read stats, latency histogram — bit-identical, with the pure-lease
     phase FUSED (lease serves fold closed-form) and every safe-read
@@ -403,8 +404,8 @@ def test_split_runner_bit_identical_and_fuses():
     plan = split_plan_fixture()
     compiled = workload.compile_plan(plan, cfg.n_groups)
     k = 8
-    general = workload.make_runner(cfg, compiled)
-    split = workload.make_split_runner(cfg, compiled, k=k)
+    general = runner_mod.make_runner(cfg, (compiled,))
+    split = runner_mod.make_runner(cfg, (compiled,), split=True, k=k)
 
     def fresh():
         return (

@@ -1,11 +1,11 @@
 """graftcheck: repo-specific static analysis for the TPU-kernel and parity
 invariants (docs/STATIC_ANALYSIS.md).
 
-Usage:  python -m tools.graftcheck raft_tpu tests bench.py benches
+Usage:  python -m tools.graftcheck raft_tpu tests docs README.md CHANGES.md
 
 Rules (each with a `# graftcheck: allow-<rule> — <why>` escape hatch):
 
-  GC001 no-implicit-dtype          explicit dtypes in device/bench modules
+  GC001 no-implicit-dtype          explicit dtypes in device modules
   GC002 no-host-sync-in-jit        no host syncs in sim/kernels/pallas_step
   GC003 no-python-branch-on-traced no Python control flow on traced values
   GC004 metrics-guarded            metrics hooks behind the enabled-check

@@ -299,7 +299,7 @@ def extract(
         candidates = [
             c
             for c in _DOTTED_RE.findall(oracle_text)
-            # drop file names (majority.rs, bench.py): a citation, not a
+            # drop file names (majority.rs, sim.py): a citation, not a
             # symbol
             if c.rsplit(".", 1)[-1] not in ("rs", "cpp", "cc", "h", "go",
                                             "py", "md")

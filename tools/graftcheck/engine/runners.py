@@ -52,8 +52,8 @@ _GATHERS = {"round", "phase", "op", "fire", "fold"}
 _DTYPES = {"int32", "uint32", "bool"}
 
 # The modules whose schedule handling must go through the registry
-# accessors — the unified runner, the four wrapper modules, and sim.py's
-# dispatch sites.
+# accessors — the runner factory, the schedule modules, the autopilot,
+# and sim.py's dispatch sites.
 _RUNNER_MODULES = (
     "chaos", "reconfig", "workload", "autopilot", "runner", "sim",
 )

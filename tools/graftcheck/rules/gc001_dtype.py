@@ -1,12 +1,12 @@
 """GC001 no-implicit-dtype.
 
-Every jnp array constructor in the device modules (and the benches that
-feed them) must pass an explicit dtype.  The batched backend's parity
-contract is "all planes are int32/bool" (raft_tpu/multiraft/kernels.py);
-jnp's weak-typing rules otherwise promote Python scalars platform- and
-context-dependently (int -> int32 vs int64 under x64, bool -> bool vs
-int32 after arithmetic), which is exactly the class of silent divergence
-the scalar-vs-device parity suite cannot localize.
+Every jnp array constructor in the device modules must pass an explicit
+dtype.  The batched backend's parity contract is "all planes are int32/bool"
+(raft_tpu/multiraft/kernels.py); jnp's weak-typing rules otherwise promote
+Python scalars platform- and context-dependently (int -> int32 vs int64
+under x64, bool -> bool vs int32 after arithmetic), which is exactly the
+class of silent divergence the scalar-vs-device parity suite cannot
+localize.
 """
 
 from __future__ import annotations
@@ -33,17 +33,10 @@ _CTORS = {
 class NoImplicitDtype(Rule):
     id = "GC001"
     slug = "no-implicit-dtype"
-    doc = "jnp constructors in device/bench modules must pass an explicit dtype"
+    doc = "jnp constructors in device modules must pass an explicit dtype"
 
     def applies(self, sf: SourceFile) -> bool:
-        p = sf.norm()
-        return sf.is_python and (
-            "raft_tpu/multiraft/" in p
-            or p.endswith("/bench.py")
-            or p == "bench.py"
-            or "/benches/" in p
-            or p.startswith("benches/")
-        )
+        return sf.is_python and "raft_tpu/multiraft/" in sf.norm()
 
     def check(self, sf: SourceFile, ctx: Context) -> Iterator[Violation]:
         for node in ast.walk(sf.ast_tree):

@@ -1,6 +1,6 @@
 """GC014: the committed jaxpr-size budget (tools/graftcheck/jaxpr_budget.json).
 
-The budget file is the compile-time twin of BENCH_baseline.json: one
+The budget file is a committed baseline for compile time: one
 committed equation count per inventoried graph, checked on every trace run
 and regenerated only deliberately (``--update-budget`` / ``make
 jaxpr-budget``), so jaxpr growth — which is compile time, which is tier-1
@@ -24,8 +24,8 @@ File format::
 Failure modes (each a GC014 violation): a measured graph above its entry
 by more than ``tolerance_pct``; an inventoried graph with no entry (new
 graphs must be budgeted in the same PR); a budget entry naming no
-inventoried graph (stale — regenerate).  Shrinkage never fails (mirroring
-the bench gate, which only gates regressions) but is recorded in the diff
+inventoried graph (stale — regenerate).  Shrinkage never fails (only
+regressions are gated) but is recorded in the diff
 artifact so an intentional reduction can be re-baselined.
 """
 
@@ -335,7 +335,7 @@ def check_budget(
                 )
             )
         elif eqns < budget * (1.0 - tolerance / 100.0):
-            # An improvement never fails (the bench-gate convention), but a
+            # An improvement never fails, but a
             # stale high baseline hands the next regression free headroom —
             # the diff artifact flags it for re-baselining.
             status = "shrunk"

@@ -3,7 +3,7 @@
 Each ``GraphSpec`` names one compiled artifact of the production system —
 entry point + flag combination + the donation structure its production
 wrapper declares — and a builder that constructs it EXACTLY the way the
-production wrapper does (``ClusterSim``'s jits, ``chaos.make_runner``,
+production wrapper does (``ClusterSim``'s jits, ``runner.make_runner``,
 ``sharding.sharded_step``, the fused dispatchers), at a tiny audit shape
 (G=8, P=3: jaxpr size and donation structure are shape-independent, so
 the audit shape only has to be cheap).  ``trace/analysis.py`` runs
@@ -13,9 +13,8 @@ GC011-GC014 over the built artifacts; ``jaxpr_budget.json`` is keyed by
 This registry is deliberately declarative — the flag matrix
 (plain/counters/health/chaos x undamped/cq/cq+pv) and each graph's
 expected donate_argnums live HERE, not scattered through the builders —
-as the first concrete piece of ROADMAP item 5's promote-the-registry-to-
-source-of-truth refactor: a new plane or flag lands as one more row, and
-the trace gates come for free.
+so a new plane or flag lands as one more row, and the trace gates come
+for free.
 
 Builders import jax/raft_tpu lazily so this module (and the rule
 registry that imports it) stays importable in jax-less environments;
@@ -275,6 +274,7 @@ def _dispatcher_builder(damping: dict, with_health: bool):
 def _chaos_runner_builder(blackbox: bool = False):
     def build() -> Built:
         from raft_tpu.multiraft import chaos
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         cfg = sim.SimConfig(
@@ -293,7 +293,7 @@ def _chaos_runner_builder(blackbox: bool = False):
             ],
         )
         compiled = chaos.compile_plan(plan, G)
-        runner = chaos.make_runner(cfg, compiled)
+        runner = runner_mod.make_runner(cfg, (compiled,))
         # make_runner exposes its underlying jit and full argument list
         # (state, health, *schedule arrays) precisely for this audit.
         bb = (sim.init_blackbox(cfg),) if blackbox else ()
@@ -332,6 +332,7 @@ def _reconfig_runner_builder(
 ):
     def build() -> Built:
         from raft_tpu.multiraft import chaos, reconfig
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         dflags = (
@@ -371,7 +372,7 @@ def _reconfig_runner_builder(
             chaos_compiled = chaos.compile_plan(cplan, G)
         vm, om, lm = reconfig.initial_masks(plan, G)
         st = sim.init_state(cfg, vm, om, lm)
-        runner = reconfig.make_runner(cfg, compiled, chaos_compiled)
+        runner = runner_mod.make_runner(cfg, (compiled, chaos_compiled))
         # make_runner exposes its underlying jit and full argument list
         # (state, health, rstate, *schedule arrays) for this audit.
         return Built(
@@ -391,6 +392,7 @@ def _split_runner_builder():
         import jax.numpy as jnp
 
         from raft_tpu.multiraft import chaos, kernels, reconfig
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         cfg = sim.SimConfig(
@@ -417,9 +419,9 @@ def _split_runner_builder():
         chaos_compiled = chaos.compile_plan(cplan, G)
         vm, om, lm = reconfig.initial_masks(plan, G)
         st = sim.init_state(cfg, vm, om, lm)
-        runner = reconfig.make_split_runner(
-            cfg, compiled, chaos_compiled, k=DISPATCH_K, window=4,
-            with_counters=True,
+        runner = runner_mod.make_runner(
+            cfg, (compiled, chaos_compiled), split=True, k=DISPATCH_K,
+            window=4, with_counters=True,
         )
         # The fused-block jit is the split runner's hot graph: the
         # steady-predicate + pending guard, the fused kernel, AND the
@@ -471,7 +473,8 @@ def _autopilot_runner_builder():
     def build() -> Built:
         import jax.numpy as jnp
 
-        from raft_tpu.multiraft import autopilot, chaos, kernels, reconfig
+        from raft_tpu.multiraft import chaos, kernels, reconfig
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         cfg = sim.SimConfig(
@@ -488,15 +491,13 @@ def _autopilot_runner_builder():
             ],
         )
         chaos_compiled = chaos.compile_plan(cplan, G)
-        compiled = autopilot.empty_reconfig_schedule(
+        compiled = reconfig.empty_reconfig_schedule(
             SCAN_ROUNDS * 2, P, G
         )
-        runner = autopilot.make_cadence_runner(
-            cfg, compiled, chaos_compiled, SCAN_ROUNDS
+        runner = runner_mod.make_runner(
+            cfg, (compiled, chaos_compiled), cadence=SCAN_ROUNDS
         )
         st, _, _ = _base_args(cfg)
-        from raft_tpu.multiraft import runner as runner_mod
-
         # The flat schedule tail comes from the registry
         # (runner.schedule_args) — never hand-listed (GC018).
         args = (
@@ -564,6 +565,7 @@ def _client_plan():
 def _workload_runner_builder():
     def build() -> Built:
         from raft_tpu.multiraft import reconfig, workload
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         cfg = sim.SimConfig(
@@ -571,7 +573,7 @@ def _workload_runner_builder():
             check_quorum=True, lease_read=True,
         )
         compiled = workload.compile_plan(_client_plan(), G)
-        runner = workload.make_runner(cfg, compiled)
+        runner = runner_mod.make_runner(cfg, (compiled,))
         st, _, _ = _base_args(cfg)
         return Built(
             runner.jitted,
@@ -591,6 +593,7 @@ def _workload_split_builder():
         import jax.numpy as jnp
 
         from raft_tpu.multiraft import chaos, kernels, reconfig, workload
+        from raft_tpu.multiraft import runner as runner_mod
 
         sim = _sim()
         cfg = sim.SimConfig(
@@ -598,7 +601,9 @@ def _workload_split_builder():
             check_quorum=True, lease_read=True,
         )
         compiled = workload.compile_plan(_client_plan(), G)
-        runner = workload.make_split_runner(cfg, compiled, k=DISPATCH_K)
+        runner = runner_mod.make_runner(
+            cfg, (compiled,), split=True, k=DISPATCH_K
+        )
         st, _, _ = _base_args(cfg)
         # The fused-block jit is the split runner's hot graph: the
         # steady/read-pending/lease-provable predicate, the fused damped
@@ -758,27 +763,18 @@ _RUNNER_BUILDERS: Dict[str, Callable[..., Callable[[], Built]]] = {
     "autopilot": _autopilot_runner_builder,
 }
 
-# builder key -> the repo-relative module the variant's legacy entry
-# point (now a thin wrapper over runner.make_runner) lives in.
-_RUNNER_ANCHORS: Dict[str, str] = {
-    "chaos": "raft_tpu/multiraft/chaos.py",
-    "reconfig": "raft_tpu/multiraft/reconfig.py",
-    "reconfig_split": "raft_tpu/multiraft/reconfig.py",
-    "workload": "raft_tpu/multiraft/workload.py",
-    "workload_split": "raft_tpu/multiraft/workload.py",
-    "autopilot": "raft_tpu/multiraft/autopilot.py",
-}
+# Every runner variant is built by runner.make_runner.
+_RUNNER_ANCHOR = "raft_tpu/multiraft/runner.py"
 
 
 def _runner_specs() -> List[GraphSpec]:
     """One GraphSpec per schedules.RUNNER_VARIANTS row: names, builder
-    selection, and builder options all come from the schedule registry
-    (the ROADMAP item 5 source-of-truth promotion, runner half)."""
+    selection, and builder options all come from the schedule registry."""
     schedules = _schedules_mod()
     return [
         GraphSpec(
             name=variant.name,
-            anchor=_RUNNER_ANCHORS[variant.builder],
+            anchor=_RUNNER_ANCHOR,
             build=_RUNNER_BUILDERS[variant.builder](
                 **dict(variant.options)
             ),
