@@ -9,8 +9,9 @@ end-to-end metrics; `--trace 1`: its per-layer metrics), each as
 `device` holds `platform`, `kind`, `count`, `memory_peak_bytes` and, traced,
 `window_s` and `busy_s` with 0 < busy_s <= window_s.  A per-layer metric may
 be absent only where the run said it left it out (`run.read_metrics`: the
-program under test does not carry a name the metric's reader asks it for);
-`validate` is told which.
+program under test does not carry a name the metric's reader asks it for, or
+the subject the metric's file `needs` did not run in the window); `validate`
+is told which.
 
 `build` is the only place a value is cast for JSON, and it refuses what
 JSON cannot say (NaN, infinities): a metric that cannot be computed is an

@@ -20,11 +20,25 @@ what the program under test carries (`run.program_names`): a None from a
 reader whose names the program lacks leaves the metric out of the line (an
 older or newer program is no fault of the run); a None although the program
 carries every name stops the run.
+
+A metric file may also state `"needs": <condition>`: the subject its reader
+reads, by a name of `CONDITIONS`.  `not_run` holds it against the window's
+exact counts: a None from a reader whose subject did not run in the window
+leaves the metric out too (nothing ran that it could have read); a None
+although the subject ran stops the run.  No reader returns 0 for "not run".
 """
 
 import importlib
 import re
-from typing import Dict, List
+from typing import Callable, Dict, List, Optional, Tuple
+
+# condition -> (did the subject run, by `facts["counters"]`; the reason where not)
+CONDITIONS: Dict[str, Tuple[Callable[[dict], bool], str]] = {
+    "general_rounds": (
+        lambda c: c["group_rounds"] - c.get("fused_rounds", 0) > 0,
+        "the window ran no general round",
+    ),
+}
 
 
 def load(name: str):
@@ -38,6 +52,15 @@ def lacking(reducer, args: dict, program: Dict[str, set]) -> List[str]:
         return []
     return [f"{kind} {name!r}" for kind, names in ask(args).items()
             for name in names if name not in program.get(kind, ())]
+
+
+def not_run(needs: Optional[str], counters: dict) -> Optional[str]:
+    """Why the subject a metric file `needs` did not run in the window, by
+    the reports' exact counts; None where it ran, or the file states none."""
+    if needs is None:
+        return None
+    ran, reason = CONDITIONS[needs]
+    return None if ran(counters) else reason
 
 
 def matching(op_seconds: Dict[str, List[float]], pattern: str):

@@ -21,7 +21,7 @@ import json
 import os
 import shutil
 import sys
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -60,15 +60,31 @@ def find_cell(bench: dict, workload: str) -> Tuple[dict, dict, dict]:
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = load_json(ROOT, entry["file"])
     mix = traffic.load_mix(cell["traffic"])
+    n = mix.get("counted_segments")
+    if n is not None and (isinstance(n, bool) or not isinstance(n, int) or n < 1):
+        raise BenchError(f"mix {cell['traffic']!r}: counted_segments is not a positive integer: {n!r}")
     return cell, config, mix
 
 
-def metric_readers(bench: dict, workload: str) -> Dict[str, Tuple[str, object, dict]]:
-    """{per-layer metric of this cell: (unit, reducer module, args)}."""
+class Reader(NamedTuple):
+    """One per-layer metric's file, loaded."""
+
+    unit: str
+    reducer: object  # a module of reducers/
+    args: dict
+    needs: Optional[str] = None  # a name of reducers.CONDITIONS
+
+
+def metric_readers(bench: dict, workload: str) -> Dict[str, Reader]:
+    """{per-layer metric of this cell: its reader}."""
     out = {}
     for name, unit in line.expected_metrics(bench, workload, traced=True).items():
         spec = load_json(HERE, "metrics", f"{name}.json")
-        out[name] = (unit, reducers.load(spec["reducer"]), spec.get("args", {}))
+        needs = spec.get("needs")
+        if needs is not None and needs not in reducers.CONDITIONS:
+            raise BenchError(f"metrics/{name}.json needs {needs!r}, which is not one of "
+                             f"{sorted(reducers.CONDITIONS)}")
+        out[name] = Reader(unit, reducers.load(spec["reducer"]), spec.get("args", {}), needs)
     return out
 
 
@@ -92,23 +108,29 @@ def program_names(report: dict) -> Dict[str, set]:
     }
 
 
-def read_metrics(readers, facts: dict, program: Dict[str, set], workload: str, say):
+def read_metrics(readers: Dict[str, Reader], facts: dict, program: Dict[str, set],
+                 workload: str, say):
     """({metric: (value, unit)}, [metrics left out]).  A reader that finds
     nothing: left out where the program lacks a name the reader asks it for,
-    an error where it carries them all."""
+    or where the subject its file `needs` did not run in the window by the
+    reports' exact counts; an error in every other case."""
     metrics, left_out = {}, []
-    for name, (unit, reducer, args) in readers.items():
-        value = reducer.read(facts, args)
+    for name, r in readers.items():
+        value = r.reducer.read(facts, r.args)
         if value is not None:
-            metrics[name] = (value, unit)
+            metrics[name] = (value, r.unit)
             continue
-        lacks = reducers.lacking(reducer, args, program)
-        if not lacks:
+        lacks = reducers.lacking(r.reducer, r.args, program)
+        if lacks:
+            why = f"the program has no {', '.join(lacks)}"
+        else:
+            why = reducers.not_run(r.needs, facts.get("counters", {}))
+        if why is None:
             raise BenchError(
                 f"per-layer metric {name!r} is listed for {workload} but its "
                 "reader found nothing to read in this run"
             )
-        say(f"metric {name} left out: the program has no {', '.join(lacks)}")
+        say(f"metric {name} left out: {why}")
         left_out.append(name)
     return metrics, left_out
 
@@ -272,22 +294,62 @@ def median(values: List[float]) -> float:
     return s[n // 2] if n % 2 else 0.5 * (s[n // 2 - 1] + s[n // 2])
 
 
-def end_to_end(reports, seconds: float, setup_s: float, n_groups: int,
-               wanted: Dict[str, str]) -> Dict[str, Tuple[float, str]]:
-    rounds = sum(r["rounds"] for r in reports)
-    ms_per_round = 1e3 * seconds / rounds
-    values = {"setup_s": setup_s, "group_rounds_per_s": n_groups * rounds / seconds}
+def counted(reports: List[dict], mix: dict) -> List[dict]:
+    """The timed reports the line's exact counts are taken over: the first
+    `counted_segments` where the mix states it (what there is, where the
+    window held fewer), every one where it does not.  A count that is exact
+    per segment then does not follow how many segments a program fits into
+    the window; time is taken over the whole window all the same."""
+    n = mix.get("counted_segments")
+    return reports if n is None else reports[:n]
+
+
+def op_counts(reports: List[dict], ops_per_segment: int) -> Dict[str, int]:
+    """Operations offered in some segments, and those of them that failed
+    inside a segment: fires dropped behind a read in flight, and reads
+    still outstanding at a segment's end."""
+    dropped = sum(int(r["dropped_fires"]) for r in reports)
+    outstanding = sum(
+        max(0, r["reads_issued"] - r["served_lease"] - r["served_quorum"]) for r in reports
+    )
+    return {
+        "segments": len(reports),
+        "attempted": len(reports) * ops_per_segment,
+        "dropped_fires": dropped,
+        "outstanding_reads": outstanding,
+        "failed_in_segments": dropped + outstanding,
+    }
+
+
+def round_counts(reports: List[dict], wanted) -> Dict[str, float]:
+    """The round counts inside the two latencies: the medians over some
+    segments of the read histogram's p99 and of the mean leaderless episode."""
+    out = {}
     if "read_p99_ms" in wanted:
         p99 = [r["read_p99"] for r in reports]
         if min(p99) < 0:
             raise BenchError("a segment served no read: read_p99 is -1, not a latency")
-        # +1: a read served in its own round is answered when the round ends.
-        values["read_p99_ms"] = (median(p99) + 1) * ms_per_round
+        out["read_p99_rounds"] = median(p99)
     if "recover_ms" in wanted:
         mttr = [r["mttr_rounds"] for r in reports]
         if any(m is None for m in mttr):
             raise BenchError("a segment ended no leaderless episode: no mttr_rounds")
-        values["recover_ms"] = median(mttr) * ms_per_round
+        out["mttr_rounds"] = median(mttr)
+    return out
+
+
+def end_to_end(reports, counts: Dict[str, float], seconds: float, setup_s: float,
+               n_groups: int, wanted: Dict[str, str]) -> Dict[str, Tuple[float, str]]:
+    """`reports`: every timed segment — rounds and seconds are the whole
+    window's.  `counts`: `round_counts` of the counted segments."""
+    rounds = sum(r["rounds"] for r in reports)
+    ms_per_round = 1e3 * seconds / rounds
+    values = {"setup_s": setup_s, "group_rounds_per_s": n_groups * rounds / seconds}
+    if "read_p99_ms" in wanted:
+        # +1: a read served in its own round is answered when the round ends.
+        values["read_p99_ms"] = (counts["read_p99_rounds"] + 1) * ms_per_round
+    if "recover_ms" in wanted:
+        values["recover_ms"] = counts["mttr_rounds"] * ms_per_round
     missing = set(wanted) - set(values)
     if missing:
         raise BenchError(f"no code computes the end-to-end metric(s) {sorted(missing)}")
@@ -398,17 +460,27 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
         say(f"warning: {compiled_inside} program(s) compiled inside the window")
 
     counters = summed(reports, G)
-    attempted = len(reports) * (seg.read_fires + seg.write_batches + seg.conf_ops)
     # Groups that are not back in the configuration's membership: an op of
     # the last segment that did not land.
     astray = int(np.any(
         [(got != home).any(axis=0) for got, home in zip((voter, outgoing, learner), fleet.home)],
         axis=0).sum())
-    outstanding = sum(
-        max(0, r["reads_issued"] - r["served_lease"] - r["served_quorum"])
-        for r in reports
-    )
-    failed = int(counters["dropped_fires"]) + outstanding + rejected + astray
+    # The line's exact counts are taken over the mix's stated number of
+    # segments.  `rejected` (the checks read every report) and `astray` (a
+    # state at the window's end) are the whole window's: 0 on a sound program.
+    ops_per_segment = seg.read_fires + seg.write_batches + seg.conf_ops
+    in_line = counted(reports, mix)
+    ops, whole = op_counts(in_line, ops_per_segment), op_counts(reports, ops_per_segment)
+    rounds_in_line = round_counts(in_line, wanted)
+    attempted = ops["attempted"]
+    failed = ops["failed_in_segments"] + rejected + astray
+    whole_failed = whole["failed_in_segments"] + rejected + astray
+    say(json.dumps({"counted": {
+        "counted_segments": mix.get("counted_segments"), **ops, **rounds_in_line,
+        "failed": failed, "failed_share_pct": 100.0 * failed / attempted,
+        "whole_window": {**whole, "failed": whole_failed,
+                         "failed_share_pct": 100.0 * whole_failed / whole["attempted"]},
+    }}))
     say(json.dumps({"window": {
         "seconds": window_s, "segments": len(reports), "counters": counters,
         "read_p99_rounds": [r["read_p99"] for r in reports],
@@ -419,7 +491,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float, traced: bool
     }}))
 
     if not traced:
-        metrics = end_to_end(reports, window_s, setup_s, G, wanted)
+        metrics = end_to_end(reports, rounds_in_line, window_s, setup_s, G, wanted)
         return line.build(
             correct=rejected == 0, attempted=attempted, failed=failed,
             metrics=metrics, device=device,

@@ -105,6 +105,30 @@ def test_every_per_layer_metric_has_a_reader_that_agrees(bench):
         assert callable(reducers.load(spec["reducer"]).read)
         if "roofline" in m["name"]:
             assert m["unit"] == "%"
+        assert set(spec) <= {"layer", "unit", "moves", "source", "reducer", "args", "needs"}
+        if "needs" in spec:  # the reader's subject, by a condition run.py can evaluate
+            assert spec["needs"] in reducers.CONDITIONS, (m["name"], spec["needs"])
+
+
+def test_conditions_are_a_predicate_on_the_counts_and_a_reason():
+    mixed = {"group_rounds": 10, "fused_rounds": 8}
+    for name, (ran, reason) in reducers.CONDITIONS.items():
+        assert callable(ran) and isinstance(reason, str) and reason, name
+        assert reducers.not_run(name, mixed) is None
+    assert reducers.not_run(None, {}) is None
+    all_fused = {"group_rounds": 10, "fused_rounds": 10}
+    assert reducers.not_run("general_rounds", all_fused) == "the window ran no general round"
+    assert reducers.not_run("general_rounds", {"group_rounds": 10}) is None  # no fused accounting
+
+
+def test_counted_segments_is_a_positive_integer_where_a_mix_states_it(bench):
+    for mix in sorted({w["traffic"] for w in bench["workloads"]}):
+        with open(os.path.join(ROOT, "benchmark", "traffic", mix + ".json"), encoding="utf-8") as f:
+            doc = json.load(f)
+        if "counted_segments" in doc:
+            n = doc["counted_segments"]
+            assert isinstance(n, int) and not isinstance(n, bool) and n >= 1, (mix, n)
+            assert "counted_segments" in doc["assumed"], mix  # and says why
 
 
 def test_file_names_under_paths_use_name_characters():

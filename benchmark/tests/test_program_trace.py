@@ -1,10 +1,17 @@
 """The one reader of a run's capture — what the program itself writes into
 it, merged with `trace.py`'s reduction into the `facts` every reducer gets —
-and the four reducers that read the program's names: on a small recording from the chip WITH stats and
-name stacks (tests/data/program_trace.json: TPU v5 lite, a 2048 x 3 fleet,
-two `run_reads(split=True)` calls of two fused blocks and a general tail of
-one round, PR 26), and on a capture made here on the CPU for the wire
-format."""
+and the reducers that read the program's names: on small recordings from the
+chip WITH stats and name stacks (tests/data/program_trace*.json, TPU v5
+lite: `program_trace.json`, a 2048 x 3 fleet, two `run_reads(split=True)`
+calls of two fused blocks and a general tail of one round, PR 26;
+`program_trace_rebalance.json`, the head of one `.rebalance` segment at
+2048 x 5, PR 33), and on a capture made here on the CPU for the wire format.
+
+Which metric files read the program's names is derived from
+`metrics/*.json` (a reducer with a `names` function), and each file is read
+on the first recording whose program carries every name it asks for: a
+later PR that adds such a file adds a recording that shows it, and edits no
+list here."""
 
 import glob
 import json
@@ -17,6 +24,8 @@ from benchmark import reducers, run, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 RECORDED = os.path.join(HERE, "data", "program_trace.json")
+RECORDINGS = [RECORDED] + sorted(  # the PR 26 one first: most files read it
+    set(glob.glob(os.path.join(HERE, "data", "program_trace*.json"))) - {RECORDED})
 METRICS = os.path.join(os.path.dirname(HERE), "metrics")
 SPECS = {
     os.path.basename(p)[:-len(".json")]: json.load(open(p, encoding="utf-8"))
@@ -37,6 +46,33 @@ def cap():
     return pt.load_recorded(RECORDED)
 
 
+def carried(cap):
+    """The names a recording shows its program to carry: its spans, the
+    counts they were closed with, every component of its ops' name stacks."""
+    parts = {part for o in cap.ops for part in o.path.rstrip(":").split("/")}
+    return {"spans": {s.name for s in cap.spans}, "scopes": parts, "kernels": parts,
+            "counts": {k for s in cap.spans for k in s.stats}}
+
+
+@pytest.fixture(scope="module")
+def recording_of():
+    """{metric file that reads the program's names: (capture, facts) of the
+    first recording that carries them all}."""
+    shown = []
+    for path in RECORDINGS:
+        cap = pt.load_recorded(path)
+        shown.append((path, cap, pt.facts_of(cap), carried(cap)))
+    out = {}
+    for name in NEW:
+        spec = SPECS[name]
+        reducer = reducers.load(spec["reducer"])
+        for path, cap, facts, program in shown:
+            if not reducers.lacking(reducer, spec["args"], program):
+                out[name] = (path, cap, facts)
+                break
+    return out
+
+
 @pytest.fixture(scope="module")
 def facts(cap):
     """What run.py would hand a reader, plus the capture."""
@@ -52,13 +88,11 @@ def test_the_loader_looks_where_run_py_traces():
     assert pt.TRACE_DIR == run.TRACE_DIR
 
 
-def test_the_metric_files_that_read_the_programs_names_are_there():
-    assert NEW == sorted([
-        "append_drop_share", "block_guard_share", "damped_kernel_share",
-        "idle_dispatch_ms", "idle_prepare_ms", "idle_report_ms",
-        "leaderless_rounds_share", "op_gather_share", "programs_per_segment",
-        "quorum_commit_share", "recover_p99_rounds",
-    ])
+def test_every_metric_file_that_reads_the_programs_names_has_a_recording(recording_of):
+    """A reader of a name no recording carries has never been shown to read
+    a number off the chip: record one (`program_trace.py export`) beside it."""
+    missing = sorted(set(NEW) - set(recording_of))
+    assert NEW and not missing, f"no recording under tests/data carries the names of {missing}"
     assert not any("loader" in s for s in SPECS.values())  # one way only: facts
 
 
@@ -118,23 +152,28 @@ def test_name_stack_rules():
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_reader_finds_its_number_on_the_recording(name, facts):
+def test_reader_finds_its_number_on_its_recording(name, recording_of):
+    path, _cap, facts = recording_of[name]
     value = read(name, facts)
     assert isinstance(value, float)
     unit = spec_of(name)["unit"]
     if unit == "%":
         assert 0.0 <= value <= 100.0
-    if name == "recover_p99_rounds":
-        assert value == -1.0  # nothing was lost, so no episode ended
-    elif name in ("leaderless_rounds_share", "append_drop_share"):
-        assert value == 0.0
-    elif name != "idle_prepare_ms":
-        assert value > 0.0
+    if path == RECORDED:  # PR 26's: nothing was lost
+        if name == "recover_p99_rounds":
+            assert value == -1.0  # ... so no episode ended
+        elif name in ("leaderless_rounds_share", "append_drop_share"):
+            assert value == 0.0
+        elif name != "idle_prepare_ms":
+            assert value > 0.0
+    else:
+        assert value >= 0.0
 
 
 @pytest.mark.parametrize("name", NEW)
-def test_reader_returns_none_for_a_program_without_names(name, cap, facts):
+def test_reader_returns_none_for_a_program_without_names(name, recording_of):
     """The parent of PR 26: no `raft.` span, no scope, no kernel name."""
+    _path, cap, facts = recording_of[name]
     bare = pt.Capture(
         [s for s in cap.spans if not s.name.startswith(pt.PROGRAM_PREFIX)],
         [o._replace(path="") for o in cap.ops],
@@ -304,12 +343,14 @@ PROGRAM = {"spans": {"raft.run_reads", "raft.run_reads.report", "raft.run_reads.
 
 
 def readers_of(*names):
-    return {n: (SPECS[n]["unit"], reducers.load(SPECS[n]["reducer"]), SPECS[n]["args"])
+    return {n: run.Reader(SPECS[n]["unit"], reducers.load(SPECS[n]["reducer"]), SPECS[n]["args"],
+                          SPECS[n].get("needs"))
             for n in names}
 
 
 # A share of a scope no op of the recording carries.
-NO_SUCH = {"later_scope_share": ("%", reducers.load("scope_share"), {"scope": "later.scope"})}
+NO_SUCH = {"later_scope_share": run.Reader("%", reducers.load("scope_share"),
+                                           {"scope": "later.scope"})}
 
 
 def test_a_metric_whose_name_the_program_lacks_is_left_out(facts):
@@ -345,6 +386,94 @@ def test_a_count_the_report_lacks_is_a_name_the_program_lacks(facts):
     assert got == {} and left_out == ["append_drop_share"]
 
 
+# --- the third case: the reader's subject did not run in the window ---------------
+
+SERVE = "fleet-100k-r5.serve"
+NEEDY = ["general_round_ms", "quorum_commit_share", "op_gather_share"]  # BENCHMARK.json's order
+
+
+def serve_facts(cap, fused_of_17):
+    """What a traced `.serve` run would hand its readers, from the PR 26
+    recording (two calls of 17 rounds at 2048 x 3) with the reports' counts
+    grafted on: `fused_of_17` of each call's rounds ran fused."""
+    G = 2048
+    counters = {"segments": 2, "rounds": 34, "group_rounds": 34 * G, "total_rounds": 34 * G,
+                "fused_rounds": 2 * fused_of_17 * G}
+    return {**pt.facts_of(cap), "counters": counters, "shape": {"n_groups": G, "n_peers": 3},
+            "peaks": run.load_json(os.path.dirname(HERE), "peaks.json")["TPU v5 lite"]}
+
+
+def without_general_ops(cap):
+    """The recording as a window of fused blocks alone would leave it: no
+    op under a scope of the general path (here: each call's one tail round)."""
+    return cap._replace(ops=[o for o in cap.ops if not any(
+        pt.has_scope(o.path, s) for s in ("op_gather", "quorum_commit"))])
+
+
+def test_no_general_round_in_the_window_leaves_its_readers_out_and_the_line_is_ok(bench, cap):
+    facts = serve_facts(without_general_ops(cap), fused_of_17=17)
+    readers = run.metric_readers(bench, SERVE)
+    assert [n for n, r in readers.items() if r.needs] == NEEDY
+    said = []
+    metrics, left_out = run.read_metrics(readers, facts, PROGRAM, SERVE, said.append)
+    assert left_out == NEEDY
+    assert said == [f"metric {n} left out: the window ran no general round" for n in NEEDY]
+    assert metrics["fused_frac"] == (1.0, "ratio")
+    t = facts["trace"]
+    text = run.line.build(
+        correct=True, attempted=10, failed=0, metrics=metrics,
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1,
+                "window_s": t["window_s"], "busy_s": t["busy_s"]},
+        breakdown={"device_ops": pt.top_ops(facts, KNOWN), "idle_gaps": t["idle_gaps"]})
+    assert run.line.validate(text + "\n", bench, SERVE, True, left_out=left_out) == []  # "line ok"
+    missing = run.line.validate(text + "\n", bench, SERVE, True)
+    assert len(missing) == 3 and all("absent" in p for p in missing)  # ... only when told
+
+
+def test_a_reader_that_finds_its_subject_keeps_its_value_whatever_the_counts_say(bench, cap):
+    """All fused by the counts, but ops under `quorum_commit` and
+    `op_gather` ran (a tail audit's, say): a number read is a number kept."""
+    facts = serve_facts(cap, fused_of_17=17)
+    metrics, left_out = run.read_metrics(
+        run.metric_readers(bench, SERVE), facts, PROGRAM, SERVE, lambda _t: None)
+    assert left_out == ["general_round_ms"]
+    assert metrics["op_gather_share"][0] > 0 and metrics["quorum_commit_share"][0] > 0
+
+
+@pytest.mark.parametrize("name", NEEDY)
+def test_general_rounds_ran_and_the_trace_shows_nothing_stops_the_run_as_before(name, cap):
+    facts = serve_facts(without_general_ops(cap), fused_of_17=16)
+    if name == "general_round_ms":  # reads busy time whenever a general round ran
+        assert read(name, facts) > 0
+        return
+    with pytest.raises(run.BenchError, match=f"{name}.*found nothing to read"):
+        run.read_metrics(readers_of(name), facts, PROGRAM, SERVE, print)
+
+
+def test_a_reader_without_needs_that_finds_nothing_stops_the_run_as_before(cap):
+    facts = serve_facts(without_general_ops(cap), fused_of_17=17)
+    bare = {**facts, "trace": {**facts["trace"], "op_seconds": {}}}
+    assert "needs" not in SPECS["fused_kernel_share"]
+    with pytest.raises(run.BenchError, match="fused_kernel_share.*found nothing to read"):
+        run.read_metrics(readers_of("fused_kernel_share"), bare, PROGRAM, SERVE, print)
+    # ... and so does one of the three with its `needs` taken away.
+    plain = {"op_gather_share": readers_of("op_gather_share")["op_gather_share"]._replace(needs=None)}
+    with pytest.raises(run.BenchError, match="op_gather_share.*found nothing to read"):
+        run.read_metrics(plain, facts, PROGRAM, SERVE, print)
+
+
+def test_a_metric_file_that_needs_an_unknown_condition_is_refused(bench, monkeypatch):
+    real = run.load_json
+
+    def load(*parts):
+        doc = real(*parts)
+        return {**doc, "needs": "fused_rounds"} if parts[-1] == "fused_frac.json" else doc
+
+    monkeypatch.setattr(run, "load_json", load)
+    with pytest.raises(run.BenchError, match="fused_frac.json needs 'fused_rounds'"):
+        run.metric_readers(bench, SERVE)
+
+
 def test_program_names_are_the_catalogue_and_the_reports_counts():
     from raft_tpu import profiling
 
@@ -370,7 +499,7 @@ def test_a_run_that_stops_over_a_listed_metric_exits_2_and_prints_no_line():
         "import sys\n"
         "from benchmark import run\n"
         "def stop(*a, **k):\n"
-        "    run.read_metrics({'m': ('%', run.reducers.load('op_share'), {'pattern': 'x'})},\n"
+        "    run.read_metrics({'m': run.Reader('%', run.reducers.load('op_share'), {'pattern': 'x'})},\n"
         "                     {'trace': {'op_seconds': {}, 'busy_s': 1.0}}, {}, 'a.cell', print)\n"
         "run.run_cell = stop\n"
         "sys.exit(run.main(['--workload', 'fleet-100k-r5.serve', '--seed', '1',\n"

@@ -179,7 +179,7 @@ def test_on_a_program_without_the_names_they_are_left_out_of_the_line(recorded):
     for name in NAMED:
         spec = spec_of(name)
         assert read(name, facts) is None
-        readers[name] = (spec["unit"], reducers.load(spec["reducer"]), spec["args"])
+        readers[name] = run.Reader(spec["unit"], reducers.load(spec["reducer"]), spec["args"])
     said = []
     metrics, left_out = run.read_metrics(readers, facts, program, CELL, said.append)
     assert metrics == {} and left_out == list(NAMED) and len(said) == 4

@@ -17,6 +17,13 @@ A mix is data only (see README.md "Adding a traffic mix").  Its keys:
                           {"kind": "every_region"} — every region takes the
                           same share (hashed keys)
   split                   whether run_reads is called with split=True
+  counted_segments        optional, an integer >= 1, read by run.py and not by
+                          the generator: the line's exact counts
+                          (`attempted`, `failed`, the round counts inside the
+                          two latencies) are taken over the first so many
+                          timed segments, time over the whole window.  A mix
+                          whose healthy share of failures is not 0 states it
+                          (README.md, "Exact counts")
   chaos                   optional {"for_each_peer": [phase, ...], "then":
                           [phase, ...]}: chaos phases in the program's plan
                           grammar ("rounds", "crash", "partition"), the first
