@@ -98,6 +98,12 @@ here is missing from it or untested under tests/.
                                simref.ReconfigOracle performs the
                                bit-identical scalar surgery —
                                tests/test_reconfig_parity.py
+  select_row               <-> a per-group row look-up, plane[idx[g], ..., g]
+                               (no reference twin: numpy's take_along_axis
+                               is the oracle); N - 1 static selects under
+                               reconfig._gather_peer / _gather_op and the
+                               transfer abort of apply_confchange —
+                               bit-equality in tests/test_row_selects.py
   apply_transfer           <-> Raft.handle_transfer_leader — the leader-side
                                MsgTransferLeader step (reference:
                                raft.rs:1821-1889): validate the target
@@ -215,6 +221,28 @@ def _quorum_of_rows(
     for p, row in enumerate(srt):
         out = jnp.where(qpos == p, row, out)
     return jnp.where(count == 0, INF, out)
+
+
+def select_row(plane: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """plane[N, ..., G], idx int32[..., G] in [0, N) -> plane[idx[g], ..., g],
+    shaped as plane[0] broadcast against idx.
+
+    N - 1 static selects over the leading axis (the form _quorum_of_rows
+    picks its position by) instead of a take_along_axis: G stays on the
+    lanes and every operand is read at HBM speed, where a dynamic gather of
+    the same planes runs two orders slower on the TPU (PERF.md §6, PR 34).
+    lax.select, not jnp.where: a jitted helper's ops drop the caller's
+    name stack, and the selects are what a scope around this must name.
+    """
+    shape = jnp.broadcast_shapes(plane.shape[1:], idx.shape)
+    out = jnp.broadcast_to(plane[0], shape)
+    for n in range(1, plane.shape[0]):
+        out = jax.lax.select(
+            jnp.broadcast_to(idx == n, shape),
+            jnp.broadcast_to(plane[n], shape),
+            out,
+        )
+    return out
 
 
 @profiling.scope("quorum_commit")
@@ -1100,9 +1128,7 @@ def apply_confchange(
         # owner must survive the change as leader.
         P = transferee.shape[0]
         joint_v = vm | om
-        tgt_in = jnp.take_along_axis(
-            joint_v, jnp.clip(transferee - 1, 0, P - 1), axis=0
-        )
+        tgt_in = select_row(joint_v, jnp.clip(transferee - 1, 0, P - 1))
         tr = jnp.where(
             ap & ((transferee > 0) & ~tgt_in | step_down), 0, transferee
         )

@@ -652,19 +652,14 @@ READ_REPORT_CONF_NAMES = (
 def _gather_peer(plane: jnp.ndarray, owner: jnp.ndarray) -> jnp.ndarray:
     """plane[P, G], owner int32[G] (1-based, 0-safe) -> plane[owner-1, g]."""
     o = jnp.clip(owner - 1, 0, plane.shape[0] - 1)
-    return jnp.take_along_axis(plane, o[None, :], axis=0)[0]
+    return kernels.select_row(plane, o)
 
 
 @profiling.scope("op_gather")
 def _gather_op(plane: jnp.ndarray, op_ptr: jnp.ndarray) -> jnp.ndarray:
     """plane[K, ..., G], op_ptr int32[G] -> plane[op_ptr[g], ..., g]."""
     k = jnp.clip(op_ptr, 0, plane.shape[0] - 1)
-    if plane.ndim == 2:
-        return jnp.take_along_axis(plane, k[None, :], axis=0)[0]
-    idx = jnp.broadcast_to(
-        k[None, None, :], (1, plane.shape[1], plane.shape[2])
-    )
-    return jnp.take_along_axis(plane, idx, axis=0)[0]
+    return kernels.select_row(plane, k)
 
 
 def pending_in_horizon(

@@ -94,7 +94,10 @@ SCOPES: Dict[str, str] = {
     ),
     "op_gather": (
         "reconfig._gather_peer / _gather_op: the op protocol's per-group "
-        "look-ups in every _runner_body round, a plan scheduled or not"
+        "look-ups in every _runner_body round, a plan scheduled or not. "
+        "Static row selects (kernels.select_row) since PR 34, no gather; "
+        "the name stays because benchmark/metrics/op_gather_share.json "
+        "reads it"
     ),
     "reconfig.gate": (
         "the op protocol's first half in every _runner_body round: "

@@ -265,6 +265,19 @@ def lowered_text():
     ).as_text(debug_info=True)
     texts["latency"] = jax.jit(workload.latency_percentiles).lower(
         zeros(workload.N_LAT_BUCKETS)).as_text(debug_info=True)
+    # The op protocol with K = 6 op slots (the block program's no-op
+    # schedule has one): a voter leaves and comes back, twice.
+    churn = reconfig.compile_plan(reconfig.plan_from_dict({
+        "name": "k6", "peers": P, "phases": [
+            {"rounds": 4, "op": op} for p in (3, 2) for op in (
+                {"remove_voter": p}, {"add_learner": p},
+                {"promote_learner": p})
+        ]}), G)
+    assert churn.tgt_voter.shape == (6, P, G)
+    scan = runner_mod.make_runner(cfg, (churn,))
+    texts["reconfig"] = scan.jitted.lower(
+        st, sim.init_health(cfg), rst, *scan.schedule_args
+    ).as_text(debug_info=True)
     return texts
 
 
@@ -295,6 +308,24 @@ def test_quorum_commit_is_static_slices_and_selects(lowered_text, banned):
     assert any(t in ("max", "min") for t in tails), "the network is there"
     assert re.search(r'"[^"]*/round\.damped/[^"]*/quorum_commit/max"',
                      lowered_text["block"]), "and in the damped round"
+    hits = sorted({t for t in tails if banned in t})
+    assert not hits, hits
+
+
+@pytest.mark.parametrize("program", ["block", "reconfig"], ids=["K1", "K6"])
+@pytest.mark.parametrize(
+    "banned", ["gather", "sort", "take_along_axis", "dynamic_slice", "transpose"]
+)
+def test_op_gather_is_static_slices_and_selects(lowered_text, program, banned):
+    """The op protocol's row look-ups (reconfig._gather_peer / _gather_op)
+    are static slices and N - 1 selects (kernels.select_row), with one op
+    slot (every fixture without a reconfig plan, `.serve`) and with six: no
+    op under `op_gather` is a gather or a relayout.  The v5e ran the
+    take_along_axis form at 29 of a general round's 35.7 ms (PERF.md §6,
+    PR 34); a CPU run would not notice it coming back."""
+    text = lowered_text[program]
+    tails = re.findall(r'"[^"]*/op_gather/([^"]*)"', text)
+    assert "select_n" in tails, "the selects are there"
     hits = sorted({t for t in tails if banned in t})
     assert not hits, hits
 
