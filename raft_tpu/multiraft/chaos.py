@@ -394,10 +394,18 @@ CS_MAX_STREAK = 2  # longest leaderless streak observed anywhere
 CS_LEADERLESS_ROUNDS = 3  # total leaderless (group, round) pairs
 CS_APPENDS_OFFERED = 4  # (group, round) pairs the schedule offered entries
 CS_APPENDS_DROPPED = 5  # ... of which no acting leader took them
+# What `reelections` cannot see — a hand-over with no leaderless round
+# between, and whether the leader regained is the one that was lost — and
+# what a campaign costs the rest of the group.  Folded (update_leader_stats)
+# by the runners that carry each group's last acting leader
+# (workload.ReadCarry.last_leader: the client-workload runners); 0 in
+# every other runner's vector.
+CS_LEADER_CHANGES = 6  # (group, round) pairs that ended under a NEW leader
+CS_TERM_BUMPS = 7  # summed growth of each group's highest term
 # The ended episodes by length: slot CS_RECOVER_HIST + i counts episodes
 # that lasted i rounds, the last slot every length >= RECOVER_CAP (capped
 # like workload.lat_hist) — so recovery has a p99, not only a mean.
-CS_RECOVER_HIST = 6
+CS_RECOVER_HIST = 8
 RECOVER_CAP = 64
 N_RECOVER_BUCKETS = RECOVER_CAP + 1
 N_CHAOS_STATS = CS_RECOVER_HIST + N_RECOVER_BUCKETS
@@ -409,6 +417,8 @@ CHAOS_STAT_NAMES = (
     "leaderless_group_rounds",
     "appends_offered",
     "appends_dropped",
+    "leader_changes",
+    "term_bumps",
 )
 
 
@@ -432,7 +442,7 @@ def update_chaos_stats(
     took (sim.ReconfigProposal.dropped).  A fused block folds its `rounds`
     rounds at once: the plane is 0 at every round's end (a leader held),
     every offer was taken (dropped=None), and `offered` counts `rounds`
-    times."""
+    times.  The two leadership slots are update_leader_stats' to fold."""
     healed = (prev_leaderless > 0) & (new_leaderless == 0)
     # dtype= on the sums: bare reductions widen to int64 under x64 (GC007).
     n_healed = jnp.sum(healed, dtype=jnp.int32)
@@ -462,6 +472,8 @@ def update_chaos_stats(
                 jnp.sum(new_leaderless > 0, dtype=jnp.int32),
                 count(offered) * jnp.int32(rounds),
                 count(dropped),
+                jnp.int32(0),  # CS_LEADER_CHANGES: update_leader_stats'
+                jnp.int32(0),  # CS_TERM_BUMPS: likewise
             ]
         ),
         hist,
@@ -470,3 +482,44 @@ def update_chaos_stats(
     return out.at[CS_MAX_STREAK].set(
         jnp.maximum(stats[CS_MAX_STREAK], jnp.max(new_leaderless))
     )
+
+
+def update_leader_stats(
+    stats: jnp.ndarray,  # gc: int32[S]
+    last_leader: jnp.ndarray,  # gc: int32[G]
+    prev_health,  # gc: HealthState
+    bumps: jnp.ndarray,  # gc: int32[G]
+    state: jnp.ndarray,  # gc: int32[P, G]
+    term: jnp.ndarray,  # gc: int32[P, G]
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Fold one round's end into the two leadership slots: a group counts
+    a leader change when its acting leader at the round's end
+    (kernels.acting_leader_id: the alive leader of the highest term, off
+    the planes sim.step returned — before the op protocol's apply) is a
+    peer other than `last_leader`, the last acting leader it had (0 = none
+    seen yet, which counts nothing) — leaderless rounds between the two or
+    not.  The term bumps come off the planes the round already reduced:
+    kernels.update_health adds each group's growth of its highest term to
+    HP_TERM_BUMPS after zeroing the plane where the churn window is fresh,
+    so the round's growth is `bumps` (the plane after the round) less the
+    plane `prev_health` (the round-entry HealthState) carried into it.
+    Returns (stats', last_leader').  A fused block never calls this: its
+    predicate proves one standing leader and no campaign, so both slots
+    and the plane stand."""
+    lead = kernels.acting_leader_id(state, term, crashed)
+    changed = (lead > 0) & (last_leader > 0) & (lead != last_leader)
+    carried = jnp.where(
+        prev_health.window_pos == 0, 0,
+        prev_health.planes[kernels.HP_TERM_BUMPS],
+    )
+    # dtype= on the sums: bare reductions widen to int64 under x64 (GC007).
+    delta = jnp.stack([
+        jnp.sum(changed, dtype=jnp.int32),
+        jnp.sum(bumps - carried, dtype=jnp.int32),
+    ])
+    # The two slots are neighbours: a static pad, no scatter.
+    stats = stats + jnp.pad(
+        delta, (CS_LEADER_CHANGES, N_CHAOS_STATS - CS_TERM_BUMPS - 1)
+    )
+    return stats, jnp.where(lead > 0, lead, last_leader)

@@ -135,7 +135,7 @@ def load_reconfig_state(path: str):
     return ReconfigState(**fields)
 
 
-_READ_FORMAT_VERSION = 1
+_READ_FORMAT_VERSION = 2  # 2: ReadCarry.last_leader
 
 # The persisted read-protocol planes, in registry save order: the
 # outstanding-read carry (workload.ReadCarry) plus the run's accumulators,
@@ -154,6 +154,7 @@ def save_read_state(rcar, read_stats, lat_hist, path: str) -> None:
     arrays = {
         "pending_mode": np.asarray(rcar.pending_mode),
         "pending_since": np.asarray(rcar.pending_since),
+        "last_leader": np.asarray(rcar.last_leader),
         "read_stats": np.asarray(read_stats),
         "lat_hist": np.asarray(lat_hist),
         "__read_version__": np.asarray(_READ_FORMAT_VERSION),
@@ -203,6 +204,7 @@ def load_read_state(path: str):
         ReadCarry(
             pending_mode=fields["pending_mode"],
             pending_since=fields["pending_since"],
+            last_leader=fields["last_leader"],
         ),
         fields["read_stats"],
         fields["lat_hist"],

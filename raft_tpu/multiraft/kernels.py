@@ -550,8 +550,11 @@ SV_COMMIT_NO_QUORUM = 5  # a commit advance lacking either joint majority
 SV_CONF_DOUBLE_CHANGE = 6  # an illegal single-step membership transition
 # Linearizability slots (ISSUE 13): checked only when the optional
 # lease-read args are given (same uniform-shape rule as the joint slots).
-SV_STALE_READ = 7  # a lease-served read older than a fleet-committed index
-SV_DUAL_LEASE = 8  # two peers hold a live read lease for one group at once
+# The "holder" is whoever would answer a read locally: a live lease under
+# check-quorum lease reads; on raft-rs's default Config (no lease exists)
+# a peer whose ReadIndex gate passes (sim.read_index_holders, ISSUE 35).
+SV_STALE_READ = 7  # a holder's answer older than a fleet-committed index
+SV_DUAL_LEASE = 8  # two holders for one group at once
 N_SAFETY = 9
 
 SAFETY_NAMES = (

@@ -324,6 +324,11 @@ REGISTRY: Tuple[PlaneSpec, ...] = (
         checkpoint="read", sharding="minor-G",
     ),
     PlaneSpec(
+        "last_leader", "workload", "read-carry", "[G]", "int32",
+        bound="1-based peer id of the last acting leader (<= n_peers)",
+        checkpoint="read", sharding="minor-G",
+    ),
+    PlaneSpec(
         "read_stats", "workload", "read-carry", "[R]", "int32",
         bound="slot growth per READ_PLANES; rounds x G < 2**31",
         checkpoint="read", sharding="replicate",

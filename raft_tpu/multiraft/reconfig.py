@@ -954,16 +954,27 @@ def _runner_body(
             psince = jnp.where(fresh, r, rcar.pending_since)
             read_propose = pmode
             # The linearizability audit's inputs, off the round-ENTRY
-            # (= serve-time) state: the full lease-holder mask and the
-            # groups with a lease-mode read live this round.
-            lease_holder, _, _ = kernels.lease_read(
-                st.state, st.term, st.leader_id, st.election_elapsed,
-                st.commit, st.term_start_index, crashed,
-                cfg.election_tick,
-                cfg.check_quorum and cfg.lease_read, st.transferee,
-                st.recent_active, st.voter_mask, st.outgoing_mask,
-            )
-            lease_fire = pmode == sim_mod.READ_LEASE
+            # (= serve-time) state: every peer that would answer a read
+            # now, and the groups with such a read live this round.
+            if cfg.check_quorum or cfg.pre_vote:
+                # The full lease-holder mask and the lease-mode reads.
+                lease_holder, _, _ = kernels.lease_read(
+                    st.state, st.term, st.leader_id, st.election_elapsed,
+                    st.commit, st.term_start_index, crashed,
+                    cfg.election_tick,
+                    cfg.check_quorum and cfg.lease_read, st.transferee,
+                    st.recent_active, st.voter_mask, st.outgoing_mask,
+                )
+                lease_fire = pmode == sim_mod.READ_LEASE
+            else:
+                # raft-rs's default Config: no lease exists and every
+                # read, whatever mode the client asked for, is a ReadIndex
+                # round — the audit holds every peer whose ReadIndex gate
+                # passes (the step's own probe: ReadReceipt.holders,
+                # below) to the same two slots: no answer older than an
+                # index committed fleet-wide, one answering peer a group.
+                lease_holder = None
+                lease_fire = pmode > sim_mod.READ_NONE
         else:
             read_propose = None
             lease_holder = None
@@ -987,6 +998,8 @@ def _runner_body(
         receipt = None
         if client is not None:
             step_out, receipt = step_out[:-1], step_out[-1]
+            if lease_holder is None:
+                lease_holder = receipt.holders
         if with_counters:
             st2, ctrs2, hl2, prop = step_out
         else:
@@ -1080,6 +1093,18 @@ def _runner_body(
             stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
             offered=offered > 0, dropped=prop.dropped,
         )
+        if client is not None:
+            # The round's end against the last acting leader each group
+            # had, and the growth of its highest term.  Off the post-step
+            # planes (st2), the ones the health fold's `has_leader` read:
+            # an apply-time step-down shows from the next round, and the
+            # leader compare is the fold's own — off st3 the damped round
+            # was 1.2% slower on the chip (PERF.md §6, PR 35).
+            stats, last_leader = chaos_mod.update_leader_stats(
+                stats, rcar.last_leader, hl,
+                hl2.planes[kernels.HP_TERM_BUMPS],
+                st2.state, st2.term, crashed,
+            )
         with profiling.scope("reconfig.apply"):
             # dtype= on the counts: bare bool sums widen to int64 under
             # x64 (GC007) and these feed the int32 accumulator.
@@ -1129,6 +1154,7 @@ def _runner_body(
             rcar = type(rcar)(
                 pending_mode=jnp.where(served, 0, pmode),
                 pending_since=jnp.where(served, 0, psince),
+                last_leader=last_leader,
             )
             out = out + (rcar, rdstats, lat_hist)
         if with_bb:

@@ -430,7 +430,7 @@ def client_sharding(mesh: Mesh, axis: str = "groups"):
         append=xg,
         n_peers=None,
     )
-    rcar = ReadCarry(pending_mode=g, pending_since=g)
+    rcar = ReadCarry(pending_mode=g, pending_since=g, last_leader=g)
     return sched, rcar, rep
 
 

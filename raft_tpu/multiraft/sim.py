@@ -383,11 +383,16 @@ class ReadReceipt(NamedTuple):
     like sim.read_index (the scalar pump's perturbation is confined to
     the ReadOracle's throwaway copy).  simref.ReadOracle reproduces
     index, serve round, and the degrade decision bit-for-bit
-    (tests/test_read_lease.py)."""
+    (tests/test_read_lease.py).  `holders` is the audit's view of the same
+    probe where no lease exists (check-quorum and pre-vote off): EVERY
+    peer whose ReadIndex gate passed (read_index_holders), of which
+    `index` is the acting leader's row; None under damping, where the
+    lease-holder mask plays that part."""
 
     index: jnp.ndarray  # gc: int32[G]
     lease: jnp.ndarray  # gc: bool[G]
     degraded: jnp.ndarray  # gc: bool[G]
+    holders: Optional[jnp.ndarray] = None  # gc: bool[P, G]
 
 
 def _read_quorum_damped(
@@ -503,14 +508,17 @@ def _read_phase(
     serve_l = lease_want & lease_served
     fallback = want & ~serve_l
     if cfg.check_quorum or cfg.pre_vote:
+        holders = None
         ri = _read_quorum_damped(cfg, st, crashed, link)
     else:
-        ri = read_index(cfg, st, crashed, link)
+        holders = read_index_holders(cfg, st, crashed, link)
+        ri = _acting_index(st, crashed, holders)
     index = jnp.where(
         serve_l, lease_idx, jnp.where(fallback, ri, jnp.int32(-1))
     )
     return ReadReceipt(
-        index=index, lease=serve_l, degraded=lease_want & ~serve_l
+        index=index, lease=serve_l, degraded=lease_want & ~serve_l,
+        holders=holders,
     )
 
 
@@ -1827,6 +1835,10 @@ def _linked_step(
     """
     G, P = cfg.n_groups, cfg.n_peers
     st_in = st
+    # The parts of the round, each under its profiling scope (the names
+    # the device ops carry in a trace; they change no equation).
+    sec = profiling.Sections()
+    sec.at("linked.read_probe")
     # Client-read phase (ISSUE 13): pure probe on the round-entry state,
     # link-aware, reported as the trailing ReadReceipt extra.
     read_extra = (
@@ -1834,6 +1846,7 @@ def _linked_step(
         if read_propose is None
         else _read_phase(cfg, st, crashed, read_propose, link)
     )
+    sec.at("linked.tick")
     t_extra = None
     if st.transferee is not None:
         # The transfer pre-tick pump, link-gated (see _transfer_phase).
@@ -1887,6 +1900,7 @@ def _linked_step(
     req = want_campaign
     hb_send = want_heartbeat  # tick_kernel gates this on leadership
 
+    sec.at("linked.election")
     # ---- wave 1: tick-queued traffic, per receiver in sender order.  The
     # running planes (T, V, Ld, ...) play each receiver's sequential
     # message processing; candidate payloads are the pre-round cursors
@@ -2048,6 +2062,7 @@ def _linked_step(
     matched3 = jnp.where(won[:, None, :], 0, st.matched)
     matched3 = jnp.where(won[:, None, :] & eye_pp, li2[:, None, :], matched3)
 
+    sec.at("linked.replicate")
     # ---- waves 3+: append deliveries.  Pass 1 = winner noop broadcasts
     # plus heartbeat-triggered catch-ups (the heartbeat-response path needs
     # the REVERSE link — it both resumes a paused Progress and reports the
@@ -2130,6 +2145,7 @@ def _linked_step(
         ),
     )
 
+    sec.at("linked.commit")
     # Stage-A quorum commit per leader off the freshly acked matched rows
     # (the term gate is raft_log.maybe_commit's own-term check).
     def _commit_a_body(C, xs):
@@ -2152,6 +2168,7 @@ def _linked_step(
         _commit_a_body, C, (matched3, St, TS, sender_ids)
     )
 
+    sec.at("linked.replicate")
     # Pass 2: a commit advance re-broadcasts appends to every member whose
     # Progress can still send (bcast_append on maybe_commit; reference:
     # raft.rs:893-904): Replicate members (acked since this leader's
@@ -2207,6 +2224,8 @@ def _linked_step(
         (E, Erev, adv, resumed, li2, lt2, term, sender_ids),
     )
 
+    sec.at("linked.commit")
+
     def _commit_b_body(C, xs):
         (m3_row, st_row, ts_row, e_s, erev_s, res_s, agree_s, li2_row,
          csend_row, t_row, sid) = xs
@@ -2250,6 +2269,7 @@ def _linked_step(
         ),
     )
 
+    sec.at("linked.workload")
     # ---- the round's append workload at the acting leader (the scalar
     # round's propose-then-pump segment, evaluated after the tick pump
     # quiesces): link-gated port of the all-visible Phase D.
@@ -2343,6 +2363,7 @@ def _linked_step(
     )
     C = jnp.where(is_acting_leader, lead_commit, C)
     C = jnp.where(sync_b, jnp.maximum(C, lead_commit), C)
+    sec.end()
 
     if transferee is not None:
         # reset-abort invariant (see step()): only standing leaders keep
@@ -3579,84 +3600,119 @@ def _damped_linked_step(
     return (out,) + extras
 
 
-def read_index(
+def read_index_holders(
     cfg: SimConfig,
     st: SimState,
     crashed: jnp.ndarray,  # gc: bool[P, G]
     link: Optional[jnp.ndarray] = None,  # gc: bool[P, P, G]
 ) -> jnp.ndarray:
-    """Batched linearizable ReadIndex barrier, Safe mode (reference:
+    """The ReadIndex gate of EVERY peer (Safe mode; reference:
     read_only.rs:65-140 + raft.rs step_leader MsgReadIndex 2067-2096 +
-    handle_heartbeat_response ack-quorum 1805-1818): for every group, the
-    index a read issued at the acting leader at this round boundary would
-    return, or -1 when it cannot complete:
+    handle_heartbeat_response ack-quorum 1805-1818): bool[P, G], true
+    where a read asked of peer p at this round boundary would complete
+    and return p's commit index.  A peer holds when
 
-      * no alive leader, or
-      * the leader has not committed an entry in its own term yet
-        (commit < term_start_index — the commit_to_current_term gate), or
-      * the ack quorum fails: alive members at term <= the leader's ack
-        the ctx heartbeat; members at a HIGHER term silently IGNORE it —
-        they neither ack nor (for this pure probe) depose; with
-        check_quorum on they would ALSO nudge-depose the stale leader,
-        which a probing read must not do, so the probe models the ack set
-        only (the scalar probe does perturb — parity tests probe last).
-        Joint configs need both majorities; a singleton group answers
+      * it is an alive leader, and
+      * it has committed an entry in its own term (commit >=
+        term_start_index — the commit_to_current_term gate), and
+      * its ack quorum stands: alive members at term <= ITS term ack the
+        ctx heartbeat; members at a HIGHER term silently IGNORE it — they
+        neither ack nor (for this pure probe) depose; with check_quorum on
+        they would ALSO nudge-depose the stale leader, which a probing
+        read must not do, so the probe models the ack set only (the
+        scalar probe does perturb — parity tests probe last).  Joint
+        configs need both majorities; a singleton group answers
         immediately without heartbeats (raft.rs:2075-2079).
 
     `link` (optional bool[P, P, G] directed reachability, the chaos
     engine's plane) makes the barrier link-aware: an ack needs the
     leader->member link for the ctx heartbeat AND the member->leader link
-    for the response.  None keeps the crash-mask-only graph unchanged.
+    for the response (a one-way reachable member heartbeats but never
+    acks).  None keeps the crash-mask-only graph.
 
-    Pure and jittable: probing reads never mutates `st` (the scalar oracle's
-    probe DOES perturb its cluster, so parity tests probe last).
-    Returns int32[G].
+    `read_index` serves the acting leader's row of this mask; the
+    workload scan hands the WHOLE mask (`ReadReceipt.holders`) to the
+    safety audit (`kernels.check_safety`'s `lease_holder`), which is what
+    holds a
+    ReadIndex read to its acknowledging majority: a client is routed to
+    the acting leader, but a leader that is cut off, alive and — without
+    check-quorum — never deposed still believes it leads, and only the
+    ack quorum keeps it from answering with an index the rest of its
+    group has moved past.  Pure and jittable.
     """
+    P = cfg.n_peers
     alive = ~crashed
     member = st.voter_mask | st.outgoing_mask | st.learner_mask
-    is_lead = (st.state == ROLE_LEADER) & alive
-    lead_term = jnp.max(jnp.where(is_lead, st.term, -1), axis=0)  # [G]
-    acting = is_lead & (st.term == lead_term[None, :])  # [P, G], unique
-    has_lead = jnp.any(acting, axis=0)
-    # dtype= so the probed indices stay int32 under x64 (GC007).
-    lead_commit = jnp.sum(
-        jnp.where(acting, st.commit, 0), axis=0, dtype=jnp.int32
+    is_lead = (st.state == ROLE_LEADER) & alive  # [P_l, G]
+    own = jnp.eye(P, dtype=bool)[:, :, None]
+    # ack[l, m]: member m acknowledges leader l's ctx heartbeat.
+    ack = (alive & member)[None, :, :] & (
+        st.term[None, :, :] <= st.term[:, None, :]
     )
-    lead_ts = jnp.sum(
-        jnp.where(acting, st.term_start_index, 0), axis=0, dtype=jnp.int32
-    )
-    servable = has_lead & (lead_commit >= lead_ts)
-
-    n_i = jnp.sum(st.voter_mask, axis=0).astype(jnp.int32)
-    n_o = jnp.sum(st.outgoing_mask, axis=0).astype(jnp.int32)
-    singleton = (n_i == 1) & (n_o == 0)
-
-    acker = (alive & member & (st.term <= lead_term[None, :])) | acting
     if link is not None:
-        # Link-aware barrier (DESIGN.md §7's last gap, closed by ISSUE 7):
-        # the ctx heartbeat must REACH the member (leader -> member link)
-        # and its ack must RETURN (member -> leader link); a one-way
-        # reachable member heartbeats but never acks.  `link=None` keeps
-        # the crash-mask-only graph bit-identical.
-        reach = jnp.any(link & acting[:, None, :], axis=0)  # [P_m, G]
-        ret = jnp.any(link & acting[None, :, :], axis=1)  # member -> l
-        acker = (acker & reach & ret) | acting
+        ack = ack & link & jnp.swapaxes(link, 0, 1)
+    ack = ack | own  # add_request seeds acks = {self}
 
     def half_quorum(mask):
-        n = jnp.sum(mask, axis=0).astype(jnp.int32)
-        acks = jnp.sum(acker & mask, axis=0).astype(jnp.int32)
-        return (acks >= n // 2 + 1) | (n == 0)
+        # dtype= so the counts stay int32 under x64 (GC007).
+        n = jnp.sum(mask, axis=0, dtype=jnp.int32)
+        acks = jnp.sum(ack & mask[None, :, :], axis=1, dtype=jnp.int32)
+        return (acks >= (n // 2 + 1)[None, :]) | (n == 0)[None, :]
 
     quorum = half_quorum(st.voter_mask) & half_quorum(st.outgoing_mask)
+    n_i = jnp.sum(st.voter_mask, axis=0, dtype=jnp.int32)
+    singleton = (n_i == 1) & ~jnp.any(st.outgoing_mask, axis=0)
     # The ack-quorum is only ever EVALUATED inside
     # handle_heartbeat_response (raft.rs:1805-1818), so at least one OTHER
     # alive member must actually respond — a joint config whose quorum is
     # the leader alone (e.g. incoming == outgoing == {leader}) hangs its
     # reads until leave-joint, because is_singleton() requires an EMPTY
     # outgoing half (found by randomized-config fuzz).
-    any_other = jnp.any(acker & ~acting, axis=0)
-    ok = servable & (singleton | (quorum & any_other))
-    return jnp.where(ok, lead_commit, jnp.int32(-1))
+    any_other = jnp.any(ack & ~own, axis=1)
+    return (
+        is_lead
+        & (st.commit >= st.term_start_index)
+        & (singleton[None, :] | (quorum & any_other))
+    )
+
+
+def read_index(
+    cfg: SimConfig,
+    st: SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    link: Optional[jnp.ndarray] = None,  # gc: bool[P, P, G]
+) -> jnp.ndarray:
+    """Batched linearizable ReadIndex barrier, Safe mode: for every group,
+    the index a read issued at the ACTING leader (the alive leader of the
+    highest term — where the sim routes client reads) at this round
+    boundary would return, or -1 when it cannot complete: no alive leader,
+    or the acting leader's gate does not hold (`read_index_holders`: not
+    committed in its own term yet, or no acknowledging majority).
+
+    Pure and jittable: probing reads never mutates `st` (the scalar oracle's
+    probe DOES perturb its cluster, so parity tests probe last).
+    Returns int32[G].
+    """
+    return _acting_index(
+        st, crashed, read_index_holders(cfg, st, crashed, link)
+    )
+
+
+def _acting_index(
+    st: SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    holders: jnp.ndarray,  # gc: bool[P, G]
+) -> jnp.ndarray:
+    """The acting leader's row of `holders` (read_index_holders): its
+    commit index where it holds, -1 where it does not or nobody leads."""
+    is_lead = (st.state == ROLE_LEADER) & ~crashed
+    lead_term = jnp.max(jnp.where(is_lead, st.term, -1), axis=0)  # [G]
+    serving = is_lead & (st.term == lead_term[None, :]) & holders  # unique
+    # dtype= so the probed indices stay int32 under x64 (GC007).
+    index = jnp.sum(
+        jnp.where(serving, st.commit, 0), axis=0, dtype=jnp.int32
+    )
+    return jnp.where(jnp.any(serving, axis=0), index, jnp.int32(-1))
 
 
 class ClusterSim:
@@ -3763,6 +3819,9 @@ class ClusterSim:
         # runner it belongs to: only that runner's next call resumes it.
         self._reconfig_state = None
         self._reconfig_state_of = None
+        # The read carry the last run_reads call ended with; its
+        # `last_leader` plane seeds the next call's.
+        self._read_carry = None
         if cfg.collect_health:
             self._health = init_health(cfg)
             if mesh is not None:
@@ -4606,9 +4665,15 @@ class ClusterSim:
                     rst = self._place_reconfig_state(
                         reconfig_mod.init_reconfig_state(self.state)
                     )
+                # Fresh reads each call, over the last acting leader the
+                # previous call saw each group have.
                 rcar = jax.tree.map(
                     lambda x: self._put(x, True),
-                    workload_mod.init_read_carry(self.cfg.n_groups),
+                    workload_mod.init_read_carry(
+                        self.cfg.n_groups,
+                        None if self._read_carry is None
+                        else self._read_carry.last_leader,
+                    ),
                 )
                 args = [self.state, health, rst, rcar]
                 if self._blackbox is not None:

@@ -300,22 +300,32 @@ class HostClientSchedule:
 
 
 class ReadCarry(NamedTuple):
-    """The runner's per-group outstanding-read carry: `pending_mode` is
-    the sim.READ_* code of the read in flight (0 = none — one read per
-    group at a time; new fires drop), `pending_since` the absolute round
-    it was issued (latency = serve round - pending_since).  Persisted by
-    checkpoint.save_read_state; values bounded by the mode codes and the
-    plan's round count (GC008 READ_PLANES registry)."""
+    """The runner's per-group read carry: `pending_mode` is the sim.READ_*
+    code of the read in flight (0 = none — one read per group at a time;
+    new fires drop), `pending_since` the absolute round it was issued
+    (latency = serve round - pending_since), `last_leader` the 1-based id
+    of the last acting leader the group had (0 = none seen yet; what the
+    report's `leader_changes` compares a round's end with —
+    chaos.update_leader_stats; a fused block proves a standing leader and leaves
+    the plane as it is).  Persisted by checkpoint.save_read_state; values
+    bounded by the mode codes, the plan's round count and n_peers (GC008
+    READ_PLANES registry)."""
 
     pending_mode: jnp.ndarray  # gc: int32[G]
     pending_since: jnp.ndarray  # gc: int32[G]
+    last_leader: jnp.ndarray  # gc: int32[G]
 
 
-def init_read_carry(n_groups: int) -> ReadCarry:
-    """Fresh no-reads-outstanding carry."""
+def init_read_carry(n_groups: int, last_leader=None) -> ReadCarry:
+    """Fresh no-reads-outstanding carry over `last_leader` (default: no
+    leader seen yet) — ClusterSim.run_reads hands the plane from call to
+    call, so a change across a call boundary counts."""
+    if last_leader is None:
+        last_leader = jnp.zeros((n_groups,), jnp.int32)
     return ReadCarry(
         pending_mode=jnp.zeros((n_groups,), jnp.int32),
         pending_since=jnp.zeros((n_groups,), jnp.int32),
+        last_leader=last_leader,
     )
 
 
@@ -520,9 +530,11 @@ def read_report(
         CS_APPENDS_DROPPED,
         CS_APPENDS_OFFERED,
         CS_HEALED_ROUNDS,
+        CS_LEADER_CHANGES,
         CS_LEADERLESS_ROUNDS,
         CS_MAX_STREAK,
         CS_REELECTIONS,
+        CS_TERM_BUMPS,
         recover_hist,
     )
     from .kernels import SAFETY_NAMES
@@ -545,6 +557,8 @@ def read_report(
         "leaderless_group_rounds": int(stats[CS_LEADERLESS_ROUNDS]),
         "appends_offered": int(stats[CS_APPENDS_OFFERED]),
         "appends_dropped": int(stats[CS_APPENDS_DROPPED]),
+        "leader_changes": int(stats[CS_LEADER_CHANGES]),
+        "term_bumps": int(stats[CS_TERM_BUMPS]),
         "recover_hist": [int(v) for v in recover_hist(stats)],
         "recover_p50_rounds": int(recover_p[0]),
         "recover_p90_rounds": int(recover_p[1]),

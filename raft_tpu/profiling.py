@@ -70,7 +70,28 @@ SPANS: Dict[str, str] = {
 
 SCOPES: Dict[str, str] = {
     "round": "sim.step's undamped all-links-up round body",
-    "round.linked": "sim._linked_step: the undamped round under a link plane",
+    "round.linked": (
+        "sim._linked_step: the undamped round under a link plane — the "
+        "body of a fleet at raft-rs's default Config under faults"
+    ),
+    "linked.read_probe": "the round-entry read probe (the ReadIndex round)",
+    "linked.tick": (
+        "the transfer pre-tick pump, the delivery plane, timers, campaign "
+        "local effects"
+    ),
+    "linked.election": (
+        "waves 1-2: tick-queued heartbeats and vote requests per receiver, "
+        "the responses' tallies per candidate, winners and losers"
+    ),
+    "linked.replicate": (
+        "waves 3+: winner noops and catch-up appends (pass 1), the "
+        "commit-advance re-broadcast (pass 2)"
+    ),
+    "linked.commit": (
+        "the per-leader quorum commit off the acked rows (stages A and B) "
+        "and the settled commit's propagation"
+    ),
+    "linked.workload": "the round's append workload at the acting leader",
     "round.damped": (
         "sim._damped_linked_step: check-quorum / pre-vote / lease round — "
         "the only body the benchmark's cells run"

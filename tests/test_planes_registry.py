@@ -92,6 +92,7 @@ def _read_carrier(g=G):
             rcar = workload.init_read_carry(g)
             self.pending_mode = rcar.pending_mode
             self.pending_since = rcar.pending_since
+            self.last_leader = rcar.last_leader
             self.read_stats = jnp.zeros((workload.N_READ_STATS,), jnp.int32)
             self.lat_hist = jnp.zeros((workload.N_LAT_BUCKETS,), jnp.int32)
 
@@ -115,12 +116,15 @@ def _round_trip(family, carrier, path):
         return checkpoint.load_blackbox_state(path)
     if family == "read":
         checkpoint.save_read_state(
-            workload.ReadCarry(carrier.pending_mode, carrier.pending_since),
+            workload.ReadCarry(
+                carrier.pending_mode, carrier.pending_since,
+                carrier.last_leader,
+            ),
             carrier.read_stats, carrier.lat_hist, path,
         )
         rcar, stats, hist = checkpoint.load_read_state(path)
         out = _read_carrier()
-        out.pending_mode, out.pending_since = rcar
+        out.pending_mode, out.pending_since, out.last_leader = rcar
         out.read_stats, out.lat_hist = stats, hist
         return out
     assert family == "reconfig"

@@ -238,7 +238,8 @@ def lowered_text():
     """Debug text of the lowered programs that, between them, run every
     scope: the split block program of the damped fleet (guard, both arms,
     the damped round with reads and health, the safety audit), the
-    undamped round with and without a link plane, the percentile fold."""
+    undamped round without a link plane and, serving a read, with one, the
+    percentile fold."""
     cfg = damped_cfg()
     client = workload.compile_plan(client_plan(), G)
     run = runner_mod.make_runner(cfg, (client,), split=True, k=8)
@@ -259,9 +260,10 @@ def lowered_text():
     app = jnp.ones((G,), jnp.int32)
     step = jax.jit(lambda s, c, a: sim.step(plain, s, c, a))
     texts["plain"] = step.lower(pst, crashed, app).as_text(debug_info=True)
-    linked = jax.jit(lambda s, c, a, l: sim.step(plain, s, c, a, link=l))
+    linked = jax.jit(lambda s, c, a, l, rd: sim.step(
+        plain, s, c, a, link=l, read_propose=rd))
     texts["linked"] = linked.lower(
-        pst, crashed, app, jnp.ones((P, P, G), bool)
+        pst, crashed, app, jnp.ones((P, P, G), bool), app
     ).as_text(debug_info=True)
     texts["latency"] = jax.jit(workload.latency_percentiles).lower(
         zeros(workload.N_LAT_BUCKETS)).as_text(debug_info=True)
@@ -281,7 +283,8 @@ def lowered_text():
     return texts
 
 
-WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency"}
+WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency",
+         **{s: "linked" for s in profiling.SCOPES if s.startswith("linked.")}}
 
 
 @pytest.mark.parametrize("scope", sorted(profiling.SCOPES))

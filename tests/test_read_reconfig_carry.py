@@ -305,7 +305,7 @@ def test_without_a_plan_the_report_is_what_it_was(split):
         assert shown == {k: old[k] for k in OLD_KEYS}, f"call {call}"
         extra = set(report) - OLD_KEYS
         assert extra - {"fused_rounds", "total_rounds", "fused_frac"} == set(
-            CONF_KEYS + ("conf_unfinished",))
+            CONF_KEYS + ("conf_unfinished", "leader_changes", "term_bumps"))
         assert not any(conf_counts(report).values())
         assert set(workload.report_counts(report)) >= set(
             CONF_KEYS + ("conf_unfinished",))

@@ -164,7 +164,8 @@ def _run_reconfig(G, split):
 def _run_workload(G, split):
     """run_reads twice on one sim: state, health, the op-protocol carry
     kept between the calls and the read carry, against the factory's
-    runner with a fresh read carry a call."""
+    runner with fresh reads a call, over the last call's `last_leader`
+    plane."""
     cfg = SimConfig(n_groups=G, n_peers=3, collect_health=True)
     plan = _client_plan()
     cs = ClusterSim(cfg)
@@ -174,9 +175,10 @@ def _run_workload(G, split):
     st, hl = sim_mod.init_state(cfg), sim_mod.init_health(cfg)
     rst = reconfig.init_reconfig_state(st)
     tag = "split" if split else "plain"
+    last = None
     for call in (1, 2):
         report = cs.run_reads(plan, split=split, split_k=4)
-        out = run(st, hl, rst, workload.init_read_carry(G))
+        out = run(st, hl, rst, workload.init_read_carry(G, last))
         st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist = out[:9]
         _assert_tree_equal(
             (cs.state, cs._health, cs._reconfig_state, cs._read_carry),
@@ -187,6 +189,7 @@ def _run_workload(G, split):
             *jax.device_get((rdstats, lat_p, safety, stats)), plan.n_rounds,
             *jax.device_get((recover_p, rstats)),
         )
+        last = rcar.last_leader
         if split:
             total = plan.n_rounds * G
             want.update(

@@ -136,13 +136,16 @@ def test_latency_percentiles_nearest_rank():
 # --- end-to-end: workload scan vs oracle-driven host replay ---------------
 
 
-def host_replay(cfg, client_plan, chaos_plan=None):
+def host_replay(cfg, client_plan, chaos_plan=None, each_round=None):
     """Mirror the workload runner's retry/drop protocol in plain python,
     driving simref.ReadOracle (real scalar pumps on throwaway copies) for
-    every receipt; returns (read stats, latency hist, oracle)."""
+    every receipt; returns (read stats, latency hist, oracle).
+    `each_round(cluster, crashed[P, G], pending[G])` is called at every
+    round's end (`pending`: the mode of each group's read in flight)."""
     G, P = cfg.n_groups, cfg.n_peers
     cl = ScalarCluster(
         G, P, election_tick=cfg.election_tick,
+        heartbeat_tick=cfg.heartbeat_tick,
         check_quorum=cfg.check_quorum, pre_vote=cfg.pre_vote,
     )
     oracle = ReadOracle(
@@ -187,6 +190,8 @@ def host_replay(cfg, client_plan, chaos_plan=None):
             hist[min(r - since[g], workload.LAT_CAP)] += 1
         pending = np.where(served, 0, pending)
         since = np.where(served, 0, since)
+        if each_round is not None:
+            each_round(cl, crashed, pending)
     return stats, hist, oracle
 
 
@@ -489,9 +494,8 @@ def test_reads_pending_in_horizon():
     )
     assert got.any()
     # An outstanding read pends regardless of the schedule.
-    stuck = workload.ReadCarry(
+    stuck = idle._replace(
         pending_mode=jnp.asarray(np.array([2, 0, 0], np.int32)),
-        pending_since=jnp.zeros((G,), jnp.int32),
     )
     got = np.asarray(
         workload.reads_pending_in_horizon(compiled, stuck, jnp.int32(0), 4)
