@@ -97,6 +97,7 @@ from .. import profiling
 from . import chaos as chaos_mod
 from . import kernels
 from . import sim as sim_mod
+from . import workload as workload_mod
 from ..confchange import Changer
 from ..confchange.changer import MapChangeType
 from ..eraftpb import ConfChangeSingle, ConfChangeType
@@ -1136,9 +1137,7 @@ def _runner_body(
             lat_cap = lat_hist.shape[0] - 1
             served = (receipt.index >= 0) & (pmode > 0)
             lat = jnp.clip(r - psince, 0, lat_cap)
-            lat_hist = lat_hist.at[jnp.where(served, lat, 0)].add(
-                served.astype(jnp.int32)
-            )
+            lat_hist = workload_mod.fold_latencies(lat_hist, served, lat)
             # dtype= on the counts: GC007 (bare bool sums widen under
             # x64) — these feed the int32 read-stats accumulator.
             rdstats = rdstats + jnp.stack(

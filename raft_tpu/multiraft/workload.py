@@ -329,6 +329,26 @@ def init_read_carry(n_groups: int, last_leader=None) -> ReadCarry:
     )
 
 
+@profiling.scope("read_fold")
+def fold_latencies(
+    lat_hist: jnp.ndarray,  # gc: int32[L]
+    served: jnp.ndarray,  # gc: bool[G]
+    lat: jnp.ndarray,  # gc: int32[G]
+) -> jnp.ndarray:
+    """One round's served reads into the latency histogram: bucket i grows
+    by the groups with `served` and `lat == i` (`lat` already clipped to
+    [0, L - 1] by the caller; `served` masks whatever the others hold).
+    Compare-and-sum over [buckets, G], the idiom of
+    chaos.update_chaos_stats' recover_hist — no scatter: a scatter-add of
+    G elements was the largest op of every general round on the chip
+    (PERF.md §6, PR 36)."""
+    bucket = jnp.arange(lat_hist.shape[0], dtype=jnp.int32)[:, None]
+    # dtype= on the sum: a bare bool reduction widens under x64 (GC007).
+    return lat_hist + jnp.sum(
+        served[None, :] & (lat[None, :] == bucket), axis=1, dtype=jnp.int32
+    )
+
+
 @profiling.scope("read_latency")
 def latency_percentiles(
     hist: jnp.ndarray,  # gc: int32[L]

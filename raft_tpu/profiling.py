@@ -133,6 +133,10 @@ SCOPES: Dict[str, str] = {
     "safety_audit": "kernels.check_safety: the per-round safety slots",
     "health_fold": "kernels.update_health: the fleet-health planes",
     "read_latency": "workload.latency_percentiles over a histogram",
+    "read_fold": (
+        "workload.fold_latencies: the round's served reads folded into the "
+        "latency histogram, in every _runner_body round with a client plan"
+    ),
     "runner.block_guard": (
         "everything a split block computes before its lax.cond: its "
         "tabled schedule rows unpacked (workload.BlockRows), lease_read, "

@@ -239,7 +239,7 @@ def lowered_text():
     scope: the split block program of the damped fleet (guard, both arms,
     the damped round with reads and health, the safety audit), the
     undamped round without a link plane and, serving a read, with one, the
-    percentile fold."""
+    percentile fold, the reconfig scan and the client-workload scan."""
     cfg = damped_cfg()
     client = workload.compile_plan(client_plan(), G)
     run = runner_mod.make_runner(cfg, (client,), split=True, k=8)
@@ -279,6 +279,13 @@ def lowered_text():
     scan = runner_mod.make_runner(cfg, (churn,))
     texts["reconfig"] = scan.jitted.lower(
         st, sim.init_health(cfg), rst, *scan.schedule_args
+    ).as_text(debug_info=True)
+    # The scan program WITH a client plan (the shape `.outage` and
+    # `.rebalance` run): the read fold is in the scan's body.
+    cscan = runner_mod.make_runner(cfg, (client,))
+    texts["client_scan"] = cscan.jitted.lower(
+        st, sim.init_health(cfg), rst, workload.init_read_carry(G),
+        *cscan.schedule_args,
     ).as_text(debug_info=True)
     return texts
 
@@ -329,6 +336,26 @@ def test_op_gather_is_static_slices_and_selects(lowered_text, program, banned):
     text = lowered_text[program]
     tails = re.findall(r'"[^"]*/op_gather/([^"]*)"', text)
     assert "select_n" in tails, "the selects are there"
+    hits = sorted({t for t in tails if banned in t})
+    assert not hits, hits
+
+
+@pytest.mark.parametrize("program", ["block", "client_scan"], ids=["split", "scan"])
+@pytest.mark.parametrize(
+    "banned", ["scatter", "gather", "dynamic_update_slice", "while"]
+)
+def test_read_fold_is_a_compare_and_reduce(lowered_text, program, banned):
+    """The round's served reads go into the latency histogram
+    (workload.fold_latencies) by a compare against the static buckets and
+    a sum over G, in the split program's general arm and in the scan's
+    body: no op under `read_fold` is a scatter, a gather or a loop.  The
+    v5e ran the `lat_hist.at[lat].add(served)` form at 0.875 of a general
+    round's 6.5 ms at 100k x 5 and 8.75 of 54.5 at 1M x 3, the largest op
+    of every general round (PERF.md §6, PR 36); a CPU run would not notice
+    it coming back."""
+    text = lowered_text[program]
+    tails = re.findall(r'"(?:[^"]*/)?read_fold/([^"]*)"', text)
+    assert "reduce_sum" in tails and "eq" in tails, "the compare and the sum are there"
     hits = sorted({t for t in tails if banned in t})
     assert not hits, hits
 
