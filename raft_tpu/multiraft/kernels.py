@@ -551,8 +551,10 @@ SV_CONF_DOUBLE_CHANGE = 6  # an illegal single-step membership transition
 # Linearizability slots (ISSUE 13): checked only when the optional
 # lease-read args are given (same uniform-shape rule as the joint slots).
 # The "holder" is whoever would answer a read locally: a live lease under
-# check-quorum lease reads; on raft-rs's default Config (no lease exists)
-# a peer whose ReadIndex gate passes (sim.read_index_holders, ISSUE 35).
+# check-quorum lease reads; where no lease exists (lease reads off) a peer
+# whose ReadIndex gate passes (sim.read_index_holders at raft-rs's default
+# Config, ISSUE 35; sim.read_quorum_damped_holders under check-quorum or
+# pre-vote, ISSUE 40).
 SV_STALE_READ = 7  # a holder's answer older than a fleet-committed index
 SV_DUAL_LEASE = 8  # two holders for one group at once
 N_SAFETY = 9

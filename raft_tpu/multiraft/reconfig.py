@@ -957,7 +957,7 @@ def _runner_body(
             # The linearizability audit's inputs, off the round-ENTRY
             # (= serve-time) state: every peer that would answer a read
             # now, and the groups with such a read live this round.
-            if cfg.check_quorum or cfg.pre_vote:
+            if cfg.lease_read:
                 # The full lease-holder mask and the lease-mode reads.
                 lease_holder, _, _ = kernels.lease_read(
                     st.state, st.term, st.leader_id, st.election_elapsed,
@@ -968,12 +968,13 @@ def _runner_body(
                 )
                 lease_fire = pmode == sim_mod.READ_LEASE
             else:
-                # raft-rs's default Config: no lease exists and every
-                # read, whatever mode the client asked for, is a ReadIndex
-                # round — the audit holds every peer whose ReadIndex gate
-                # passes (the step's own probe: ReadReceipt.holders,
-                # below) to the same two slots: no answer older than an
-                # index committed fleet-wide, one answering peer a group.
+                # No lease exists (raft-rs's default Config, or damping
+                # with ReadOnlyOption::Safe) and every read, whatever mode
+                # the client asked for, is a ReadIndex round — the audit
+                # holds every peer whose ReadIndex gate passes (the step's
+                # own probe: ReadReceipt.holders, below) to the same two
+                # slots: no answer older than an index committed
+                # fleet-wide, one answering peer a group.
                 lease_holder = None
                 lease_fire = pmode > sim_mod.READ_NONE
         else:

@@ -384,10 +384,11 @@ class ReadReceipt(NamedTuple):
     the ReadOracle's throwaway copy).  simref.ReadOracle reproduces
     index, serve round, and the degrade decision bit-for-bit
     (tests/test_read_lease.py).  `holders` is the audit's view of the same
-    probe where no lease exists (check-quorum and pre-vote off): EVERY
-    peer whose ReadIndex gate passed (read_index_holders), of which
-    `index` is the acting leader's row; None under damping, where the
-    lease-holder mask plays that part."""
+    probe where no lease exists (cfg.lease_read off): EVERY peer whose
+    ReadIndex gate passed (read_index_holders; under check-quorum or
+    pre-vote read_quorum_damped_holders), of which `index` is the acting
+    leader's row; None where lease reads are on and the lease-holder mask
+    (kernels.lease_read) plays that part."""
 
     index: jnp.ndarray  # gc: int32[G]
     lease: jnp.ndarray  # gc: bool[G]
@@ -395,26 +396,112 @@ class ReadReceipt(NamedTuple):
     holders: Optional[jnp.ndarray] = None  # gc: bool[P, G]
 
 
-def _read_quorum_damped(
+class _Halves(NamedTuple):
+    """The two halves of every group's configuration (incoming and
+    outgoing voters; joint.rs): their sizes, their majorities, and the
+    singleton — one incoming voter and an EMPTY outgoing half — whose
+    leader answers a read without heartbeats (raft.rs:2075-2079)."""
+
+    n_i: jnp.ndarray  # gc: int32[G]
+    n_o: jnp.ndarray  # gc: int32[G]
+    singleton: jnp.ndarray  # gc: bool[G]
+    q_i: jnp.ndarray  # gc: int32[G]
+    q_o: jnp.ndarray  # gc: int32[G]
+
+
+def _halves(st: SimState) -> _Halves:
+    n_i = jnp.sum(st.voter_mask, axis=0).astype(jnp.int32)
+    n_o = jnp.sum(st.outgoing_mask, axis=0).astype(jnp.int32)
+    singleton = (n_i == 1) & (n_o == 0)
+    return _Halves(n_i, n_o, singleton, n_i // 2 + 1, n_o // 2 + 1)
+
+
+def _acks_and_nudges(
+    st: SimState,
+    resp: jnp.ndarray,  # gc: bool[..., P, G]
+    l_term: jnp.ndarray,  # gc: int32[..., G]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Split the delivered responses `resp[..., m, :]` to a leader at term
+    `l_term[..., :]`'s ctx heartbeat: a member at or under the leader's
+    term acks; a HIGHER-term member answers with an empty
+    MsgAppendResponse at its own term (reference: raft.rs step's
+    m.term < self.term arm under check_quorum/pre_vote), which deposes
+    the leader when processed — the nudge."""
+    ack_v = resp & (st.term <= l_term[..., None, :])
+    ndg_v = resp & (st.term > l_term[..., None, :])
+    return ack_v, ndg_v
+
+
+def _acks_before_nudge(
+    st: SimState,
+    ack_v: jnp.ndarray,  # gc: bool[..., P, G]
+    ndg_v: jnp.ndarray,  # gc: bool[..., P, G]
+    cnt_i: jnp.ndarray,  # gc: int32[..., G]
+    cnt_o: jnp.ndarray,  # gc: int32[..., G]
+    h: _Halves,
+) -> jnp.ndarray:
+    """THE damped ReadIndex gate: does a leader's ack quorum land STRICTLY
+    BEFORE the first deposing nudge of its response stream (peer-id order
+    — the harness pump's wave order)?  `ack_v` / `ndg_v` are the stream
+    (_acks_and_nudges, member axis second to last), `cnt_i` / `cnt_o` the
+    leader's own ack in the two halves of the configuration (add_request
+    seeds acks = {self}).  A processed nudge deposes the leader, and
+    become_follower's reset() WIPES the pending read queue, so every later
+    ack is stepped by a follower and ignored.  Ack quorum evaluation
+    happens per processed ack (handle_heartbeat_response), so the joint
+    self-quorum hang and the at-least-one-responder rule fall out of the
+    same loop.
+
+    One body for one leader a group (the acting leader's planes, no
+    leading axis: _acting_read_gate) and for every peer as its own leader
+    (a leading [P_l] axis: read_quorum_damped_holders), so the probe and
+    the audit cannot drift apart and a control that weakens this function
+    weakens both.  Returns bool[..., G]."""
+    served = jnp.zeros(cnt_i.shape, bool)
+    dead = jnp.zeros(cnt_i.shape, bool)
+    for v in range(st.term.shape[0]):
+        # The nudge at stream position v deposes a leader not yet served;
+        # every later response is stepped by a follower and ignored.
+        dead = dead | (ndg_v[..., v, :] & ~served)
+        a = ack_v[..., v, :] & ~dead
+        cnt_i = cnt_i + (a & st.voter_mask[v]).astype(jnp.int32)
+        cnt_o = cnt_o + (a & st.outgoing_mask[v]).astype(jnp.int32)
+        quorum = ((cnt_i >= h.q_i) | (h.n_i == 0)) & (
+            (cnt_o >= h.q_o) | (h.n_o == 0)
+        )
+        # has_quorum(acks) is only EVALUATED inside
+        # handle_heartbeat_response — i.e. on processing ack `a` — which
+        # is what makes the leader-alone joint quorum hang until some
+        # other member responds (read_index's any_other rule).
+        served = served | (a & quorum)
+    return served
+
+
+def _links_up(
+    alive: jnp.ndarray,  # gc: bool[P, G]
+    link: Optional[jnp.ndarray],  # gc: bool[P, P, G]
+) -> jnp.ndarray:
+    """E[src, dst, G]: a message from src reaches dst this round — both
+    alive, two peers, the directed link up."""
+    P = alive.shape[0]
+    off_diag = ~jnp.eye(P, dtype=bool)[:, :, None]
+    E = alive[:, None, :] & alive[None, :, :] & off_diag
+    if link is not None:
+        E = E & link
+    return E
+
+
+def _acting_read_gate(
     cfg: SimConfig,
     st: SimState,
     crashed: jnp.ndarray,  # gc: bool[P, G]
     link: Optional[jnp.ndarray],  # gc: bool[P, P, G]
-) -> jnp.ndarray:
-    """The Safe-mode ReadIndex barrier under damping (check_quorum or
-    pre_vote): like sim.read_index, but with the low-term nudge cutoff
-    the damped scalar pump applies — a ctx heartbeat reaching a
-    HIGHER-term member draws an empty MsgAppendResponse at the member's
-    term (reference: raft.rs step's m.term < self.term arm under
-    check_quorum/pre_vote), which deposes the leader when processed;
-    become_follower's reset() WIPES the pending read queue, so the read
-    completes only if a quorum of acks lands STRICTLY BEFORE the first
-    deposing nudge in the response stream (peer-id order — the harness
-    pump's wave order).  Ack quorum evaluation happens per processed ack
-    (handle_heartbeat_response), so the joint self-quorum hang and the
-    at-least-one-responder rule fall out of the same loop.  Pure probe,
-    like read_index; returns int32[G] (-1 = not served)."""
-    G, P = cfg.n_groups, cfg.n_peers
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The damped ReadIndex gate at the group's ACTING leader — where the
+    sim routes client reads: (is_acting bool[P, G], ok bool[G], the acting
+    leader's commit index int32[G]).  `ok` is the acting leader's row of
+    read_quorum_damped_holders, computed on that one leader's planes."""
+    P = cfg.n_peers
     alive = ~crashed
     member = st.voter_mask | st.outgoing_mask | st.learner_mask
     is_lead = (st.state == ROLE_LEADER) & alive
@@ -433,20 +520,12 @@ def _read_quorum_damped(
         jnp.where(is_acting, st.term_start_index, 0), axis=0, dtype=jnp.int32
     )
     servable = has_lead & (lead_commit >= lead_ts)
-    n_i = jnp.sum(st.voter_mask, axis=0).astype(jnp.int32)
-    n_o = jnp.sum(st.outgoing_mask, axis=0).astype(jnp.int32)
-    singleton = (n_i == 1) & (n_o == 0)
-    q_i = n_i // 2 + 1
-    q_o = n_o // 2 + 1
-    off_diag = ~jnp.eye(P, dtype=bool)[:, :, None]
-    E = alive[:, None, :] & alive[None, :, :] & off_diag
-    if link is not None:
-        E = E & link
+    h = _halves(st)
+    E = _links_up(alive, link)
     reach = jnp.any(E & is_acting[:, None, :], axis=0)  # [P_m, G] l -> m
     ret = jnp.any(E & is_acting[None, :, :], axis=1)  # [P_m, G] m -> l
     resp = member & reach & ret & ~is_acting  # a delivered response
-    ack_v = resp & (st.term <= lead_term[None, :])
-    ndg_v = resp & (st.term > lead_term[None, :])  # the deposing nudge
+    ack_v, ndg_v = _acks_and_nudges(st, resp, lead_term)
     # The leader's own ack (add_request seeds acks = {self}).
     cnt_i = jnp.sum(
         jnp.where(is_acting & st.voter_mask, 1, 0), axis=0, dtype=jnp.int32
@@ -455,25 +534,85 @@ def _read_quorum_damped(
         jnp.where(is_acting & st.outgoing_mask, 1, 0), axis=0,
         dtype=jnp.int32,
     )
-    served = jnp.zeros((G,), bool)
-    dead = jnp.zeros((G,), bool)
-    for v in range(P):
-        # The nudge at stream position v deposes a leader not yet served;
-        # every later response is stepped by a follower and ignored.
-        dead = dead | (ndg_v[v] & ~served)
-        a = ack_v[v] & ~dead
-        cnt_i = cnt_i + (a & st.voter_mask[v]).astype(jnp.int32)
-        cnt_o = cnt_o + (a & st.outgoing_mask[v]).astype(jnp.int32)
-        quorum = ((cnt_i >= q_i) | (n_i == 0)) & (
-            (cnt_o >= q_o) | (n_o == 0)
-        )
-        # has_quorum(acks) is only EVALUATED inside
-        # handle_heartbeat_response — i.e. on processing ack `a` — which
-        # is what makes the leader-alone joint quorum hang until some
-        # other member responds (read_index's any_other rule).
-        served = served | (a & quorum)
-    ok = servable & (singleton | served)
+    served = _acks_before_nudge(st, ack_v, ndg_v, cnt_i, cnt_o, h)
+    return is_acting, servable & (h.singleton | served), lead_commit
+
+
+def _read_quorum_damped(
+    cfg: SimConfig,
+    st: SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    link: Optional[jnp.ndarray],  # gc: bool[P, P, G]
+) -> jnp.ndarray:
+    """The Safe-mode ReadIndex barrier under damping (check_quorum or
+    pre_vote): like sim.read_index, but with the low-term nudge cutoff
+    the damped scalar pump applies — the read completes only if a quorum
+    of acks lands STRICTLY BEFORE the first deposing nudge in the response
+    stream (_acks_before_nudge, at the acting leader).  Pure probe, like
+    read_index; returns int32[G] (-1 = not served)."""
+    _, ok, lead_commit = _acting_read_gate(cfg, st, crashed, link)
     return jnp.where(ok, lead_commit, jnp.int32(-1))
+
+
+def read_quorum_damped_holders(
+    cfg: SimConfig,
+    st: SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    link: Optional[jnp.ndarray] = None,  # gc: bool[P, P, G]
+) -> jnp.ndarray:
+    """The damped ReadIndex gate of EVERY peer (read_index_holders' twin
+    under check-quorum / pre-vote): bool[P, G], true where a Safe read
+    asked of peer l at this round boundary would complete — l is an alive
+    role-leader, has committed in its own term, and its ack quorum (both
+    halves of a joint configuration, at least one responder, the
+    singleton rule) lands strictly before the first deposing nudge of ITS
+    response stream: _read_quorum_damped's rules with "the acting leader"
+    replaced by "peer l", one computation over a leader axis.  The acting
+    leader's row is _read_quorum_damped's answer.  Pure and jittable."""
+    member = st.voter_mask | st.outgoing_mask | st.learner_mask
+    alive = ~crashed
+    is_lead = (st.state == ROLE_LEADER) & alive  # [P_l, G]
+    E = _links_up(alive, link)
+    # resp[l, m]: l's ctx heartbeat reaches member m and m's response l.
+    resp = member[None, :, :] & E & jnp.swapaxes(E, 0, 1)
+    h = _halves(st)
+    ack_v, ndg_v = _acks_and_nudges(st, resp, st.term)
+    served = _acks_before_nudge(
+        st, ack_v, ndg_v,
+        st.voter_mask.astype(jnp.int32), st.outgoing_mask.astype(jnp.int32),
+        h,
+    )
+    return (
+        is_lead
+        & (st.commit >= st.term_start_index)
+        & (h.singleton[None, :] | served)
+    )
+
+
+def _read_holders_damped(
+    cfg: SimConfig,
+    st: SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    link: Optional[jnp.ndarray],  # gc: bool[P, P, G]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(read_quorum_damped_holders, _read_quorum_damped) of one round, the
+    per-peer gate paid only in the rounds that can need it.  A peer passes
+    the gate only as an alive role-leader, so in a round in which no group
+    has an alive role-leader other than its acting leader the mask IS the
+    acting leader's row of the probe the round computes anyway
+    (docs/READINDEX_AUDIT.md); under a store-loss mix a second alive
+    role-leader exists only while a cut-off store's leaders wait for
+    their check-quorum boundary."""
+    is_acting, ok, lead_commit = _acting_read_gate(cfg, st, crashed, link)
+    is_lead = (st.state == ROLE_LEADER) & ~crashed
+    stale = jnp.any(is_lead & ~is_acting)
+
+    def full():
+        with profiling.scope("damped.read_holders"):
+            return read_quorum_damped_holders(cfg, st, crashed, link)
+
+    holders = jax.lax.cond(stale, full, lambda: is_acting & ok[None, :])
+    return holders, jnp.where(ok, lead_commit, jnp.int32(-1))
 
 
 def _read_phase(
@@ -507,9 +646,13 @@ def _read_phase(
     )
     serve_l = lease_want & lease_served
     fallback = want & ~serve_l
-    if cfg.check_quorum or cfg.pre_vote:
+    if cfg.lease_read:
+        # A lease exists (lease_read needs check_quorum): the audit holds
+        # the lease holders (kernels.lease_read), not the fallback's gate.
         holders = None
         ri = _read_quorum_damped(cfg, st, crashed, link)
+    elif cfg.check_quorum or cfg.pre_vote:
+        holders, ri = _read_holders_damped(cfg, st, crashed, link)
     else:
         holders = read_index_holders(cfg, st, crashed, link)
         ri = _acting_index(st, crashed, holders)

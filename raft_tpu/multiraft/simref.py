@@ -674,6 +674,35 @@ class ReadOracle(TransferOracle):
         _seed_clone_memo(net, memo)
         return copy.deepcopy(net, memo)
 
+    def _pump_read(self, g: int, crashed_row, link_col, peer: int,
+                   lease: bool) -> int:
+        """Step one MsgReadIndex at `peer` of a THROWAWAY copy of group
+        g's Network under the round's faults and pump it dry: the index
+        its read state carries, -1 where the read did not complete."""
+        from ..read_only_option import ReadOnlyOption
+
+        net = self._clone_group(g)
+        self.cluster._apply_crash_mask(net, crashed_row, link_col)
+        iface = net.peers[peer]
+        iface.raft.read_only.option = (
+            ReadOnlyOption.LeaseBased if lease else ReadOnlyOption.Safe
+        )
+        self._probe_seq += 1
+        ctx = b"read-%d" % self._probe_seq
+        before = len(iface.raft.read_states)
+        net.send([
+            Message(
+                msg_type=MessageType.MsgReadIndex,
+                from_=peer,
+                to=peer,
+                entries=[Entry(data=ctx)],
+            )
+        ])
+        rs = iface.raft.read_states
+        if len(rs) > before and bytes(rs[-1].request_ctx) == ctx:
+            return rs[-1].index
+        return -1
+
     def read_probe(self, g: int, crashed_row, link_col, mode: int) -> tuple:
         """One group's read receipt for this round: (index, lease,
         degraded) — the scalar twin of sim.ReadReceipt's per-group lanes.
@@ -685,29 +714,33 @@ class ReadOracle(TransferOracle):
         degraded = mode == self.READ_LEASE and not lease
         if lead is None:
             return -1, False, degraded
-        from ..read_only_option import ReadOnlyOption
-
-        net = self._clone_group(g)
-        self.cluster._apply_crash_mask(net, crashed_row, link_col)
-        iface = net.peers[lead]
-        iface.raft.read_only.option = (
-            ReadOnlyOption.LeaseBased if lease else ReadOnlyOption.Safe
+        return (
+            self._pump_read(g, crashed_row, link_col, lead, lease),
+            lease, degraded,
         )
-        self._probe_seq += 1
-        ctx = b"read-%d" % self._probe_seq
-        before = len(iface.raft.read_states)
-        net.send([
-            Message(
-                msg_type=MessageType.MsgReadIndex,
-                from_=lead,
-                to=lead,
-                entries=[Entry(data=ctx)],
+
+    def read_holders(self, g: int, crashed_row, link_col) -> list:
+        """The per-peer question behind sim.ReadReceipt.holders where no
+        lease exists: for every peer of group g, would a Safe ReadIndex
+        read asked of IT at this round boundary complete?  Every alive
+        role-leader — the acting leader and any deposed-but-unaware one
+        beside it — drives the real Safe pump on a throwaway copy of its
+        own; a peer that is crashed or no leader answers nothing (raft-rs
+        forwards or drops its MsgReadIndex).  [bool] * P, the scalar twin
+        of sim.read_index_holders / sim.read_quorum_damped_holders."""
+        peers = self.cluster.networks[g].peers
+        out = []
+        for p in range(self.cluster.n_peers):
+            lead = (
+                not crashed_row[p]
+                and peers[p + 1].raft.state == StateRole.Leader
             )
-        ])
-        rs = iface.raft.read_states
-        if len(rs) > before and bytes(rs[-1].request_ctx) == ctx:
-            return rs[-1].index, lease, degraded
-        return -1, lease, degraded
+            out.append(
+                lead
+                and self._pump_read(g, crashed_row, link_col, p + 1, False)
+                >= 0
+            )
+        return out
 
     def round(self, crashed=None, append_n=None, link=None,
               conf_propose=None, kick=None, transfer_propose=None,

@@ -97,6 +97,12 @@ SCOPES: Dict[str, str] = {
         "the only body the benchmark's cells run"
     ),
     "damped.read_probe": "the round-entry read probe (lease gate, ReadIndex)",
+    "damped.read_holders": (
+        "sim.read_quorum_damped_holders inside damped.read_probe: the "
+        "ReadIndex gate of every peer, in the rounds in which some group "
+        "has an alive role-leader beside its acting leader (a fleet with "
+        "damping on and lease reads off)"
+    ),
     "damped.tick": "timers, the check-quorum boundary, campaign local effects",
     "damped.wave1": "heartbeats + (pre-)vote requests, per receiver",
     "damped.wave2": "heartbeat responses + nudges back at each leader",

@@ -39,9 +39,10 @@ ROUNDS = 48
 
 
 def damped_cfg(**kw):
+    kw = {"lease_read": True, **kw}
     return SimConfig(
         G, P, election_tick=10, heartbeat_tick=2, check_quorum=True,
-        pre_vote=True, lease_read=True, collect_health=True, **kw,
+        pre_vote=True, collect_health=True, **kw,
     )
 
 
@@ -265,6 +266,13 @@ def lowered_text():
     texts["linked"] = linked.lower(
         pst, crashed, app, jnp.ones((P, P, G), bool), app
     ).as_text(debug_info=True)
+    # Damping on, lease reads off: the ReadIndex round on the damped body,
+    # its per-peer gate in the arm of the rounds that can need it.
+    safe = damped_cfg(lease_read=False)
+    texts["readindex"] = jax.jit(lambda s, c, a, l, rd: sim.step(
+        safe, s, c, a, link=l, read_propose=rd)).lower(
+        sim.init_state(safe), crashed, app, jnp.ones((P, P, G), bool), app
+    ).as_text(debug_info=True)
     texts["latency"] = jax.jit(workload.latency_percentiles).lower(
         zeros(workload.N_LAT_BUCKETS)).as_text(debug_info=True)
     # The op protocol with K = 6 op slots (the block program's no-op
@@ -291,6 +299,7 @@ def lowered_text():
 
 
 WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency",
+         "damped.read_holders": "readindex",
          **{s: "linked" for s in profiling.SCOPES if s.startswith("linked.")}}
 
 

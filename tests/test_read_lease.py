@@ -164,6 +164,40 @@ def run_read_storm(
     assert_state_parity(oracle, st, f"seed {seed} end")
 
 
+def receipt_digest(step_fn, st, G, P, rounds=60, seed=40):
+    """sha1 over every round's ReadReceipt (index, lease, degraded, and the
+    audit's mask where the fleet has one) of a seeded storm of crashes,
+    directed link cuts and read modes — device only, integers only, so the
+    digest is a constant of the PROGRAM: pinned below and in
+    tests/test_stock_fleet.py from the tree before ISSUE 40 touched
+    sim._read_phase, to show it changed one branch only."""
+    import hashlib
+
+    rng = np.random.RandomState(seed)
+    h = hashlib.sha1()
+    served = held = 0
+    crashed = np.zeros((P, G), bool)
+    up = np.ones((P, P, G), bool)
+    for r in range(rounds):
+        flip = rng.rand(P, G) < 0.04
+        crashed ^= flip
+        crashed[:, crashed.all(axis=0)] = False
+        cut = up & ~(rng.rand(P, P, G) < 0.06)
+        modes = rng.randint(0, 3, size=G).astype(np.int32)
+        st, receipt = step_fn(
+            st, jnp.asarray(crashed), jnp.asarray(rng.randint(0, 3, size=G), jnp.int32),
+            link=jnp.asarray(cut), read_propose=jnp.asarray(modes),
+        )
+        for part in (receipt.index, receipt.lease, receipt.degraded):
+            h.update(np.asarray(part).astype(np.int32).tobytes())
+        h.update(b"no mask" if receipt.holders is None
+                 else np.asarray(receipt.holders).astype(np.int8).tobytes())
+        served += int((np.asarray(receipt.index) >= 0).sum())
+        held += 0 if receipt.holders is None else int(np.asarray(receipt.holders).sum())
+    h.update(np.asarray(st.commit).astype(np.int32).tobytes())
+    return h.hexdigest(), served, held
+
+
 # --- steady + edge cases (tier-1: small G, one jitted step per config) ---
 
 
@@ -383,6 +417,24 @@ def test_lease_read_requires_check_quorum():
 
 
 # --- the stale-read trap (the safety net's negative test) -----------------
+
+
+@pytest.mark.parametrize("flags, want", [
+    (dict(check_quorum=True), "765efbe2013061d4f6c76b719bf5e47ea8718473"),
+    pytest.param(dict(check_quorum=True, pre_vote=True),
+                 "f2cc0501801f28e31053c8bd8ea5155590817d65",
+                 marks=pytest.mark.slow),  # cq+pv is a third damped wave compile
+])
+def test_lease_fleet_receipts_are_what_they_were_before_issue_40(flags, want):
+    """ISSUE 40 gave `_read_phase` a third branch (damping on, lease reads
+    off: the per-peer ReadIndex mask).  With lease reads on every receipt
+    of a seeded storm is bit-equal to the tree before it (digests taken at
+    0586a2e), and carries no mask: the lease holders stay the audit's."""
+    G, P = 2, 3
+    _oracle, _cfg, st, step_fn = build_pair(G, P, **flags)
+    digest, served, held = receipt_digest(step_fn, st, G, P)
+    assert served > 0 and held == 0
+    assert digest == want
 
 
 def _inject_trap(freeze_clock: bool):

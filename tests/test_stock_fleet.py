@@ -36,6 +36,7 @@ import pytest
 
 from benchmark import line, run, traffic
 from raft_tpu.multiraft import ClusterSim, SimConfig, chaos, workload
+from test_read_lease import receipt_digest
 from test_workload import host_replay
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -123,6 +124,22 @@ def test_store_loss_mix_equals_the_scalar_replay():
     # the last 60 rounds leave two alive leaders in some group, at
     # different terms, which is no safety violation.
     assert report["reelections"] > 0
+
+
+def test_stock_receipts_are_what_they_were_before_issue_40():
+    """ISSUE 40 gave `sim._read_phase` a branch for damping with lease reads
+    off; the stock branch (`read_index_holders`, its acting row the probe)
+    is bit-equal to the tree before it on a seeded storm of crashes and
+    link cuts (digest taken at 0586a2e), mask included."""
+    import functools
+
+    from raft_tpu.multiraft import sim
+
+    cfg = stock_cfg()
+    step = jax.jit(functools.partial(sim.step, cfg))
+    digest, served, held = receipt_digest(step, sim.init_state(cfg), G, P, rounds=90)
+    assert served > 0 and held >= served  # every answer is a holder's
+    assert digest == "552fc786de97f02375afb8ca07fe4647a54b02b5"
 
 
 @pytest.mark.parametrize("fires", ["write_only", "safe_fire"])
