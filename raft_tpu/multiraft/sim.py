@@ -2604,6 +2604,31 @@ def _linked_step(
     return (out,) + extras
 
 
+def _sender_scan(body, carry, xs):
+    """A damped wave's loop over the P stacked sender rows: ONE `scan`
+    equation in the jaxpr (the per-sender body traces once — the PR 6
+    jaxpr-size discipline), lowered STRAIGHT-LINE (`unroll=True`: P is a
+    static shape, 3 or 5) with an `optimization_barrier` on the carry at
+    the head of every trip.
+
+    Rolled, the loop is an XLA `while` whose every trip cuts its `[P, G]`
+    rows out of the `[P, P, G]` planes with a dynamic_slice and rewrites
+    the whole stacked outputs with a dynamic_update_slice: on the chip a
+    third of a damped round at 100k x 5 (PERF.md §6, PR 41).  Unrolled
+    WITHOUT the barrier that bookkeeping goes and the round gets slower
+    all the same: the compiler schedules the five trips as one pool of
+    ops, the carried planes (`agree_run[P, P, G]` above all) fall out of
+    the chip's fast memory, and selects and reduces far from the loop run
+    three to six times slower.  The barrier keeps the trips apart as the
+    `while` did — one trip's working set live at a time — and costs no op.
+    Same ops in the same order per sender, so every output is bit-equal."""
+
+    def trip(carry, x):
+        return body(jax.lax.optimization_barrier(carry), x)
+
+    return jax.lax.scan(trip, carry, xs, unroll=True)
+
+
 @profiling.scope("round.damped")
 def _damped_linked_step(
     cfg: SimConfig,
@@ -2815,7 +2840,8 @@ def _damped_linked_step(
     # ---- wave 1: heartbeats + (pre-)vote requests, per receiver in
     # sender order.  Mirrors _linked_step's wave 1 plus the damping
     # branches: lease ignores, lower-term nudges, and pre-vote's
-    # no-bump/no-record grant rule.
+    # no-bump/no-record grant rule.  Like every sender loop of this round
+    # it is one scan equation lowered straight-line (_sender_scan).
     def _w1_body(carry, xs):
         T, V, Ld, St, EE, HB, RT, C = carry
         (d, hb_s, req_s, t_row, rqt_row, m_row, c_row, lt_row, li_row,
@@ -2895,7 +2921,7 @@ def _damped_linked_step(
             ys = (g, at, snap, h_acc, h_ndg, h_ndg_t)
         return (T, V, Ld, St, EE, HB, RT, C), ys
 
-    w1_carry, w1_ys = jax.lax.scan(
+    w1_carry, w1_ys = _sender_scan(
         _w1_body,
         (term, vote, leader_id, state, ee, hb, rt, st.commit),
         (
@@ -3260,7 +3286,7 @@ def _damped_linked_step(
             ys = (ack, ndg, ndg_t, retry_cand)
         return (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run), ys
 
-    w3_carry, w3_ys = jax.lax.scan(
+    w3_carry, w3_ys = _sender_scan(
         _w3_body,
         (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run),
         (
@@ -3374,11 +3400,10 @@ def _damped_linked_step(
     # per sender in index order (resends of different leaders interleave
     # sender-ordered like every wave).
     def _apply_retry(fire, t_send, li_a, lt_a, csend_a, planes):
-        # lax.scan over the stacked sender rows (NOT an unrolled python
-        # loop: the per-sender body traces once — the PR 6 jaxpr-size
-        # discipline; compile time is tier-1 budget).  T is read-only
-        # here: a resend is accepted only at equal term, and acceptance
-        # never bumps.
+        # One scan equation over the stacked sender rows, lowered
+        # straight-line like the wave loops (_sender_scan).  T is
+        # read-only here: a resend is accepted only at equal term, and
+        # acceptance never bumps.
         T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run = planes
 
         def body(carry, xs):
@@ -3399,7 +3424,7 @@ def _damped_linked_step(
             agree_run = _merge_agree(agree_run, in_s, li_row, lead_row)
             return (St, Ld, EE, C, LI, LT, agree_run), (acc,)
 
-        (St, Ld, EE, C, LI, LT, agree_run), (acc_all,) = jax.lax.scan(
+        (St, Ld, EE, C, LI, LT, agree_run), (acc_all,) = _sender_scan(
             body,
             (St, Ld, EE, C, LI, LT, agree_run),
             (fire, t_send, li_a, lt_a, csend_a, sender_ids),
@@ -3473,7 +3498,7 @@ def _damped_linked_step(
         sent_term5 = term
     (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run), (
         ack5, ndg5, ndg5_t, retry5,
-    ) = jax.lax.scan(
+    ) = _sender_scan(
         _w5_body,
         (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run),
         (
