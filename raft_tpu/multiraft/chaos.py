@@ -51,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import profiling
 from . import kernels
 
 
@@ -312,6 +313,7 @@ def schedule_planes(
     return link, loss, crashed, compiled.append[ph]
 
 
+@profiling.scope("runner.chaos_masks")
 def schedule_masks(
     compiled: CompiledChaos,
     round_idx: jnp.ndarray,  # gc: int32[]

@@ -923,60 +923,67 @@ def _runner_body(
         else:
             st, hl, rst, stats, rstats, safety = carry
             ctrs = None
-        ph = sched.phase_of_round[r]
-        append = sched.append[ph]
+        # What the round is offered, under `runner.client` (the names the
+        # device ops carry in a trace; they change no equation).
+        with profiling.scope("runner.client"):
+            ph = sched.phase_of_round[r]
+            append = sched.append[ph]
         if chaos_sched is not None:
             link, crashed, capp = chaos_mod.schedule_masks(chaos_sched, r)
-            append = append + capp
+            with profiling.scope("runner.client"):
+                append = append + capp
         else:
             link = None
-            crashed = jnp.zeros((P, G), bool)
+            with profiling.scope("runner.client"):
+                crashed = jnp.zeros((P, G), bool)
         if actions is not None:
             act_round, transfer_plane, kick_plane = actions
-            fire = r == act_round
-            transfer_propose = jnp.where(fire, transfer_plane, 0)
-            campaign_kick = kick_plane & fire
+            with profiling.scope("runner.client"):
+                fire = r == act_round
+                transfer_propose = jnp.where(fire, transfer_plane, 0)
+                campaign_kick = kick_plane & fire
         else:
             transfer_propose = None
             campaign_kick = None
         if client is not None:
-            # The round's client traffic: phase append skew plus read
-            # fires (packed bits along G); an outstanding read retries
-            # every round until served, a fire finding one outstanding is
-            # dropped (one read in flight per group).
-            cph = client.phase_of_round[r]
-            append = append + client.append[cph]
-            fire_row = kernels.unpack_bits_g(client.read_fire_packed[r], G)
-            mode_row = client.read_mode[cph]
-            fire = fire_row & (mode_row > 0)
-            fresh = fire & (rcar.pending_mode == 0)
-            dropped = fire & (rcar.pending_mode > 0)
-            pmode = jnp.where(fresh, mode_row, rcar.pending_mode)
-            psince = jnp.where(fresh, r, rcar.pending_since)
-            read_propose = pmode
-            # The linearizability audit's inputs, off the round-ENTRY
-            # (= serve-time) state: every peer that would answer a read
-            # now, and the groups with such a read live this round.
-            if cfg.lease_read:
-                # The full lease-holder mask and the lease-mode reads.
-                lease_holder, _, _ = kernels.lease_read(
-                    st.state, st.term, st.leader_id, st.election_elapsed,
-                    st.commit, st.term_start_index, crashed,
-                    cfg.election_tick,
-                    cfg.check_quorum and cfg.lease_read, st.transferee,
-                    st.recent_active, st.voter_mask, st.outgoing_mask,
-                )
-                lease_fire = pmode == sim_mod.READ_LEASE
-            else:
-                # No lease exists (raft-rs's default Config, or damping
-                # with ReadOnlyOption::Safe) and every read, whatever mode
-                # the client asked for, is a ReadIndex round — the audit
-                # holds every peer whose ReadIndex gate passes (the step's
-                # own probe: ReadReceipt.holders, below) to the same two
-                # slots: no answer older than an index committed
-                # fleet-wide, one answering peer a group.
-                lease_holder = None
-                lease_fire = pmode > sim_mod.READ_NONE
+            with profiling.scope("runner.client"):
+                # The round's client traffic: phase append skew plus read
+                # fires (packed bits along G); an outstanding read retries
+                # every round until served, a fire finding one outstanding is
+                # dropped (one read in flight per group).
+                cph = client.phase_of_round[r]
+                append = append + client.append[cph]
+                fire_row = kernels.unpack_bits_g(client.read_fire_packed[r], G)
+                mode_row = client.read_mode[cph]
+                fire = fire_row & (mode_row > 0)
+                fresh = fire & (rcar.pending_mode == 0)
+                dropped = fire & (rcar.pending_mode > 0)
+                pmode = jnp.where(fresh, mode_row, rcar.pending_mode)
+                psince = jnp.where(fresh, r, rcar.pending_since)
+                read_propose = pmode
+                # The linearizability audit's inputs, off the round-ENTRY
+                # (= serve-time) state: every peer that would answer a read
+                # now, and the groups with such a read live this round.
+                if cfg.lease_read:
+                    # The full lease-holder mask and the lease-mode reads.
+                    lease_holder, _, _ = kernels.lease_read(
+                        st.state, st.term, st.leader_id, st.election_elapsed,
+                        st.commit, st.term_start_index, crashed,
+                        cfg.election_tick,
+                        cfg.check_quorum and cfg.lease_read, st.transferee,
+                        st.recent_active, st.voter_mask, st.outgoing_mask,
+                    )
+                    lease_fire = pmode == sim_mod.READ_LEASE
+                else:
+                    # No lease exists (raft-rs's default Config, or damping
+                    # with ReadOnlyOption::Safe) and every read, whatever mode
+                    # the client asked for, is a ReadIndex round — the audit
+                    # holds every peer whose ReadIndex gate passes (the step's
+                    # own probe: ReadReceipt.holders, below) to the same two
+                    # slots: no answer older than an index committed
+                    # fleet-wide, one answering peer a group.
+                    lease_holder = None
+                    lease_fire = pmode > sim_mod.READ_NONE
         else:
             read_propose = None
             lease_holder = None
@@ -986,8 +993,10 @@ def _runner_body(
             start = _gather_op(sched.op_start, rst.op_ptr)
             active = (rst.op_ptr < sched.n_ops) & (r >= start)
             want_prop = active & (rst.stage == 0)
-        prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
-        offered = append + want_prop.astype(jnp.int32)
+        with profiling.scope("runner.stats"):
+            prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
+        with profiling.scope("runner.client"):
+            offered = append + want_prop.astype(jnp.int32)
         step_out = sim_mod.step(
             cfg, st, crashed,
             offered,
@@ -1052,9 +1061,9 @@ def _runner_body(
             )
             # dtype= keeps the slot sums int32 under x64 (GC007); the
             # per-group sums equal check_safety's counts exactly.
-            safety = safety + jnp.sum(viol, axis=1, dtype=jnp.int32)
+            audit = jnp.sum(viol, axis=1, dtype=jnp.int32)
         else:
-            safety = safety + kernels.check_safety(
+            audit = kernels.check_safety(
                 st2.state, st2.term, st2.commit, st2.last_index, st2.agree,
                 st.commit,
                 voter_mask=st2.voter_mask,
@@ -1066,6 +1075,8 @@ def _runner_body(
                 lease_holder=lease_holder,
                 lease_fire=lease_fire,
             )
+        with profiling.scope("runner.stats"):
+            safety = safety + audit
         with profiling.scope("reconfig.apply"):
             # The gated swap: target masks of the op being applied, the
             # reference's apply-time reactions on the batched planes.
@@ -1091,22 +1102,24 @@ def _runner_body(
                 matched=matched3, voter_mask=vm3, outgoing_mask=om3,
                 learner_mask=lm3, recent_active=ra3, transferee=tr3,
             )
-        stats = chaos_mod.update_chaos_stats(
-            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
-            offered=offered > 0, dropped=prop.dropped,
-        )
-        if client is not None:
-            # The round's end against the last acting leader each group
-            # had, and the growth of its highest term.  Off the post-step
-            # planes (st2), the ones the health fold's `has_leader` read:
-            # an apply-time step-down shows from the next round, and the
-            # leader compare is the fold's own — off st3 the damped round
-            # was 1.2% slower on the chip (PERF.md §6, PR 35).
-            stats, last_leader = chaos_mod.update_leader_stats(
-                stats, rcar.last_leader, hl,
-                hl2.planes[kernels.HP_TERM_BUMPS],
-                st2.state, st2.term, crashed,
+        with profiling.scope("runner.stats"):
+            stats = chaos_mod.update_chaos_stats(
+                stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
+                offered=offered > 0, dropped=prop.dropped,
             )
+            if client is not None:
+                # The round's end against the last acting leader each
+                # group had, and the growth of its highest term.  Off the
+                # post-step planes (st2), the ones the health fold's
+                # `has_leader` read: an apply-time step-down shows from
+                # the next round, and the leader compare is the fold's own
+                # — off st3 the damped round was 1.2% slower on the chip
+                # (PERF.md §6, PR 35).
+                stats, last_leader = chaos_mod.update_leader_stats(
+                    stats, rcar.last_leader, hl,
+                    hl2.planes[kernels.HP_TERM_BUMPS],
+                    st2.state, st2.term, crashed,
+                )
         with profiling.scope("reconfig.apply"):
             # dtype= on the counts: bare bool sums widen to int64 under
             # x64 (GC007) and these feed the int32 accumulator.
@@ -1136,26 +1149,29 @@ def _runner_body(
             # the device histogram (bucket = min(latency, cap), cap =
             # N_LAT_BUCKETS - 1 derived from the carry shape).
             lat_cap = lat_hist.shape[0] - 1
-            served = (receipt.index >= 0) & (pmode > 0)
-            lat = jnp.clip(r - psince, 0, lat_cap)
+            with profiling.scope("runner.client"):
+                served = (receipt.index >= 0) & (pmode > 0)
+                lat = jnp.clip(r - psince, 0, lat_cap)
             lat_hist = workload_mod.fold_latencies(lat_hist, served, lat)
             # dtype= on the counts: GC007 (bare bool sums widen under
             # x64) — these feed the int32 read-stats accumulator.
-            rdstats = rdstats + jnp.stack(
-                [
-                    jnp.sum(fresh, dtype=jnp.int32),
-                    jnp.sum(served & receipt.lease, dtype=jnp.int32),
-                    jnp.sum(served & ~receipt.lease, dtype=jnp.int32),
-                    jnp.sum(served & receipt.degraded, dtype=jnp.int32),
-                    jnp.sum((pmode > 0) & ~served, dtype=jnp.int32),
-                    jnp.sum(dropped, dtype=jnp.int32),
-                ]
-            )
-            rcar = type(rcar)(
-                pending_mode=jnp.where(served, 0, pmode),
-                pending_since=jnp.where(served, 0, psince),
-                last_leader=last_leader,
-            )
+            with profiling.scope("runner.stats"):
+                rdstats = rdstats + jnp.stack(
+                    [
+                        jnp.sum(fresh, dtype=jnp.int32),
+                        jnp.sum(served & receipt.lease, dtype=jnp.int32),
+                        jnp.sum(served & ~receipt.lease, dtype=jnp.int32),
+                        jnp.sum(served & receipt.degraded, dtype=jnp.int32),
+                        jnp.sum((pmode > 0) & ~served, dtype=jnp.int32),
+                        jnp.sum(dropped, dtype=jnp.int32),
+                    ]
+                )
+            with profiling.scope("runner.client"):
+                rcar = type(rcar)(
+                    pending_mode=jnp.where(served, 0, pmode),
+                    pending_since=jnp.where(served, 0, psince),
+                    last_leader=last_leader,
+                )
             out = out + (rcar, rdstats, lat_hist)
         if with_bb:
             # The ring records the round-EXIT (post-apply) state; the

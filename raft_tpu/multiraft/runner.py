@@ -143,7 +143,8 @@ def _make_chaos(cfg: sim_mod.SimConfig, compiled: chaos_mod.CompiledChaos):
             st, hl, stats, safety = carry
             bb = None
         link, crashed, append = chaos_mod.schedule_masks(sched, r)
-        prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
+        with profiling.scope("runner.stats"):
+            prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
         st2, hl2 = sim_mod.step(
             cfg, st, crashed, append, health=hl, link=link
         )
@@ -165,9 +166,10 @@ def _make_chaos(cfg: sim_mod.SimConfig, compiled: chaos_mod.CompiledChaos):
                 st2.state, st2.term, st2.commit, st2.last_index, st2.agree,
                 st.commit,
             )
-        stats = chaos_mod.update_chaos_stats(
-            stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS]
-        )
+        with profiling.scope("runner.stats"):
+            stats = chaos_mod.update_chaos_stats(
+                stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS]
+            )
         out = (
             (st2, hl2, bb, stats, safety)
             if with_bb

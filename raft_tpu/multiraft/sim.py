@@ -2812,6 +2812,7 @@ def _damped_linked_step(
             return jnp.zeros((P, G), bool)
         return (Ld != 0) & (EE < et)
 
+    @profiling.scope("damped.merge_agree")
     def _merge_agree(agree_pl, in_s, new_last, lead_row):
         """Pairwise-agreement update after wholesale adoption: everyone
         in the sync set `in_s` now holds exactly the sender's log (length
@@ -2829,6 +2830,7 @@ def _damped_linked_step(
             ),
         )
 
+    @profiling.scope("damped.cut_before")
     def _cut_before(eff, axis):
         """True strictly AFTER the first effective nudge along `axis` —
         the response-stream cutoff: a deposed sender ignores everything
@@ -2984,6 +2986,7 @@ def _damped_linked_step(
         cnt_o = cnt_o + (dg_v & om_v).astype(jnp.int32)
         return (cnt_i, cnt_o, rec_i, rec_o, ff), ()
 
+    @profiling.scope("tally.real")
     def _real_tally(C, cand_active, t_grants, t_resps, t_snap, agree_pl):
         """Per-candidate voter-order tally -> (C', won, lost)."""
 
@@ -3159,15 +3162,16 @@ def _damped_linked_step(
             return (C, T, V, St, Ld, EE, HB, RT), (won_f,)
 
         pre_active = req & (St == kernels.ROLE_PRE_CANDIDATE)
-        (C, T, V, St, Ld, EE, HB, RT), (pre_won,) = jax.lax.scan(
-            _pre_body,
-            (C, T, V, St, Ld, EE, HB, RT),
-            (
-                pre_active, p_grants, p_resps, p_snap, p_resp_t, Erev,
-                st.agree, st.voter_mask, st.outgoing_mask, t_c0,
-                sender_ids,
-            ),
-        )
+        with profiling.scope("tally.pre"):
+            (C, T, V, St, Ld, EE, HB, RT), (pre_won,) = jax.lax.scan(
+                _pre_body,
+                (C, T, V, St, Ld, EE, HB, RT),
+                (
+                    pre_active, p_grants, p_resps, p_snap, p_resp_t, Erev,
+                    st.agree, st.voter_mask, st.outgoing_mask, t_c0,
+                    sender_ids,
+                ),
+            )
         real_req = pre_won  # broadcasts queued at win time
         rqt2 = t_c0 + 1
 

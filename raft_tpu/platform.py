@@ -90,8 +90,10 @@ def enable_compile_cache() -> str:
 
     Where `JAX_COMPILATION_CACHE_DIR` is set jax already uses it and no
     directory is set in code; otherwise the cache lives at one fixed path
-    inside the checkout (the path is part of the cache key, so a directory
-    that moves never hits).  Call before the process's first compile."""
+    inside the checkout.  Off the pinned CPU a program's metadata — its
+    profiling names and its source positions — is part of its cache key
+    (below), so there a checkout that moves never hits.  Call before the
+    process's first compile."""
     import jax
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
@@ -102,6 +104,17 @@ def enable_compile_cache() -> str:
     # fused-kernel jits; sub-second ones would only bloat the cache.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    if not pinned_to_cpu():
+        # The names a trace shows (raft_tpu.profiling's scopes) live in the
+        # compiled program's metadata, which jax leaves out of the cache
+        # key by default: a source that differs from a cached one by names
+        # alone is handed that executable, and its trace the OLD names
+        # (seen on the chip: PERF.md §6, PR 42).  On the chip the names are
+        # part of the program.  CPU-pinned processes (the tests) never
+        # trace a device, and keep sharing entries across call sites.
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True
+        )
     return path
 
 

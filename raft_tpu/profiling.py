@@ -107,12 +107,34 @@ SCOPES: Dict[str, str] = {
     "damped.wave1": "heartbeats + (pre-)vote requests, per receiver",
     "damped.wave2": "heartbeat responses + nudges back at each leader",
     "damped.tally": "the (pre-)vote tallies and post-election bookkeeping",
+    "tally.real": (
+        "_real_tally inside damped.tally: the real election's rolled "
+        "per-candidate, per-voter tally (wave 2 without pre-vote, wave 4 "
+        "with it)"
+    ),
+    "tally.pre": (
+        "the pre-vote tally inside damped.tally: _pre_body's loop over the "
+        "candidates and _pre_inner's over the voters"
+    ),
     "damped.wave3": "appends: winner noops, catch-ups, their retry chains",
-    "damped.stage_fold": "_stage_fold: the ack/nudge fold of waves 4 and 6",
+    "damped.stage_fold": (
+        "_stage_fold: the ack/nudge fold of waves 4 and 6 (inside "
+        "damped.tally and damped.wave5)"
+    ),
     "damped.wave5": "commit-advance re-broadcasts and their retry chains",
     "damped.workload": (
         "the round's append workload at the acting leader; dropped "
         "appends are counted here"
+    ),
+    "damped.merge_agree": (
+        "_merge_agree: the rewrite of agree_run[P, P, G] after a wholesale "
+        "adoption, once per sender trip of waves 3 and 5 and of their retry "
+        "passes, once in the workload"
+    ),
+    "damped.cut_before": (
+        "_cut_before: the response-stream cut-off after the first effective "
+        "nudge (a cumulative sum), in waves 2, 3 and 5, the stage fold and "
+        "the workload"
     ),
     "quorum_commit": (
         "the quorum position of the acked indexes: kernels.committed_index "
@@ -142,6 +164,23 @@ SCOPES: Dict[str, str] = {
     "read_fold": (
         "workload.fold_latencies: the round's served reads folded into the "
         "latency histogram, in every _runner_body round with a client plan"
+    ),
+    "runner.chaos_masks": (
+        "chaos.schedule_masks: the round's link, crash and append-skew "
+        "planes cut out of the chaos schedule, in every round of a runner "
+        "with a chaos plan"
+    ),
+    "runner.client": (
+        "what reconfig._runner_body offers the round: the schedules' append "
+        "rows and, with a client plan, the read fires unpacked "
+        "(kernels.unpack_bits_g), the pending-read bookkeeping before and "
+        "after the step and the audit's inputs (kernels.lease_read's holder "
+        "mask)"
+    ),
+    "runner.stats": (
+        "chaos.update_chaos_stats / update_leader_stats and "
+        "_runner_body's read-stats fold: the round's counts into the "
+        "report's accumulators"
     ),
     "runner.block_guard": (
         "everything a split block computes before its lax.cond: its "

@@ -240,7 +240,8 @@ def lowered_text():
     scope: the split block program of the damped fleet (guard, both arms,
     the damped round with reads and health, the safety audit), the
     undamped round without a link plane and, serving a read, with one, the
-    percentile fold, the reconfig scan and the client-workload scan."""
+    percentile fold, the reconfig scan and the client-workload scan
+    without and with a chaos plan."""
     cfg = damped_cfg()
     client = workload.compile_plan(client_plan(), G)
     run = runner_mod.make_runner(cfg, (client,), split=True, k=8)
@@ -295,11 +296,20 @@ def lowered_text():
         st, sim.init_health(cfg), rst, workload.init_read_carry(G),
         *cscan.schedule_args,
     ).as_text(debug_info=True)
+    # ... and under a chaos plan (the shape `.outage` runs): the round's
+    # masks are cut out of the chaos schedule in the scan's body.
+    xscan = runner_mod.make_runner(
+        cfg, (chaos.compile_plan(crash_plan(), G), client))
+    texts["client_chaos_scan"] = xscan.jitted.lower(
+        st, sim.init_health(cfg), rst, workload.init_read_carry(G),
+        *xscan.schedule_args,
+    ).as_text(debug_info=True)
     return texts
 
 
 WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency",
          "damped.read_holders": "readindex",
+         "runner.chaos_masks": "client_chaos_scan",
          **{s: "linked" for s in profiling.SCOPES if s.startswith("linked.")}}
 
 
