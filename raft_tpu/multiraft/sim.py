@@ -105,7 +105,7 @@ tests/test_sim_fuzz.py for the schedules that originally exposed them.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple, Union
+from typing import Callable, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -416,6 +416,32 @@ def _halves(st: SimState) -> _Halves:
     return _Halves(n_i, n_o, singleton, n_i // 2 + 1, n_o // 2 + 1)
 
 
+def _has_quorum(
+    h: _Halves,
+    cnt_i: jnp.ndarray,  # gc: int32[..., G]
+    cnt_o: jnp.ndarray,  # gc: int32[..., G]
+) -> jnp.ndarray:
+    """The counts (grants, acks) reach the majority of BOTH halves of the
+    configuration; an empty half agrees (joint.rs vote_result)."""
+    return ((cnt_i >= h.q_i) | (h.n_i == 0)) & (
+        (cnt_o >= h.q_o) | (h.n_o == 0)
+    )
+
+
+def _cannot_win(
+    h: _Halves,
+    cnt_i: jnp.ndarray,  # gc: int32[..., G]
+    cnt_o: jnp.ndarray,  # gc: int32[..., G]
+    rec_i: jnp.ndarray,  # gc: int32[..., G]
+    rec_o: jnp.ndarray,  # gc: int32[..., G]
+) -> jnp.ndarray:
+    """Some half's grants plus its voters yet to respond fall short of its
+    majority (VoteResult::Lost)."""
+    return ((h.n_i > 0) & (cnt_i + (h.n_i - rec_i) < h.q_i)) | (
+        (h.n_o > 0) & (cnt_o + (h.n_o - rec_o) < h.q_o)
+    )
+
+
 def _acks_and_nudges(
     st: SimState,
     resp: jnp.ndarray,  # gc: bool[..., P, G]
@@ -466,9 +492,7 @@ def _acks_before_nudge(
         a = ack_v[..., v, :] & ~dead
         cnt_i = cnt_i + (a & st.voter_mask[v]).astype(jnp.int32)
         cnt_o = cnt_o + (a & st.outgoing_mask[v]).astype(jnp.int32)
-        quorum = ((cnt_i >= h.q_i) | (h.n_i == 0)) & (
-            (cnt_o >= h.q_o) | (h.n_o == 0)
-        )
+        quorum = _has_quorum(h, cnt_i, cnt_o)
         # has_quorum(acks) is only EVALUATED inside
         # handle_heartbeat_response — i.e. on processing ack `a` — which
         # is what makes the leader-alone joint quorum hang until some
@@ -2629,6 +2653,183 @@ def _sender_scan(body, carry, xs):
     return jax.lax.scan(trip, carry, xs, unroll=True)
 
 
+# ---- the damped round's tallies.  A candidate's tally reads and writes
+# only its own row of the [P, G] planes and its own [P_voter, G] slab of the
+# response planes, so the candidate axis is a batch axis: each tally is ONE
+# walk over the P voters whose carry is [P_cand, G] planes — the same
+# integer and boolean work per (candidate, voter) pair, in the same voter
+# order, as one candidate at a time (tests/test_tally_batched.py holds both
+# to a plain per-candidate, per-voter reference).
+
+
+def _voter_scan(body, carry, xs):
+    """A damped tally's loop over the P voter-major slabs: a ROLLED
+    `lax.scan` of P trips — the one `while` a tally keeps.
+
+    Straight-line forms were traced on the chip and are faster there
+    (without a barrier between the trips the compiler fuses across them:
+    PERF.md §6, PR 43), but XLA's CPU backend pays for them at compile
+    time — a scanned cq + pv round compiles in 11 s rolled, as before the
+    tallies were batched, in 23 s straight-line behind `_sender_scan`'s
+    barrier, in 37 s without it, and in 14 minutes with one more compare
+    hoisted out of the loop — and tier-1 compiles dozens of such rounds.
+    Rolled, each trip cuts its `[P_cand, G]` slabs out of the stacks with a
+    dynamic_slice by the VOTER's index; nothing is indexed by a
+    candidate."""
+    return jax.lax.scan(body, carry, xs)
+
+
+def _voter_major(
+    plane: jnp.ndarray,  # gc: any
+) -> jnp.ndarray:
+    """[P_cand, P_voter, G] -> [P_voter, P_cand, G]: trip v of a tally's
+    voter loop reads one whole slab."""
+    return jnp.swapaxes(plane, 0, 1)
+
+
+@profiling.scope("tally.real")
+def _real_tally(
+    st: SimState,
+    h: _Halves,
+    C: jnp.ndarray,  # gc: int32[P, G]
+    cand_active: jnp.ndarray,  # gc: bool[P, G]
+    t_grants: jnp.ndarray,  # gc: bool[P, P, G]
+    t_resps: jnp.ndarray,  # gc: bool[P, P, G]
+    t_snap: jnp.ndarray,  # gc: int32[P, P, G]
+    agree_pl: jnp.ndarray,  # gc: int32[P, P, G]
+    erev: jnp.ndarray,  # gc: bool[P, P, G]
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The real-election tally (the _linked_step wave-2 machinery) of every
+    candidate at once, responses in voter order -> (C', won, lost).  The
+    response planes are [P_cand, P_voter, G]; `erev` says whose response
+    reaches its candidate.  A reject carries the voter's commit (`t_snap`),
+    which fast-forwards an undecided candidate that agrees that far."""
+
+    def trip(carry, xs):
+        cnt_i, cnt_o, rec_i, rec_o, ff = carry  # [P_cand, G]
+        # Voter v's slab of the response planes, and its mask rows [G].
+        dg_v, dr_v, snap_v, agree_v, vm_v, om_v = xs
+        decided = _has_quorum(h, cnt_i, cnt_o) | _cannot_win(
+            h, cnt_i, cnt_o, rec_i, rec_o
+        )
+        ok = dr_v & ~decided & (snap_v <= agree_v)
+        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
+        resp_v = dg_v | dr_v
+        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
+        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
+        cnt_i = cnt_i + (dg_v & vm_v).astype(jnp.int32)
+        cnt_o = cnt_o + (dg_v & om_v).astype(jnp.int32)
+        return (cnt_i, cnt_o, rec_i, rec_o, ff), ()
+
+    del_g = t_grants & erev
+    del_r = (t_resps & ~t_grants) & erev
+    # A candidate's own vote counts in the halves it sits in.
+    cnt_i = (cand_active & st.voter_mask).astype(jnp.int32)
+    cnt_o = (cand_active & st.outgoing_mask).astype(jnp.int32)
+    (cnt_i, cnt_o, rec_i, rec_o, ff), _ = _voter_scan(
+        trip,
+        (cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros_like(C)),
+        (
+            _voter_major(del_g), _voter_major(del_r), _voter_major(t_snap),
+            _voter_major(agree_pl), st.voter_mask, st.outgoing_mask,
+        ),
+    )
+    won = cand_active & _has_quorum(h, cnt_i, cnt_o)
+    lost = cand_active & ~won & _cannot_win(h, cnt_i, cnt_o, rec_i, rec_o)
+    return jnp.maximum(C, ff), won, lost
+
+
+@profiling.scope("tally.pre")
+def _pre_tally(
+    st: SimState,
+    h: _Halves,
+    planes: Tuple[jnp.ndarray, ...],  # gc: any
+    act: jnp.ndarray,  # gc: bool[P, G]
+    t_c0: jnp.ndarray,  # gc: int32[P, G]
+    p_grants: jnp.ndarray,  # gc: bool[P, P, G]
+    p_resps: jnp.ndarray,  # gc: bool[P, P, G]
+    p_resp_t: jnp.ndarray,  # gc: int32[P, P, G]
+    p_snap: jnp.ndarray,  # gc: int32[P, P, G]
+    erev: jnp.ndarray,  # gc: bool[P, P, G]
+    draw: Callable[[jnp.ndarray], jnp.ndarray],
+) -> Tuple[jnp.ndarray, ...]:
+    """The pre-vote tally of every pre-candidate (`act`, pre-campaign terms
+    `t_c0`) at once, and each one's end-of-wave state -> (C, T, V, St, EE,
+    HB, RT, pre_won).  Responses in voter order; a reject at a term above
+    the candidate's CURRENT term deposes it (become_follower at the
+    response term, chainable), a reject at exactly its pre-campaign term
+    records a poll rejection, grants record while undecided; on quorum the
+    pre-winner runs campaign(Election) — term+1, vote self, timers reset.
+    Deposition after the win knocks the fresh candidate back down (its
+    queued broadcast still delivers).  `draw(T)` is the round's timeout
+    draw on whole [P, G] planes."""
+    C, T, V, St, EE, HB, RT = planes
+
+    def trip(carry, xs):
+        (cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f,
+         cur_t) = carry  # [P_cand, G]
+        dg_v, dr_v, rt_v, snap_v, agree_v, vm_v, om_v = xs
+        dep_now = dr_v & (rt_v > cur_t)
+        undecided = ~dep_f & ~won_f & ~lost_f
+        rec_grant = dg_v & undecided
+        rec_rej = dr_v & (rt_v == t_c0) & undecided
+        ok = rec_rej & (snap_v <= agree_v)
+        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
+        cnt_i = cnt_i + (rec_grant & vm_v).astype(jnp.int32)
+        cnt_o = cnt_o + (rec_grant & om_v).astype(jnp.int32)
+        resp_v = rec_grant | rec_rej
+        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
+        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
+        won_now = rec_grant & _has_quorum(h, cnt_i, cnt_o)
+        lost_now = rec_rej & _cannot_win(h, cnt_i, cnt_o, rec_i, rec_o)
+        cur_t = jnp.where(won_now, t_c0 + 1, cur_t)
+        won_f = won_f | won_now
+        lost_f = lost_f | lost_now
+        dep_f = dep_f | dep_now
+        cur_t = jnp.where(dep_now, jnp.maximum(cur_t, rt_v), cur_t)
+        return (
+            cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f, cur_t,
+        ), ()
+
+    del_g = p_grants & erev
+    del_r = (p_resps & ~p_grants) & erev
+    cnt_i = (act & st.voter_mask).astype(jnp.int32)
+    cnt_o = (act & st.outgoing_mask).astype(jnp.int32)
+    won0 = act & _has_quorum(h, cnt_i, cnt_o)
+    none = jnp.zeros_like(act)
+    (_, _, _, _, ff, won_f, lost_f, dep_f, cur_t), _ = _voter_scan(
+        trip,
+        (
+            cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros_like(C), won0, none,
+            none, jnp.where(won0, t_c0 + 1, t_c0),
+        ),
+        (
+            _voter_major(del_g), _voter_major(del_r),
+            _voter_major(p_resp_t), _voter_major(p_snap),
+            _voter_major(st.agree), st.voter_mask, st.outgoing_mask,
+        ),
+    )
+    won_f = won_f & act
+    lost_f = lost_f & act
+    dep_f = dep_f & act
+    # End-of-wave state of every candidate row.
+    self_id = jnp.arange(act.shape[0], dtype=jnp.int32)[:, None] + 1
+    C = jnp.maximum(C, ff)
+    T = jnp.where(act, cur_t, T)
+    bumped = act & (cur_t != t_c0)
+    V = jnp.where(won_f & ~dep_f, self_id, jnp.where(dep_f & bumped, 0, V))
+    St = jnp.where(
+        won_f & ~dep_f,
+        ROLE_CANDIDATE,
+        jnp.where(dep_f | lost_f, ROLE_FOLLOWER, St),
+    )
+    settled = won_f | lost_f | dep_f
+    EE = jnp.where(settled, 0, EE)
+    HB = jnp.where(settled, 0, HB)
+    RT = jnp.where(won_f | dep_f, draw(T), RT)
+    return C, T, V, St, EE, HB, RT, won_f
+
+
 @profiling.scope("round.damped")
 def _damped_linked_step(
     cfg: SimConfig,
@@ -2802,11 +3003,6 @@ def _damped_linked_step(
     hb_send = want_heartbeat
     sender_ids = jnp.arange(P, dtype=jnp.int32)
 
-    n_i = jnp.sum(st.voter_mask, axis=0).astype(jnp.int32)
-    n_o = jnp.sum(st.outgoing_mask, axis=0).astype(jnp.int32)
-    q_i = n_i // 2 + 1
-    q_o = n_o // 2 + 1
-
     def in_lease(Ld, EE):
         if not cq:  # graftcheck: allow-no-python-branch-on-traced — closes over the static SimConfig damping flag (trace-time constant)
             return jnp.zeros((P, G), bool)
@@ -2966,212 +3162,25 @@ def _damped_linked_step(
     RT = jnp.where(hdep, draw(T), RT)
 
     sec.at("damped.tally")
-    # ---- real-election tally (the _linked_step wave-2 machinery): used
-    # at wave 2 without pre-vote, at wave 4 with it.
-    def _tally_inner(carry, xs):
-        cnt_i, cnt_o, rec_i, rec_o, ff = carry
-        dg_v, dr_v, snap_v, agree_v, vm_v, om_v = xs
-        won_before = ((cnt_i >= q_i) | (n_i == 0)) & (
-            (cnt_o >= q_o) | (n_o == 0)
-        )
-        lost_before = ((n_i > 0) & (cnt_i + (n_i - rec_i) < q_i)) | (
-            (n_o > 0) & (cnt_o + (n_o - rec_o) < q_o)
-        )
-        ok = dr_v & ~won_before & ~lost_before & (snap_v <= agree_v)
-        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
-        resp_v = dg_v | dr_v
-        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
-        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
-        cnt_i = cnt_i + (dg_v & vm_v).astype(jnp.int32)
-        cnt_o = cnt_o + (dg_v & om_v).astype(jnp.int32)
-        return (cnt_i, cnt_o, rec_i, rec_o, ff), ()
-
-    @profiling.scope("tally.real")
-    def _real_tally(C, cand_active, t_grants, t_resps, t_snap, agree_pl):
-        """Per-candidate voter-order tally -> (C', won, lost)."""
-
-        def body(C, xs):
-            (act_s, grants_s, resps_s, snap_s, erev_s, agree_s, vm_row,
-             om_row, sid) = xs
-            del_g = grants_s & erev_s
-            del_r = (resps_s & ~grants_s) & erev_s
-            cnt_i = (act_s & vm_row).astype(jnp.int32)
-            cnt_o = (act_s & om_row).astype(jnp.int32)
-            (cnt_i, cnt_o, rec_i, rec_o, ff), _ = jax.lax.scan(
-                _tally_inner,
-                (cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros((G,), jnp.int32)),
-                (
-                    del_g, del_r, snap_s, agree_s, st.voter_mask,
-                    st.outgoing_mask,
-                ),
-            )
-            won_ci = (
-                act_s
-                & ((cnt_i >= q_i) | (n_i == 0))
-                & ((cnt_o >= q_o) | (n_o == 0))
-            )
-            lost_ci = (
-                act_s
-                & ~won_ci
-                & (
-                    ((n_i > 0) & (cnt_i + (n_i - rec_i) < q_i))
-                    | ((n_o > 0) & (cnt_o + (n_o - rec_o) < q_o))
-                )
-            )
-            row = jax.lax.dynamic_index_in_dim(C, sid, 0, keepdims=False)
-            C = jnp.where(p_idx == sid, jnp.maximum(row, ff)[None, :], C)
-            return C, (won_ci, lost_ci)
-
-        C, (won, lost) = jax.lax.scan(
-            body,
-            C,
-            (
-                cand_active, t_grants, t_resps, t_snap, Erev, agree_pl,
-                st.voter_mask, st.outgoing_mask, sender_ids,
-            ),
-        )
-        return C, won, lost
-
+    halves = _halves(st)
     if not pv:
         # ---- wave 2b: the real tally now, exactly like _linked_step.
         cand_active = req & (St == ROLE_CANDIDATE)
         C, won, lost = _real_tally(
-            C, cand_active, grants, resps, rej_snap, st.agree
+            st, halves, C, cand_active, grants, resps, rej_snap, st.agree,
+            Erev,
         )
         real_req = jnp.zeros((P, G), bool)
         rqt2 = req_term  # unused senders masked off
     else:
-        # ---- wave 2b: pre-vote tally.  Responses in voter order; a
-        # reject at a term above the candidate's CURRENT term deposes it
-        # (become_follower at the response term, chainable), a reject at
-        # exactly its pre-campaign term records a poll rejection, grants
-        # record while undecided; on quorum the pre-winner runs
-        # campaign(Election) — term+1, vote self, timers reset — and its
-        # REAL vote broadcast is queued for wave 3.  Deposition after the
-        # win knocks the fresh candidate back down (its queued broadcast
-        # still delivers).
+        # ---- wave 2b: the pre-vote tally; a pre-winner's REAL vote
+        # broadcast is queued for wave 3.
         t_c0 = term  # pre-campaign terms
-
-        def _pre_inner(carry, xs):
-            (cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f,
-             cur_t) = carry
-            dg_v, dr_v, rt_v, snap_v, agree_v, vm_v, om_v, t0_row = xs
-            won_before = won_f
-            lost_before = lost_f
-            dep_now = dr_v & (rt_v > cur_t)
-            undecided = ~dep_f & ~won_before & ~lost_before
-            rec_grant = dg_v & undecided
-            rec_rej = dr_v & (rt_v == t0_row) & undecided
-            ok = rec_rej & (snap_v <= agree_v)
-            ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
-            cnt_i = cnt_i + (rec_grant & vm_v).astype(jnp.int32)
-            cnt_o = cnt_o + (rec_grant & om_v).astype(jnp.int32)
-            resp_v = rec_grant | rec_rej
-            rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
-            rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
-            won_now = (
-                rec_grant
-                & ((cnt_i >= q_i) | (n_i == 0))
-                & ((cnt_o >= q_o) | (n_o == 0))
-            )
-            lost_now = rec_rej & (
-                ((n_i > 0) & (cnt_i + (n_i - rec_i) < q_i))
-                | ((n_o > 0) & (cnt_o + (n_o - rec_o) < q_o))
-            )
-            cur_t = jnp.where(won_now, t0_row + 1, cur_t)
-            won_f = won_f | won_now
-            lost_f = lost_f | lost_now
-            dep_f = dep_f | dep_now
-            cur_t = jnp.where(dep_now, jnp.maximum(cur_t, rt_v), cur_t)
-            return (
-                cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f,
-                cur_t,
-            ), ()
-
-        def _pre_body(carry, xs):
-            C, T, V, St, Ld, EE, HB, RT = carry
-            (act_s, grants_s, resps_s, snap_s, respt_s, erev_s, agree_s,
-             vm_row, om_row, t0_row, sid) = xs
-            del_g = grants_s & erev_s
-            del_r = (resps_s & ~grants_s) & erev_s
-            cnt_i = (act_s & vm_row).astype(jnp.int32)
-            cnt_o = (act_s & om_row).astype(jnp.int32)
-            won0 = (
-                act_s
-                & ((cnt_i >= q_i) | (n_i == 0))
-                & ((cnt_o >= q_o) | (n_o == 0))
-            )
-            cur0 = jnp.where(won0, t0_row + 1, t0_row)
-            (cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f,
-             cur_t), _ = jax.lax.scan(
-                _pre_inner,
-                (
-                    cnt_i, cnt_o, cnt_i, cnt_o,
-                    jnp.zeros((G,), jnp.int32), won0,
-                    jnp.zeros((G,), bool), jnp.zeros((G,), bool), cur0,
-                ),
-                (
-                    del_g, del_r, respt_s, snap_s, agree_s,
-                    st.voter_mask, st.outgoing_mask,
-                    jnp.broadcast_to(t0_row, (P, G)),
-                ),
-            )
-            won_f = won_f & act_s
-            lost_f = lost_f & act_s
-            dep_f = dep_f & act_s
-            # End-of-wave state for candidate row sid.
-            row = jax.lax.dynamic_index_in_dim(C, sid, 0, keepdims=False)
-            C = jnp.where(p_idx == sid, jnp.maximum(row, ff)[None, :], C)
-            t_new = jnp.where(act_s, cur_t, jnp.take(T, sid, axis=0))
-            bumped = act_s & (cur_t != t0_row)
-            v_new = jnp.where(
-                won_f & ~dep_f,
-                sid + 1,
-                jnp.where(
-                    dep_f & bumped, 0, jnp.take(V, sid, axis=0)
-                ),
-            )
-            st_new = jnp.where(
-                won_f & ~dep_f,
-                ROLE_CANDIDATE,
-                jnp.where(
-                    dep_f | lost_f,
-                    ROLE_FOLLOWER,
-                    jnp.take(St, sid, axis=0),
-                ),
-            )
-            settled = won_f | lost_f | dep_f
-            ee_new = jnp.where(settled, 0, jnp.take(EE, sid, axis=0))
-            hb_new = jnp.where(settled, 0, jnp.take(HB, sid, axis=0))
-            rt_new = jnp.where(
-                won_f | dep_f,
-                kernels.timeout_draw(
-                    jnp.take(node_key, sid, axis=0),
-                    t_new.astype(jnp.uint32),
-                    jnp.take(lo, sid, axis=0),
-                    jnp.take(hi, sid, axis=0),
-                ),
-                jnp.take(RT, sid, axis=0),
-            )
-            T = jnp.where(p_idx == sid, t_new[None, :], T)
-            V = jnp.where(p_idx == sid, v_new[None, :], V)
-            St = jnp.where(p_idx == sid, st_new[None, :], St)
-            EE = jnp.where(p_idx == sid, ee_new[None, :], EE)
-            HB = jnp.where(p_idx == sid, hb_new[None, :], HB)
-            RT = jnp.where(p_idx == sid, rt_new[None, :], RT)
-            return (C, T, V, St, Ld, EE, HB, RT), (won_f,)
-
         pre_active = req & (St == kernels.ROLE_PRE_CANDIDATE)
-        with profiling.scope("tally.pre"):
-            (C, T, V, St, Ld, EE, HB, RT), (pre_won,) = jax.lax.scan(
-                _pre_body,
-                (C, T, V, St, Ld, EE, HB, RT),
-                (
-                    pre_active, p_grants, p_resps, p_snap, p_resp_t, Erev,
-                    st.agree, st.voter_mask, st.outgoing_mask, t_c0,
-                    sender_ids,
-                ),
-            )
+        C, T, V, St, EE, HB, RT, pre_won = _pre_tally(
+            st, halves, (C, T, V, St, EE, HB, RT), pre_active, t_c0,
+            p_grants, p_resps, p_resp_t, p_snap, Erev, draw,
+        )
         real_req = pre_won  # broadcasts queued at win time
         rqt2 = t_c0 + 1
 
@@ -3378,7 +3387,8 @@ def _damped_linked_step(
     if pv:
         cand_active = real_req & (St == ROLE_CANDIDATE)
         C, won, lost = _real_tally(
-            C, cand_active, r_grants, r_resps, r_snap, agree_run
+            st, halves, C, cand_active, r_grants, r_resps, r_snap,
+            agree_run, Erev,
         )
         li2 = LI + won.astype(jnp.int32)
         lt2 = jnp.where(won, T, lt2)

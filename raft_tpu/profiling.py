@@ -108,13 +108,14 @@ SCOPES: Dict[str, str] = {
     "damped.wave2": "heartbeat responses + nudges back at each leader",
     "damped.tally": "the (pre-)vote tallies and post-election bookkeeping",
     "tally.real": (
-        "_real_tally inside damped.tally: the real election's rolled "
-        "per-candidate, per-voter tally (wave 2 without pre-vote, wave 4 "
-        "with it)"
+        "_real_tally inside damped.tally: the real election's tally of "
+        "every candidate at once, one walk over the P voters on "
+        "[P_cand, G] planes (wave 2 without pre-vote, wave 4 with it)"
     ),
     "tally.pre": (
-        "the pre-vote tally inside damped.tally: _pre_body's loop over the "
-        "candidates and _pre_inner's over the voters"
+        "_pre_tally inside damped.tally: the pre-vote tally of every "
+        "pre-candidate at once, one walk over the P voters on [P_cand, G] "
+        "planes, and the candidates' end-of-wave state on whole planes"
     ),
     "damped.wave3": "appends: winner noops, catch-ups, their retry chains",
     "damped.stage_fold": (

@@ -1,4 +1,4 @@
-"""How the damped round's sender loops lower (ISSUE 41, ROADMAP A5).
+"""How the damped round's loops lower (ISSUE 41, ISSUE 43; ROADMAP A5).
 
 `sim._damped_linked_step` walks the P sender rows five times a round
 (wave 1, wave 3, wave 5 and the two retry passes).  Each walk is ONE `scan`
@@ -10,16 +10,27 @@ trip, which on the chip was a third of the round (PERF.md §6, PR 41).  Each
 trip opens with an `optimization_barrier` on the carry: without it the
 straight-line round was 19% SLOWER on the chip than the rolled one (the
 compiler pooled the trips and the carried planes fell out of fast memory),
-with it 26% faster — so the barrier is held here too.  The
-tallies (`_real_tally` / `_tally_inner`, the pre-vote tally / `_pre_inner`)
-are P x P bodies of `[G]` rows and stay rolled: two `while`s without
-pre-vote, four with it.
+with it 26% faster — so the barrier is held here too.
+
+The tallies (`sim._real_tally`, `sim._pre_tally`) were P x P rolled trips
+on `[G]` rows — an outer loop over the candidates around an inner one over
+the voters — until PR 43: a candidate's tally touches only its own row, so
+the candidate axis is a batch axis, and each tally is ONE walk of P trips
+over the voters on `[P_cand, G]` planes (`sim._voter_scan`): one rolled
+`scan`, the one `while` a tally keeps (1 without pre-vote, 2 with it,
+where there were 2 and 4).  Its trips slice the voter-major stacks by the
+VOTER's index; no loop over candidates is left, and with it no `take`,
+`gather` or row rewrite indexed by a candidate id under `damped.tally`.
+Straight-line forms of that loop are faster on the chip and cost XLA's CPU
+backend minutes of compile in tier-1 (PERF.md §6, PR 43), so it is rolled.
 
 Bit-equality of the rounds is the parity suites' subject
-(`tests/test_damping_parity.py`, `tests/test_readindex_damped.py`, ...);
-this file holds the FORM, on the lowered text of `sim.step` under a link
-plane with a read probe, at G = 8.  `sim._linked_step` (the stock fleet's
-body, ROADMAP A13) is the control: all of its loops stay rolled.
+(`tests/test_damping_parity.py`, `tests/test_readindex_damped.py`, ...) and
+of the tallies alone `tests/test_tally_batched.py`'s; this file holds the
+FORM, on the jaxpr and the lowered text of `sim.step` under a link plane
+with a read probe, at G = 8.  `sim._linked_step` (the stock fleet's body,
+ROADMAP A13) is the control: all of its loops stay rolled, its tally's
+P x P form among them.
 """
 
 import re
@@ -29,6 +40,7 @@ import jax.numpy as jnp
 import pytest
 
 from raft_tpu.multiraft import sim
+from test_round_map import leaves, sub_jaxprs
 
 G = 8
 # (check_quorum, pre_vote, lease_read); lease reads need check-quorum.
@@ -62,20 +74,27 @@ def faulted_step(P, cq, pv, lease):
             jax.jit(one_round).lower(*args).as_text())
 
 
-def scans(jaxpr, depth=0):
-    """[(depth, length, unroll, first equation of the body)] of every `scan`
-    equation, nested ones too."""
+def names_of(eqn):
+    return tuple(c for c in str(eqn.source_info.name_stack).split("/") if c)
+
+
+def scans(jaxpr, depth=0, names=()):
+    """[(depth, length, unroll, first equation of the body, name stack)] of
+    every `scan` equation, nested ones too."""
     found = []
     for eqn in jaxpr.eqns:
+        own = names + names_of(eqn)
         if eqn.primitive.name == "scan":
             found.append((depth, eqn.params["length"], eqn.params["unroll"],
-                          eqn.params["jaxpr"].jaxpr.eqns[0].primitive.name))
-        for param in eqn.params.values():
-            for sub in param if isinstance(param, (list, tuple)) else [param]:
-                inner = getattr(sub, "jaxpr", sub)
-                if hasattr(inner, "eqns"):
-                    found.extend(scans(inner, depth + 1))
+                          eqn.params["jaxpr"].jaxpr.eqns[0].primitive.name, own))
+        for inner in sub_jaxprs(eqn):
+            found.extend(scans(inner, depth + 1, own))
     return found
+
+
+def primitives_under(jaxpr, scope):
+    """The primitives of every leaf equation whose name stack holds `scope`."""
+    return {prim for prim, names, _ in leaves(jaxpr) if scope in names}
 
 
 def updates_of_planes(text, P):
@@ -85,34 +104,47 @@ def updates_of_planes(text, P):
             if "dynamic_update_slice" in ln and plane in ln]
 
 
+# What a loop over candidates would index its rows with (a rolled loop
+# over VOTERS slices its stacked inputs by the trip count at lowering, not
+# in the jaxpr).
+BY_CANDIDATE = {"dynamic_slice", "dynamic_update_slice", "gather", "scatter"}
+
+
 @pytest.mark.parametrize("cq, pv, lease", DAMPED)
 @pytest.mark.parametrize("P", [3, 5])
 def test_sender_loops_lower_straight_line(P, cq, pv, lease):
     jaxpr, text = faulted_step(P, cq, pv, lease)
     found = scans(jaxpr)
-    assert all(s[1] == P for s in found), found
-    unrolled = [s for s in found if s[2] == P]
-    rolled = [s for s in found if s[2] == 1]
-    assert len(unrolled) + len(rolled) == len(found), found
-    # One `scan` equation per sender loop, none nested (traced once each),
-    # every trip behind its barrier.
-    top = unrolled[0][0]
-    assert unrolled == [(top, P, P, "optimization_barrier")] * SENDER_LOOPS, found
-    # The tallies: outer over candidates, inner over voters, still rolled.
-    tallies = 2 if pv else 1
-    assert sorted(s[0] - top for s in rolled) == [0] * tallies + [1] * tallies, found
-    assert all(s[3] != "optimization_barrier" for s in rolled), found
-    # The only `while`s left are the tallies' (9 at cq + pv before PR 41).
-    assert len(re.findall(r"stablehlo\.while", text)) == 2 * tallies
+    # One `scan` equation per loop, P trips, none nested (traced once each).
+    top = found[0][0]
+    assert all(s[:2] == (top, P) for s in found), found
+    # The sender loops: straight-line, every trip behind its barrier.
+    senders = [s for s in found if "damped.tally" not in s[4]]
+    assert [s[2:4] for s in senders] == [(P, "optimization_barrier")] * SENDER_LOOPS, found
+    # The tallies: ONE rolled voter loop each (pre-vote and real with
+    # pre-vote, the real one alone without), nothing in them indexed by a
+    # candidate.
+    tallies = [s for s in found if "damped.tally" in s[4]]
+    want = ["tally.pre", "tally.real"] if pv else ["tally.real"]
+    assert [next(n for n in s[4] if n.startswith("tally.")) for s in tallies] == want
+    assert all(s[2] == 1 for s in tallies), found
+    assert not primitives_under(jaxpr, "damped.tally") & BY_CANDIDATE
+    # The only `while`s left are the tallies' voter loops (9 at cq + pv
+    # before PR 41, 4 before PR 43), and no stacked [P, P, G] output is
+    # rewritten a trip.
+    assert len(re.findall(r"stablehlo\.while", text)) == len(want)
     assert updates_of_planes(text, P) == []
 
 
 @pytest.mark.parametrize("P", [3, 5])
 def test_the_stock_fleets_loops_stay_rolled(P):
-    """`_linked_step` is not PR 41's: six sender loops and the tally's inner
-    one, each a rolled `scan` (the control cell's program is the parent's)."""
+    """`_linked_step` is not PR 41's nor PR 43's: six sender loops, the
+    tally's over the candidates among them, and the tally's inner one over
+    the voters, each a rolled `scan` (the control cell's program is the
+    parent's)."""
     jaxpr, text = faulted_step(P, False, False, False)
     found = scans(jaxpr)
     assert len(found) == 7 and all(s[1:3] == (P, 1) for s in found), found
+    assert sorted(s[0] - found[0][0] for s in found) == [0] * 6 + [1], found
     assert len(re.findall(r"stablehlo\.while", text)) == len(found)
     assert updates_of_planes(text, P) != []
