@@ -3491,12 +3491,15 @@ def _damped_linked_step(
         )
         LI = jnp.where(adopt, li2_row[None, :], LI)
         LT = jnp.where(adopt, lt2_row[None, :], LT)
-        ack = adopt & erev_s
+        # With pre-vote the stream hands out the acks.  Without it, WHO
+        # adopted: wave 6 needs the adopters (below), and the acks are
+        # theirs whose way back is up (`took5 & Erev` after the scan).
+        took = adopt & erev_s if pv else adopt
         sent_any = jnp.any(adopt, axis=0)
         in_s = adopt | ((p_idx == sid) & sent_any[None, :])
         agree_run = _merge_agree(agree_run, in_s, li2_row, agree_s)
         return (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run), (
-            ack, ndg, ndg_t, retry_cand,
+            took, ndg, ndg_t, retry_cand,
         )
 
     # prev for the probe check: re-broadcasts carry prev = the leader's
@@ -3511,7 +3514,7 @@ def _damped_linked_step(
         w5_noop = jnp.zeros((P, G), bool)
         sent_term5 = term
     (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run), (
-        ack5, ndg5, ndg5_t, retry5,
+        took5, ndg5, ndg5_t, retry5,
     ) = _sender_scan(
         _w5_body,
         (T, V, St, Ld, EE, HB, RT, C, LI, LT, agree_run),
@@ -3521,6 +3524,7 @@ def _damped_linked_step(
             sent_term5, TS, sender_ids,
         ),
     )
+    ack5 = took5 if pv else took5 & Erev
     # Wave-5 retry chains: survival gate, then the resends land as
     # wholesale adoption; their acks fold into the wave-6 stage together
     # with the wave-3 chains' (the undamped path collapses the same
@@ -3561,10 +3565,20 @@ def _damped_linked_step(
         & (sent_term5[:, None, :] >= T[None, :, :])
         & ((agree_run >= li2[:, None, :]) | Erev)
     )
-    C = jnp.maximum(
-        C,
-        jnp.max(jnp.where(elig6, C[:, None, :], 0), axis=0),
-    )
+    heard6 = jnp.where(elig6, C[:, None, :], 0)
+    if not pv:
+        # A re-broadcast carries its sender's commit of wave 5 whoever the
+        # sender is by now: one deposed by a nudge later in the very wave-3
+        # stream whose acks advanced its commit — a wave-2 winner among
+        # them — is no leader at wave 6, and its message was in flight
+        # before the nudge was stepped (ROADMAP C15,
+        # docs/CHECK_QUORUM_WITHOUT_PREVOTE.md).  A standing leader's
+        # settled commit above is at least this.  The pre-vote arm is as it
+        # was: those fleets' graphs are text-identical.
+        heard6 = jnp.maximum(
+            heard6, jnp.where(took5, C_send5[:, None, :], 0)
+        )
+    C = jnp.maximum(C, jnp.max(heard6, axis=0))
     RA = jnp.where(elig6 & Erev, True, RA)
     ndg6 = send6 & (sent_term5[:, None, :] < T[None, :, :]) & Erev
     dep6_t = jnp.max(jnp.where(ndg6, T[None, :, :], 0), axis=1)

@@ -42,7 +42,8 @@ class ScalarCluster:
                  heartbeat_tick: int = 1, voters=None, voters_outgoing=None,
                  learners=None, check_quorum: bool = False,
                  pre_vote: bool = False, metrics=None,
-                 timeout_seed_base: int = 0):
+                 timeout_seed_base: int = 0,
+                 max_inflight_msgs: int = 1 << 20):
         """`voters`/`voters_outgoing`/`learners` (peer-id lists) bootstrap
         every group in that (possibly joint) configuration; default: all
         peers voters.  `check_quorum`/`pre_vote` configure every Raft the
@@ -56,7 +57,12 @@ class ScalarCluster:
         `timeout_seed_base` offsets every group's timeout_seed (group g
         draws from stream timeout_seed_base + g): the forensics one-group
         repro (raft_tpu/multiraft/forensics.py) replays GLOBAL group id g
-        as a 1-group cluster on stream g, bit-identical to the fleet."""
+        as a 1-group cluster on stream g, bit-identical to the fleet.
+        `max_inflight_msgs` is every Progress's Inflights window, which the
+        port preallocates: the default is effectively unbounded and costs
+        8 MB a Progress (13 GB and minutes to build at G = 64, P = 5); a
+        whole-fleet replay passes a window its plan cannot fill (1 << 14,
+        the `_TWIN_CAP` argument below)."""
         self.n_groups = n_groups
         self.n_peers = n_peers
         self.networks: List[Network] = []
@@ -65,7 +71,7 @@ class ScalarCluster:
                 election_tick=election_tick,
                 heartbeat_tick=heartbeat_tick,
                 max_size_per_msg=NO_LIMIT,
-                max_inflight_msgs=1 << 20,  # effectively unbounded window
+                max_inflight_msgs=max_inflight_msgs,
                 timeout_seed=timeout_seed_base + g,
                 check_quorum=check_quorum,
                 pre_vote=pre_vote,

@@ -65,8 +65,15 @@ class HealthHarness:
 
         def step(n, append):
             a = jnp.full((G,), append, jnp.int32)
+            # A copy: on the CPU `jnp.asarray` may alias the numpy buffer,
+            # the rounds are dispatched without waiting, and the schedule's
+            # next `crash` / `recover` line writes `crashed` in place — on a
+            # loaded machine (tier-1 under xdist) rounds still queued then
+            # read the NEXT mask (ROADMAP C10: the failure that came and
+            # went with the machine's load).
+            mask = jnp.asarray(crashed.copy())
             for _ in range(n):
-                sim.run_round(jnp.asarray(crashed), a)
+                sim.run_round(mask, a)
 
         for line in td.input.splitlines():
             toks = line.split()

@@ -195,13 +195,15 @@ def test_higher_term_follower_nudges_mid_stream():
     served = rp.round(crashed, link, tag="heal round")
     # Both outcomes of the stream order are in the sample.
     assert 0 < served.sum() < G
-    # Eight rounds on, no more: from the twelfth round after such a heal
-    # the ROUND BODY's commit plane leaves the scalar replay's by one entry
-    # on two followers, reads or no reads (check-quorum without pre-vote
-    # under a per-group link plane; found here, ROADMAP C15) — the gate's
-    # inputs are compared while the replay still holds.
-    for r in range(8):
+    # Forty rounds on, through the returned member's next campaigns: a
+    # winner of wave 2 whose noop commits on the first acks and whose
+    # later responses depose it still gets its commit to the followers
+    # (the wave-5 re-broadcast was in flight; ROADMAP C15, closed by
+    # ISSUE 44 — before it the commit plane left the scalar replay's from
+    # the twelfth round after the heal).
+    for r in range(40):
         rp.round(crashed, link, tag=f"after heal {r}")
+        assert_state_parity(rp.oracle, rp.st, f"after heal {r}")
     rp.end("nudging follower")
 
 

@@ -17,6 +17,7 @@
 """
 
 import contextlib
+import functools
 import hashlib
 import json
 import os
@@ -36,11 +37,17 @@ G = 8
 ROUNDS = 48
 
 
-def damped_cfg(P, lease=True):
+def damped_cfg(P, lease=True, pre_vote=True):
     return SimConfig(
         G, P, election_tick=10, heartbeat_tick=2, check_quorum=True,
-        pre_vote=True, collect_health=True, lease_read=lease,
+        pre_vote=pre_vote, collect_health=True, lease_read=lease,
     )
+
+
+# Check-quorum WITHOUT pre-vote, reads through ReadIndex (`fleet-100k-r5-cq`,
+# ISSUE 44): the `not pv` arms of the damped round — the campaign that raises
+# the term at once, the real tally in wave 2, a re-broadcast's commit.
+CQ_ONLY = {"lease": False, "pre_vote": False}
 
 
 def client_of(P):
@@ -54,8 +61,8 @@ def chaos_of(P):
         {"rounds": 4}, {"rounds": 14, "crash": [1]}, {"rounds": 30, "crash": [2]}]}), G)
 
 
-def step_program(P, lease):
-    cfg = damped_cfg(P, lease)
+def step_program(P, lease, pre_vote=True):
+    cfg = damped_cfg(P, lease, pre_vote)
 
     def fn(st, crashed, app, link, rd):
         return sim.step(cfg, st, crashed, app, link=link, read_propose=rd,
@@ -66,10 +73,10 @@ def step_program(P, lease):
                 jnp.ones((P, P, G), bool), app)
 
 
-def scan_program(with_chaos, P=3):
-    """The client scan runner — the shape `.outage` and `.rebalance` run —
-    without and with a chaos plan."""
-    cfg = damped_cfg(P)
+def scan_program(with_chaos, P=3, **flags):
+    """The client scan runner — the shape `.outage`, `.netsplit` and
+    `.rebalance` run — without and with a chaos plan."""
+    cfg = damped_cfg(P, **flags)
     scheds = ((chaos_of(P),) if with_chaos else ()) + (client_of(P),)
     run = runner_mod.make_runner(cfg, scheds)
     st = sim.init_state(cfg)
@@ -96,8 +103,10 @@ def block_program(P=3):
 PROGRAMS = {
     **{f"step-P{P}-{'lease' if lease else 'readindex'}": (step_program, (P, lease))
        for P in (3, 5) for lease in (True, False)},
+    "step-P5-cq": (functools.partial(step_program, 5, **CQ_ONLY), ()),
     "client-scan": (scan_program, (False,)),
     "client-chaos-scan": (scan_program, (True,)),
+    "client-chaos-scan-cq": (functools.partial(scan_program, True, 5, **CQ_ONLY), ()),
 }
 
 
@@ -216,6 +225,7 @@ ROUND_PROGRAMS = {
     "block": (block_program, ()),
     "client-scan": (scan_program, (False,)),
     "client-chaos-scan": (scan_program, (True,)),
+    "client-chaos-scan-cq": PROGRAMS["client-chaos-scan-cq"],
 }
 
 
