@@ -2655,36 +2655,59 @@ def _sender_scan(body, carry, xs):
 
 # ---- the damped round's tallies.  A candidate's tally reads and writes
 # only its own row of the [P, G] planes and its own [P_voter, G] slab of the
-# response planes, so the candidate axis is a batch axis: each tally is ONE
-# walk over the P voters whose carry is [P_cand, G] planes — the same
-# integer and boolean work per (candidate, voter) pair, in the same voter
-# order, as one candidate at a time (tests/test_tally_batched.py holds both
-# to a plain per-candidate, per-voter reference).
+# response planes, so the candidate axis is a batch axis (PR 43); and what a
+# walk over the voters in receipt order carries from one response to the
+# next has a closed form along the voter axis, so neither tally holds a loop
+# (PR 45): one prefix sum, elementwise planes and reductions over axis 1 of
+# the [P_cand, P_voter, G] response planes as the waves hand them over —
+# candidate-major, never transposed, never sliced by a traced index.  Every
+# flag a later wave reads comes out of a REDUCE, where XLA's CPU backend
+# stops copying a producer into its consumers' fusions (docs/PERF.md holds
+# the argument and the compile times).  tests/test_tally_batched.py holds
+# both tallies to a plain per-candidate, per-voter, per-group reference.
 
 
-def _voter_scan(body, carry, xs):
-    """A damped tally's loop over the P voter-major slabs: a ROLLED
-    `lax.scan` of P trips — the one `while` a tally keeps.
-
-    Straight-line forms were traced on the chip and are faster there
-    (without a barrier between the trips the compiler fuses across them:
-    PERF.md §6, PR 43), but XLA's CPU backend pays for them at compile
-    time — a scanned cq + pv round compiles in 11 s rolled, as before the
-    tallies were batched, in 23 s straight-line behind `_sender_scan`'s
-    barrier, in 37 s without it, and in 14 minutes with one more compare
-    hoisted out of the loop — and tier-1 compiles dozens of such rounds.
-    Rolled, each trip cuts its `[P_cand, G]` slabs out of the stacks with a
-    dynamic_slice by the VOTER's index; nothing is indexed by a
-    candidate."""
-    return jax.lax.scan(body, carry, xs)
-
-
-def _voter_major(
-    plane: jnp.ndarray,  # gc: any
+def _ended_before(
+    won0: jnp.ndarray,  # gc: bool[P, G]
+    event: jnp.ndarray,  # gc: bool[P, P, G]
 ) -> jnp.ndarray:
-    """[P_cand, P_voter, G] -> [P_voter, P_cand, G]: trip v of a tally's
-    voter loop reads one whole slab."""
-    return jnp.swapaxes(plane, 0, 1)
+    """[c, v]: the poll of candidate c had ended BEFORE voter v's response
+    — it was won on the candidate's own vote (`won0`), or an earlier voter's
+    response was an `event`: v lies past the first one."""
+    n = event.shape[1]
+    voter = jnp.arange(n, dtype=jnp.int32)[None, :, None]
+    first = jnp.min(jnp.where(event, voter, n), axis=1)
+    return won0[:, None] | (voter > first[:, None])
+
+
+def _unpack(p: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:  # gc: any
+    """(cnt_i, cnt_o, rec_i, rec_o) out of `_poll_counts`' packed counts."""
+    return p & 0xFF, (p >> 8) & 0xFF, (p >> 16) & 0xFF, p >> 24
+
+
+def _poll_counts(
+    st: SimState,
+    own: jnp.ndarray,  # gc: bool[P, G]
+    grant: jnp.ndarray,  # gc: bool[P, P, G]
+    reject: jnp.ndarray,  # gc: bool[P, P, G]
+) -> Tuple[jnp.ndarray, ...]:
+    """A poll's four counts — grants in the incoming and in the outgoing
+    half, recorded responses in each — a byte apiece in one int32 (none
+    passes P), so that ONE prefix sum along the voter axis carries all four
+    -> (own, before, after, total): a candidate's own vote, counted in the
+    halves it sits in [P_cand, G]; what voter v's response finds and what
+    it leaves [P_cand, P_voter, G]; the poll's end [P_cand, G].  A `grant`
+    counts as a grant and as a response, a recorded `reject` as a
+    response."""
+    half = st.voter_mask.astype(jnp.int32) + (
+        st.outgoing_mask.astype(jnp.int32) << 8
+    )
+    w_g, w_r = half * 0x10001, half << 16
+    each = jnp.where(grant, w_g[None], jnp.where(reject, w_r[None], 0))
+    mine = jnp.where(own, w_g, 0)
+    after = mine[:, None] + jax.lax.cumsum(each, axis=1)
+    before = after - each
+    return mine, before, after, mine + jnp.sum(each, axis=1, dtype=jnp.int32)
 
 
 @profiling.scope("tally.real")
@@ -2703,39 +2726,21 @@ def _real_tally(
     candidate at once, responses in voter order -> (C', won, lost).  The
     response planes are [P_cand, P_voter, G]; `erev` says whose response
     reaches its candidate.  A reject carries the voter's commit (`t_snap`),
-    which fast-forwards an undecided candidate that agrees that far."""
+    which fast-forwards an undecided candidate that agrees that far.
 
-    def trip(carry, xs):
-        cnt_i, cnt_o, rec_i, rec_o, ff = carry  # [P_cand, G]
-        # Voter v's slab of the response planes, and its mask rows [G].
-        dg_v, dr_v, snap_v, agree_v, vm_v, om_v = xs
-        decided = _has_quorum(h, cnt_i, cnt_o) | _cannot_win(
-            h, cnt_i, cnt_o, rec_i, rec_o
-        )
-        ok = dr_v & ~decided & (snap_v <= agree_v)
-        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
-        resp_v = dg_v | dr_v
-        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
-        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
-        cnt_i = cnt_i + (dg_v & vm_v).astype(jnp.int32)
-        cnt_o = cnt_o + (dg_v & om_v).astype(jnp.int32)
-        return (cnt_i, cnt_o, rec_i, rec_o, ff), ()
-
+    poll() records every delivered response whatever the tally stands at,
+    so the counts voter v's response finds are plain prefix sums; only the
+    fast-forward asks whether they had decided the election by then."""
     del_g = t_grants & erev
     del_r = (t_resps & ~t_grants) & erev
-    # A candidate's own vote counts in the halves it sits in.
-    cnt_i = (cand_active & st.voter_mask).astype(jnp.int32)
-    cnt_o = (cand_active & st.outgoing_mask).astype(jnp.int32)
-    (cnt_i, cnt_o, rec_i, rec_o, ff), _ = _voter_scan(
-        trip,
-        (cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros_like(C)),
-        (
-            _voter_major(del_g), _voter_major(del_r), _voter_major(t_snap),
-            _voter_major(agree_pl), st.voter_mask, st.outgoing_mask,
-        ),
-    )
-    won = cand_active & _has_quorum(h, cnt_i, cnt_o)
-    lost = cand_active & ~won & _cannot_win(h, cnt_i, cnt_o, rec_i, rec_o)
+    _, before, _, total = _poll_counts(st, cand_active, del_g, del_r)
+    cnt = _unpack(before)
+    decided = _has_quorum(h, *cnt[:2]) | _cannot_win(h, *cnt)
+    ok = del_r & ~decided & (t_snap <= agree_pl)
+    ff = jnp.max(jnp.where(ok, t_snap, 0), axis=1)
+    total = _unpack(total)
+    won = cand_active & _has_quorum(h, *total[:2])
+    lost = cand_active & ~won & _cannot_win(h, *total)
     return jnp.maximum(C, ff), won, lost
 
 
@@ -2762,52 +2767,47 @@ def _pre_tally(
     pre-winner runs campaign(Election) — term+1, vote self, timers reset.
     Deposition after the win knocks the fresh candidate back down (its
     queued broadcast still delivers).  `draw(T)` is the round's timeout
-    draw on whole [P, G] planes."""
+    draw on whole [P, G] planes.
+
+    The first-event rule.  A pre-candidate polls only while it IS one, and
+    three responses end that, each for good: the grant that makes the
+    quorum (campaign(Election)), the same-term reject that puts it out of
+    reach (become_follower at its own term), a reject from a higher term
+    (become_follower there).  Up to and at the first of them the poll has
+    recorded every grant and every same-term reject, so its counts are the
+    FREE counts — prefix sums that never ask whether it still polls — and
+    its term is still `t_c0`; after it nothing records.  So the first voter
+    whose response wins or loses on the free counts, or rejects above
+    `t_c0`, IS the first event, and `ended` (one before this voter) is the
+    walk's `~undecided`, exactly.  Deposition alone goes on afterwards:
+    become_follower compares with the CURRENT term — `t_c0`, `t_c0 + 1`
+    from a win on (a win is a first event: it lies before voter v iff the
+    poll had ended by v and was won at all), then the highest deposing term
+    so far; a reject which that running maximum hides is at or below it, so
+    counting it changes neither the flag nor the final term."""
     C, T, V, St, EE, HB, RT = planes
-
-    def trip(carry, xs):
-        (cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f,
-         cur_t) = carry  # [P_cand, G]
-        dg_v, dr_v, rt_v, snap_v, agree_v, vm_v, om_v = xs
-        dep_now = dr_v & (rt_v > cur_t)
-        undecided = ~dep_f & ~won_f & ~lost_f
-        rec_grant = dg_v & undecided
-        rec_rej = dr_v & (rt_v == t_c0) & undecided
-        ok = rec_rej & (snap_v <= agree_v)
-        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
-        cnt_i = cnt_i + (rec_grant & vm_v).astype(jnp.int32)
-        cnt_o = cnt_o + (rec_grant & om_v).astype(jnp.int32)
-        resp_v = rec_grant | rec_rej
-        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
-        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
-        won_now = rec_grant & _has_quorum(h, cnt_i, cnt_o)
-        lost_now = rec_rej & _cannot_win(h, cnt_i, cnt_o, rec_i, rec_o)
-        cur_t = jnp.where(won_now, t_c0 + 1, cur_t)
-        won_f = won_f | won_now
-        lost_f = lost_f | lost_now
-        dep_f = dep_f | dep_now
-        cur_t = jnp.where(dep_now, jnp.maximum(cur_t, rt_v), cur_t)
-        return (
-            cnt_i, cnt_o, rec_i, rec_o, ff, won_f, lost_f, dep_f, cur_t,
-        ), ()
-
+    t0 = t_c0[:, None]
     del_g = p_grants & erev
     del_r = (p_resps & ~p_grants) & erev
-    cnt_i = (act & st.voter_mask).astype(jnp.int32)
-    cnt_o = (act & st.outgoing_mask).astype(jnp.int32)
-    won0 = act & _has_quorum(h, cnt_i, cnt_o)
-    none = jnp.zeros_like(act)
-    (_, _, _, _, ff, won_f, lost_f, dep_f, cur_t), _ = _voter_scan(
-        trip,
-        (
-            cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros_like(C), won0, none,
-            none, jnp.where(won0, t_c0 + 1, t_c0),
-        ),
-        (
-            _voter_major(del_g), _voter_major(del_r),
-            _voter_major(p_resp_t), _voter_major(p_snap),
-            _voter_major(st.agree), st.voter_mask, st.outgoing_mask,
-        ),
+    same_t = del_r & (p_resp_t == t0)
+    mine, _, after, _ = _poll_counts(st, act, del_g, same_t)
+    won0 = act & _has_quorum(h, *_unpack(mine)[:2])
+    cnt = _unpack(after)
+    won_free = del_g & _has_quorum(h, *cnt[:2])
+    lost_free = same_t & _cannot_win(h, *cnt)
+    ended = _ended_before(
+        won0, won_free | lost_free | (del_r & (p_resp_t > t0))
+    )
+    won_f = won0 | jnp.any(won_free & ~ended, axis=1)
+    lost_f = jnp.any(lost_free & ~ended, axis=1)
+    ok = same_t & ~ended & (p_snap <= st.agree)
+    ff = jnp.max(jnp.where(ok, p_snap, 0), axis=1)
+    won_before = (ended & won_f[:, None]).astype(jnp.int32)
+    dep = del_r & (p_resp_t > t0 + won_before)
+    dep_f = jnp.any(dep, axis=1)
+    cur_t = jnp.maximum(
+        t_c0 + won_f.astype(jnp.int32),
+        jnp.max(jnp.where(dep, p_resp_t, 0), axis=1),
     )
     won_f = won_f & act
     lost_f = lost_f & act

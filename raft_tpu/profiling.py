@@ -109,13 +109,13 @@ SCOPES: Dict[str, str] = {
     "damped.tally": "the (pre-)vote tallies and post-election bookkeeping",
     "tally.real": (
         "_real_tally inside damped.tally: the real election's tally of "
-        "every candidate at once, one walk over the P voters on "
-        "[P_cand, G] planes (wave 2 without pre-vote, wave 4 with it)"
+        "every candidate at once, prefix counts and reductions along the "
+        "voter axis, no loop (wave 2 without pre-vote, wave 4 with it)"
     ),
     "tally.pre": (
         "_pre_tally inside damped.tally: the pre-vote tally of every "
-        "pre-candidate at once, one walk over the P voters on [P_cand, G] "
-        "planes, and the candidates' end-of-wave state on whole planes"
+        "pre-candidate at once, prefix counts and a first-event mask along "
+        "the voter axis, no loop, and the end-of-wave state on whole planes"
     ),
     "damped.wave3": "appends: winner noops, catch-ups, their retry chains",
     "damped.stage_fold": (

@@ -1,4 +1,4 @@
-"""How the damped round's loops lower (ISSUE 41, ISSUE 43; ROADMAP A5).
+"""How the damped round's loops lower (ISSUE 41, 43, 45; ROADMAP A5).
 
 `sim._damped_linked_step` walks the P sender rows five times a round
 (wave 1, wave 3, wave 5 and the two retry passes).  Each walk is ONE `scan`
@@ -14,15 +14,20 @@ with it 26% faster — so the barrier is held here too.
 
 The tallies (`sim._real_tally`, `sim._pre_tally`) were P x P rolled trips
 on `[G]` rows — an outer loop over the candidates around an inner one over
-the voters — until PR 43: a candidate's tally touches only its own row, so
-the candidate axis is a batch axis, and each tally is ONE walk of P trips
-over the voters on `[P_cand, G]` planes (`sim._voter_scan`): one rolled
-`scan`, the one `while` a tally keeps (1 without pre-vote, 2 with it,
-where there were 2 and 4).  Its trips slice the voter-major stacks by the
-VOTER's index; no loop over candidates is left, and with it no `take`,
-`gather` or row rewrite indexed by a candidate id under `damped.tally`.
-Straight-line forms of that loop are faster on the chip and cost XLA's CPU
-backend minutes of compile in tier-1 (PERF.md §6, PR 43), so it is rolled.
+the voters — until PR 43 made the candidate axis a batch axis, and ONE
+rolled walk over the voters on `[P_cand, G]` planes until PR 45: what that
+walk carried from one response to the next has a closed form along the
+voter axis (prefix counts, and for the pre-vote tally a first-event mask;
+the docstrings at `sim._pre_tally` and docs/PERF.md argue it), so a tally
+holds no loop at all.  Under `damped.tally` there is NO `scan` equation,
+and in the lowered text of one damped `sim.step` NO `stablehlo.while` (2
+with pre-vote and 1 without before PR 45, 4 and 2 before PR 43, 9 at
+cq + pv before PR 41).  The response planes stay candidate-major as the
+waves hand them over: under `tally.*` no `transpose`, no slice by a traced
+index, and still no `take`, `gather` or row rewrite indexed by a candidate.
+(PR 43 measured unrolled forms of the old loop faster on the chip and could
+not commit them: XLA's CPU backend took minutes to compile them in tier-1.
+The closed form compiles there in about the rolled loop's time.)
 
 Bit-equality of the rounds is the parity suites' subject
 (`tests/test_damping_parity.py`, `tests/test_readindex_damped.py`, ...) and
@@ -104,10 +109,10 @@ def updates_of_planes(text, P):
             if "dynamic_update_slice" in ln and plane in ln]
 
 
-# What a loop over candidates would index its rows with (a rolled loop
-# over VOTERS slices its stacked inputs by the trip count at lowering, not
-# in the jaxpr).
+# What a loop over candidates would index its rows with, and what a walk
+# over voter-major slabs would be fed by.
 BY_CANDIDATE = {"dynamic_slice", "dynamic_update_slice", "gather", "scatter"}
+VOTER_MAJOR = {"transpose", "scan", "while"}
 
 
 @pytest.mark.parametrize("cq, pv, lease", DAMPED)
@@ -121,24 +126,24 @@ def test_sender_loops_lower_straight_line(P, cq, pv, lease):
     # The sender loops: straight-line, every trip behind its barrier.
     senders = [s for s in found if "damped.tally" not in s[4]]
     assert [s[2:4] for s in senders] == [(P, "optimization_barrier")] * SENDER_LOOPS, found
-    # The tallies: ONE rolled voter loop each (pre-vote and real with
-    # pre-vote, the real one alone without), nothing in them indexed by a
-    # candidate.
-    tallies = [s for s in found if "damped.tally" in s[4]]
-    want = ["tally.pre", "tally.real"] if pv else ["tally.real"]
-    assert [next(n for n in s[4] if n.startswith("tally.")) for s in tallies] == want
-    assert all(s[2] == 1 for s in tallies), found
+    # The tallies: no loop, nothing in them indexed by a candidate or by a
+    # traced voter index, the response planes never turned voter-major.
+    assert [s for s in found if "damped.tally" in s[4]] == []
     assert not primitives_under(jaxpr, "damped.tally") & BY_CANDIDATE
-    # The only `while`s left are the tallies' voter loops (9 at cq + pv
-    # before PR 41, 4 before PR 43), and no stacked [P, P, G] output is
+    for tally in ["tally.pre", "tally.real"] if pv else ["tally.real"]:
+        under = primitives_under(jaxpr, tally)
+        assert under, tally
+        assert not under & (BY_CANDIDATE | VOTER_MAJOR), (tally, under)
+    # No `while` is left in a damped round (9 at cq + pv before PR 41, 4
+    # before PR 43, 2 before PR 45), and no stacked [P, P, G] output is
     # rewritten a trip.
-    assert len(re.findall(r"stablehlo\.while", text)) == len(want)
+    assert len(re.findall(r"stablehlo\.while", text)) == 0
     assert updates_of_planes(text, P) == []
 
 
 @pytest.mark.parametrize("P", [3, 5])
 def test_the_stock_fleets_loops_stay_rolled(P):
-    """`_linked_step` is not PR 41's nor PR 43's: six sender loops, the
+    """`_linked_step` is none of PR 41's, 43's or 45's: six sender loops, the
     tally's over the candidates among them, and the tally's inner one over
     the voters, each a rolled `scan` (the control cell's program is the
     parent's)."""
