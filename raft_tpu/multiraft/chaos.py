@@ -525,3 +525,61 @@ def update_leader_stats(
         delta, (CS_LEADER_CHANGES, N_CHAOS_STATS - CS_TERM_BUMPS - 1)
     )
     return stats, jnp.where(lead > 0, lead, last_leader)
+
+
+@profiling.scope("runner.learner_lag")
+def fold_learner_lag(
+    behind: jnp.ndarray,  # gc: int32[]
+    state: jnp.ndarray,  # gc: int32[P, G]
+    term: jnp.ndarray,  # gc: int32[P, G]
+    commit: jnp.ndarray,  # gc: int32[P, G]
+    learner_mask: jnp.ndarray,  # gc: bool[P, G]
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+) -> jnp.ndarray:
+    """`behind` plus the groups in which, at this round's end, some member
+    that is a learner holds a commit index below its acting leader's — the
+    alive leader of the highest term, as kernels.acting_leader_id and
+    ScalarCluster.acting_leader have it (election safety, audited every
+    round, leaves no tie).  A group with no alive leader counts nothing; a
+    crashed learner counts like any other.  A healthy round ends with every
+    member at its leader's commit (the commit-advance re-broadcast is in the
+    round), so this is the lag a learner's absence leaves WHILE ITS GROUP
+    COMMITS, and the catch-up after it: a group that is offered no entry
+    while its learner is away counts nothing.
+
+    The planes are read behind an optimization barrier, as finished arrays
+    in kernels of the count's own: fused into the round's producers the same
+    arithmetic regrouped the tally's and the apply's kernels and cost 2.9%
+    of the rate on the chip (PERF.md section 6, PR 47).  Two kernels on the
+    TPU: ONE reduce over the peer axis that carries three values (a
+    reduction a kernel cost 0.67%), and the sum.  Folded by
+    reconfig._runner_body only where the carry asks for it
+    (workload.LearnerLagCarry: a fleet that BOOTS with learners); every
+    other fleet's round is the one it was."""
+    state, term, commit, learner_mask, crashed = jax.lax.optimization_barrier(
+        (state, term, commit, learner_mask, crashed)
+    )
+    is_lead = (state == kernels.ROLE_LEADER) & ~crashed
+
+    def fold(a, b):
+        # (term, commit) of the leader of the higher term; the lowest commit
+        # a learner holds.
+        (ta, ca, la), (tb, cb, lb) = a, b
+        first = (ta > tb) | ((ta == tb) & (ca >= cb))
+        return (
+            jnp.where(first, ta, tb), jnp.where(first, ca, cb),
+            jnp.minimum(la, lb),
+        )
+
+    # One pass over the peer axis.  No alive leader: commit -1, below which
+    # no index is; no learner: INT32_MAX, which is below none.
+    top = jnp.iinfo(jnp.int32).max
+    _, lead_commit, learner_commit = jax.lax.reduce(
+        (
+            jnp.where(is_lead, term, -1), jnp.where(is_lead, commit, -1),
+            jnp.where(learner_mask, commit, top),
+        ),
+        (jnp.int32(-1), jnp.int32(-1), jnp.int32(top)), fold, (0,),
+    )
+    # dtype= on the sum: a bare bool sum widens to int64 under x64 (GC007).
+    return behind + jnp.sum(learner_commit < lead_commit, dtype=jnp.int32)

@@ -898,7 +898,11 @@ def _runner_body(
     into the on-device histogram, and runs kernels.check_safety's
     linearizability slots (lease-holder mask off the round-ENTRY state)
     alongside the joint-window audit.  None keeps every historical graph
-    byte-identical.
+    byte-identical.  Where the read carry arrives as a
+    workload.LearnerLagCarry (ISSUE 47: ClusterSim.run_reads on a fleet
+    that boots with learners) each round also folds
+    chaos.fold_learner_lag into its count; a plain ReadCarry keeps the
+    graph it had.
 
     Black-box forensics (ISSUE 15, SimConfig.blackbox): the carry gains
     a TRAILING sim.BlackboxState; each round folds
@@ -915,9 +919,11 @@ def _runner_body(
         bb = None
         if with_bb:
             carry, bb = carry[:-1], carry[-1]
-        rcar = rdstats = lat_hist = None
+        rcar = rdstats = lat_hist = lag = None
         if client is not None:
             carry, (rcar, rdstats, lat_hist) = carry[:-3], carry[-3:]
+            if isinstance(rcar, workload_mod.LearnerLagCarry):
+                rcar, lag = rcar
         if with_counters:
             st, hl, rst, stats, rstats, safety, ctrs = carry
         else:
@@ -1172,6 +1178,14 @@ def _runner_body(
                     pending_since=jnp.where(served, 0, psince),
                     last_leader=last_leader,
                 )
+            if lag is not None:
+                # A fleet that boots with learners: the round's end, off the
+                # planes the round hands on.
+                lag = chaos_mod.fold_learner_lag(
+                    lag, st3.state, st3.term, st3.commit, st3.learner_mask,
+                    crashed,
+                )
+                rcar = workload_mod.LearnerLagCarry(rcar, lag)
             out = out + (rcar, rdstats, lat_hist)
         if with_bb:
             # The ring records the round-EXIT (post-apply) state; the

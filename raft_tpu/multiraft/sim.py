@@ -3946,6 +3946,11 @@ class ClusterSim:
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.cfg = cfg
+        # A fleet that BOOTS with learners (TiFlash replicas: learners for
+        # good) has run_reads count their lag; known here, once, on the host.
+        self._boots_learners = learner_mask is not None and bool(
+            jnp.any(learner_mask)
+        )
         if mesh is None:
             self.state = init_state(
                 cfg, voter_mask, outgoing_mask, learner_mask
@@ -4871,6 +4876,13 @@ class ClusterSim:
                         else self._read_carry.last_leader,
                     ),
                 )
+                # The learners' lag rides the scan beside the read carry
+                # (the split runner's fused arm cannot count it).
+                count_lag = self._boots_learners and not split
+                if count_lag:
+                    rcar = workload_mod.LearnerLagCarry(
+                        rcar, self._put_replicated(jnp.int32(0))
+                    )
                 args = [self.state, health, rst, rcar]
                 if self._blackbox is not None:
                     args.append(self._blackbox)
@@ -4880,6 +4892,10 @@ class ClusterSim:
                 self.state, self._health, self._reconfig_state, stats,
                 rstats, safety, self._read_carry, rdstats, lat_hist,
             ) = out[:9]
+            lag = ()
+            if count_lag:
+                self._read_carry, behind = self._read_carry
+                lag = (behind,)
             self._reconfig_state_of = runner
             i = 9
             if self._blackbox is not None:
@@ -4904,7 +4920,7 @@ class ClusterSim:
                     # scan.
                     got = jax.device_get(
                         (rdstats, lat_p, safety, stats, recover_p, rstats,
-                         unfinished, *fused)
+                         unfinished, *fused, *lag)
                     )
                 (
                     rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h,
@@ -4913,6 +4929,7 @@ class ClusterSim:
                 report = workload_mod.read_report(
                     rdstats_h, lat_p_h, safety_h, stats_h, compiled.n_rounds,
                     recover_p_h, rstats_h, unfinished_h,
+                    learner_behind=got[-1] if count_lag else None,
                 )
                 if split or fused_zero:
                     total = compiled.n_rounds * self.cfg.n_groups

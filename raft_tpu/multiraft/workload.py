@@ -316,6 +316,20 @@ class ReadCarry(NamedTuple):
     last_leader: jnp.ndarray  # gc: int32[G]
 
 
+class LearnerLagCarry(NamedTuple):
+    """What ClusterSim.run_reads hands the workload scan in the read
+    carry's place for a fleet that BOOTS with learners: the ReadCarry and
+    the call's count of (group, round) pairs that ended with a learner
+    behind its leader's commit (chaos.fold_learner_lag, the report's
+    `learner_behind_group_rounds`).  reconfig._runner_body folds the count
+    where it finds this type and nowhere else, so a fleet that boots
+    without learners runs the round it always ran; the runners pass the
+    carry through as they pass a ReadCarry."""
+
+    reads: ReadCarry
+    behind: jnp.ndarray  # gc: int32[]
+
+
 def init_read_carry(n_groups: int, last_leader=None) -> ReadCarry:
     """Fresh no-reads-outstanding carry over `last_leader` (default: no
     leader seen yet) — ClusterSim.run_reads hands the plane from call to
@@ -535,7 +549,7 @@ def block_tables(client: CompiledClient, k: int) -> BlockRows:
 
 def read_report(
     rdstats, lat_p, safety, stats, rounds: int, recover_p=(-1, -1, -1),
-    rstats=(0, 0, 0, 0), conf_unfinished: int = 0,
+    rstats=(0, 0, 0, 0), conf_unfinished: int = 0, learner_behind=None,
 ) -> dict:
     """The per-scenario read-workload summary off the device accumulators
     (host-side formatter; ClusterSim.run_reads emits it).  `lat_p` is
@@ -545,7 +559,10 @@ def read_report(
     is the op protocol's [reconfig.N_RECONFIG_STATS] vector (conf entries
     proposed / ops applied / entries given up with their owner /
     group-rounds in a joint configuration) and `conf_unfinished` the groups
-    with ops of their chain left at the end; all zero with no reconfig plan."""
+    with ops of their chain left at the end; all zero with no reconfig plan.
+    `learner_behind` is the LearnerLagCarry's count, and
+    `learner_behind_group_rounds` is in the report only where the run
+    carried one (a fleet that boots with learners, through the scan)."""
     from .chaos import (
         CS_APPENDS_DROPPED,
         CS_APPENDS_OFFERED,
@@ -579,6 +596,10 @@ def read_report(
         "appends_dropped": int(stats[CS_APPENDS_DROPPED]),
         "leader_changes": int(stats[CS_LEADER_CHANGES]),
         "term_bumps": int(stats[CS_TERM_BUMPS]),
+        **(
+            {} if learner_behind is None
+            else {"learner_behind_group_rounds": int(learner_behind)}
+        ),
         "recover_hist": [int(v) for v in recover_hist(stats)],
         "recover_p50_rounds": int(recover_p[0]),
         "recover_p90_rounds": int(recover_p[1]),

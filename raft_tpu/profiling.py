@@ -183,6 +183,12 @@ SCOPES: Dict[str, str] = {
         "_runner_body's read-stats fold: the round's counts into the "
         "report's accumulators"
     ),
+    "runner.learner_lag": (
+        "chaos.fold_learner_lag: the round's groups with a learner behind "
+        "its acting leader's commit, in every _runner_body round of a "
+        "run_reads scan on a fleet that boots with learners "
+        "(workload.LearnerLagCarry); no other fleet's round has it"
+    ),
     "runner.block_guard": (
         "everything a split block computes before its lax.cond: its "
         "tabled schedule rows unpacked (workload.BlockRows), lease_read, "

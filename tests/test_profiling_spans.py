@@ -304,12 +304,20 @@ def lowered_text():
         st, sim.init_health(cfg), rst, workload.init_read_carry(G),
         *xscan.schedule_args,
     ).as_text(debug_info=True)
+    # ... as ClusterSim.run_reads calls it for a fleet that boots with
+    # learners: their lag is counted beside the read carry.
+    texts["learner_scan"] = xscan.jitted.lower(
+        st, sim.init_health(cfg), rst,
+        workload.LearnerLagCarry(workload.init_read_carry(G), jnp.int32(0)),
+        *xscan.schedule_args,
+    ).as_text(debug_info=True)
     return texts
 
 
 WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency",
          "damped.read_holders": "readindex",
          "runner.chaos_masks": "client_chaos_scan",
+         "runner.learner_lag": "learner_scan",
          **{s: "linked" for s in profiling.SCOPES if s.startswith("linked.")}}
 
 

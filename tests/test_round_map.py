@@ -21,6 +21,7 @@ import functools
 import hashlib
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -73,15 +74,24 @@ def step_program(P, lease, pre_vote=True):
                 jnp.ones((P, P, G), bool), app)
 
 
-def scan_program(with_chaos, P=3, **flags):
+def scan_program(with_chaos, P=3, learners=False, **flags):
     """The client scan runner — the shape `.outage`, `.netsplit` and
-    `.rebalance` run — without and with a chaos plan."""
+    `.rebalance` run — without and with a chaos plan; with `learners`, as
+    `ClusterSim.run_reads` calls it for a fleet that boots voters {1, 2, 3}
+    and learners {4, 5} (`fleet-100k-r3l2`, ISSUE 47): the learners' lag
+    rides beside the read carry."""
     cfg = damped_cfg(P, **flags)
     scheds = ((chaos_of(P),) if with_chaos else ()) + (client_of(P),)
     run = runner_mod.make_runner(cfg, scheds)
-    st = sim.init_state(cfg)
+    rcar = workload.init_read_carry(G)
+    if learners:
+        rows = jnp.broadcast_to(jnp.arange(P)[:, None], (P, G))
+        st = sim.init_state(cfg, rows < 3, None, rows >= 3)
+        rcar = workload.LearnerLagCarry(rcar, jnp.int32(0))
+    else:
+        st = sim.init_state(cfg)
     return run.jitted, (st, sim.init_health(cfg), reconfig.init_reconfig_state(st),
-                        workload.init_read_carry(G), *run.schedule_args)
+                        rcar, *run.schedule_args)
 
 
 def block_program(P=3):
@@ -107,6 +117,7 @@ PROGRAMS = {
     "client-scan": (scan_program, (False,)),
     "client-chaos-scan": (scan_program, (True,)),
     "client-chaos-scan-cq": (functools.partial(scan_program, True, 5, **CQ_ONLY), ()),
+    "client-chaos-scan-learners": (functools.partial(scan_program, True, 5, learners=True), ()),
 }
 
 
@@ -114,7 +125,10 @@ def jaxpr_digests():
     out = {}
     for name, (build, args) in PROGRAMS.items():
         fn, operands = build(*args)
-        out[name] = hashlib.sha1(str(jax.make_jaxpr(fn)(*operands)).encode()).hexdigest()
+        # A `reduce` with a combiner of its own prints the Python function's
+        # address beside its jaxpr (chaos.fold_learner_lag): not an equation.
+        text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(*operands)))
+        out[name] = hashlib.sha1(text.encode()).hexdigest()
     return out
 
 
@@ -226,6 +240,7 @@ ROUND_PROGRAMS = {
     "client-scan": (scan_program, (False,)),
     "client-chaos-scan": (scan_program, (True,)),
     "client-chaos-scan-cq": PROGRAMS["client-chaos-scan-cq"],
+    "client-chaos-scan-learners": PROGRAMS["client-chaos-scan-learners"],
 }
 
 
@@ -240,3 +255,17 @@ def test_every_equation_of_a_round_carries_a_catalogue_scope(program):
                          for prim, stack, inside in bare if not set(inside) <= ONCE})
     assert not in_a_round, in_a_round
     assert len(bare) <= 20, "the once-per-call set-up stays a handful of equations"
+
+
+def test_the_learners_lag_is_in_the_learner_fleets_round_alone():
+    """`runner.learner_lag` names equations of the round only where the carry
+    asks for the count (`workload.LearnerLagCarry`); the same fleet's round
+    under a plain read carry — what every fleet that boots without learners
+    runs — holds none, and is the program it was."""
+    def scopes(learners):
+        fn, operands = scan_program(True, 5, learners=learners)
+        return {c for _prim, stack, _inside in leaves(jax.make_jaxpr(fn)(*operands).jaxpr)
+                for c in stack}
+
+    assert "runner.learner_lag" in scopes(True)
+    assert "runner.learner_lag" not in scopes(False)
