@@ -440,14 +440,18 @@ class Autopilot:
 
     def _runner_for(self, compiled, chaos_compiled, rounds: int):
         # Schedule arrays enter the jit as runtime arguments (GC012), so
-        # one compiled runner serves every plan with the same SHAPES —
-        # the key is shape-only on purpose (an evacuation swap recompiles
-        # once, later swaps with the same op count reuse it).
+        # one compiled runner serves every plan with the same SHAPES and
+        # the same trace-time static (CompiledChaos.lossless selects the
+        # round's program: a lossless plan's runner draws no loss sample
+        # and must never serve a plan with a loss rate) — the key holds
+        # nothing else on purpose (an evacuation swap recompiles once,
+        # later swaps with the same op count reuse it).
         key = (
             rounds,
             tuple(compiled.op_start.shape),
             tuple(compiled.append.shape),
             compiled.phase_of_round.shape[0],
+            chaos_compiled.lossless,
         )
         r = self._runners.get(key)
         if r is None:

@@ -194,6 +194,16 @@ class CompiledChaos(NamedTuple):
     crashed_packed: uint32[NPH, 1, G]       per-phase crash masks, bit p
     append:         int32[NPH, G]           per-phase append workload
     n_peers:        static python int, the unpack shape
+    lossless:       static python bool, true where no phase of the plan
+                    has a loss rate (compile_plan reads it off the numpy
+                    loss array before anything is lowered).  It selects
+                    schedule_masks' program at trace time: a lossless
+                    plan neither unpacks loss_packed nor draws the loss
+                    sample (the draw would compare a non-negative sample
+                    with 0 and knock out nothing), a plan with any rate
+                    keeps the draw.  The masks are bit-identical either
+                    way; like n_peers it rides the runner's closure
+                    template and is never a jit argument.
     """
 
     phase_of_round: jnp.ndarray  # gc: int32[R]
@@ -202,6 +212,7 @@ class CompiledChaos(NamedTuple):
     crashed_packed: jnp.ndarray  # gc: uint32[NPH, 1, G]
     append: jnp.ndarray  # gc: int32[NPH, G]
     n_peers: int
+    lossless: bool
 
     @property
     def n_rounds(self) -> int:
@@ -287,6 +298,33 @@ def compile_plan(plan: ChaosPlan, n_groups: int) -> CompiledChaos:
         ).swapaxes(0, 1),
         append=jnp.asarray(append, dtype=jnp.int32),
         n_peers=P,
+        lossless=not loss.any(),
+    )
+
+
+def _base_planes(
+    compiled: CompiledChaos,
+    ph: jnp.ndarray,  # gc: int32[]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Phase `ph`'s (base_link bool[P, P, G], crashed bool[P, G]),
+    gathered and unpacked."""
+    P = compiled.n_peers
+    G = compiled.append.shape[1]
+    link = kernels.unpack_bits(compiled.link_packed[ph], P * P).reshape(
+        P, P, G
+    )
+    return link, kernels.unpack_bits(compiled.crashed_packed[ph], P)
+
+
+def _loss_plane(
+    compiled: CompiledChaos,
+    ph: jnp.ndarray,  # gc: int32[]
+) -> jnp.ndarray:
+    """Phase `ph`'s loss rates int32[P, P, G], gathered and unpacked."""
+    P = compiled.n_peers
+    G = compiled.append.shape[1]
+    return kernels.unpack_u16_pairs(compiled.loss_packed[ph], P * P).reshape(
+        P, P, G
     )
 
 
@@ -300,17 +338,9 @@ def schedule_planes(
     fused dispatch (the reconfig split runner) needs the base plane for
     its steady predicate and the raw rates for the in-kernel draw — both
     constant across a phase, so one gather covers a whole fused block."""
-    P = compiled.n_peers
-    G = compiled.append.shape[1]
     ph = compiled.phase_of_round[round_idx]
-    link = kernels.unpack_bits(compiled.link_packed[ph], P * P).reshape(
-        P, P, G
-    )
-    loss = kernels.unpack_u16_pairs(compiled.loss_packed[ph], P * P).reshape(
-        P, P, G
-    )
-    crashed = kernels.unpack_bits(compiled.crashed_packed[ph], P)
-    return link, loss, crashed, compiled.append[ph]
+    link, crashed = _base_planes(compiled, ph)
+    return link, _loss_plane(compiled, ph), crashed, compiled.append[ph]
 
 
 @profiling.scope("runner.chaos_masks")
@@ -319,11 +349,20 @@ def schedule_masks(
     round_idx: jnp.ndarray,  # gc: int32[]
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Device-side (link, crashed, append) for one round of the schedule:
-    gather the round's (packed) phase row, unpack it on device, and knock
-    out the seeded loss sample."""
-    link, loss, crashed, append = schedule_planes(compiled, round_idx)
-    drop = kernels.link_loss_draw(round_idx, loss)
-    return link & ~drop, crashed, append
+    gather the round's (packed) phase row, unpack it on device, and —
+    only where the plan has a loss rate (CompiledChaos.lossless) — knock
+    out the seeded loss sample.
+
+    link and crashed leave through one optimization_barrier: the round
+    reads FINISHED planes.  Unbarriered, the unpack (one cheap elementwise
+    expression) is copied into every consumer's fusion of the round."""
+    ph = compiled.phase_of_round[round_idx]
+    link, crashed = _base_planes(compiled, ph)
+    if not compiled.lossless:
+        drop = kernels.link_loss_draw(round_idx, _loss_plane(compiled, ph))
+        link = link & ~drop
+    link, crashed = jax.lax.optimization_barrier((link, crashed))
+    return link, crashed, compiled.append[ph]
 
 
 # --- host twins (the ChaosOracle side; must stay bit-identical) -----------

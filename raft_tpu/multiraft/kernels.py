@@ -469,12 +469,29 @@ def pack_bits(planes: jnp.ndarray) -> jnp.ndarray:  # gc: bool[K, ...]
     return jnp.stack(words)
 
 
+def _unpack_fields(
+    words: jnp.ndarray,  # gc: uint32[W, ...]
+    k: int,
+    width: int,
+) -> jnp.ndarray:
+    """The first k `width`-bit fields of a uint32 word stack, field j of
+    word w at row w * (32 // width) + j: uint32[W, ...] -> uint32[k, ...].
+
+    ONE broadcast shift over [W, 32 // width, ...] and a static slice — no
+    row is built on its own and nothing is stacked, so the whole unpack is
+    one elementwise kernel for any k (ISSUE 48: k stacked rows lowered to
+    a `concatenate` that cost five times its bytes at k = 25)."""
+    per = 32 // width
+    shifts = jnp.arange(0, 32, width, dtype=jnp.uint32).reshape(
+        (1, per) + (1,) * (words.ndim - 1)
+    )
+    fields = (words[:, None] >> shifts) & jnp.uint32((1 << width) - 1)
+    return fields.reshape((words.shape[0] * per,) + words.shape[1:])[:k]
+
+
 def unpack_bits(words: jnp.ndarray, k: int) -> jnp.ndarray:  # gc: uint32[W, ...]
     """Inverse of pack_bits: uint32[ceil(k/32), ...] -> bool[k, ...]."""
-    planes = [
-        ((words[j // 32] >> (j % 32)) & jnp.uint32(1)) != 0 for j in range(k)
-    ]
-    return jnp.stack(planes)
+    return _unpack_fields(words, k, 1) != 0
 
 
 def pack_u16_pairs(vals: jnp.ndarray) -> jnp.ndarray:  # gc: int32[K, ...]
@@ -495,11 +512,7 @@ def pack_u16_pairs(vals: jnp.ndarray) -> jnp.ndarray:  # gc: int32[K, ...]
 
 def unpack_u16_pairs(words: jnp.ndarray, k: int) -> jnp.ndarray:  # gc: uint32[W, ...]
     """Inverse of pack_u16_pairs: uint32[ceil(k/2), ...] -> int32[k, ...]."""
-    planes = []
-    for j in range(k):
-        half = words[j // 2] >> (16 * (j % 2))
-        planes.append((half & jnp.uint32(0xFFFF)).astype(jnp.int32))
-    return jnp.stack(planes)
+    return _unpack_fields(words, k, 16).astype(jnp.int32)
 
 
 def pack_bits_g(plane: jnp.ndarray) -> jnp.ndarray:  # gc: bool[..., G]

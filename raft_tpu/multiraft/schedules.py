@@ -142,7 +142,8 @@ class RunnerVariant(NamedTuple):
 
 # --- the registry -----------------------------------------------------------
 # Row order within a family is the compiled NamedTuple's field order
-# (minus the trailing static n_peers) — GC018 checks both directions.
+# (minus the trailing statics: n_peers, and chaos.CompiledChaos.lossless)
+# — GC018 checks both directions.
 
 SCHEDULES: Tuple[ScheduleSpec, ...] = (
     # ---- chaos: link/loss/crash/append phases (chaos.CompiledChaos).

@@ -203,7 +203,7 @@ def chaos_sharding(mesh: Mesh, axis: str = "groups"):
     xxg = NamedSharding(mesh, P(None, None, axis))
     return CompiledChaos(
         phase_of_round=rep, link_packed=xxg, loss_packed=xxg,
-        crashed_packed=xxg, append=xg, n_peers=None,
+        crashed_packed=xxg, append=xg, n_peers=None, lossless=None,
     )
 
 
@@ -213,11 +213,9 @@ def shard_chaos(compiled, mesh: Mesh, axis: str = "groups"):
     sched_sh = chaos_sharding(mesh, axis)
     return compiled._replace(
         **{
-            name: jax.device_put(
-                getattr(compiled, name), getattr(sched_sh, name)
-            )
-            for name in compiled._fields
-            if name != "n_peers"
+            name: jax.device_put(getattr(compiled, name), sh)
+            for name, sh in sched_sh._asdict().items()
+            if sh is not None  # the trailing statics ride as they are
         }
     )
 

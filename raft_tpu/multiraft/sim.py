@@ -4844,18 +4844,22 @@ class ClusterSim:
                     or cached[5] != mode
                 ):
                     prepare.set_metadata(miss=1)
-                    compiled, runner, n_ops = self._build_read_runner(
-                        plan, chaos_plan, reconfig_plan, split, split_k
+                    compiled, runner, n_ops, loss_draw = (
+                        self._build_read_runner(
+                            plan, chaos_plan, reconfig_plan, split, split_k
+                        )
                     )
                     self._read_runner = (
                         plan, chaos_plan, reconfig_plan, compiled, runner,
-                        mode, n_ops,
+                        mode, n_ops, loss_draw,
                     )
                 else:
-                    compiled, runner, n_ops = cached[3], cached[4], cached[6]
+                    compiled, runner, n_ops, loss_draw = (
+                        cached[3], cached[4], cached[6], cached[7]
+                    )
                 whole.set_metadata(
                     call=self._read_calls, rounds=compiled.n_rounds,
-                    groups=self.cfg.n_groups,
+                    groups=self.cfg.n_groups, loss_draw=loss_draw,
                 )
                 # The op protocol's carry: the one the last call of this
                 # plan triple ended with (the runner resumes it: finished
@@ -4950,9 +4954,12 @@ class ClusterSim:
         self, plan, chaos_plan, reconfig_plan, split: bool, split_k: int
     ):
         """(compiled client schedule, runner, the reconfig schedule's
-        n_ops plane or None) of one run_reads plan triple: compile
-        whatever is not compiled yet, place the schedules, build the
-        runner (the runner-cache miss of run_reads)."""
+        n_ops plane or None, loss_draw) of one run_reads plan triple:
+        compile whatever is not compiled yet, place the schedules, build
+        the runner (the runner-cache miss of run_reads).  loss_draw is 1
+        where the runner's rounds draw the chaos plan's loss sample
+        (a plan with a loss rate: chaos.CompiledChaos.lossless false),
+        else 0 — the raft.run_reads span's stat of that name."""
         from . import chaos as chaos_mod
         from . import reconfig as reconfig_mod
         from . import runner as runner_mod
@@ -4986,7 +4993,10 @@ class ClusterSim:
             split=split, k=split_k,
         )
         n_ops = None if reconfig_compiled is None else reconfig_compiled.n_ops
-        return compiled, runner, n_ops
+        loss_draw = int(
+            chaos_compiled is not None and not chaos_compiled.lossless
+        )
+        return compiled, runner, n_ops, loss_draw
 
     def counters(self) -> dict:
         """Download the device event-counter plane as {name: count}.

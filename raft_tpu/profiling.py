@@ -42,7 +42,9 @@ import jax
 SPANS: Dict[str, str] = {
     "raft.run_reads": (
         "one ClusterSim.run_reads call, entry to returned report; stats: "
-        "call (sequence number of this sim's calls), rounds, groups"
+        "call (sequence number of this sim's calls), rounds, groups, "
+        "loss_draw (1 where the runner's rounds draw a chaos plan's loss "
+        "sample, 0 for a plan with no loss rate or no chaos plan)"
     ),
     "raft.run_reads.prepare": (
         "runner cache look-up (schedule compile + make_runner on a miss: "
@@ -168,7 +170,8 @@ SCOPES: Dict[str, str] = {
     ),
     "runner.chaos_masks": (
         "chaos.schedule_masks: the round's link, crash and append-skew "
-        "planes cut out of the chaos schedule, in every round of a runner "
+        "planes cut out of the chaos schedule (the loss sample knocked out "
+        "only where the plan has a loss rate), in every round of a runner "
         "with a chaos plan"
     ),
     "runner.client": (

@@ -193,6 +193,32 @@ def test_balance_transfers_spread_leaders_by_weight():
     assert all(lead2[g] == tp[g] for g in moved)
 
 
+def test_runner_memo_keeps_a_lossless_runner_from_a_lossy_plan(monkeypatch):
+    """The cadence runner's memo is keyed on shapes AND on the chaos plan's
+    trace-time static: two plans of one shape, one with a loss rate, build
+    two runners (a lossless plan's round draws no loss sample — ISSUE 48);
+    the same static hits."""
+    from raft_tpu.multiraft import autopilot as autopilot_mod
+
+    G = 8
+    lossless = chaos.compile_plan(chaos.plan_from_dict(CRASH_PLAN), G)
+    doc = json.loads(json.dumps(CRASH_PLAN))
+    doc["phases"][1]["loss_all"] = 0.1
+    lossy = chaos.compile_plan(chaos.plan_from_dict(doc), G)
+    assert lossless.lossless and not lossy.lossless
+    assert [a.shape for a in lossless[:5]] == [a.shape for a in lossy[:5]]
+    built = []
+    monkeypatch.setattr(
+        autopilot_mod.runner_mod, "make_runner",
+        lambda cfg, scheds, **kw: built.append(scheds[1].lossless) or len(built),
+    )
+    ap = Autopilot(ClusterSim(SimConfig(n_groups=G, n_peers=3, collect_health=True)))
+    compiled = empty_reconfig_schedule(lossless.n_rounds, 3, G)
+    got = [ap._runner_for(compiled, c, 8) for c in (lossless, lossy, lossless, lossy)]
+    assert built == [True, False]
+    assert got == [1, 2, 1, 2]
+
+
 def test_empty_reconfig_schedule_shape():
     sched = empty_reconfig_schedule(10, 3, 4)
     assert sched.n_rounds == 10

@@ -383,6 +383,86 @@ def test_run_plan_matches_stepping_and_is_safe(shared_sim):
         assert report["mttr_rounds"] > 0
 
 
+# --- the per-round masks at their bytes (ISSUE 48) ---------------------------
+
+
+def masks_plan(lossy):
+    """A partition, a crash and a group selector; `lossy` adds a loss rate
+    on every link of one phase and a directed loss row in another."""
+    phases = [
+        {"rounds": 3, "append": 1},
+        {"rounds": 4, "partition": [[1, 2], [3]], "append": 2,
+         "groups": {"mod": 3, "eq": 1}},
+        {"rounds": 4, "crash": [2], "groups": {"mod": 2, "eq": 0}},
+        {"rounds": 3, "links": [{"from": 1, "to": 3, "up": False}]},
+    ]
+    if lossy:
+        phases[1]["loss_all"] = 0.3
+        phases[3]["loss"] = [{"from": 2, "to": 3, "rate": 0.5}]
+    return chaos.plan_from_dict({"name": "masks", "peers": P, "phases": phases})
+
+
+LOSS = pytest.mark.parametrize("lossy", [False, True], ids=["lossless", "lossy"])
+
+
+@LOSS
+def test_schedule_masks_equal_the_host_schedule_round_for_round(lossy):
+    """`CompiledChaos.lossless` is read off the plan, and either program of
+    schedule_masks — no loss unpack and no draw, or today's — hands the
+    step the oracle's masks in every round, jitted as the runners call it
+    (the round a traced scalar)."""
+    plan = masks_plan(lossy)
+    compiled = chaos.compile_plan(plan, G)
+    assert compiled.lossless is (not lossy)
+    host = chaos.HostSchedule(plan, G)
+    masks = jax.jit(functools.partial(chaos.schedule_masks, compiled))
+    dropped = 0
+    for r in range(plan.n_rounds):
+        link, crashed, append = masks(jnp.int32(r))
+        want_link, want_crashed, want_append = host.masks(r)
+        assert link.dtype == crashed.dtype == jnp.bool_
+        assert append.dtype == jnp.int32
+        assert np.array_equal(np.asarray(link), want_link), r
+        assert np.array_equal(np.asarray(crashed), want_crashed), r
+        assert np.array_equal(np.asarray(append), want_append), r
+        dropped += int((host.link[host.phase_of_round[r]] & ~want_link).sum())
+    assert (dropped > 0) is lossy, "the lossy plan must drop something"
+
+
+@LOSS
+def test_only_a_plan_with_a_loss_rate_draws_the_loss_sample(lossy):
+    """The chaos runner's jaxpr: a lossless plan's round has no `rem` (the
+    draw's `% LOSS_SCALE`) under `runner.chaos_masks` and reads
+    `loss_packed` nowhere — the operand stays in the jit's argument list,
+    in registry order, and is dead; a plan with a loss rate has both.
+    Neither stacks rows, and both hand the round its planes through one
+    barrier."""
+    from jax.interpreters import partial_eval as pe
+    from test_round_map import leaves  # the jaxpr walk, containers included
+
+    from raft_tpu.multiraft import runner as runner_mod
+    from raft_tpu.multiraft import schedules
+
+    cfg = SimConfig(n_groups=G, n_peers=P, collect_health=True)
+    compiled = chaos.compile_plan(masks_plan(lossy), G)
+    run = runner_mod.make_runner(cfg, (compiled,))
+    closed = jax.make_jaxpr(run.jitted)(
+        sim_mod.init_state(cfg), sim_mod.init_health(cfg), *run.schedule_args
+    )
+    fields = schedules.array_fields("chaos")
+    assert len(run.schedule_args) == len(fields)
+    _, used = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    read = dict(zip(fields, used[-len(fields):]))
+    assert read == {**dict.fromkeys(fields, True), "loss_packed": lossy}
+    masks = [
+        prim for prim, stack, _ in leaves(closed.jaxpr)
+        if "runner.chaos_masks" in stack
+    ]
+    assert ("rem" in masks) is lossy
+    assert "concatenate" not in masks
+    assert masks.count("optimization_barrier") == 1
+
+
 # --- claim 3 at scale: seeded link fuzz (slow tier) -------------------------
 
 

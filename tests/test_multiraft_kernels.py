@@ -5,6 +5,7 @@ phase 4 validation: same inputs as the quorum testdata, compared as ints)."""
 import random
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -511,6 +512,52 @@ def test_pack_u16_pairs_roundtrip_and_numpy_twin():
         assert np.array_equal(np.asarray(words), twin)
         back = kernels.unpack_u16_pairs(words, k)
         assert np.array_equal(np.asarray(back), vals)
+
+
+def _stacked_unpack_bits(words, k):
+    """unpack_bits as it was before ISSUE 48: k rows built one by one and
+    stacked — the reference the broadcast-shift form is held to."""
+    return jnp.stack([
+        ((words[j // 32] >> (j % 32)) & jnp.uint32(1)) != 0 for j in range(k)
+    ])
+
+
+def _stacked_unpack_u16_pairs(words, k):
+    """unpack_u16_pairs as it was before ISSUE 48 (stacked rows)."""
+    planes = []
+    for j in range(k):
+        half = words[j // 2] >> (16 * (j % 2))
+        planes.append((half & jnp.uint32(0xFFFF)).astype(jnp.int32))
+    return jnp.stack(planes)
+
+
+@pytest.mark.parametrize("k", [5, 9, 25, 32, 33, 49])
+@pytest.mark.parametrize("form", ["bits", "u16_pairs"])
+def test_unpack_equals_the_stacked_rows_reference(form, k):
+    """One word, the word boundary, two words: the broadcast-shift unpack
+    gives the stacked rows' values, shape and dtype for every k, on random
+    words (every bit of the last word set or not, the bits past k too) and
+    with trailing axes, eagerly and under jit — and its jaxpr stacks
+    nothing."""
+    unpack, ref, per = {
+        "bits": (kernels.unpack_bits, _stacked_unpack_bits, 32),
+        "u16_pairs": (kernels.unpack_u16_pairs, _stacked_unpack_u16_pairs, 2),
+    }[form]
+    rng = np.random.RandomState(48 + k)
+    n_words = (k + per - 1) // per
+    for tail in ((7,), (3, 7)):
+        words = jnp.asarray(
+            rng.randint(0, 1 << 32, size=(n_words,) + tail, dtype=np.uint64)
+            .astype(np.uint32)
+        )
+        want = ref(words, k)
+        for got in (unpack(words, k), jax.jit(unpack, static_argnums=1)(words, k)):
+            assert got.shape == want.shape == (k,) + tail
+            assert got.dtype == want.dtype
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+    prims = {e.primitive.name for e in jax.make_jaxpr(
+        lambda w: unpack(w, k))(words).jaxpr.eqns}
+    assert "concatenate" not in prims and "gather" not in prims, prims
 
 
 def test_committed_index_attribute_is_what_the_round_and_the_audit_call(monkeypatch):

@@ -114,6 +114,7 @@ def test_traced_call_yields_the_span_tree(tmp_path, split):
     assert [c[3]["call"] for c in calls] == [1, 2]
     for call, report in zip(calls, reports):
         assert call[3]["rounds"] == ROUNDS and call[3]["groups"] == G
+        assert call[3]["loss_draw"] == 0, "no chaos plan, no loss draw"
         kids = [s for s in spans if s is not call and inside(s, call)]
         by_name = {s[0]: s for s in kids}
         want = {"raft.run_reads.prepare", "raft.run_reads.dispatch",
@@ -145,6 +146,29 @@ def test_prepare_span_marks_the_runner_cache_miss(tmp_path):
     _reports, spans = traced_calls(tmp_path, split=False)
     prepares = [s[3] for s in spans if s[0] == "raft.run_reads.prepare"]
     assert prepares == [{"miss": 1}, {}]
+
+
+@pytest.mark.parametrize("loss_all, want", [(0.0, 0), (0.25, 1)],
+                         ids=["crash-only", "lossy"])
+def test_run_reads_span_says_whether_its_rounds_draw_the_loss_sample(
+    tmp_path, loss_all, want
+):
+    """`loss_draw` of the `raft.run_reads` span is the chaos plan's compiled
+    fact (`chaos.CompiledChaos.lossless`), on the miss and on the cache hit."""
+    doc = {"name": "c", "peers": P, "phases": [
+        {"rounds": 4}, {"rounds": ROUNDS - 4, "crash": [1], "loss_all": loss_all}]}
+    plan, cplan = client_plan(), chaos.plan_from_dict(doc)
+    assert chaos.compile_plan(cplan, G).lossless is (not want)
+    s = booted()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            s.run_reads(plan, cplan)
+    finally:
+        jax.profiler.stop_trace()
+    calls = [sp[3] for sp in read_spans(str(tmp_path)) if sp[0] == "raft.run_reads"]
+    assert [c["loss_draw"] for c in calls] == [want, want]
+    assert [c["call"] for c in calls] == [1, 2]
 
 
 def test_untraced_report_equals_traced(tmp_path):
