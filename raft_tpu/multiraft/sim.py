@@ -2071,11 +2071,16 @@ def _linked_step(
     # ---- wave 1: tick-queued traffic, per receiver in sender order.  The
     # running planes (T, V, Ld, ...) play each receiver's sequential
     # message processing; candidate payloads are the pre-round cursors
-    # (snapshotted at campaign time, before any delivery).  Each wave's
-    # sender loop is a lax.scan over the (stacked) per-sender rows rather
-    # than an unrolled python loop: the per-sender body traces ONCE, which
-    # cuts the link-path jaxpr (and its multi-second XLA compile) by ~P×
-    # while executing the identical op sequence — chaos parity stays
+    # (snapshotted at campaign time, before any delivery).  The four walks
+    # whose trips read what earlier senders left (wave 1, pass 1, pass 2,
+    # commit stage B) are one `scan` equation each over the stacked
+    # per-sender rows — the body traces ONCE, the PR 6 jaxpr discipline —
+    # lowered straight-line: pass 1 and pass 2, which carry `[P, P, G]`
+    # planes, behind a per-trip barrier (_sender_scan says why); wave 1 and
+    # commit stage B, which carry `[P, G]` planes only, without one (on the
+    # chip the barrier cost them 13% of the round, PERF.md §6 PR 49).  The
+    # tally and commit stage A, whose rows never read each other, hold no
+    # loop.  Same ops in the same order per sender: chaos parity stays
     # bit-exact (tests/test_chaos_parity.py).
     sender_ids = jnp.arange(P, dtype=jnp.int32)  # scan xs: the sender index
 
@@ -2139,76 +2144,18 @@ def _linked_step(
                 E, hb_send, req, term, st.matched, st.commit, st.last_term,
                 st.last_index, st.agree, sender_ids,
             ),
+            unroll=True,
         )
     )
 
     # ---- wave 2: responses travel the reverse links; each candidate
     # tallies in voter-index order with the scalar cutoffs (a decided
     # election stops applying rejections — raft.rs:2184-2190 — but the
-    # deciding response itself still fast-forwards, raft.rs:2236-2247).
-    n_i = jnp.sum(st.voter_mask, axis=0).astype(jnp.int32)
-    n_o = jnp.sum(st.outgoing_mask, axis=0).astype(jnp.int32)
-    q_i = n_i // 2 + 1
-    q_o = n_o // 2 + 1
-
-    def _wave2_inner(carry, xs):
-        cnt_i, cnt_o, rec_i, rec_o, ff = carry
-        dg_v, dr_v, snap_v, agree_v, vm_v, om_v = xs
-        won_before = ((cnt_i >= q_i) | (n_i == 0)) & (
-            (cnt_o >= q_o) | (n_o == 0)
-        )
-        lost_before = ((n_i > 0) & (cnt_i + (n_i - rec_i) < q_i)) | (
-            (n_o > 0) & (cnt_o + (n_o - rec_o) < q_o)
-        )
-        ok = dr_v & ~won_before & ~lost_before & (snap_v <= agree_v)
-        ff = jnp.where(ok, jnp.maximum(ff, snap_v), ff)
-        resp_v = dg_v | dr_v
-        rec_i = rec_i + (resp_v & vm_v).astype(jnp.int32)
-        rec_o = rec_o + (resp_v & om_v).astype(jnp.int32)
-        cnt_i = cnt_i + (dg_v & vm_v).astype(jnp.int32)
-        cnt_o = cnt_o + (dg_v & om_v).astype(jnp.int32)
-        return (cnt_i, cnt_o, rec_i, rec_o, ff), ()
-
-    def _wave2_body(C, xs):
-        (req_s, st_row, grants_s, resps_s, snap_s, erev_s, agree_s, vm_row,
-         om_row, sid) = xs
-        active = req_s & (st_row == ROLE_CANDIDATE)  # survived wave 1
-        del_g = grants_s & erev_s
-        del_r = (resps_s & ~grants_s) & erev_s
-        cnt_i = (active & vm_row).astype(jnp.int32)  # self-vote
-        cnt_o = (active & om_row).astype(jnp.int32)
-        (cnt_i, cnt_o, rec_i, rec_o, ff), _ = jax.lax.scan(
-            _wave2_inner,
-            (cnt_i, cnt_o, cnt_i, cnt_o, jnp.zeros((G,), jnp.int32)),
-            (
-                del_g, del_r, snap_s, agree_s, st.voter_mask,
-                st.outgoing_mask,
-            ),
-        )
-        won_ci = (
-            active
-            & ((cnt_i >= q_i) | (n_i == 0))
-            & ((cnt_o >= q_o) | (n_o == 0))
-        )
-        lost_ci = (
-            active
-            & ~won_ci
-            & (
-                ((n_i > 0) & (cnt_i + (n_i - rec_i) < q_i))
-                | ((n_o > 0) & (cnt_o + (n_o - rec_o) < q_o))
-            )
-        )
-        row = jax.lax.dynamic_index_in_dim(C, sid, 0, keepdims=False)
-        C = jnp.where(p_idx == sid, jnp.maximum(row, ff)[None, :], C)
-        return C, (won_ci, lost_ci)
-
-    C, (won, lost) = jax.lax.scan(
-        _wave2_body,
-        C,
-        (
-            req, St, grants, resps, rej_snap, Erev, st.agree,
-            st.voter_mask, st.outgoing_mask, sender_ids,
-        ),
+    # deciding response itself still fast-forwards, raft.rs:2236-2247):
+    # every candidate at once, in closed form along the voter axis.
+    C, won, lost = _real_tally(
+        st, _halves(st), C, req & (St == ROLE_CANDIDATE), grants, resps,
+        rej_snap, st.agree, Erev,
     )
 
     # Winners become leaders and append their noop (reference:
@@ -2303,7 +2250,7 @@ def _linked_step(
     (
         (T, V, St, Ld, EE, RT, C, matched3, agree_run, LI, LT),
         (resumed,),
-    ) = jax.lax.scan(
+    ) = _sender_scan(
         _pass1_body,
         (T, V, St, Ld, EE, RT, C, matched3, agree_run, li2, lt2),
         (
@@ -2314,26 +2261,17 @@ def _linked_step(
 
     sec.at("linked.commit")
     # Stage-A quorum commit per leader off the freshly acked matched rows
-    # (the term gate is raft_log.maybe_commit's own-term check).
-    def _commit_a_body(C, xs):
-        m3_row, st_row, ts_row, sid = xs
-        mci = jnp.minimum(
-            _quorum_index(m3_row, st.voter_mask),
-            _quorum_index(m3_row, st.outgoing_mask),
-        )
-        c_s = jax.lax.dynamic_index_in_dim(C, sid, 0, keepdims=False)
-        ok = (
-            (st_row == ROLE_LEADER)
-            & (mci >= ts_row)
-            & (mci < kernels.INF)
-        )
-        c_new = jnp.where(ok, jnp.maximum(c_s, mci), c_s)
-        C = jnp.where(p_idx == sid, c_new[None, :], C)
-        return C, (c_new > c_s,)
-
-    C, (adv,) = jax.lax.scan(
-        _commit_a_body, C, (matched3, St, TS, sender_ids)
-    )
+    # (the term gate is raft_log.maybe_commit's own-term check).  An owner
+    # reads and writes its own row only, so the owners are a batch axis.
+    by_owner = jnp.swapaxes(matched3, 1, 2)  # [P_owner, G, P_peer]
+    mci = jnp.minimum(
+        kernels.committed_index(by_owner, st.voter_mask.T[None]),
+        kernels.committed_index(by_owner, st.outgoing_mask.T[None]),
+    )  # [P_owner, G]
+    ok = (St == ROLE_LEADER) & (mci >= TS) & (mci < kernels.INF)
+    c_new = jnp.where(ok, jnp.maximum(C, mci), C)
+    adv = c_new > C
+    C = c_new
 
     sec.at("linked.replicate")
     # Pass 2: a commit advance re-broadcasts appends to every member whose
@@ -2385,7 +2323,7 @@ def _linked_step(
         )
         return (T, V, St, Ld, EE, RT, LI, LT, matched3, agree_run), ()
 
-    (T, V, St, Ld, EE, RT, LI, LT, matched3, agree_run), _ = jax.lax.scan(
+    (T, V, St, Ld, EE, RT, LI, LT, matched3, agree_run), _ = _sender_scan(
         _pass2_body,
         (T, V, St, Ld, EE, RT, LI, LT, matched3, agree_run),
         (E, Erev, adv, resumed, li2, lt2, term, sender_ids),
@@ -2434,6 +2372,7 @@ def _linked_step(
             matched3, St, TS, E, Erev, resumed, agree_run, li2, C_send,
             term, sender_ids,
         ),
+        unroll=True,
     )
 
     sec.at("linked.workload")
@@ -2629,11 +2568,12 @@ def _linked_step(
 
 
 def _sender_scan(body, carry, xs):
-    """A damped wave's loop over the P stacked sender rows: ONE `scan`
-    equation in the jaxpr (the per-sender body traces once — the PR 6
-    jaxpr-size discipline), lowered STRAIGHT-LINE (`unroll=True`: P is a
-    static shape, 3 or 5) with an `optimization_barrier` on the carry at
-    the head of every trip.
+    """A wave's loop over the P stacked sender rows (the damped round's
+    five, the stock round's two retry passes): ONE `scan` equation in the
+    jaxpr (the per-sender body traces once — the PR 6 jaxpr-size
+    discipline), lowered STRAIGHT-LINE (`unroll=True`: P is a static shape,
+    3 or 5) with an `optimization_barrier` on the carry at the head of
+    every trip.
 
     Rolled, the loop is an XLA `while` whose every trip cuts its `[P, G]`
     rows out of the `[P, P, G]` planes with a dynamic_slice and rewrites
@@ -2653,7 +2593,8 @@ def _sender_scan(body, carry, xs):
     return jax.lax.scan(trip, carry, xs, unroll=True)
 
 
-# ---- the damped round's tallies.  A candidate's tally reads and writes
+# ---- the tallies (the real one is the stock round's too; the pre-vote one
+# the damped round's alone).  A candidate's tally reads and writes
 # only its own row of the [P, G] planes and its own [P_voter, G] slab of the
 # response planes, so the candidate axis is a batch axis (PR 43); and what a
 # walk over the voters in receipt order carries from one response to the

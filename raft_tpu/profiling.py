@@ -110,9 +110,10 @@ SCOPES: Dict[str, str] = {
     "damped.wave2": "heartbeat responses + nudges back at each leader",
     "damped.tally": "the (pre-)vote tallies and post-election bookkeeping",
     "tally.real": (
-        "_real_tally inside damped.tally: the real election's tally of "
-        "every candidate at once, prefix counts and reductions along the "
-        "voter axis, no loop (wave 2 without pre-vote, wave 4 with it)"
+        "_real_tally inside damped.tally and linked.election: the real "
+        "election's tally of every candidate at once, prefix counts and "
+        "reductions along the voter axis, no loop (wave 2 without "
+        "pre-vote, wave 4 with it)"
     ),
     "tally.pre": (
         "_pre_tally inside damped.tally: the pre-vote tally of every "

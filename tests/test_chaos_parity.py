@@ -347,6 +347,121 @@ def test_asymmetric_partition_term_inflation(shared_sim):
     assert (splits[4:] == 0).all()
 
 
+ROUND_C = 8  # deposed_candidate_rounds: 1 + 3 settle, then A, B, B2, B', C
+
+
+def deposed_candidate_rounds(n_peers, one_way):
+    """[(link[P, P], append_n, kicked peers)]: a JOINT fleet (incoming
+    {1, 2, 3}, outgoing the last two peers at P = 3, {3, 4, 5} at P = 5) is
+    led into the round in which peer 1 campaigns a term below peer 2 and
+    still holds the leader's entries below the leader's commit.  The last
+    peer leads.  Round A loses every ack on one-way links (entries reach
+    all, nothing commits); B cuts leader -> 1 alone, so the catch-up
+    commit reaches everyone but peer 1; B2 grows the leader's log past
+    peer 1's with the acks lost again; B' lets peer 2 campaign unheard
+    (term + 1).  In round C both are kicked: peer 2's request (term + 2)
+    deposes candidate 1 (term + 1) in wave 1, and the voters reject peer
+    1 — its log is short — with a commit peer 1 holds the entry of.
+    `one_way` cuts 1 -> 2 in round C, so no append of the new leader's is
+    adopted by peer 1 and its end-of-round commit is what the rejects
+    left it.  Two settled rounds follow."""
+    P = n_peers
+    lead, c1, c2 = P - 1, 0, 1
+    up = lambda: np.ones((P, P), bool)
+    rounds = [(up(), 0, [lead])] + [(up(), 1, [])] * 3
+    a = up()
+    a[:, lead] = False
+    b = up()
+    b[lead, c1] = False
+    b2 = a.copy()
+    b2[lead, c1] = False
+    b3 = b.copy()
+    b3[c2, :] = False
+    c = up()
+    c[c1, c2] = not one_way
+    rounds += [(a, 2, []), (b, 0, []), (b2, 1, []), (b3, 0, [c2]),
+               (c, 0, [c1, c2])]
+    return rounds + [(up(), 1, [])] * 2
+
+
+@functools.lru_cache(maxsize=None)
+def deposed_candidate_runs(n_peers):
+    """{one_way: (first mismatch against the scalar oracle or None,
+    commit[peer 1] before and after round C on the device)} — both variants
+    through ONE jitted `sim.step` (the link plane is an argument)."""
+    n_groups = 8
+    voters = [1, 2, 3]
+    outgoing = [2, 3] if n_peers == 3 else [3, 4, 5]
+    vm = np.zeros((n_peers, n_groups), bool)
+    om = np.zeros((n_peers, n_groups), bool)
+    vm[[i - 1 for i in voters]] = True
+    om[[i - 1 for i in outgoing]] = True
+    cfg = SimConfig(n_groups=n_groups, n_peers=n_peers)
+    step = jax.jit(
+        lambda st, crashed, app, link, kick: sim_mod.step(
+            cfg, st, crashed, app, link=link, campaign_kick=kick)
+    )
+    crash = np.zeros((n_groups, n_peers), bool)
+    runs = {}
+    for one_way in (False, True):
+        scalar = ScalarCluster(
+            n_groups, n_peers, voters=voters, voters_outgoing=outgoing)
+        oracle = ChaosOracle(scalar, window=WINDOW)
+        state = sim_mod.init_state(cfg, jnp.asarray(vm), jnp.asarray(om))
+        mismatch, commits = None, []
+        for r, (ln, app, kicked) in enumerate(
+                deposed_candidate_rounds(n_peers, one_way)):
+            link = np.repeat(ln[:, :, None], n_groups, axis=2)
+            kick = np.zeros((n_groups, n_peers), bool)
+            kick[:, kicked] = True
+            app_n = np.full(n_groups, app, np.int64)
+            oracle.round(crash, app_n, link, kick=kick)
+            state = step(
+                state, jnp.asarray(crash.T.copy()),
+                jnp.asarray(app_n, dtype=jnp.int32), jnp.asarray(link),
+                jnp.asarray(kick.T.copy()),
+            )
+            commits.append(np.asarray(state.commit)[0])
+            want = scalar.snapshot()
+            for f in FIELDS:
+                got = np.asarray(getattr(state, f), dtype=np.int64).T
+                if mismatch is None and not np.array_equal(want[f], got):
+                    mismatch = (r, f, want[f][0].tolist(), got[0].tolist())
+        runs[one_way] = (mismatch, commits[ROUND_C - 1], commits[ROUND_C])
+    return runs
+
+
+@pytest.mark.parametrize("n_peers", [3, 5])
+def test_a_candidate_deposed_in_wave_1_matches_the_scalar_replay(n_peers):
+    """The round ISSUE 49's step 1 rewrote (the link-path tally is
+    `sim._real_tally` now), driven where its inputs are least plain: a
+    joint configuration, one-way links on the way in, a candidate deposed
+    in wave 1 whose voters reject it with a commit above its own.  Every
+    link is up in round C, so the new leader's commit reaches the deposed
+    candidate in the same round: every plane of every round against the
+    scalar replay."""
+    mismatch, before, after = deposed_candidate_runs(n_peers)[False]
+    assert mismatch is None, mismatch
+    assert (before == 4).all() and (after == 8).all(), (before, after)
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "a link-path divergence from the scalar port that PR 49 found and did "
+    "not cure (ROADMAP C17): raft.rs steps a MsgRequestVoteResponse "
+    "through step_candidate only, so a candidate deposed before the "
+    "response arrives takes no commit from it; sim._real_tally (and the "
+    "rolled tally before it) fast-forwards it all the same"))
+@pytest.mark.parametrize("n_peers", [3, 5])
+def test_a_deposed_candidate_takes_no_commit_from_its_rejects(n_peers):
+    """The same rounds with 1 -> 2 cut in round C (a one-way link): the new
+    leader's noop finds no matching probe and no reverse link at peer 1, so
+    nothing but the rejects can move peer 1's commit.  The scalar port
+    leaves it at 4; the device, which does not mask the tally's
+    fast-forward by `active`, reads 6: (8, "commit", [4, ...], [6, ...])."""
+    mismatch, _, _ = deposed_candidate_runs(n_peers)[True]
+    assert mismatch is None, mismatch
+
+
 def test_run_plan_matches_stepping_and_is_safe(shared_sim):
     """One-scan run_plan == round-by-round stepping (same masks, same
     PRNG), zero safety violations, and the MTTR report is well-formed."""

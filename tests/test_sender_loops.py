@@ -33,9 +33,18 @@ Bit-equality of the rounds is the parity suites' subject
 (`tests/test_damping_parity.py`, `tests/test_readindex_damped.py`, ...) and
 of the tallies alone `tests/test_tally_batched.py`'s; this file holds the
 FORM, on the jaxpr and the lowered text of `sim.step` under a link plane
-with a read probe, at G = 8.  `sim._linked_step` (the stock fleet's body,
-ROADMAP A13) is the control: all of its loops stay rolled, its tally's
-P x P form among them.
+with a read probe, at G = 8.
+
+`sim._linked_step` (the stock fleet's body, ROADMAP A13) was the control of
+those three PRs, every loop rolled — six sender loops and the tally's inner
+one over the voters, 7 `stablehlo.while` a round — until PR 49: its four
+walks whose trips depend on each other lower straight-line — pass 1 and
+pass 2, which carry `[P, P, G]` planes, through `sim._sender_scan` like the
+damped ones; wave 1 and commit stage B, which carry `[P, G]` planes only,
+as a plain `unroll=True` (the barrier rule: traced on the chip, the barrier
+cost those two 13% of the round) — its tally IS `sim._real_tally`, and its
+stage-A commit, whose trips never read each other, is one
+`kernels.committed_index` over the owners.  No `while` there either.
 """
 
 import re
@@ -141,15 +150,35 @@ def test_sender_loops_lower_straight_line(P, cq, pv, lease):
     assert updates_of_planes(text, P) == []
 
 
+# wave 1, pass 1, pass 2, commit stage B: the scope each walk sits under,
+# and whether its trips start behind the barrier (a `[P, P, G]` carry)
+STOCK_SENDER_LOOPS = [
+    ("linked.election", False), ("linked.replicate", True),
+    ("linked.replicate", True), ("linked.commit", False),
+]
+
+
 @pytest.mark.parametrize("P", [3, 5])
-def test_the_stock_fleets_loops_stay_rolled(P):
-    """`_linked_step` is none of PR 41's, 43's or 45's: six sender loops, the
-    tally's over the candidates among them, and the tally's inner one over
-    the voters, each a rolled `scan` (the control cell's program is the
-    parent's)."""
+def test_the_stock_fleets_loops_lower_straight_line(P):
+    """`_linked_step` after PR 49: wave 1, pass 1, pass 2 and commit stage B
+    are one `scan` equation each, P trips unrolled, none nested, the two
+    passes' behind their barrier and the other two's without one; the tally (`tally.real`) and the stage-A commit (`quorum_commit` on
+    whole planes) hold no loop; nothing is left for a `while`."""
     jaxpr, text = faulted_step(P, False, False, False)
     found = scans(jaxpr)
-    assert len(found) == 7 and all(s[1:3] == (P, 1) for s in found), found
-    assert sorted(s[0] - found[0][0] for s in found) == [0] * 6 + [1], found
-    assert len(re.findall(r"stablehlo\.while", text)) == len(found)
-    assert updates_of_planes(text, P) != []
+    top = found[0][0]
+    assert all(s[:2] == (top, P) for s in found), found
+    assert all(s[2] == P for s in found), found
+    assert [(s[4][-1], s[3] == "optimization_barrier")
+            for s in found] == STOCK_SENDER_LOOPS, found
+    under = primitives_under(jaxpr, "tally.real")
+    assert under and not under & (BY_CANDIDATE | VOTER_MAJOR), under
+    # Stage A reads every owner's row at once; stage B's walk still commits
+    # per owner, so the scope holds both forms and neither is a loop of its own.
+    commits = [n for _, n, _ in leaves(jaxpr) if "quorum_commit" in n]
+    assert commits and all(
+        "linked.commit" in n or "linked.workload" in n for n in commits)
+    # 7 `while`s before PR 49 (six sender loops, one of them around the
+    # tally's inner loop over the voters); no stacked plane rewritten a trip.
+    assert len(re.findall(r"stablehlo\.while", text)) == 0
+    assert updates_of_planes(text, P) == []
