@@ -24,7 +24,7 @@ cadence and emits batched actions whose ACTUATION is device-resident:
              degraded-site framing.
 
 Execution shape: the chaos horizon runs as cadence-sized donated jitted
-segments (`runner.make_runner(..., cadence=)` over reconfig._runner_body,
+segments (`runner.make_runner(..., cadence=)` over runner._runner_body,
 so the op protocol, the MTTR/safety folds, and the chaos masks are the
 SAME code the reconfig runner uses); between segments the fixed-size
 health summary crosses to the host, the policy decides, and the next

@@ -592,7 +592,7 @@ def fold_learner_lag(
     of the rate on the chip (PERF.md section 6, PR 47).  Two kernels on the
     TPU: ONE reduce over the peer axis that carries three values (a
     reduction a kernel cost 0.67%), and the sum.  Folded by
-    reconfig._runner_body only where the carry asks for it
+    runner._runner_body only where the carry asks for it
     (workload.LearnerLagCarry: a fleet that BOOTS with learners); every
     other fleet's round is the one it was."""
     state, term, commit, learner_mask, crashed = jax.lax.optimization_barrier(

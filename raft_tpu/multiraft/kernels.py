@@ -423,7 +423,7 @@ def link_loss_draw(
     loss_rate: int32[P, P, G] per-directed-link loss probability in units
                of 1/LOSS_SCALE (0 = lossless, LOSS_SCALE = always down).
     group_ids: optional int32[G] GLOBAL group ids when loss_rate is a
-               gathered sub-batch (pallas_step's per-group storm split):
+               slice of the fleet that is not groups 0..G-1:
                the (round, src, dst, group) PRNG key must keep drawing
                from each group's global stream, exactly like sim.step's
                group_ids= keeps the timeout PRNG global.

@@ -15,13 +15,16 @@ scoped-VMEM budget (`_tiling`), and whether Pallas interprets or Mosaic
 compiles is raft_tpu.platform's decision, never a caller's.  Speeds come
 from `python3 benchmark/run.py` on the chip (PERF.md); none is quoted here.
 
-`steady_predicate(cfg, st, crashed, horizon=k)` decides whether the
-invariant provably holds for the next k rounds; `fast_multi_round` then
-lax.cond's between the fused kernel and k sequential general steps, so the
-fast path is a pure optimization with IDENTICAL semantics
-(tests/test_pallas_step.py asserts bit-parity round by round; the crashed
-mask and per-round append workload are held constant across the k rounds,
-which is exactly the lockstep schedule ScalarCluster drives).
+`steady_mask(cfg, st, crashed, horizon=k)` decides, group by group,
+whether the invariant provably holds for the next k rounds
+(`steady_predicate` is its reduce over the fleet); where it holds,
+`steady_round(cfg, rounds=k)` is bit-identical to k sequential general
+steps (tests/test_pallas_step.py asserts the parity; the crashed mask and
+per-round append workload are held constant across the k rounds, which is
+exactly the lockstep schedule ScalarCluster drives).  This module holds no
+dispatcher: choosing between the fused kernel and the general round is
+`runner.make_runner(..., split=True)`'s business (`block_run` /
+`fused_block_run`, and the autopilot's cadence segment with `fused=True`).
 
 Coverage matrix (docs/PERF.md): the INSTRUMENTED configurations ride the
 fused path too — `with_health` tracks ticks_since_commit in-kernel and
@@ -61,7 +64,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import platform, profiling
 from . import kernels as kernels_mod
 from . import planes
-from . import sim as sim_mod
 from .kernels import (
     CTR_COMMIT_ENTRIES,
     CTR_HEARTBEATS,
@@ -1583,381 +1585,3 @@ def steady_predicate(
     return jnp.all(
         steady_mask(cfg, st, crashed, horizon, link, loss_rate=loss_rate)
     )
-
-
-def fast_step(cfg: SimConfig, with_health: bool = False):
-    """Dispatcher: the fused pallas round when steady, the general XLA step
-    otherwise.  Same signature/semantics as sim.step; with `with_health`
-    the fn takes/returns a HealthState extra exactly like sim.step's."""
-    pallas_fn = steady_round(cfg, rounds=1, with_health=with_health)
-
-    if with_health:
-
-        def fn_health(st: SimState, crashed, append_n, health):
-            pred = steady_predicate(cfg, st, crashed, horizon=1)
-            return jax.lax.cond(
-                pred,
-                lambda args: pallas_fn(*args),
-                lambda args: sim_mod.step(
-                    cfg, args[0], args[1], args[2], health=args[3]
-                ),
-                (st, crashed, append_n, health),
-            )
-
-        return fn_health
-
-    def fn(st: SimState, crashed, append_n) -> SimState:
-        pred = steady_predicate(cfg, st, crashed, horizon=1)
-        return jax.lax.cond(
-            pred,
-            lambda args: pallas_fn(*args),
-            lambda args: sim_mod.step(cfg, *args),
-            (st, crashed, append_n),
-        )
-
-    return fn
-
-
-def fast_multi_round(
-    cfg: SimConfig,
-    k: int = 16,
-    with_health: bool = False,
-    with_chaos: bool = False,
-    with_counters: bool = False,
-    count_fused: bool = False,
-):
-    """Dispatcher advancing k protocol rounds per call (same crashed/append
-    every round): the k-fused pallas kernel when provably steady for the
-    whole horizon, else k sequential general steps.  Semantically identical
-    to calling sim.step k times.
-
-    With `with_health`, fn(st, crashed, append_n, health) -> (SimState,
-    HealthState): both branches thread the health planes, so per-round
-    health parity holds whichever branch runs (tests/test_pallas_step.py).
-
-    With `with_counters`, the fn threads the [N_COUNTERS] int32 plane the
-    same way (extras order counters-then-health, like sim.step).
-
-    With `with_chaos`, fn(st, crashed, append_n, link, loss_rate,
-    round_base, *extras): the link plane and per-link loss rates are the
-    chaos engine's fault surface, round_base the absolute round index of
-    the first of the k rounds (the loss PRNG replay key).  The fused
-    kernel runs when the steady invariant holds AND the link plane is
-    fully healed among alive peers (loss is folded in-kernel); otherwise k
-    sequential sim.step(link=link & ~loss_draw) rounds run — bit-identical
-    either way (tests/test_pallas_step.py).  The chaos predicate feeds the
-    loss plane into steady_mask's PER-GROUP check-quorum boundary bound
-    (ISSUE 11): loss-free groups keep the lossless saturation proof, so a
-    zero-rate chaos overlay no longer forbids in-horizon boundaries.
-
-    With `count_fused`, the fn takes ONE extra trailing int32[] argument —
-    the fused GROUP-round accumulator — and returns it (appended last)
-    incremented by k * n_groups when the fused branch ran, unchanged
-    otherwise.  This is the measured fused-fraction metric
-    (`fused_frac`): an exact in-graph count, not a log line.  int32 bound:
-    the caller keeps total group-rounds below 2**31 (draining it per
-    run).  count_fused=False leaves every existing graph unchanged."""
-    pallas_fn = steady_round(
-        cfg,
-        rounds=k,
-        with_health=with_health,
-        with_chaos=with_chaos,
-        with_counters=with_counters,
-    )
-
-    if with_chaos or with_counters:
-        n_extra = (1 if with_counters else 0) + (1 if with_health else 0)
-        # Static arg layout, resolved at build time: args[3:6] are
-        # (link, loss, round_base) when chaos is on; extras follow.
-        extras_at = 6 if with_chaos else 3
-        chaos_at = 3 if with_chaos else None
-        idx_counters = 0 if with_counters else None
-        idx_health = (1 if with_counters else 0) if with_health else None
-
-        def slow_general(args):
-            st, crashed, append_n = args[:3]
-            link = loss = round_base = None
-            if chaos_at is not None:
-                link, loss, round_base = args[chaos_at : chaos_at + 3]
-            extras = args[extras_at:]
-
-            def body(carry, r):
-                s, *ex = carry
-                kw = {}
-                if idx_counters is not None:
-                    kw["counters"] = ex[idx_counters]
-                if idx_health is not None:
-                    kw["health"] = ex[idx_health]
-                if link is not None:
-                    kw["link"] = link & ~kernels_mod.link_loss_draw(
-                        round_base + r, loss
-                    )
-                res = sim_mod.step(cfg, s, crashed, append_n, **kw)
-                # NB: SimState is itself a NamedTuple, so the bare-state
-                # return must be wrapped by flag, not isinstance.
-                if idx_counters is None and idx_health is None:
-                    res = (res,)
-                return tuple(res), ()
-
-            carry, _ = jax.lax.scan(
-                body,
-                (st,) + tuple(extras),
-                jnp.arange(k, dtype=jnp.int32),
-            )
-            return carry if n_extra else carry[0]
-
-        def fast(args):
-            st, crashed, append_n = args[:3]
-            if chaos_at is None:
-                return pallas_fn(st, crashed, append_n, *args[3:])
-            loss, round_base = args[4], args[5]
-            return pallas_fn(
-                st, crashed, append_n, loss, round_base, *args[6:]
-            )
-
-        def fn_general(st, crashed, append_n, *rest):
-            if count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-                fused = rest[-1]
-                rest = rest[:-1]
-            link = rest[0] if chaos_at is not None else None
-            loss = rest[1] if chaos_at is not None else None
-            pred = steady_predicate(
-                cfg, st, crashed, horizon=k, link=link, loss_rate=loss
-            )
-            out = jax.lax.cond(
-                pred,
-                fast,
-                slow_general,
-                (st, crashed, append_n) + tuple(rest),
-            )
-            if not count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-                return out
-            fused = fused + jnp.where(
-                pred, jnp.int32(k * cfg.n_groups), jnp.int32(0)
-            )
-            if n_extra:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-                return tuple(out) + (fused,)
-            return out, fused
-
-        return fn_general
-
-    if with_health:
-
-        def slow_health(args):
-            st, crashed, append_n, health = args
-
-            def body(carry, _):
-                s, h = carry
-                s, h = sim_mod.step(cfg, s, crashed, append_n, health=h)
-                return (s, h), ()
-
-            return jax.lax.scan(body, (st, health), None, length=k)[0]
-
-        def fn_health(st: SimState, crashed, append_n, health, *acc):
-            pred = steady_predicate(cfg, st, crashed, horizon=k)
-            out = jax.lax.cond(
-                pred,
-                lambda args: pallas_fn(*args),
-                slow_health,
-                (st, crashed, append_n, health),
-            )
-            if not count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-                return out
-            fused = acc[0] + jnp.where(
-                pred, jnp.int32(k * cfg.n_groups), jnp.int32(0)
-            )
-            return tuple(out) + (fused,)
-
-        return fn_health
-
-    def slow(args):
-        st, crashed, append_n = args
-
-        def body(s, _):
-            return sim_mod.step(cfg, s, crashed, append_n), ()
-
-        return jax.lax.scan(body, st, None, length=k)[0]
-
-    def fn(st: SimState, crashed, append_n, *acc):
-        pred = steady_predicate(cfg, st, crashed, horizon=k)
-        out = jax.lax.cond(
-            pred,
-            lambda args: pallas_fn(*args),
-            slow,
-            (st, crashed, append_n),
-        )
-        if not count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            return out
-        fused = acc[0] + jnp.where(
-            pred, jnp.int32(k * cfg.n_groups), jnp.int32(0)
-        )
-        return out, fused
-
-    return fn
-
-
-def hybrid_multi_round(
-    cfg: SimConfig,
-    k: int = 16,
-    storm_slots: int = 4096,
-    with_chaos: bool = False,
-    count_fused: bool = False,
-):
-    """k protocol rounds with a PER-GROUP steady/slow split.
-
-    fast_multi_round drops the ENTIRE batch to k sequential general steps
-    when ANY group is non-steady — so one election among 100k groups costs
-    the whole batch its ~3-4x fused-kernel advantage.  This dispatcher
-    instead gathers the (few) non-steady groups into a fixed-capacity
-    [P, storm_slots] sub-batch (static shapes: an argsort permutation, storm
-    groups first), advances the sub-batch with k general sim.steps (passing
-    global group_ids so each group's (group, term)-keyed timeout PRNG stream
-    is unchanged), runs the fused kernel over the full batch, and scatters
-    the sub-batch results over the storm groups' (discarded) fused outputs.
-    Groups are independent in the lockstep model, so the split is exact —
-    bit-identical to k sequential sim.steps (tests/test_pallas_step.py).
-
-    Falls back to k general steps on the whole batch only when more than
-    `storm_slots` groups are non-steady (mass storms: elections at boot,
-    correlated failures).
-
-    With `with_chaos` (ISSUE 11), the fn signature grows (link, loss_rate,
-    round_base) after append_n — the chaos fault surface — and the split
-    becomes the per-group answer to the lossy damped boundary problem:
-    steady_mask's PER-GROUP check-quorum bound (loss-aware via
-    `loss_rate`) decides each group, so only the groups whose boundary
-    actually falls inside the horizon (or whose links are faulted) take
-    the general branch, while the rest of the batch stays on the fused
-    chaos/damped kernel.  Spread boundary phases no longer collapse the
-    whole batch to the wave path.  The storm sub-batch passes its global
-    group ids into both the timeout PRNG and the per-link loss PRNG
-    (kernels.link_loss_draw group_ids=), so every group's seeded streams
-    are unchanged — bit-identical to k sequential
-    sim.step(link=link & ~loss_draw) rounds.
-
-    With `count_fused`, one extra trailing int32[] accumulator rides the
-    signature and returns incremented by k * (fused group count) — the
-    per-group fused-fraction metric (group-rounds, exact).
-
-    Health planes are NOT threaded here (use fast_multi_round(...,
-    with_health=True) or the general step): the storm split would need a
-    per-sub-batch window-position fork that the closed-form steady fold
-    cannot express."""
-    G = cfg.n_groups
-    S = min(storm_slots, G)
-    pallas_fn = steady_round(cfg, rounds=k, with_chaos=with_chaos)
-    sub_cfg = cfg._replace(n_groups=S)
-
-    def group_mask(st, crashed, link, loss):
-        if with_chaos:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            return steady_mask(
-                cfg, st, crashed, horizon=k, link=link, loss_rate=loss
-            )
-        return steady_mask(cfg, st, crashed, horizon=k)
-
-    def slow(args):
-        st, crashed, append_n = args[:3]
-        if with_chaos:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            link, loss, rb = args[3:6]
-
-            def body_c(s, r):
-                lk = link & ~kernels_mod.link_loss_draw(rb + r, loss)
-                return sim_mod.step(cfg, s, crashed, append_n, link=lk), ()
-
-            return jax.lax.scan(
-                body_c, st, jnp.arange(k, dtype=jnp.int32)
-            )[0]
-
-        def body(s, _):
-            return sim_mod.step(cfg, s, crashed, append_n), ()
-
-        return jax.lax.scan(body, st, None, length=k)[0]
-
-    def hybrid(args):
-        st, crashed, append_n = args[:3]
-        link = loss = rb = None
-        if with_chaos:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            link, loss, rb = args[3:6]
-        mask = group_mask(st, crashed, link, loss)  # [G] True = steady
-        # Stable sort: storm groups (False=0) first, original order kept.
-        order = jnp.argsort(mask.astype(jnp.int8), stable=True)
-        idx = order[:S]  # [S] global ids of the storm groups (+ padding)
-        take_sub = ~mask[idx]  # padding entries are steady -> keep fused
-
-        sub = jax.tree.map(lambda a: a[..., idx], st)
-        sub_crashed = crashed[:, idx]
-        sub_append = append_n[idx]
-
-        if with_chaos:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            sub_link = link[:, :, idx]
-            sub_loss = loss[:, :, idx]
-
-            def body_c(s, r):
-                # Global group ids key BOTH seeded streams (timeouts and
-                # per-link loss), so the gathered replay is bit-identical.
-                lk = sub_link & ~kernels_mod.link_loss_draw(
-                    rb + r, sub_loss, group_ids=idx.astype(jnp.int32)
-                )
-                return (
-                    sim_mod.step(
-                        sub_cfg, s, sub_crashed, sub_append,
-                        group_ids=idx, link=lk,
-                    ),
-                    (),
-                )
-
-            sub_out = jax.lax.scan(
-                body_c, sub, jnp.arange(k, dtype=jnp.int32)
-            )[0]
-            fast_out = pallas_fn(st, crashed, append_n, loss, rb)
-        else:
-
-            def body(s, _):
-                return (
-                    sim_mod.step(
-                        sub_cfg, s, sub_crashed, sub_append, group_ids=idx
-                    ),
-                    (),
-                )
-
-            sub_out = jax.lax.scan(body, sub, None, length=k)[0]
-            fast_out = pallas_fn(st, crashed, append_n)
-
-        def merge(fast, subv):
-            gathered = jnp.where(take_sub, subv, fast[..., idx])
-            return fast.at[..., idx].set(gathered)
-
-        return jax.tree.map(merge, fast_out, sub_out)
-
-    def pure(args):
-        if with_chaos:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            return pallas_fn(args[0], args[1], args[2], args[4], args[5])
-        return pallas_fn(*args)
-
-    def fn(st: SimState, crashed, append_n, *rest) -> SimState:
-        if count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            fused = rest[-1]
-            rest = rest[:-1]
-        link = rest[0] if with_chaos else None
-        loss = rest[1] if with_chaos else None
-        n_storm = jnp.sum(
-            ~group_mask(st, crashed, link, loss)
-        ).astype(jnp.int32)
-        # Three-way dispatch: the all-steady case takes the PURE fused
-        # kernel (no argsort/gather/sub-batch overhead — the common case
-        # must cost exactly what fast_multi_round costs), sparse storms the
-        # gathered split, mass storms the whole-batch general fallback.
-        out = jax.lax.cond(
-            n_storm == 0,
-            pure,
-            lambda args: jax.lax.cond(n_storm <= S, hybrid, slow, args),
-            (st, crashed, append_n) + tuple(rest),
-        )
-        if not count_fused:  # graftcheck: allow-no-python-branch-on-traced — static builder flag
-            return out
-        fused_groups = jnp.where(
-            n_storm <= S, jnp.int32(G) - n_storm, jnp.int32(0)
-        )
-        return out, fused + jnp.int32(k) * fused_groups
-
-    return fn

@@ -2,8 +2,12 @@
 builds every whole-scenario runner — chaos-only, reconfig(+chaos), client
 workload, the two split-horizon variants, and the autopilot cadence
 segment — from the schedule registry (schedules.py) over the shared scan
-body (``reconfig._runner_body``).  Its docstring is the dispatch table
-and each variant's contract.
+body (:func:`_runner_body`, the one general round: client traffic, chaos
+masks, the op protocol, the audits and the folds).  make_runner's
+docstring is the dispatch table and each variant's contract.  The split
+variants are also the ONE place a fused kernel is chosen over the general
+round (``block_run`` / ``fused_block_run``: pallas_step.steady_mask, one
+``lax.cond``, pallas_step.steady_round or a scan of the body).
 
 The schedule modules (chaos, reconfig, workload) know nothing of this
 one; ``ClusterSim`` and the autopilot call it.
@@ -104,6 +108,374 @@ def rebuild_scheds(compiled, chaos_compiled, sched_args):
     else:
         chaos_sched = None
     return sched, chaos_sched
+
+
+# --- the round every variant but the chaos scan runs --------------------
+
+
+def _validate_plans(
+    cfg: sim_mod.SimConfig,
+    compiled: reconfig_mod.CompiledReconfig,
+    chaos_compiled: Optional[chaos_mod.CompiledChaos],
+) -> None:
+    """The runner-input compatibility checks of the reconfig scan and
+    split runners: equal horizons, agreeing peer counts."""
+    if chaos_compiled is not None:
+        if chaos_compiled.n_rounds != compiled.n_rounds:
+            raise ValueError(
+                f"chaos plan spans {chaos_compiled.n_rounds} rounds but "
+                f"the reconfig plan spans {compiled.n_rounds} — phases "
+                "must cover the same horizon to compose in one scan"
+            )
+        if chaos_compiled.n_peers != compiled.n_peers:
+            raise ValueError("chaos and reconfig plans disagree on peers")
+    if compiled.n_peers != cfg.n_peers:
+        raise ValueError(
+            f"plan has {compiled.n_peers} peers but cfg.n_peers == "
+            f"{cfg.n_peers}"
+        )
+
+
+def _runner_body(
+    cfg: sim_mod.SimConfig,
+    sched: reconfig_mod.CompiledReconfig,
+    chaos_sched: Optional[chaos_mod.CompiledChaos],
+    with_counters: bool = False,
+    actions: Optional[Tuple] = None,
+    client=None,
+):
+    """One general round of the compiled reconfig(+chaos) scenario as a
+    lax.scan body over the absolute round index — the SINGLE source of the
+    op propose/gate/apply protocol, shared by every runner.make_runner
+    variant: the reconfig runner's whole-horizon scan, the split runners'
+    general segments / fused-block fallback, the autopilot's cadence
+    segment, and the client-workload runner.
+
+    Carry: (state, health, rstate, stats, rstats, safety) with an
+    [N_COUNTERS] int32 plane appended when `with_counters` (the split
+    runner's production configuration threads it; the scan runner keeps
+    the historical carry and graph).
+
+    `actions` (ISSUE 12, the autopilot's device-resident actuation) is an
+    optional (action_round, transfer_plane int32[G], kick_plane
+    bool[P, G]) triple: at the one round whose absolute index equals
+    `action_round` the transfer commands and campaign kicks are handed to
+    sim.step; every other round passes the zero action.  None keeps the
+    historical graphs byte-identical.
+
+    `client` (ISSUE 13, the compiled client workload — a
+    workload.CompiledClient rebuilt from runtime args) appends
+    (read_carry, read_stats[workload.N_READ_STATS],
+    lat_hist[workload.N_LAT_BUCKETS]) to the carry: each round gathers
+    the schedule's read fires and append skew, retries outstanding reads
+    through `sim.step(read_propose=)`, folds per-read latency-in-rounds
+    into the on-device histogram, and runs kernels.check_safety's
+    linearizability slots (lease-holder mask off the round-ENTRY state)
+    alongside the joint-window audit.  None keeps every historical graph
+    byte-identical.  Where the read carry arrives as a
+    workload.LearnerLagCarry (ISSUE 47: ClusterSim.run_reads on a fleet
+    that boots with learners) each round also folds
+    chaos.fold_learner_lag into its count; a plain ReadCarry keeps the
+    graph it had.
+
+    Black-box forensics (ISSUE 15, SimConfig.blackbox): the carry gains
+    a TRAILING sim.BlackboxState; each round folds
+    kernels.check_safety_groups instead of check_safety — summing the
+    per-group indicators into the IDENTICAL safety counts
+    (tests/test_forensics.py pins the slot-for-slot equality) — and
+    records the post-round trace plus the fired (group, round) pairs in
+    one kernels.blackbox_fold.  blackbox=False keeps every historical
+    graph byte-identical."""
+    P, G = cfg.n_peers, cfg.n_groups
+    with_bb = cfg.blackbox
+
+    def body(carry, r):
+        bb = None
+        if with_bb:
+            carry, bb = carry[:-1], carry[-1]
+        rcar = rdstats = lat_hist = lag = None
+        if client is not None:
+            carry, (rcar, rdstats, lat_hist) = carry[:-3], carry[-3:]
+            if isinstance(rcar, workload_mod.LearnerLagCarry):
+                rcar, lag = rcar
+        if with_counters:
+            st, hl, rst, stats, rstats, safety, ctrs = carry
+        else:
+            st, hl, rst, stats, rstats, safety = carry
+            ctrs = None
+        # What the round is offered, under `runner.client` (the names the
+        # device ops carry in a trace; they change no equation).
+        with profiling.scope("runner.client"):
+            ph = sched.phase_of_round[r]
+            append = sched.append[ph]
+        if chaos_sched is not None:
+            link, crashed, capp = chaos_mod.schedule_masks(chaos_sched, r)
+            with profiling.scope("runner.client"):
+                append = append + capp
+        else:
+            link = None
+            with profiling.scope("runner.client"):
+                crashed = jnp.zeros((P, G), bool)
+        if actions is not None:
+            act_round, transfer_plane, kick_plane = actions
+            with profiling.scope("runner.client"):
+                fire = r == act_round
+                transfer_propose = jnp.where(fire, transfer_plane, 0)
+                campaign_kick = kick_plane & fire
+        else:
+            transfer_propose = None
+            campaign_kick = None
+        if client is not None:
+            with profiling.scope("runner.client"):
+                # The round's client traffic: phase append skew plus read
+                # fires (packed bits along G); an outstanding read retries
+                # every round until served, a fire finding one outstanding is
+                # dropped (one read in flight per group).
+                cph = client.phase_of_round[r]
+                append = append + client.append[cph]
+                fire_row = kernels.unpack_bits_g(client.read_fire_packed[r], G)
+                mode_row = client.read_mode[cph]
+                fire = fire_row & (mode_row > 0)
+                fresh = fire & (rcar.pending_mode == 0)
+                dropped = fire & (rcar.pending_mode > 0)
+                pmode = jnp.where(fresh, mode_row, rcar.pending_mode)
+                psince = jnp.where(fresh, r, rcar.pending_since)
+                read_propose = pmode
+                # The linearizability audit's inputs, off the round-ENTRY
+                # (= serve-time) state: every peer that would answer a read
+                # now, and the groups with such a read live this round.
+                if cfg.lease_read:
+                    # The full lease-holder mask and the lease-mode reads.
+                    lease_holder, _, _ = kernels.lease_read(
+                        st.state, st.term, st.leader_id, st.election_elapsed,
+                        st.commit, st.term_start_index, crashed,
+                        cfg.election_tick,
+                        cfg.check_quorum and cfg.lease_read, st.transferee,
+                        st.recent_active, st.voter_mask, st.outgoing_mask,
+                    )
+                    lease_fire = pmode == sim_mod.READ_LEASE
+                else:
+                    # No lease exists (raft-rs's default Config, or damping
+                    # with ReadOnlyOption::Safe) and every read, whatever mode
+                    # the client asked for, is a ReadIndex round — the audit
+                    # holds every peer whose ReadIndex gate passes (the step's
+                    # own probe: ReadReceipt.holders, below) to the same two
+                    # slots: no answer older than an index committed
+                    # fleet-wide, one answering peer a group.
+                    lease_holder = None
+                    lease_fire = pmode > sim_mod.READ_NONE
+        else:
+            read_propose = None
+            lease_holder = None
+            lease_fire = None
+        # Op eligibility: the next unapplied op, once its phase starts.
+        with profiling.scope("reconfig.gate"):
+            start = reconfig_mod._gather_op(sched.op_start, rst.op_ptr)
+            active = (rst.op_ptr < sched.n_ops) & (r >= start)
+            want_prop = active & (rst.stage == 0)
+        with profiling.scope("runner.stats"):
+            prev_leaderless = hl.planes[kernels.HP_LEADERLESS]
+        with profiling.scope("runner.client"):
+            offered = append + want_prop.astype(jnp.int32)
+        step_out = sim_mod.step(
+            cfg, st, crashed,
+            offered,
+            counters=ctrs, health=hl, link=link,
+            reconfig_propose=want_prop,
+            transfer_propose=transfer_propose,
+            campaign_kick=campaign_kick,
+            read_propose=read_propose,
+        )
+        receipt = None
+        if client is not None:
+            step_out, receipt = step_out[:-1], step_out[-1]
+            if lease_holder is None:
+                lease_holder = receipt.holders
+        if with_counters:
+            st2, ctrs2, hl2, prop = step_out
+        else:
+            st2, hl2, prop = step_out
+            ctrs2 = None
+        with profiling.scope("reconfig.gate"):
+            # Record where the conf entry landed (owner 0 = no alive
+            # leader this round; the op stays at stage 0 and retries).
+            got = want_prop & (prop.owner > 0)
+            stage = jnp.where(got, 1, rst.stage)
+            powner = jnp.where(got, prop.owner, rst.prop_owner)
+            pindex = jnp.where(got, prop.index, rst.prop_index)
+            pterm = jnp.where(got, prop.term, rst.prop_term)
+            # The dual-majority commit gate, off the post-round planes:
+            # the owner still leads at its propose term (its log cannot
+            # have been overwritten — a leader only appends) and is not
+            # crashed (a frozen isolated owner can never advance), and its
+            # commit covers the entry.  Commit advancement itself already
+            # required BOTH majorities of the joint config (joint.rs
+            # min-of-halves in every step path), so `commit >= index` IS
+            # the dual-quorum gate.
+            own_lead = (
+                (
+                    reconfig_mod._gather_peer(st2.state, powner)
+                    == kernels.ROLE_LEADER
+                )
+                & (reconfig_mod._gather_peer(st2.term, powner) == pterm)
+                & ~reconfig_mod._gather_peer(crashed, powner)
+            )
+            committed = reconfig_mod._gather_peer(st2.commit, powner) >= pindex
+            apply_mask = (stage == 1) & own_lead & committed
+            retry = (stage == 1) & ~own_lead
+            stage = jnp.where(apply_mask | retry, 0, stage)
+        # Joint-window safety invariants on the post-step (pre-apply)
+        # state under the masks that governed the step; the mask
+        # TRANSITION pair (prev round's step masks -> this round's) audits
+        # the previous round's apply.
+        viol = None
+        if with_bb:
+            viol = kernels.check_safety_groups(
+                st2.state, st2.term, st2.commit, st2.last_index, st2.agree,
+                st.commit,
+                voter_mask=st2.voter_mask,
+                outgoing_mask=st2.outgoing_mask,
+                matched=st2.matched,
+                crashed=crashed,
+                prev_voter_mask=rst.prev_voter,
+                prev_outgoing_mask=rst.prev_outgoing,
+                lease_holder=lease_holder,
+                lease_fire=lease_fire,
+            )
+            # dtype= keeps the slot sums int32 under x64 (GC007); the
+            # per-group sums equal check_safety's counts exactly.
+            audit = jnp.sum(viol, axis=1, dtype=jnp.int32)
+        else:
+            audit = kernels.check_safety(
+                st2.state, st2.term, st2.commit, st2.last_index, st2.agree,
+                st.commit,
+                voter_mask=st2.voter_mask,
+                outgoing_mask=st2.outgoing_mask,
+                matched=st2.matched,
+                crashed=crashed,
+                prev_voter_mask=rst.prev_voter,
+                prev_outgoing_mask=rst.prev_outgoing,
+                lease_holder=lease_holder,
+                lease_fire=lease_fire,
+            )
+        with profiling.scope("runner.stats"):
+            safety = safety + audit
+        with profiling.scope("reconfig.apply"):
+            # The gated swap: target masks of the op being applied, the
+            # reference's apply-time reactions on the batched planes.
+            (
+                state3, leader3, commit3, matched3, vm3, om3, lm3, ra3,
+                tr3,
+            ) = kernels.apply_confchange(
+                st2.state, st2.leader_id, st2.commit,
+                st2.term_start_index,
+                st2.matched, st2.voter_mask, st2.outgoing_mask,
+                st2.learner_mask,
+                reconfig_mod._gather_op(sched.tgt_voter, rst.op_ptr),
+                reconfig_mod._gather_op(sched.tgt_outgoing, rst.op_ptr),
+                reconfig_mod._gather_op(sched.tgt_learner, rst.op_ptr),
+                reconfig_mod._gather_op(sched.added, rst.op_ptr),
+                reconfig_mod._gather_op(sched.removed, rst.op_ptr),
+                apply_mask,
+                st2.recent_active,
+                st2.transferee,
+            )
+            st3 = st2._replace(
+                state=state3, leader_id=leader3, commit=commit3,
+                matched=matched3, voter_mask=vm3, outgoing_mask=om3,
+                learner_mask=lm3, recent_active=ra3, transferee=tr3,
+            )
+        with profiling.scope("runner.stats"):
+            stats = chaos_mod.update_chaos_stats(
+                stats, prev_leaderless, hl2.planes[kernels.HP_LEADERLESS],
+                offered=offered > 0, dropped=prop.dropped,
+            )
+            if client is not None:
+                # The round's end against the last acting leader each
+                # group had, and the growth of its highest term.  Off the
+                # post-step planes (st2), the ones the health fold's
+                # `has_leader` read: an apply-time step-down shows from
+                # the next round, and the leader compare is the fold's own
+                # — off st3 the damped round was 1.2% slower on the chip
+                # (PERF.md §6, PR 35).
+                stats, last_leader = chaos_mod.update_leader_stats(
+                    stats, rcar.last_leader, hl,
+                    hl2.planes[kernels.HP_TERM_BUMPS],
+                    st2.state, st2.term, crashed,
+                )
+        with profiling.scope("reconfig.apply"):
+            # dtype= on the counts: bare bool sums widen to int64 under
+            # x64 (GC007) and these feed the int32 accumulator.
+            rstats = rstats + jnp.stack(
+                [
+                    jnp.sum(got, dtype=jnp.int32),
+                    jnp.sum(apply_mask, dtype=jnp.int32),
+                    jnp.sum(retry, dtype=jnp.int32),
+                    jnp.sum(jnp.any(om3, axis=0), dtype=jnp.int32),
+                ]
+            )
+            rst2 = reconfig_mod.ReconfigState(
+                stage=stage,
+                op_ptr=jnp.where(apply_mask, rst.op_ptr + 1, rst.op_ptr),
+                prop_owner=powner,
+                prop_index=pindex,
+                prop_term=pterm,
+                prev_voter=st2.voter_mask,
+                prev_outgoing=st2.outgoing_mask,
+            )
+        out = (st3, hl2, rst2, stats, rstats, safety)
+        if with_counters:
+            out = out + (ctrs2,)
+        if client is not None:
+            # Serve accounting: a non-negative receipt closes the group's
+            # outstanding read with latency (r - issue_round), folded into
+            # the device histogram (bucket = min(latency, cap), cap =
+            # N_LAT_BUCKETS - 1 derived from the carry shape).
+            lat_cap = lat_hist.shape[0] - 1
+            with profiling.scope("runner.client"):
+                served = (receipt.index >= 0) & (pmode > 0)
+                lat = jnp.clip(r - psince, 0, lat_cap)
+            lat_hist = workload_mod.fold_latencies(lat_hist, served, lat)
+            # dtype= on the counts: GC007 (bare bool sums widen under
+            # x64) — these feed the int32 read-stats accumulator.
+            with profiling.scope("runner.stats"):
+                rdstats = rdstats + jnp.stack(
+                    [
+                        jnp.sum(fresh, dtype=jnp.int32),
+                        jnp.sum(served & receipt.lease, dtype=jnp.int32),
+                        jnp.sum(served & ~receipt.lease, dtype=jnp.int32),
+                        jnp.sum(served & receipt.degraded, dtype=jnp.int32),
+                        jnp.sum((pmode > 0) & ~served, dtype=jnp.int32),
+                        jnp.sum(dropped, dtype=jnp.int32),
+                    ]
+                )
+            with profiling.scope("runner.client"):
+                rcar = type(rcar)(
+                    pending_mode=jnp.where(served, 0, pmode),
+                    pending_since=jnp.where(served, 0, psince),
+                    last_leader=last_leader,
+                )
+            if lag is not None:
+                # A fleet that boots with learners: the round's end, off the
+                # planes the round hands on.
+                lag = chaos_mod.fold_learner_lag(
+                    lag, st3.state, st3.term, st3.commit, st3.learner_mask,
+                    crashed,
+                )
+                rcar = workload_mod.LearnerLagCarry(rcar, lag)
+            out = out + (rcar, rdstats, lat_hist)
+        if with_bb:
+            # The ring records the round-EXIT (post-apply) state; the
+            # fired bits come from the audit above, so one fold covers
+            # trace and trigger capture.
+            bb = sim_mod.BlackboxState(*kernels.blackbox_fold(
+                bb.meta, bb.term, bb.commit, bb.trip_round, bb.round_idx,
+                st3.state, st3.term, st3.commit, crashed, viol,
+            ))
+            out = out + (bb,)
+        return out, ()
+
+    return body
 
 
 # --- the runner constructors (make_runner's docstring has each contract) ----
@@ -216,12 +588,12 @@ def _make_reconfig(
     """The reconfig(+chaos) whole-scenario runner: one scan of
     _runner_body with the tail transition audit."""
     n_rounds = compiled.n_rounds
-    reconfig_mod._validate_plans(cfg, compiled, chaos_compiled)
+    _validate_plans(cfg, compiled, chaos_compiled)
 
     with_bb = cfg.blackbox
 
     def body(carry, r, sched, chaos_sched):
-        return reconfig_mod._runner_body(cfg, sched, chaos_sched)(carry, r)
+        return _runner_body(cfg, sched, chaos_sched)(carry, r)
 
     def run(st, hl, rst, *args):
         if with_bb:
@@ -318,7 +690,7 @@ def _make_reconfig_split(
             "the closed-form health fold handles at most one churn-window "
             "crossing per block"
         )
-    reconfig_mod._validate_plans(cfg, compiled, chaos_compiled)
+    _validate_plans(cfg, compiled, chaos_compiled)
     chaos_on = chaos_compiled is not None
     segments = reconfig_mod.split_plan(compiled, k, chaos_compiled, window)
     assert segments and segments[0].start == 0 and sum(
@@ -341,7 +713,7 @@ def _make_reconfig_split(
             sched, chaos_sched = rebuild_scheds(
                 compiled, chaos_compiled, sched_args
             )
-            body = reconfig_mod._runner_body(
+            body = _runner_body(
                 cfg, sched, chaos_sched, with_counters
             )
             carry = (st, hl, rst, stats, rstats, safety)
@@ -359,7 +731,7 @@ def _make_reconfig_split(
         sched, chaos_sched = rebuild_scheds(
             compiled, chaos_compiled, sched_args
         )
-        body = reconfig_mod._runner_body(cfg, sched, chaos_sched, with_counters)
+        body = _runner_body(cfg, sched, chaos_sched, with_counters)
         if chaos_on:
             link, loss, crashed, capp = chaos_mod.schedule_planes(
                 chaos_sched, r0
@@ -510,7 +882,7 @@ def _make_workload(
         safety = jnp.zeros((kernels.N_SAFETY,), jnp.int32)
         rdstats = jnp.zeros((workload_mod.N_READ_STATS,), jnp.int32)
         lat_hist = jnp.zeros((workload_mod.N_LAT_BUCKETS,), jnp.int32)
-        body = reconfig_mod._runner_body(
+        body = _runner_body(
             cfg, sched, chaos_sched, client=csched
         )
         carry = (
@@ -642,7 +1014,7 @@ def _make_workload_split(
         fused, rows, append, *sched_args,
     ):
         csched, sched = _rebuild_client(sched_args)
-        body = reconfig_mod._runner_body(cfg, sched, None, client=csched)
+        body = _runner_body(cfg, sched, None, client=csched)
         guard = profiling.Sections()
         guard.at("runner.block_guard")
         crashed = jnp.zeros((P, G), bool)
@@ -720,7 +1092,7 @@ def _make_workload_split(
         fused, r0, *sched_args,
     ):
         csched, sched = _rebuild_client(sched_args)
-        body = reconfig_mod._runner_body(cfg, sched, None, client=csched)
+        body = _runner_body(cfg, sched, None, client=csched)
         carry, _ = jax.lax.scan(
             body,
             (st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist),
@@ -818,7 +1190,7 @@ def _make_cadence(
         sched, chaos_sched = rebuild_scheds(
             compiled, chaos_compiled, sched_args
         )
-        body = reconfig_mod._runner_body(
+        body = _runner_body(
             cfg, sched, chaos_sched, actions=(r0, transfer, kick)
         )
 
@@ -997,7 +1369,7 @@ def make_runner(
 
     reconfig scan: (state, health, rstate) -> (state', health', rstate',
     stats, rstats[N_RECONFIG_STATS], safety); one scan of
-    reconfig._runner_body — op eligibility, the propose/gate/apply
+    _runner_body — op eligibility, the propose/gate/apply
     protocol, the joint-window audit — with the chaos masks, when given,
     gathered exactly as the chaos scan's, so membership changes run
     during partitions; a tail audit covers a final-round apply.

@@ -695,8 +695,8 @@ def _node_key(
     """node_key[p, g] = g * 2**16 + (p + 1): matches the scalar side's
     Config.timeout_seed = g convention (util.deterministic_timeout).
 
-    `group_ids` overrides the iota when the step runs on a GATHERED
-    sub-batch (pallas_step.hybrid_multi_round's storm slots): the timeout
+    `group_ids` overrides the iota when the step runs on a slice of the
+    fleet that is not groups 0..G-1 (a gathered sub-batch): the timeout
     PRNG must keep drawing from each group's GLOBAL stream."""
     if group_ids is None:
         g = jnp.arange(cfg.n_groups, dtype=jnp.uint32)[None, :]

@@ -20,7 +20,7 @@ parity with sim.step(read_propose=); ReconfigOracle (ISSUE 10) walks a
 compiled membership-churn schedule (reconfig.HostReconfigSchedule) —
 proposing real conf entries, gating on the dual-majority commit, and
 applying the Changer-computed config by scalar surgery — the exact twin
-of the reconfig runner's scan (reconfig._runner_body).
+of the reconfig runner's scan (runner._runner_body).
 """
 
 from __future__ import annotations
@@ -788,7 +788,7 @@ class ReconfigOracle(HealthOracle):
     each round runs the standard lockstep round with the round's faults
     and the pending op's conf-entry propose (ScalarCluster.round's
     conf_propose), applies the IDENTICAL propose/gate/retry rules the
-    device runner folds into its scan (reconfig._runner_body), and — when
+    device runner folds into its scan (runner._runner_body), and — when
     a group's gate fires — performs the scalar surgery mirror of
     kernels.apply_confchange on every peer of the group at once:
     tracker.apply_conf with the Changer-computed configuration + map

@@ -13,7 +13,7 @@ GC008 PACKED_PLANES `bits_g`); ``runner.make_runner`` then executes the
 whole scenario inside one ``lax.scan`` with zero host round trips,
 composable with a ``chaos.CompiledChaos`` AND a
 ``reconfig.CompiledReconfig`` in the SAME scan (reads during partitions,
-reads during joint config — ``reconfig._runner_body`` is the shared round
+reads during joint config — ``runner._runner_body`` is the shared round
 body).
 
 Each round: outstanding reads retry through ``sim.step(read_propose=)``
@@ -321,7 +321,7 @@ class LearnerLagCarry(NamedTuple):
     carry's place for a fleet that BOOTS with learners: the ReadCarry and
     the call's count of (group, round) pairs that ended with a learner
     behind its leader's commit (chaos.fold_learner_lag, the report's
-    `learner_behind_group_rounds`).  reconfig._runner_body folds the count
+    `learner_behind_group_rounds`).  runner._runner_body folds the count
     where it finds this type and nowhere else, so a fleet that boots
     without learners runs the round it always ran; the runners pass the
     carry through as they pass a ReadCarry."""

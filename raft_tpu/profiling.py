@@ -176,7 +176,7 @@ SCOPES: Dict[str, str] = {
         "with a chaos plan"
     ),
     "runner.client": (
-        "what reconfig._runner_body offers the round: the schedules' append "
+        "what runner._runner_body offers the round: the schedules' append "
         "rows and, with a client plan, the read fires unpacked "
         "(kernels.unpack_bits_g), the pending-read bookkeeping before and "
         "after the step and the audit's inputs (kernels.lease_read's holder "

@@ -7,6 +7,8 @@ rule scoping matches on path suffixes (docs/STATIC_ANALYSIS.md)."""
 
 import textwrap
 
+import pytest
+
 from tools.graftcheck import Context, all_rules, run_paths
 
 
@@ -2264,6 +2266,42 @@ def test_gc018_runtime_arg_schedule_in_nested_def_passes(tmp_path):
         },
     )
     assert gc018(vs) == []
+
+
+_FACTORY_RUNNER = (
+    '"""fixture runner"""\n'
+    "from . import schedules\n\n\n"
+    "def _body_of(cfg, sched):\n"
+    "    def body(st, r):\n"
+    "        return st + sched.link_packed.sum()\n\n"
+    "    return body\n\n\n"
+    "def make_runner(cfg, compiled):\n"
+    '    fields = schedules.array_fields("chaos")\n\n'
+    "    def run(st, *args):\n"
+    "        sched = compiled._replace(**dict(zip(fields, args)))\n"
+    "        return _body_of(cfg, {passed})(st, 0)\n\n"
+    "    return run\n"
+)
+
+
+@pytest.mark.parametrize("passed, clean", [("sched", True), ("compiled", False)])
+def test_gc018_body_factory_parameter_is_judged_at_its_call_sites(
+    tmp_path, passed, clean
+):
+    # A private top-level body factory (runner._runner_body) may read
+    # schedule arrays off its parameter when every call in the module hands
+    # it a schedule rebuilt inside a traced def; handed the constructor's
+    # compiled template, the same read is the closure const it always was.
+    vs = run_engine_on(
+        tmp_path,
+        {
+            "raft_tpu/multiraft/schedules.py": schedules_fixture(),
+            "raft_tpu/multiraft/chaos.py": _FIXTURE_CHAOS,
+            "raft_tpu/multiraft/runner.py": _FACTORY_RUNNER.format(passed=passed),
+        },
+    )
+    assert (gc018(vs) == []) == clean
+    assert clean or any("closure variable" in v.message for v in gc018(vs))
 
 
 def test_gc018_hand_listed_schedule_tuple_flags(tmp_path):

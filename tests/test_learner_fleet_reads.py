@@ -77,7 +77,7 @@ def read_oracle(voters, learners, n_groups=G):
 @jax.jit
 def lease_holders(st, crashed):
     """bool[P, G]: the peers that hold a read lease now — the mask the
-    runner hands the linearizability audit (reconfig._runner_body)."""
+    runner hands the linearizability audit (runner._runner_body)."""
     return kernels.lease_read(
         st.state, st.term, st.leader_id, st.election_elapsed, st.commit,
         st.term_start_index, crashed, ELECTION_TICK, True, st.transferee,
@@ -120,7 +120,7 @@ def test_learner_fleet_lease_reads_two_segments():
         at_seg, in_seg = divmod(r - SETTLE, SEGMENT) if r >= SETTLE else (None, None)
         if in_seg == 0:
             pending[:] = 0  # run_reads starts a call with no read in flight
-        # The runner's bookkeeping (reconfig._runner_body), on the host.
+        # The runner's bookkeeping (runner._runner_body), on the host.
         fire = fires[in_seg] & (seg.read_mode[seg.phase_of_round[in_seg]] > 0) if r >= SETTLE \
             else np.zeros(G, bool)
         fresh, dropped = fire & (pending == 0), fire & (pending > 0)

@@ -1,6 +1,8 @@
 """The fused Pallas steady round must be bit-identical to the general XLA
-step whenever the steady predicate holds, and the fast_step dispatcher must
-match sim.step on full schedules including elections and crashes.
+step whenever the steady predicate holds.  Which of the two runs is the split
+runners' business (runner.make_runner(..., split=True)): full schedules with
+elections, crashes and lossy links through both arms are held to sequential
+sim.steps in tests/test_reconfig_split.py and tests/test_workload.py.
 
 Runs in interpret mode on the pinned CPU (raft_tpu.platform decides);
 the Mosaic compile path is exercised on the chip by chip_smoke.py."""
@@ -95,127 +97,6 @@ def test_multi_round_kernel_matches_k_steps():
         np.testing.assert_array_equal(
             np.asarray(getattr(want, f)), np.asarray(getattr(got, f)), err_msg=f
         )
-
-
-def test_fast_multi_round_full_schedule_parity():
-    """fast_multi_round == k sequential sim.steps, including rounds where
-    the predicate rejects (elections in progress)."""
-    import functools
-
-    cfg = SimConfig(n_groups=8, n_peers=3)
-    k = 4
-    # jitted drivers: eager per-op dispatch was the bulk of this test's
-    # wall time (tier-1 budget), and jit is how both sides run for real.
-    fast = jax.jit(pallas_step.fast_multi_round(cfg, k=k))
-    step = jax.jit(functools.partial(sim.step, cfg))
-    a = sim.init_state(cfg)
-    b = sim.init_state(cfg)
-    crashed = jnp.zeros((cfg.n_peers, cfg.n_groups), bool)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-    for blk in range(8):  # 32 rounds: covers the initial election storm
-        for _ in range(k):
-            a = step(a, crashed, append)
-        b = fast(b, crashed, append)
-        for f in a._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)),
-                np.asarray(getattr(b, f)),
-                err_msg=f"block {blk} field {f}",
-            )
-
-
-def test_fast_step_full_schedule_parity():
-    """fast_step == sim.step across elections, crashes, recovery."""
-    import functools
-
-    cfg = SimConfig(n_groups=8, n_peers=3)
-    fast = jax.jit(pallas_step.fast_step(cfg))
-    step = jax.jit(functools.partial(sim.step, cfg))
-    a = sim.init_state(cfg)
-    b = sim.init_state(cfg)
-    rng = np.random.RandomState(5)
-    crashed = np.zeros((3, 8), bool)
-    for r in range(45):
-        if rng.rand() < 0.05:
-            crashed[rng.randint(3), rng.randint(8)] ^= True
-        c = jnp.asarray(crashed)
-        append = jnp.asarray(rng.randint(0, 2, size=8).astype(np.int32))
-        a = step(a, c, append)
-        b = fast(b, c, append)
-        for f in a._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)),
-                np.asarray(getattr(b, f)),
-                err_msg=f"round {r} field {f}",
-            )
-
-
-def test_hybrid_multi_round_localized_storm_parity():
-    """hybrid_multi_round == k sequential sim.steps when a FEW groups storm
-    (leader crashes -> elections) while the rest stay steady: the storm
-    groups must ride the gathered general-step sub-batch (with global
-    timeout PRNG streams) and everyone else the fused kernel."""
-    import functools
-
-    cfg = SimConfig(n_groups=16, n_peers=3)
-    k = 4
-    hybrid = jax.jit(pallas_step.hybrid_multi_round(cfg, k=k, storm_slots=4))
-    step = jax.jit(functools.partial(sim.step, cfg))
-    a = sim.init_state(cfg)
-    b = sim.init_state(cfg)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-    crashed_np = np.zeros((cfg.n_peers, cfg.n_groups), bool)
-
-    def run_block(a, b, crashed):
-        c = jnp.asarray(crashed)
-        for _ in range(k):
-            a = step(a, c, append)
-        b = hybrid(b, c, append)
-        for f in a._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)),
-                np.asarray(getattr(b, f)),
-                err_msg=f,
-            )
-        return a, b
-
-    # settle (the boot storm exceeds storm_slots=4 -> whole-batch fallback)
-    for _ in range(8):
-        a, b = run_block(a, b, crashed_np)
-    # kill the leaders of 2 groups: localized storms, 14 groups steady
-    leaders = np.asarray(a.state).argmax(axis=0)
-    for g in (3, 11):
-        crashed_np[leaders[g], g] = True
-    for _ in range(6):
-        a, b = run_block(a, b, crashed_np)
-    # recover: re-sync storms, then fully steady again
-    crashed_np[:] = False
-    for _ in range(6):
-        a, b = run_block(a, b, crashed_np)
-
-
-def test_hybrid_storm_overflow_falls_back():
-    """More storm groups than slots: exact whole-batch general fallback."""
-    import functools
-
-    cfg = SimConfig(n_groups=8, n_peers=3)
-    k = 3
-    hybrid = jax.jit(pallas_step.hybrid_multi_round(cfg, k=k, storm_slots=1))
-    step = jax.jit(functools.partial(sim.step, cfg))
-    a = sim.init_state(cfg)  # boot: all 8 groups non-steady
-    b = sim.init_state(cfg)
-    crashed = jnp.zeros((cfg.n_peers, cfg.n_groups), bool)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-    for blk in range(10):
-        for _ in range(k):
-            a = step(a, crashed, append)
-        b = hybrid(b, crashed, append)
-        for f in a._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)),
-                np.asarray(getattr(b, f)),
-                err_msg=f"block {blk} field {f}",
-            )
 
 
 @pytest.mark.slow  # ~8s of interpret-mode compile
@@ -424,101 +305,11 @@ def test_steady_counters_closed_form():
         )
 
 
-@pytest.mark.slow  # eager link-path rounds at P=5 + the health variant
-def test_fast_multi_round_chaos_both_branches():
-    """fast_multi_round(with_chaos, with_health): the fused branch engages
-    on a healed link plane (loss folded in-kernel) and the general branch
-    on a broken one — per-round health parity and bit-identical state
-    either way, at P=5 with joint-free masks."""
-    cfg = _chaos_cfg(G=6, P=5, collect_health=True, health_window=8)
-    G, P = cfg.n_groups, cfg.n_peers
-    st = settle(cfg, rounds=150)
-    crashed = jnp.zeros((P, G), bool)
-    append = jnp.ones((G,), jnp.int32)
-    link = jnp.ones((P, P, G), bool)
-    loss = _loss_plane(G, P, seed=3)
-    k = 4
-    fast = jax.jit(
-        pallas_step.fast_multi_round(cfg, k=k, with_chaos=True,
-                                     with_health=True)
-    )
-    general = _make_general_linked(cfg, crashed, append, has_h=True)
-    h = sim.init_health(cfg)
-    h = h._replace(
-        planes=h.planes.at[2].set(2).at[3].set(1), window_pos=jnp.int32(7)
-    )
-    a, b, ha, hb = st, st, h, h
-    rb = 150
-    # healed plane -> fused branch
-    assert bool(pallas_step.steady_predicate(cfg, a, crashed, k, link))
-    for blk in range(3):
-        a, _, ha = general(a, link, loss, rb, k, health=ha)
-        b, hb = fast(b, crashed, append, link, loss, jnp.int32(rb), hb)
-        for f in st._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(a, f)), np.asarray(getattr(b, f)),
-                err_msg=f"healed block {blk} field {f}",
-            )
-        np.testing.assert_array_equal(
-            np.asarray(ha.planes), np.asarray(hb.planes)
-        )
-        assert int(ha.window_pos) == int(hb.window_pos)
-        rb += k
-    # a single down link -> predicate rejects -> general branch, still exact
-    link_bad = link.at[0, 1, 0].set(False)
-    assert not bool(
-        pallas_step.steady_predicate(cfg, a, crashed, k, link_bad)
-    )
-    a, _, ha = general(a, link_bad, loss, rb, k, health=ha)
-    b, hb = fast(b, crashed, append, link_bad, loss, jnp.int32(rb), hb)
-    for f in st._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(a, f)), np.asarray(getattr(b, f)),
-            err_msg=f"general branch field {f}",
-        )
-    np.testing.assert_array_equal(
-        np.asarray(ha.planes), np.asarray(hb.planes)
-    )
-
-
-def test_fast_multi_round_counters_both_branches():
-    """The with_counters dispatcher: the closed-form fused fold (steady
-    start) and the scan-of-general branch (boot storm) both thread the
-    CTR_* plane exactly."""
-    from raft_tpu.multiraft import kernels
-
-    cfg = SimConfig(n_groups=8, n_peers=3)
-    k = 4
-    fast = jax.jit(
-        pallas_step.fast_multi_round(cfg, k=k, with_counters=True)
-    )
-    crashed = jnp.zeros((cfg.n_peers, cfg.n_groups), bool)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-    step_c = jax.jit(
-        lambda s, c: sim.step(cfg, s, crashed, append, counters=c)
-    )
-    for start in ("steady", "boot"):
-        st = settle(cfg) if start == "steady" else sim.init_state(cfg)
-        want_st, want_c = st, kernels.zero_counters()
-        for _ in range(k):
-            want_st, want_c = step_c(want_st, want_c)
-        got_st, got_c = fast(st, crashed, append, kernels.zero_counters())
-        np.testing.assert_array_equal(
-            np.asarray(want_c), np.asarray(got_c), err_msg=start
-        )
-        for f in st._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(want_st, f)),
-                np.asarray(getattr(got_st, f)),
-                err_msg=f"{start} field {f}",
-            )
-
-
 def test_plain_jaxpr_unchanged_by_new_flags():
-    """The chaos/counters machinery must not perturb the flag-off graphs:
-    steady_round and fast_multi_round trace identically with the new flags
-    defaulted and explicitly off (the packed/donated-path extension of the
-    PR 5 chaos-off jaxpr pin)."""
+    """The chaos/counters machinery must not perturb the flag-off graph:
+    steady_round traces identically with the new flags defaulted and
+    explicitly off (the packed/donated-path extension of the PR 5 chaos-off
+    jaxpr pin)."""
     cfg = SimConfig(n_groups=4, n_peers=3)
     st = sim.init_state(cfg)
     crashed = jnp.zeros((3, 4), bool)
@@ -530,16 +321,6 @@ def test_plain_jaxpr_unchanged_by_new_flags():
     flagged = jax.make_jaxpr(
         pallas_step.steady_round(
             cfg, rounds=2, with_chaos=False, with_counters=False
-        )
-    )(st, crashed, append)
-    assert str(base) == str(flagged)
-
-    base = jax.make_jaxpr(pallas_step.fast_multi_round(cfg, k=2))(
-        st, crashed, append
-    )
-    flagged = jax.make_jaxpr(
-        pallas_step.fast_multi_round(
-            cfg, k=2, with_chaos=False, with_counters=False
         )
     )(st, crashed, append)
     assert str(base) == str(flagged)
@@ -736,9 +517,8 @@ def test_damped_steady_mask_rejection_conditions(cq_settled):
 
 def test_damped_build_leaves_undamped_graphs_unchanged():
     """The damped kernel family must not perturb the undamped traces: a
-    config with the damping flags explicitly False builds byte-identical
-    steady_round / fast_multi_round jaxprs (the ISSUE 8 extension of the
-    flags-off pin)."""
+    config with the damping flags explicitly False builds a byte-identical
+    steady_round jaxpr (the ISSUE 8 extension of the flags-off pin)."""
     cfg = SimConfig(n_groups=4, n_peers=3)
     cfg_explicit = SimConfig(
         n_groups=4, n_peers=3, check_quorum=False, pre_vote=False
@@ -746,13 +526,11 @@ def test_damped_build_leaves_undamped_graphs_unchanged():
     st = sim.init_state(cfg)
     crashed = jnp.zeros((3, 4), bool)
     append = jnp.zeros((4,), jnp.int32)
-    for build in (
-        lambda c: pallas_step.steady_round(c, rounds=2),
-        lambda c: pallas_step.fast_multi_round(c, k=2),
-    ):
-        base = jax.make_jaxpr(build(cfg))(st, crashed, append)
-        explicit = jax.make_jaxpr(build(cfg_explicit))(st, crashed, append)
-        assert str(base) == str(explicit)
+    base, explicit = (
+        jax.make_jaxpr(pallas_step.steady_round(c, rounds=2))(st, crashed, append)
+        for c in (cfg, cfg_explicit)
+    )
+    assert str(base) == str(explicit)
 
 
 def test_damped_fused_parity_matrix_plain_health(cq_settled, cq_pv_settled):
@@ -847,95 +625,7 @@ def test_damped_fused_counters_closed_form(cq_settled, cq_pv_settled):
         _assert_state_equal(want_st, got_st, note)
 
 
-def test_damped_fused_chaos_both_branches():
-    """chaos × cq and chaos(+health) × cq+pv through the dispatcher: 18
-    k=4 blocks cross the election_tick=60 boundary window, so the
-    conservative free-running cq-boundary bound rejects some blocks —
-    BOTH lax.cond branches run and every block stays bit-identical
-    (state, health planes, recent_active) to k general
-    sim.step(link & ~loss_draw) rounds."""
-    for flags in (
-        dict(check_quorum=True),
-        dict(check_quorum=True, pre_vote=True, collect_health=True,
-             health_window=8),
-    ):
-        cfg = _chaos_cfg(**flags)
-        has_h = cfg.collect_health
-        G, P = cfg.n_groups, cfg.n_peers
-        st = settle(cfg, rounds=150)
-        crashed = jnp.zeros((P, G), bool)
-        append = jnp.ones((G,), jnp.int32)
-        link = jnp.ones((P, P, G), bool)
-        loss = _loss_plane(G, P)
-        k = DK
-        fast = jax.jit(
-            pallas_step.fast_multi_round(
-                cfg, k=k, with_chaos=True, with_health=has_h
-            )
-        )
-        general = _make_general_linked(cfg, crashed, append, has_h=has_h)
-        h0 = sim.init_health(cfg) if has_h else None
-        a, b, ha, hb = st, st, h0, h0
-        rb = 150
-        n_fused = n_gen = 0
-        blocks = 18 if has_h else 8
-        for blk in range(blocks):
-            pred = bool(
-                pallas_step.steady_predicate(cfg, b, crashed, k, link)
-            )
-            n_fused += pred
-            n_gen += not pred
-            a, _, ha = general(a, link, loss, rb, k, health=ha)
-            if has_h:
-                b, hb = fast(b, crashed, append, link, loss,
-                             jnp.int32(rb), hb)
-                np.testing.assert_array_equal(
-                    np.asarray(ha.planes), np.asarray(hb.planes)
-                )
-            else:
-                b = fast(b, crashed, append, link, loss, jnp.int32(rb))
-            _assert_state_equal(a, b, f"chaos {flags} block {blk}")
-            rb += k
-        assert n_fused > 0, flags
-        if has_h:
-            # the long run crosses the boundary window: the general
-            # branch must have been taken at least once too
-            assert n_gen > 0, flags
-
-
-@pytest.mark.slow  # compiles the full cond(fused, scan-of-general) graph
-def test_fast_multi_round_health_both_branches():
-    """fast_multi_round(with_health=True): the fused branch (steady start)
-    and the general branch (boot storm) both thread the planes exactly."""
-    cfg = SimConfig(n_groups=8, n_peers=3, collect_health=True, health_window=8)
-    k = 4
-    fast = pallas_step.fast_multi_round(cfg, k=k, with_health=True)
-    crashed = jnp.zeros((cfg.n_peers, cfg.n_groups), bool)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-
-    for start in ("steady", "boot"):
-        st = settle(cfg) if start == "steady" else sim.init_state(cfg)
-        h = sim.init_health(cfg)
-        want_st, want_h = st, h
-        for _ in range(k):
-            want_st, want_h = sim.step(
-                cfg, want_st, crashed, append, health=want_h
-            )
-        got_st, got_h = fast(st, crashed, append, h)
-        np.testing.assert_array_equal(
-            np.asarray(want_h.planes),
-            np.asarray(got_h.planes),
-            err_msg=start,
-        )
-        for f in st._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(want_st, f)),
-                np.asarray(getattr(got_st, f)),
-                err_msg=f"{start} field {f}",
-            )
-
-
-# --- ISSUE 11: per-group lossy cq bound, fused counting, hybrid chaos -------
+# --- ISSUE 11: the per-group lossy check-quorum bound ------------------------
 
 
 def test_cq_boundary_safe_per_group_lossy_bound():
@@ -1014,87 +704,3 @@ def test_steady_mask_loss_rate_per_group(cq_settled):
         pallas_step.steady_mask(cfg, st, crashed, k, link=link)
     )
     assert not old.any()
-
-
-def test_fast_multi_round_count_fused_plain():
-    """count_fused: the trailing int32 accumulator counts k * n_groups
-    group-rounds per fused block, 0 per fallback block, and the counted
-    dispatch stays bit-identical to k general steps."""
-    cfg = SimConfig(n_groups=8, n_peers=3)
-    k = 2
-    fast = pallas_step.fast_multi_round(cfg, k=k, count_fused=True)
-    crashed = jnp.zeros((cfg.n_peers, cfg.n_groups), bool)
-    append = jnp.ones((cfg.n_groups,), jnp.int32)
-    for start, want_count in (("steady", k * cfg.n_groups), ("boot", 0)):
-        st = settle(cfg) if start == "steady" else sim.init_state(cfg)
-        want = st
-        for _ in range(k):
-            want = sim.step(cfg, want, crashed, append)
-        got, fused = fast(st, crashed, append, jnp.int32(5))
-        assert int(fused) - 5 == want_count, start
-        for f in st._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(want, f)),
-                np.asarray(getattr(got, f)),
-                err_msg=f"{start} field {f}",
-            )
-
-
-@pytest.mark.slow  # damped chaos fused kernel + two general damped scans
-def test_hybrid_damped_chaos_per_group_split():
-    """hybrid_multi_round(with_chaos=True) on the damped configuration:
-    spread check-quorum boundary phases + per-group loss rates split the
-    batch PER GROUP — steady groups ride the fused damped chaos kernel,
-    boundary-crossing/lossy-bound groups take the general wave path with
-    their global group ids keying both seeded PRNG streams — and the
-    merge is bit-identical to k sequential sim.step(link & ~loss_draw)
-    rounds.  The count_fused accumulator reports exactly k x (steady
-    group count)."""
-    from raft_tpu.multiraft import kernels
-
-    G, P, k = 12, 3, 4
-    cfg = SimConfig(
-        n_groups=G, n_peers=P, election_tick=16, check_quorum=True,
-        pre_vote=True,
-    )
-    st = settle(cfg, rounds=3 * cfg.election_tick)
-    crashed = jnp.zeros((P, G), bool)
-    append = jnp.ones((G,), jnp.int32)
-    link = jnp.ones((P, P, G), bool)
-    loss = jnp.where(jnp.arange(G) % 2 == 0, kernels.LOSS_SCALE // 50, 0)
-    loss = jnp.broadcast_to(loss[None, None, :], (P, P, G)).astype(jnp.int32)
-    rb = jnp.int32(100)
-    # Spread the leaders' boundary phases deterministically so SOME lossy
-    # groups have an in-horizon boundary and some don't.
-    lead = np.array(st.state == kernels.ROLE_LEADER)
-    ee = np.array(st.election_elapsed)
-    phases = (np.arange(G) * 5) % cfg.election_tick
-    for g in range(G):
-        for p in range(P):
-            if lead[p, g]:
-                ee[p, g] = phases[g]
-    st = st._replace(election_elapsed=jnp.asarray(ee))
-    mask = pallas_step.steady_mask(
-        cfg, st, crashed, horizon=k, link=link, loss_rate=loss
-    )
-    n_steady = int(mask.sum())
-    assert 0 < n_steady < G, "fixture must mix fused and storm groups"
-
-    ref = st
-    for r in range(k):
-        lk = link & ~kernels.link_loss_draw(rb + r, loss)
-        ref = sim.step(cfg, ref, crashed, append, link=lk)
-
-    fn = pallas_step.hybrid_multi_round(
-        cfg, k=k, storm_slots=8, with_chaos=True, count_fused=True
-    )
-    out, fused = jax.jit(fn)(
-        st, crashed, append, link, loss, rb, jnp.int32(0)
-    )
-    assert int(fused) == k * n_steady
-    for f in st._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ref, f)),
-            np.asarray(getattr(out, f)),
-            err_msg=f"field {f}",
-        )

@@ -455,7 +455,7 @@ def crash_run():
     cfg = s.cfg
     client = workload.compile_plan(plan, G)
     cc = chaos.compile_plan(cplan, G)
-    body = jax.jit(reconfig._runner_body(
+    body = jax.jit(runner_mod._runner_body(
         cfg, reconfig.empty_reconfig_schedule(ROUNDS, P, G), cc,
         client=client))
     zeros = lambda n: jnp.zeros((n,), jnp.int32)  # noqa: E731

@@ -1,6 +1,6 @@
 """workload.fold_latencies — one round's served reads into the latency
 histogram — against np.bincount of the served latencies (ISSUE 36).  `lat`
-is made as reconfig._runner_body makes it: clip(r - psince, 0, cap) with
+is made as runner._runner_body makes it: clip(r - psince, 0, cap) with
 the cap off the histogram's own length."""
 
 import numpy as np

@@ -3,7 +3,7 @@
 Each case replays one named scenario from
 tests/testdata/reconfig/plans.json — a ReconfigPlan paired with the
 ChaosPlan it rides through (host-materialized schedule masks, the
-propose/gate/apply protocol of reconfig._runner_body applied eagerly —
+propose/gate/apply protocol of runner._runner_body applied eagerly —
 bit-identical to the compiled scan, tests/test_reconfig_parity.py) — and
 records the end-state health planes, consensus cursors, final config
 masks, op-protocol outcome, and the per-round safety counts.  The five

@@ -1,6 +1,6 @@
 """Split-horizon reconfig execution (ISSUE 11).
 
-Three claims are pinned here:
+Four claims are pinned here:
 
   1. the split-point planner (`reconfig.plan_split_points` /
      `reconfig.split_plan`) tiles the horizon exactly, opens general
@@ -14,10 +14,13 @@ Three claims are pinned here:
      actually engaging the fused
      kernel (fused_rounds > 0) on the steady stretches between ops;
   3. the ClusterSim.run_reconfig(split=True) wiring reports the measured
-     fused fraction.
+     fused fraction;
+  4. the split runner — the one fused / general dispatcher (ISSUE 50) —
+     equals one sim.step a round on schedules with elections, crashes,
+     lossy and faulted links, both arms taken in every run.
 
-Tier-1 keeps the planner battery (pure host, no compiles) and ONE undamped
-G=8 split-vs-unsplit parity case; the G=32 production composition (health +
+Tier-1 keeps the planner battery (pure host, no compiles), ONE undamped
+G=8 split-vs-unsplit parity case and claim 4's five G=8 scenarios; the G=32 production composition (health +
 counters + chaos + cq + pv) and the ClusterSim wiring case are
 @pytest.mark.slow (long cases; tier-1 takes 247 s of its 1470 s limit under
 xdist -n 6 at PR 32; tests/test_example_plans.py runs the shipped production
@@ -289,7 +292,7 @@ def test_split_runner_prod_composition_g32():
     fused, ctrs = int(out2[6]), out2[7]
     assert 0 < fused < plan.n_rounds * G
     # Counters: exact vs the per-round with_counters body, stepped.
-    body = reconfig._runner_body(cfg, compiled, ccompiled, with_counters=True)
+    body = runner_mod._runner_body(cfg, compiled, ccompiled, with_counters=True)
     st0, hl0, rst0 = fresh()
     carry = (
         st0, hl0, rst0,
@@ -344,3 +347,94 @@ def test_cluster_sim_run_reconfig_split_report():
     assert cs._rounds_since_drain == 0
     totals = cs.counters()
     assert totals["heartbeats"] > 0 and totals["commit_entries"] > 0
+
+
+# --- claim 4: the one dispatcher against k sequential sim.steps -------------
+#
+# The scenarios the removed pallas_step dispatchers' tests ran (ISSUE 50), on
+# the dispatcher that stays: `make_runner(..., split=True)` with the no-op
+# membership schedule over a chaos plan, held — state, health planes, counter
+# plane — to one jitted `sim.step` a round on `chaos.HostSchedule`'s masks.
+
+SEQ_K = 4
+
+
+def _phases(*docs):
+    return chaos.plan_from_dict({"name": "seq", "peers": 3, "phases": list(docs)})
+
+
+SEQ_LOSSY = _phases({"rounds": 72, "append": 1, "loss": [
+    {"from": 1, "to": 2, "rate": 0.3}, {"from": 2, "to": 1, "rate": 0.5},
+    {"from": 3, "to": 2, "rate": 0.7}]})
+SEQ_DAMPED = dict(election_tick=60, check_quorum=True, pre_vote=True, health_window=8)
+# name -> (SimConfig flags, rounds settled before the plan, plan, counters threaded)
+SEQ_SCENARIOS = {
+    # The boot storm (no block can fuse), settled blocks, store 1 lost in the
+    # even groups (those it led elect again), its return and the re-sync: an
+    # election, a crash and a recovery across block boundaries, both arms.
+    "election-crash-recovery": ({}, 0, _phases(
+        {"rounds": 24, "append": 1},
+        {"rounds": 16, "append": 1, "crash": [1], "groups": {"mod": 2, "eq": 0}},
+        {"rounds": 24, "append": 1}), False),
+    # The counter plane's closed form on the fused arm, its per-round fold on
+    # the general arm (the boot storm's campaigns and wins), in one run.
+    "counters-both-arms": ({}, 0, _phases({"rounds": 40, "append": 1}), True),
+    # The damped chaos kernel drawing real loss, cq alone and cq + pre-vote:
+    # 72 rounds cross the leaders' check-quorum boundary window at tick 60,
+    # so the lossy bound sends some blocks to the general arm.
+    "damped-lossy-link-cq": (dict(SEQ_DAMPED, pre_vote=False), 150, SEQ_LOSSY, False),
+    "damped-lossy-link-cq-pv": (SEQ_DAMPED, 150, SEQ_LOSSY, True),
+    # A link that is down is never fused over: general while it is faulted,
+    # the damped kernel again once it heals.
+    "damped-faulted-link": (SEQ_DAMPED, 150, _phases(
+        {"rounds": 16, "append": 1},
+        {"rounds": 16, "append": 1, "links": [{"from": 1, "to": 2, "up": False}]},
+        {"rounds": 24, "append": 1}), False),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(SEQ_SCENARIOS))
+def test_split_runner_equals_sequential_steps(scenario):
+    flags, settle, cplan, with_counters = SEQ_SCENARIOS[scenario]
+    G, P = 8, 3
+    cfg = SimConfig(n_groups=G, n_peers=P, collect_health=True, **flags)
+    st = sim_mod.init_state(cfg)
+    if settle:
+        cs = ClusterSim(cfg)
+        cs.run_compiled(settle, append_n=jnp.ones((G,), jnp.int32))
+        st = jax.tree.map(jnp.copy, cs.state)
+    zero_ctrs = kernels.zero_counters() if with_counters else None
+
+    @jax.jit
+    def one_round(st, hl, ctrs, link, crashed, append):
+        return sim_mod.step(cfg, st, crashed, append, counters=ctrs, health=hl, link=link)
+
+    host = chaos.HostSchedule(cplan, G)
+    want_st, want_hl, want_ctrs = jax.tree.map(jnp.copy, st), sim_mod.init_health(cfg), zero_ctrs
+    for r in range(cplan.n_rounds):
+        link, crashed, append = host.masks(r)
+        out = one_round(want_st, want_hl, want_ctrs, jnp.asarray(link),
+                        jnp.asarray(crashed), jnp.asarray(append, jnp.int32))
+        if with_counters:
+            want_st, want_ctrs, want_hl = out
+        else:
+            want_st, want_hl = out
+
+    runner = runner_mod.make_runner(
+        cfg, (reconfig.empty_reconfig_schedule(cplan.n_rounds, P, G),
+              chaos.compile_plan(cplan, G)),
+        split=True, k=SEQ_K, with_counters=with_counters,
+    )
+    extra = (zero_ctrs,) if with_counters else ()
+    out = runner(st, sim_mod.init_health(cfg), reconfig.init_reconfig_state(st), *extra)
+    for f in FIELDS:
+        a, b = getattr(want_st, f), getattr(out[0], f)
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"state {f}")
+    np.testing.assert_array_equal(np.asarray(want_hl.planes), np.asarray(out[1].planes))
+    assert int(want_hl.window_pos) == int(out[1].window_pos)
+    if with_counters:
+        np.testing.assert_array_equal(np.asarray(want_ctrs), np.asarray(out[7]))
+    assert not np.asarray(out[5]).any(), "safety violations"
+    # Both arms in the one run.
+    assert 0 < int(out[6]) < cplan.n_rounds * G, int(out[6])

@@ -1,5 +1,5 @@
 """The general round's map (ISSUE 42): names on every op of a
-`reconfig._runner_body` round, and nothing but names.
+`runner._runner_body` round, and nothing but names.
 
   * the names change no equation: with `profiling.scope` patched to a null
     context before `raft_tpu.multiraft` is imported (a child process: the

@@ -442,6 +442,37 @@ def test_split_runner_bit_identical_and_fuses():
     assert rd[workload.RS_SERVED_QUORUM] > 0
 
 
+@pytest.mark.parametrize("damping", [{}, {"check_quorum": True, "pre_vote": True}],
+                         ids=["undamped", "cq-pv"])
+def test_run_reads_split_equals_sequential_steps(damping):
+    """ClusterSim.run_reads(split=True) from a COLD boot under a write load,
+    held to one sim.step a round: the boot storm's blocks take the general
+    arm (elections in progress reject the predicate), the settled ones the
+    fused kernel, and the fleet ends where k sequential general rounds leave
+    it (the full-schedule parity the removed pallas_step dispatchers' tests
+    held, ISSUE 50)."""
+    G, P, rounds, k = 8, 3, 96, 4
+    cfg = SimConfig(n_groups=G, n_peers=P, collect_health=True, **damping)
+    plan = workload.ClientPlan(
+        name="cold-boot", n_peers=P, phases=[workload.ClientPhase(rounds=rounds, append=1)],
+    )
+    cs = ClusterSim(cfg)
+    report = cs.run_reads(plan, split=True, split_k=k)
+    assert 0 < report["fused_frac"] < 1, report["fused_frac"]
+    assert not any(report["safety"].values())
+
+    step_fn = jax.jit(lambda st, hl: sim.step(
+        cfg, st, jnp.zeros((P, G), bool), jnp.ones((G,), jnp.int32), health=hl))
+    st, hl = sim.init_state(cfg), sim.init_health(cfg)
+    for _ in range(rounds):
+        st, hl = step_fn(st, hl)
+    for f in st._fields:
+        if getattr(st, f) is not None:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(st, f)), np.asarray(getattr(cs.state, f)), err_msg=f)
+    np.testing.assert_array_equal(np.asarray(hl.planes), np.asarray(cs._health.planes))
+
+
 def test_steady_mask_read_pending_rejects():
     """The read_pending rejection arm: a settled steady batch accepts the
     horizon, and the same batch with read_pending set rejects exactly the

@@ -23,10 +23,11 @@ fails to undercut the undamped run on the asymmetric-link scenario — the
 churn collapse this PR exists to demonstrate.
 
 With `--fused` (the CI setting since ISSUE 8) each scenario's damped
-half ALSO replays through the fused damped dispatcher
-(pallas_step.fast_multi_round's lax.cond — fused steady rounds and
-general chaos rounds both covered) and the run exits non-zero if any
-churn stat diverges from the scan-damped run, pinning that fusion
+half ALSO replays through the split-horizon runner
+(ClusterSim.run_reconfig(split=True) with the no-op membership schedule:
+runner.make_runner's fused blocks where the steady predicate holds, the
+general round elsewhere — both arms covered) and the run exits non-zero if
+any churn stat diverges from the scan-damped run, pinning that fusion
 cannot change churn results.
 
 On a nonzero safety count the step no longer fails with bare counts
@@ -52,13 +53,26 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# (groups, n_peers) -> (SimConfig, jitted fused dispatcher, jitted general
-# step), shared across the corpus so each graph compiles once.
-_FUSED_CACHE: dict = {}
+# Fused block length of the --fused replay.  The corpus plans are tens of
+# rounds from a cold boot at election_tick 10: a block is fused only when
+# the WHOLE fleet is steady for it, and at the runner's default of 8 no
+# block of the corpus is.
+SPLIT_K = 2
 
 
-def run_config(doc: dict, groups: int, damped: bool) -> dict:
+def run_config(
+    doc: dict, groups: int, damped: bool, split: bool = False
+) -> dict:
+    """One corpus scenario from a fresh boot, through the compiled chaos
+    scan or — `split` — through the split-horizon runner
+    (runner.make_runner(..., split=True) under ClusterSim.run_reconfig,
+    with the no-op membership schedule): every block is the fused kernel
+    where the steady predicate holds for it — healed or merely lossy
+    phases — and the general round otherwise, so BOTH arms get
+    golden-corpus coverage.  The caller diffs the split run's churn stats
+    against the scan's to pin that fusion cannot change churn results."""
     from raft_tpu.multiraft import ClusterSim, SimConfig, chaos, kernels
+    from raft_tpu.multiraft import reconfig
 
     plan = chaos.plan_from_dict(doc)
     cfg = SimConfig(
@@ -68,11 +82,22 @@ def run_config(doc: dict, groups: int, damped: bool) -> dict:
         check_quorum=damped,
         pre_vote=damped,
     )
-    sim = ClusterSim(cfg, chaos=plan)
-    report = sim.run_plan()
+    if split:
+        sim = ClusterSim(cfg)
+        report = sim.run_reconfig(
+            reconfig.empty_reconfig_schedule(
+                plan.n_rounds, plan.n_peers, groups
+            ),
+            plan,
+            split=True,
+            split_k=SPLIT_K,
+        )
+    else:
+        sim = ClusterSim(cfg, chaos=plan)
+        report = sim.run_plan()
     planes = np.asarray(sim._health.planes)
     term = np.asarray(sim.state.term)
-    return {
+    out = {
         "mttr_rounds": report["mttr_rounds"],
         "reelections": report["reelections"],
         "max_leaderless_streak": report["max_leaderless_streak"],
@@ -81,89 +106,16 @@ def run_config(doc: dict, groups: int, damped: bool) -> dict:
         "vote_splits": int(planes[kernels.HP_VOTE_SPLITS].max()),
         "safety": report["safety"],
     }
+    if split:
+        out["fused_rounds"] = report["fused_rounds"]
+        out["total_rounds"] = report["total_rounds"]
+    return out
 
 
-def run_config_fused(doc: dict, groups: int) -> dict:
-    """Replay the damped configuration through the FUSED damped
-    dispatcher (ISSUE 8): fully-healed rounds go through
-    pallas_step.fast_multi_round(k=1)'s lax.cond — fused when the damped
-    steady predicate holds, the general damped wave otherwise, so BOTH
-    branches get golden-corpus coverage — and chaos rounds run the same
-    link-gated general step the compiled scan uses.  The caller diffs the
-    churn stats against the scan-damped run to pin that fusion cannot
-    change churn results."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from raft_tpu.multiraft import SimConfig, chaos, kernels, pallas_step
-    from raft_tpu.multiraft import sim as sim_mod
-
-    plan = chaos.plan_from_dict(doc)
-    # One compile per (groups, n_peers) across the whole corpus: a fresh
-    # fast_multi_round closure per scenario would re-trace and re-compile
-    # the identical both-branches damped cond graph six times over.
-    key = (groups, plan.n_peers)
-    if key not in _FUSED_CACHE:
-        cfg = SimConfig(
-            n_groups=groups,
-            n_peers=plan.n_peers,
-            collect_health=True,
-            check_quorum=True,
-            pre_vote=True,
-        )
-        _FUSED_CACHE[key] = (
-            cfg,
-            jax.jit(
-                pallas_step.fast_multi_round(cfg, k=1, with_health=True)
-            ),
-            jax.jit(functools.partial(sim_mod.step, cfg)),
-        )
-    cfg, fast, general = _FUSED_CACHE[key]
-    sched = chaos.HostSchedule(plan, groups)
-    st = sim_mod.init_state(cfg)
-    h = sim_mod.init_health(cfg)
-    safety = np.zeros(kernels.N_SAFETY, np.int64)
-    prev_commit = np.asarray(st.commit)
-    n_fused = n_dispatched = 0
-    for r in range(plan.n_rounds):
-        link, crashed, append = sched.masks(r)
-        cj = jnp.asarray(crashed)
-        aj = jnp.asarray(append, dtype=jnp.int32)
-        if bool(link.all()):
-            # Fully-healed round: bit-identical to link=None, so it can
-            # ride the (lossless-branch) fused dispatcher.
-            n_dispatched += 1
-            n_fused += bool(
-                pallas_step.steady_predicate(cfg, st, cj, horizon=1)
-            )
-            st, h = fast(st, cj, aj, h)
-        else:
-            st, h = general(st, cj, aj, link=jnp.asarray(link), health=h)
-        safety += np.asarray(
-            kernels.check_safety(
-                st.state, st.term, st.commit, st.last_index, st.agree,
-                jnp.asarray(prev_commit),
-            )
-        )
-        prev_commit = np.asarray(st.commit)
-    planes = np.asarray(h.planes)
-    term = np.asarray(st.term)
-    return {
-        "max_term": int(term.max()),
-        "peak_term_bumps": int(planes[kernels.HP_TERM_BUMPS].max()),
-        "vote_splits": int(planes[kernels.HP_VOTE_SPLITS].max()),
-        "fused_rounds": n_fused,
-        "dispatched_rounds": n_dispatched,
-        "rounds": plan.n_rounds,
-        "safety": dict(
-            zip(kernels.SAFETY_NAMES, (int(v) for v in safety))
-        ),
-    }
-
-
-FUSED_COMPARE_KEYS = ("max_term", "peak_term_bumps", "vote_splits")
+FUSED_COMPARE_KEYS = (
+    "mttr_rounds", "reelections", "max_leaderless_streak", "max_term",
+    "peak_term_bumps", "vote_splits",
+)
 
 
 def main() -> int:
@@ -172,9 +124,9 @@ def main() -> int:
     ap.add_argument(
         "--fused",
         action="store_true",
-        help="also run each scenario's damped half through the fused "
-        "damped dispatcher (pallas_step.fast_multi_round) and fail if "
-        "any churn stat diverges from the scan-damped run",
+        help="also run each scenario's damped half through the split "
+        "runner (fused blocks where the steady predicate holds) and fail "
+        "if any churn stat diverges from the scan-damped run",
     )
     ap.add_argument("--out", default="chaos-churn-report.json")
     ap.add_argument(
@@ -213,7 +165,7 @@ def main() -> int:
         }
         checked = (("undamped", undamped), ("damped", damped))
         if args.fused:
-            fused = run_config_fused(doc, args.groups)
+            fused = run_config(doc, args.groups, damped=True, split=True)
             out["plans"][name]["damped_fused"] = fused
             checked = checked + (("damped_fused", fused),)
             total_fused += fused["fused_rounds"]
@@ -235,8 +187,8 @@ def main() -> int:
         )
     if args.fused and total_fused == 0:
         failed.append(
-            "no golden-corpus round engaged the fused damped branch; the "
-            "both-branches coverage claim is vacuous (predicate rot?)"
+            "no golden-corpus block engaged the fused damped arm; the "
+            "both-arms coverage claim is vacuous (predicate rot?)"
         )
     # The headline claim: damping collapses the asymmetric-partition term
     # inflation (the PR 5 pinned pathology).  The scenario MUST be in the

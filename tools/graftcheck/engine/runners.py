@@ -52,8 +52,9 @@ _GATHERS = {"round", "phase", "op", "fire", "fold"}
 _DTYPES = {"int32", "uint32", "bool"}
 
 # The modules whose schedule handling must go through the registry
-# accessors — the runner factory, the schedule modules, the autopilot,
-# and sim.py's dispatch sites.
+# accessors — the runner factory (with the shared scan body,
+# runner._runner_body), the schedule modules, the autopilot, and sim.py's
+# dispatch sites.
 _RUNNER_MODULES = (
     "chaos", "reconfig", "workload", "autopilot", "runner", "sim",
 )
@@ -416,8 +417,58 @@ def _check_runner_module(sched, sf: SourceFile) -> Iterator[Violation]:
     for top in ast.iter_child_nodes(sf.ast_tree):
         if isinstance(top, ast.FunctionDef):
             yield from _closure_consts(
-                sf, top, set(), arrays, call_funcs
+                sf, top, set(), arrays, call_funcs,
+                _traced_params(sf.ast_tree, top),
             )
+
+
+def _traced_params(tree: ast.Module, top: ast.FunctionDef) -> Set[str]:
+    """Parameters of the PRIVATE top-level body factory `top` (the scan
+    body runner._runner_body) that provably arrive traced: every call of
+    `top` in the module sits inside a nested def — a traced run function,
+    never a constructor's own scope — and hands the parameter a name bound
+    in that def's own scope (a schedule rebuilt from the jit's runtime
+    args).  The factory's nested body may read schedule arrays off such a
+    parameter; with no call in the module, or one that passes anything
+    else, the parameter is a closure const like any other."""
+    from ..core import walk_local
+
+    if not top.name.startswith("_"):
+        return set()
+    positional = [a.arg for a in top.args.posonlyargs + top.args.args]
+    ok: Set[str] = set(positional) | {a.arg for a in top.args.kwonlyargs}
+    seen = False
+
+    def visit(func: ast.FunctionDef, depth: int) -> None:
+        nonlocal seen
+        bound = _bound_names(func)
+        for node in walk_local(func):
+            if isinstance(node, ast.FunctionDef):
+                visit(node, depth + 1)
+            elif (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)
+                and node.func.id == top.name
+            ):
+                seen = True
+                passed = dict(zip(positional, node.args))
+                passed.update(
+                    {kw.arg: kw.value for kw in node.keywords if kw.arg}
+                )
+                for name, value in passed.items():
+                    if isinstance(value, ast.Constant):
+                        continue
+                    if not (
+                        depth > 0
+                        and isinstance(value, ast.Name)
+                        and value.id in bound
+                    ):
+                        ok.discard(name)
+
+    for node in ast.iter_child_nodes(tree):
+        if isinstance(node, ast.FunctionDef):
+            visit(node, 0)
+    return ok if seen else set()
 
 
 def _closure_consts(
@@ -426,10 +477,11 @@ def _closure_consts(
     outer: Set[str],
     arrays: Set[str],
     call_funcs: Set[int],
+    traced: Set[str] = frozenset(),
 ) -> Iterator[Violation]:
     from ..core import walk_local
 
-    bound = _bound_names(func)
+    bound = _bound_names(func) - traced
     nested: List[ast.FunctionDef] = []
     for node in walk_local(func):
         if isinstance(node, ast.FunctionDef):

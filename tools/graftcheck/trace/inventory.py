@@ -4,7 +4,7 @@ Each ``GraphSpec`` names one compiled artifact of the production system —
 entry point + flag combination + the donation structure its production
 wrapper declares — and a builder that constructs it EXACTLY the way the
 production wrapper does (``ClusterSim``'s jits, ``runner.make_runner``,
-``sharding.sharded_step``, the fused dispatchers), at a tiny audit shape
+``sharding.sharded_step``), at a tiny audit shape
 (G=8, P=3: jaxpr size and donation structure are shape-independent, so
 the audit shape only has to be cheap).  ``trace/analysis.py`` runs
 GC011-GC014 over the built artifacts; ``jaxpr_budget.json`` is keyed by
@@ -37,7 +37,7 @@ from typing import Callable, Dict, List, NamedTuple, Tuple
 G = 8
 P = 3
 SCAN_ROUNDS = 4  # run_compiled segment length in the audit graphs
-DISPATCH_K = 4  # fused-dispatcher horizon in the audit graphs
+DISPATCH_K = 4  # the split runners' fused block length in the audit graphs
 
 # Per-graph jaxpr-const byte budget (GC012).  The healthy graphs carry
 # only scalar/iota-sized consts (<= 64B observed across the whole
@@ -93,11 +93,6 @@ COLLECTIVE_ALLOW: Dict[Tuple[str, str], str] = {
     ): "the event-counter fold (kernels.count_events) psums per-round "
        "event counts into the [N_COUNTERS] replicated plane — the "
        "instrumented configuration's documented ICI cost, off by default",
-    (
-        "sharded_dispatch@spmd", "all-reduce",
-    ): "fast_multi_round's fused-vs-general lax.cond predicate "
-       "(pallas_step.steady_mask) is a global all() — one scalar "
-       "all-reduce per K-round block, amortized 1/K per round",
 }
 
 
@@ -244,29 +239,6 @@ def _read_index_builder(chaos: bool):
         fn = jax.jit(functools.partial(sim.read_index, cfg))
         args = (st, crashed) + ((_full_link(),) if chaos else ())
         return Built(fn, args)
-
-    return build
-
-
-def _dispatcher_builder(damping: dict, with_health: bool):
-    def build() -> Built:
-        import jax
-
-        from raft_tpu.multiraft import pallas_step
-
-        sim = _sim()
-        cfg = sim.SimConfig(n_groups=G, n_peers=P, **damping)
-        # On the audit's CPU mesh pallas builds in interpret mode: the
-        # pallas_call wrapping differs from the Mosaic one but the kernel
-        # jaxpr inside (what GC014 counts) does not.
-        fn = pallas_step.fast_multi_round(
-            cfg, k=DISPATCH_K, with_health=with_health
-        )
-        st, crashed, append_n = _base_args(cfg)
-        args: tuple = (st, crashed, append_n)
-        if with_health:
-            args = args + (sim.init_health(cfg),)
-        return Built(jax.jit(fn), args)
 
     return build
 
@@ -725,26 +697,6 @@ def _sharded_drain_builder():
     return build
 
 
-def _sharded_dispatch_builder():
-    """fast_multi_round under the mesh: the fused kernel (interpret mode
-    partitions as plain XLA ops), the k general steps, and the steady-
-    predicate cond — the per-shard fused-block ride of ISSUE 14."""
-
-    def build() -> Built:
-        import jax
-
-        from raft_tpu.multiraft import pallas_step
-
-        sim = _sim()
-        cfg = sim.SimConfig(n_groups=G_SHARDED, n_peers=P, spmd=True)
-        mesh = _sharded_mesh()
-        st, crashed, append_n = _sharded_args(cfg, mesh)
-        fn = pallas_step.fast_multi_round(cfg, k=DISPATCH_K)
-        return Built(jax.jit(fn), (st, crashed, append_n))
-
-    return build
-
-
 # --- the registry -----------------------------------------------------------
 
 # builder key (schedules.RunnerVariant.builder) -> the local builder
@@ -878,25 +830,6 @@ def _specs() -> List[GraphSpec]:
             audit_donation=False,
         )
     )
-    pallas_py = "raft_tpu/multiraft/pallas_step.py"
-    out.append(
-        GraphSpec(
-            # fast_multi_round's cond carries BOTH branches (fused kernel
-            # + k general steps) in one graph — the budget covers both.
-            name=f"dispatch{DISPATCH_K}@plain",
-            anchor=pallas_py,
-            build=_dispatcher_builder({}, with_health=False),
-        )
-    )
-    out.append(
-        GraphSpec(
-            name=f"dispatch{DISPATCH_K}@health+cq+pv",
-            anchor=pallas_py,
-            build=_dispatcher_builder(
-                {"check_quorum": True, "pre_vote": True}, with_health=True
-            ),
-        )
-    )
     out.append(
         GraphSpec(
             # The forensics-instrumented round (ISSUE 15): health + the
@@ -980,17 +913,6 @@ def _specs() -> List[GraphSpec]:
             # registered all-reduce/all-gather set and nothing else.
             name="sharded_drain@health", anchor=sharding_py,
             build=_sharded_drain_builder(),
-            audit_collectives=True,
-        )
-    )
-    out.append(
-        GraphSpec(
-            # The fused dispatcher riding per-shard (ISSUE 14): only the
-            # steady-predicate cond's scalar all-reduce, once per K-round
-            # block.
-            name="sharded_dispatch@spmd",
-            anchor="raft_tpu/multiraft/pallas_step.py",
-            build=_sharded_dispatch_builder(),
             audit_collectives=True,
         )
     )
