@@ -343,6 +343,21 @@ def schedule_planes(
     return link, _loss_plane(compiled, ph), crashed, compiled.append[ph]
 
 
+def phase_faulted(compiled: CompiledChaos) -> jnp.ndarray:
+    """bool[NPH]: the phase has a fault in some group — a crashed peer, a
+    link that is down or a loss rate — read off the packed planes.  What
+    the workload split runner tables per block (workload.BlockRows.faulted)
+    and counts (`blocks_faulted`): a fused block inside such a phase runs
+    beside the fault, one outside it between two."""
+    P = compiled.n_peers
+    healed = kernels.pack_bits(jnp.ones((P * P, 1), bool))  # [Wl, 1]
+    return (
+        jnp.any(compiled.link_packed != healed[None], axis=(1, 2))
+        | jnp.any(compiled.crashed_packed != 0, axis=(1, 2))
+        | jnp.any(compiled.loss_packed != 0, axis=(1, 2))
+    )
+
+
 @profiling.scope("runner.chaos_masks")
 def schedule_masks(
     compiled: CompiledChaos,

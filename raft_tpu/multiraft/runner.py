@@ -942,23 +942,90 @@ def _make_workload(
     return runner
 
 
+@profiling.scope("runner.guard_refusals")
+def _guard_refusals(
+    cfg: sim_mod.SimConfig,
+    st: sim_mod.SimState,
+    crashed: jnp.ndarray,  # gc: bool[P, G]
+    horizon: int,
+    loss_rate: jnp.ndarray,  # gc: int32[P, P, G]
+    read_pending: jnp.ndarray,  # gc: bool[G]
+) -> jnp.ndarray:
+    """int32[len(workload.GUARD_TERMS)]: the groups each term of a chaos
+    block's guard refuses at this block's entry — pallas_step.steady_mask's
+    campaign bound, its one alive leader, its uniform terms and its
+    check-quorum boundary (the `link=` / `loss_rate=` forms the block's
+    guard takes), and the read rejection.  A group refused by two terms
+    counts under both.  It DECIDES nothing: steady_mask does, and
+    tests/test_workload_split_chaos.py holds these terms' conjunction
+    equal to it.  Only a block that did NOT fuse counts (the general arm
+    calls it, beside k general rounds): a fused block pays nothing.
+
+    The planes are read behind an optimization barrier and the five counts
+    leave through ONE reduce over the group axis, as
+    chaos.fold_learner_lag's count does (PERF.md section 6, PR 47): fused
+    into the guard's producers the same arithmetic would regroup them."""
+    state, term, elapsed, timeout, voter, outgoing, ra, crashed = (
+        jax.lax.optimization_barrier((
+            st.state, st.term, st.election_elapsed, st.randomized_timeout,
+            st.voter_mask, st.outgoing_mask, st.recent_active, crashed,
+        ))
+    )
+    alive = ~crashed
+    may_fire = (state != kernels.ROLE_LEADER) & voter & (
+        elapsed + horizon >= timeout
+    )
+    is_leader = (state == kernels.ROLE_LEADER) & alive
+    lead_term = jnp.max(jnp.where(is_leader, term, 0), axis=0)
+    refused = [
+        jnp.any(may_fire, axis=0),
+        jnp.sum(is_leader, axis=0, dtype=jnp.int32) != 1,
+        ~jnp.all(jnp.where(alive, term == lead_term, True), axis=0),
+        (
+            ~kernels.cq_boundary_safe(
+                ra, voter, outgoing, state, crashed, elapsed, horizon,
+                cfg.election_tick,
+                lossy=jnp.any(loss_rate != 0, axis=(0, 1)),
+            )
+            if cfg.check_quorum
+            else jnp.zeros_like(read_pending)
+        ),
+        read_pending,
+    ]
+    counts = jax.lax.reduce(
+        tuple(r.astype(jnp.int32) for r in refused),
+        (jnp.int32(0),) * len(refused),
+        lambda a, b: tuple(x + y for x, y in zip(a, b)),
+        (0,),
+    )
+    return jnp.stack(counts)
+
+
 def _make_workload_split(
     cfg: sim_mod.SimConfig,
     client: workload_mod.CompiledClient,
     k: int,
-    chaos_compiled,
+    chaos_compiled: Optional[chaos_mod.CompiledChaos],
     reconfig_compiled,
 ):
     """The fused client-workload runner: k-round blocks behind the
     steady + provably-servable-lease predicate, lease receipts folded
-    closed-form on the fast arm."""
+    closed-form on the fast arm.  With a chaos plan a block is also one
+    chaos phase: its guard and its kernel take the link, loss and crash
+    planes of the block's first round (chaos.schedule_planes, as the
+    reconfig split runner's fused_block_run), the general arm and the tail
+    scan _runner_body with the chaos schedule, and the carry ends in
+    (healthy_refused, guard_refusals[len(workload.GUARD_TERMS)]) — the
+    blocks outside a faulted phase that did not fuse, and what the guard
+    refused in them, by term.  With none the programs are those of a bare
+    client plan, equation for equation.  A reconfig plan stays refused."""
     from . import pallas_step
 
-    if chaos_compiled is not None or reconfig_compiled is not None:
+    if reconfig_compiled is not None:
         raise ValueError(
-            "the workload split runner runs bare client plans; compose "
-            "chaos/reconfig schedules through the unsplit runner (or the "
-            "reconfig split runner) instead"
+            "the workload split runner runs a client plan, bare or under "
+            "a chaos plan; compose a reconfig schedule through the unsplit "
+            "runner (or the reconfig split runner) instead"
         )
     if cfg.blackbox:
         raise ValueError(
@@ -979,45 +1046,81 @@ def _make_workload_split(
             f"{cfg.health_window}: the closed-form health fold handles "
             "at most one churn-window crossing per block"
         )
-    workload_mod._validate(cfg, client, None, None)
+    workload_mod._validate(cfg, client, chaos_compiled, None)
     reconfig_sched = reconfig_mod.empty_reconfig_schedule(
         client.n_rounds, cfg.n_peers, cfg.n_groups
     )
+    _validate_plans(cfg, reconfig_sched, chaos_compiled)
+    chaos_on = chaos_compiled is not None
+    # A plan with no loss rate keeps the kernel of a bare plan: with every
+    # rate 0 the in-kernel draw knocks out nothing, so the kernel without
+    # the loss operand and the draw is the same k rounds.
+    lossless = chaos_on and chaos_compiled.lossless
+    kernel_loss = chaos_on and not lossless
     n_rounds = client.n_rounds
     P, G = cfg.n_peers, cfg.n_groups
     n_blocks, tail = n_rounds // k, n_rounds % k
     n_client = len(schedules_mod.array_fields("client"))
-    fused_fn = pallas_step.steady_round(cfg, rounds=k, with_health=True)
+    fused_fn = pallas_step.steady_round(
+        cfg, rounds=k, with_health=True, with_chaos=kernel_loss
+    )
 
-    def _rebuild_client(sched_args):
+    def _rebuild(sched_args):
         csched = rebuild("client", client, sched_args)
-        sched, _ = rebuild_scheds(
-            reconfig_sched, None, sched_args[n_client:]
+        sched, chaos_sched = rebuild_scheds(
+            reconfig_sched, chaos_compiled, sched_args[n_client:]
         )
-        return csched, sched
+        return csched, sched, chaos_sched
 
     def tables_run(starts, *sched_args):
         """Every block's workload.BlockRows and the write load of the
         rounds `starts` (one block start per distinct load, below), each
         as a list of rows."""
-        csched, sched = _rebuild_client(sched_args)
+        csched, sched, chaos_sched = _rebuild(sched_args)
         append = (
             sched.append[sched.phase_of_round[starts]]
             + csched.append[csched.phase_of_round[starts]]
         )
-        return jax.tree.map(
-            list, (workload_mod.block_tables(csched, k), append)
-        )
+        if chaos_on:
+            append = append + chaos_sched.append[
+                chaos_sched.phase_of_round[starts]
+            ]
+            tables = workload_mod.block_tables(csched, k, chaos_sched)
+        else:
+            tables = workload_mod.block_tables(csched, k)
+        return jax.tree.map(list, (tables, append))
 
     def block_run(
         st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
-        fused, rows, append, *sched_args,
+        fused, *rest,
     ):
-        csched, sched = _rebuild_client(sched_args)
-        body = _runner_body(cfg, sched, None, client=csched)
+        if chaos_on:
+            healthy_refused, refusals, rows, append, *sched_args = rest
+        else:
+            rows, append, *sched_args = rest
+        csched, sched, chaos_sched = _rebuild(sched_args)
+        body = _runner_body(cfg, sched, chaos_sched, client=csched)
         guard = profiling.Sections()
+        if chaos_on:
+            # The planes of the block's first round stand for all k: the
+            # guard's `same_chaos_phase` row says whether they do.
+            # Finished planes, as chaos.schedule_masks hands a round its
+            # own: unbarriered the unpack is copied into every consumer.
+            guard.at("runner.block_planes")
+            link, loss, crashed, _ = chaos_mod.schedule_planes(
+                chaos_sched, rows.r0
+            )
+            if lossless:
+                link, crashed = jax.lax.optimization_barrier((link, crashed))
+                loss = jnp.zeros_like(loss)
+            else:
+                link, loss, crashed = jax.lax.optimization_barrier(
+                    (link, loss, crashed)
+                )
         guard.at("runner.block_guard")
-        crashed = jnp.zeros((P, G), bool)
+        if not chaos_on:
+            link = loss = None
+            crashed = jnp.zeros((P, G), bool)
         # The schedule's half of the guard is the block's own rows
         # (workload.BlockRows); only the fleet's half is computed here.
         read_block = (rcar.pending_mode > 0) | kernels.unpack_bits_g(
@@ -1041,16 +1144,27 @@ def _make_workload_split(
             else jnp.zeros((G,), bool)
         )
         mask = pallas_step.steady_mask(
-            cfg, st, crashed, horizon=k, read_pending=read_block
+            cfg, st, crashed, horizon=k, link=link, loss_rate=loss,
+            read_pending=read_block,
         )
         pred = jnp.all(mask & lease_prov) & rows.same_phase
+        if chaos_on:
+            pred = pred & rows.same_chaos_phase
+            # The blocks between faults that did not fuse: the re-fuse
+            # delay after a fault.
+            healthy_refused = healthy_refused + (
+                ~pred & ~rows.faulted
+            ).astype(jnp.int32)
         guard.end()
 
         @profiling.scope("runner.fused_arm")
         def fast(args):
-            st, hl, rst, stats, rstats, safety, rcar, rdstats, lat = args
+            st, hl, rst, stats, rstats, safety, rcar, rdstats, lat = args[:9]
             prev_ll = hl.planes[kernels.HP_LEADERLESS]
-            st2, hl2 = fused_fn(st, crashed, append, hl)
+            if kernel_loss:
+                st2, hl2 = fused_fn(st, crashed, append, loss, rows.r0, hl)
+            else:
+                st2, hl2 = fused_fn(st, crashed, append, hl)
             # The predicate proves a standing leader with no transfer
             # pending: every one of the k offers was taken.
             stats2 = chaos_mod.update_chaos_stats(
@@ -1070,55 +1184,89 @@ def _make_workload_split(
             lat = lat.at[0].add(n_served)
             rdstats2 = rdstats.at[workload_mod.RS_ISSUED].add(n_served)
             rdstats2 = rdstats2.at[workload_mod.RS_SERVED_LEASE].add(n_served)
+            if chaos_on:
+                # The standing leader is the last acting leader the group
+                # had, as k general rounds leave it: under a plan leaders
+                # do change later, and the change is counted against this
+                # plane (a bare plan's fleet never has one to count).
+                lead = kernels.acting_leader_id(st2.state, st2.term, crashed)
+                rcar = rcar._replace(
+                    last_leader=jnp.where(lead > 0, lead, rcar.last_leader)
+                )
             return (
                 st2, hl2, rst2, stats2, rstats, safety, rcar, rdstats2,
                 lat,
-            )
+            ) + args[9:]
 
         @profiling.scope("runner.general_arm")
         def slow(args):
+            if chaos_on:
+                # Why a block between faults did not fuse, by guard term.
+                counts = _guard_refusals(
+                    cfg, args[0], crashed, k, loss, read_block
+                )
+                refusals = args[9] + jnp.where(rows.faulted, 0, counts)
             carry, _ = jax.lax.scan(
-                body, args, rows.r0 + jnp.arange(k, dtype=jnp.int32)
+                body, args[:9], rows.r0 + jnp.arange(k, dtype=jnp.int32)
             )
-            return carry
+            return carry + ((refusals,) if chaos_on else ())
 
         args = (st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist)
+        if chaos_on:
+            args = args + (refusals,)
         carry = jax.lax.cond(pred, fast, slow, args)
         fused = fused + jnp.where(pred, jnp.int32(k * G), jnp.int32(0))
+        if chaos_on:
+            return carry[:9] + (fused, healthy_refused, carry[9])
         return carry + (fused,)
+
+    n_extra = 3 if chaos_on else 1  # fused[, healthy_refused, refusals]
 
     def tail_run(
         st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
-        fused, r0, *sched_args,
+        *rest,
     ):
-        csched, sched = _rebuild_client(sched_args)
-        body = _runner_body(cfg, sched, None, client=csched)
+        extra, r0 = rest[:n_extra], rest[n_extra]
+        sched_args = rest[n_extra + 1:]
+        csched, sched, chaos_sched = _rebuild(sched_args)
+        body = _runner_body(cfg, sched, chaos_sched, client=csched)
         carry, _ = jax.lax.scan(
             body,
             (st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist),
             r0 + jnp.arange(tail, dtype=jnp.int32),
         )
-        return carry + (fused,)
+        return carry + extra
 
     donate = (0, 1, 2, 6)
     fused_jit = jax.jit(block_run, donate_argnums=donate)
     tail_audit_jit = jax.jit(_tail_audit)
     tail_jit = jax.jit(tail_run, donate_argnums=donate) if tail else None
-    sched_args = schedule_args(client, reconfig_sched)
-    # Blocks that start in one client phase share one append row (the
-    # template above has a single phase), so the rows never outgrow the
-    # schedule's own planes.
+    sched_args = schedule_args(client, reconfig_sched, chaos_compiled)
+    # Blocks that start in one client phase (and, under a chaos plan, one
+    # chaos phase) share one append row (the template above has a single
+    # phase), so the rows never outgrow the schedules' own planes.
     starts = np.arange(n_blocks, dtype=np.int32) * k
+    load_of = np.asarray(client.phase_of_round)[starts]
+    if chaos_on:
+        chaos_phase = np.asarray(chaos_compiled.phase_of_round)[starts]
+        load_of = load_of * (int(chaos_phase.max(initial=0)) + 1) + chaos_phase
     _, first, row_of = np.unique(
-        np.asarray(client.phase_of_round)[starts],
-        return_index=True, return_inverse=True,
+        load_of, return_index=True, return_inverse=True
     )
     tables, loads = jax.jit(tables_run)(starts[first], *sched_args)
     block_args = [
-        (workload_mod.BlockRows(*(t[b] for t in tables)), loads[row_of[b]])
+        (
+            jax.tree.map(
+                lambda t: t[b], tables, is_leaf=lambda t: isinstance(t, list)
+            ),
+            loads[row_of[b]],
+        )
         for b in range(n_blocks)
     ]
     tail_r0 = jnp.int32(n_blocks * k)
+    blocks_faulted = (
+        int(np.sum(jax.device_get(tables.faulted))) if chaos_on else 0
+    )
 
     def runner(st, hl, rst, rcar):
         stats = jnp.zeros((chaos_mod.N_CHAOS_STATS,), jnp.int32)
@@ -1130,26 +1278,29 @@ def _make_workload_split(
             st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
             jnp.int32(0),
         )
-        with profiling.span("raft.runner.blocks", blocks=n_blocks, tail=tail):
+        if chaos_on:
+            carry = carry + (
+                jnp.int32(0),
+                jnp.zeros((len(workload_mod.GUARD_TERMS),), jnp.int32),
+            )
+        with profiling.span(
+            "raft.runner.blocks", blocks=n_blocks, tail=tail,
+            chaos=int(chaos_on), blocks_faulted=blocks_faulted,
+        ):
             for block in block_args:
                 carry = fused_jit(*carry, *block, *sched_args)
         if tail_jit is not None:
             carry = tail_jit(*carry, tail_r0, *sched_args)
-        (
-            stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
-            lat_hist, fused,
-        ) = carry
         # Inert here with the no-op schedule, kept for bit-parity with the
         # unsplit runner.
-        safety = safety + tail_audit_jit(stf, rstf)
-        return (
-            stf, hlf, rstf, stats, rstats, safety, rcarf, rdstats,
-            lat_hist, fused,
-        )
+        safety = carry[5] + tail_audit_jit(carry[0], carry[2])
+        return carry[:5] + (safety,) + carry[6:]
 
     runner.fused_jit = fused_jit  # type: ignore[attr-defined]
     runner.block_args = block_args  # type: ignore[attr-defined]
     runner.schedule_args = sched_args  # type: ignore[attr-defined]
+    runner.n_blocks = n_blocks  # type: ignore[attr-defined]
+    runner.blocks_faulted = blocks_faulted  # type: ignore[attr-defined]
     return runner
 
 
@@ -1345,7 +1496,7 @@ def make_runner(
         [reconfig, chaos?], split=True        reconfig split (k, window,
                                               with_counters)
         [client, chaos?, reconfig?]           workload scan
-        [client], split=True                  workload split (k)
+        [client, chaos?], split=True          workload split (k)
         [reconfig, chaos?], cadence=rounds    autopilot cadence segment
                                               (fused)
 
@@ -1417,12 +1568,21 @@ def make_runner(
     heartbeat_tick == 1 re-saturates recent_active every round.  The
     fused arm folds the receipts closed-form (every fire served in its
     round: lat_hist[0], issued and served_lease += fires; the read carry
-    stays empty; every safety slot zero).  Bare client plans only.  What
-    a block needs of the schedule is tabled once, when the runner is
-    built (workload.block_tables; block b's operands are
-    ``.block_args[b]``), so a block's cost does not follow the schedule's
-    length; only the general arm reads the planes.  Also exposes
-    ``.fused_jit``.
+    stays empty; every safety slot zero).  What a block needs of the
+    schedule is tabled once, when the runner is built
+    (workload.block_tables; block b's operands are ``.block_args[b]``),
+    so a block's cost does not follow the schedule's length; only the
+    general arm reads the planes.  With a chaos plan (ISSUE 51) a block
+    is also one chaos phase (tabled: BlockRows.same_chaos_phase): its
+    guard and its kernel take the link / crash / loss planes of its first
+    round (chaos.schedule_planes; steady_mask(link=, loss_rate=),
+    kernels.lease_read(crashed)), a plan with no loss rate keeps the
+    kernel without the loss operand, both arms' rounds are the chaos
+    scan's, and the outputs end (..., fused_rounds, healthy_refused,
+    guard_refusals[len(workload.GUARD_TERMS)]): the blocks outside a
+    faulted phase that did not fuse, and the groups each guard term
+    refused in them.  A reconfig plan is refused.  Also exposes ``.fused_jit``,
+    ``.n_blocks``, ``.blocks_faulted``.
 
     cadence segment: returns the bare jit (st, hl, rst, stats, rstats,
     safety[, blackbox], cs_rounds, r0, transfer_plane, kick_plane,

@@ -4753,10 +4753,22 @@ class ClusterSim:
         through the workload split runner: steady stretches whose reads
         are pure lease serves ride the fused Pallas kernel in
         `split_k`-round blocks (the lease receipts fold closed-form),
-        while quorum-round reads, chaos, and reconfig rounds run the
-        general per-round body — bit-identical either way, with the
-        measured `fused_frac` added to the report.  Only a bare plan
-        (no chaos/reconfig composition) supports the split mode."""
+        while quorum-round reads and unsteady stretches run the general
+        per-round body — bit-identical either way, with the measured
+        `fused_frac` added to the report.  A chaos plan composes with the
+        split mode (ISSUE 51): a block is then also one chaos phase, its
+        guard and its kernel take that phase's link / crash / loss planes,
+        and the stretches BETWEEN a schedule's faults fuse (inside one, a
+        block fuses where every group stays steady beside the fault — a
+        crashed peer keeps ticking and campaigns once a timeout, so a
+        large fleet's down stretch does not).  Such a run also reports
+        `split_blocks`, `split_blocks_faulted` (blocks whose chaos phase
+        has a crash, a cut or a loss rate), `split_blocks_healthy` and
+        `split_blocks_healthy_refused` (the others, and those of them
+        that did not fuse), and `guard_refusals`: the groups each guard
+        term (workload.GUARD_TERMS) refused, summed over the blocks outside
+        a faulted phase that did not fuse.  A reconfig plan does not
+        compose with the split mode."""
         from . import reconfig as reconfig_mod
         from . import workload as workload_mod
 
@@ -4801,6 +4813,7 @@ class ClusterSim:
                 whole.set_metadata(
                     call=self._read_calls, rounds=compiled.n_rounds,
                     groups=self.cfg.n_groups, loss_draw=loss_draw,
+                    split=int(split), chaos=int(chaos_plan is not None),
                 )
                 # The op protocol's carry: the one the last call of this
                 # plan triple ended with (the runner resumes it: finished
@@ -4847,6 +4860,9 @@ class ClusterSim:
                 self._blackbox = out[i]
                 i += 1
             fused = (out[i],) if split else ()  # the fused group-rounds
+            # A split run under a chaos plan: the blocks between faults
+            # that did not fuse and the guard's refusals in them, by term.
+            guard = out[i + 1:i + 3] if split and chaos_plan is not None else ()
             with profiling.span("raft.run_reads.report") as reporting:
                 lat_p, recover_p = workload_mod.report_percentiles(
                     lat_hist, stats
@@ -4865,7 +4881,7 @@ class ClusterSim:
                     # scan.
                     got = jax.device_get(
                         (rdstats, lat_p, safety, stats, recover_p, rstats,
-                         unfinished, *fused, *lag)
+                         unfinished, *fused, *guard, *lag)
                     )
                 (
                     rdstats_h, lat_p_h, safety_h, stats_h, recover_p_h,
@@ -4883,6 +4899,10 @@ class ClusterSim:
                     report["fused_frac"] = round(
                         report["fused_rounds"] / total, 4
                     )
+                if guard:
+                    report.update(workload_mod.split_chaos_report(
+                        runner.n_blocks, runner.blocks_faulted, *got[8:10]
+                    ))
                 reporting.set_metadata(
                     call=self._read_calls, groups=self.cfg.n_groups,
                     **workload_mod.report_counts(report),

@@ -49,7 +49,7 @@ from __future__ import annotations
 import json
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
@@ -495,12 +495,13 @@ def lease_fires_in_block(
 
 
 class BlockRows(NamedTuple):
-    """What ONE fused k-round block needs of the client schedule: every
-    field is a function of the schedule and the block's number alone, so
+    """What ONE fused k-round block needs of the schedules: every field is
+    a function of the schedules and the block's number alone, so
     :func:`block_tables` computes them once per schedule and the split
     runner hands block b its rows as operands — a block's guard reads no
-    [R, ...] or [NPH, ...] plane (on the v5e those planes are laid out
-    phase-minor, so ONE row read costs the whole plane: PERF.md, PR 31).
+    [R, ...] or [NPH, ...] plane of the client schedule (on the v5e those
+    planes are laid out phase-minor, so ONE row read costs the whole plane:
+    PERF.md, PR 31).
 
     r0:         int32[]       the block's first round
     same_phase: bool[]        its first and last round share a client phase
@@ -511,6 +512,14 @@ class BlockRows(NamedTuple):
                               bit-packed along G like read_fire_packed
     lease_fire: uint32[Wg]    groups with a LEASE-mode fire inside it
                               (lease_fires_in_block's `any`), packed alike
+
+    Only where the runner has a chaos plan (None, and no leaf of the
+    pytree, where it has none):
+
+    same_chaos_phase: bool[]  its first and last round share a chaos phase
+                              (the fused kernel takes the planes of the
+                              block's first round for all k)
+    faulted:          bool[]  that phase has a fault (chaos.phase_faulted)
     """
 
     r0: jnp.ndarray  # gc: int32[]
@@ -518,13 +527,20 @@ class BlockRows(NamedTuple):
     n_lease: jnp.ndarray  # gc: int32[]
     safe_fire: jnp.ndarray  # gc: uint32[WG]
     lease_fire: jnp.ndarray  # gc: uint32[WG]
+    same_chaos_phase: Optional[jnp.ndarray] = None  # gc: bool[]
+    faulted: Optional[jnp.ndarray] = None  # gc: bool[]
 
 
-def block_tables(client: CompiledClient, k: int) -> BlockRows:
-    """The :class:`BlockRows` of every whole k-round block of `client`,
-    stacked along a leading [n_rounds // k] axis.  One pass over the
-    schedule in PACKED space — a round's fire words AND its phase's packed
-    mode mask — so nothing of [n_blocks, k, G] is ever built."""
+def block_tables(
+    client: CompiledClient,
+    k: int,
+    chaos_compiled: Optional[chaos_mod.CompiledChaos] = None,
+) -> BlockRows:
+    """The :class:`BlockRows` of every whole k-round block of `client`
+    (and of `chaos_compiled`, a plan of the same length, where the runner
+    has one), stacked along a leading [n_rounds // k] axis.  One pass over
+    the schedule in PACKED space — a round's fire words AND its phase's
+    packed mode mask — so nothing of [n_blocks, k, G] is ever built."""
     n_blocks = client.n_rounds // k
     n = n_blocks * k
     phase = client.phase_of_round[:n]
@@ -536,7 +552,7 @@ def block_tables(client: CompiledClient, k: int) -> BlockRows:
 
     lease = fires(sim_mod.READ_LEASE)
     by_block = phase.reshape(n_blocks, k)
-    return BlockRows(
+    rows = BlockRows(
         r0=jnp.arange(n_blocks, dtype=jnp.int32) * k,
         same_phase=by_block[:, 0] == by_block[:, k - 1],
         n_lease=jnp.sum(
@@ -544,6 +560,13 @@ def block_tables(client: CompiledClient, k: int) -> BlockRows:
         ),
         safe_fire=jnp.bitwise_or.reduce(fires(sim_mod.READ_SAFE), axis=1),
         lease_fire=jnp.bitwise_or.reduce(lease, axis=1),
+    )
+    if chaos_compiled is None:
+        return rows
+    chaos_by_block = chaos_compiled.phase_of_round[:n].reshape(n_blocks, k)
+    return rows._replace(
+        same_chaos_phase=chaos_by_block[:, 0] == chaos_by_block[:, k - 1],
+        faulted=chaos_mod.phase_faulted(chaos_compiled)[chaos_by_block[:, 0]],
     )
 
 
@@ -612,13 +635,42 @@ def read_report(
     }
 
 
+# The guard terms the workload split runner counts under a chaos plan, in
+# the order of `guard_refusals`' columns (runner._guard_refusals).
+GUARD_TERMS = (
+    "no_campaign", "one_leader", "terms_ok", "cq_boundary", "read_pending",
+)
+
+
+def split_chaos_report(
+    n_blocks: int, blocks_faulted: int, healthy_refused, refusals
+) -> dict:
+    """What a split run under a chaos plan adds to its report: the blocks
+    by kind — `faulted` where the block's chaos phase has a crash, a cut or
+    a loss rate, `healthy` where it has none — the healthy ones that did
+    not fuse (the fleet was not steady yet: the re-fuse delay after a
+    fault), and `refusals` int[len(GUARD_TERMS)], the groups each guard
+    term refused summed over those."""
+    return {
+        "split_blocks": int(n_blocks),
+        "split_blocks_faulted": int(blocks_faulted),
+        "split_blocks_healthy": int(n_blocks - blocks_faulted),
+        "split_blocks_healthy_refused": int(healthy_refused),
+        "guard_refusals": {
+            name: int(v) for name, v in zip(GUARD_TERMS, refusals)
+        },
+    }
+
+
 def report_counts(report: dict) -> Dict[str, int]:
     """Every integer of a report, flat — what the `raft.run_reads.report`
-    span is closed with (the safety slots as `safety.<name>`)."""
+    span is closed with (the safety slots as `safety.<name>`, the guard's
+    refusals as `guard_refusals.<term>`)."""
     out = {
         k: v for k, v in report.items()
         if isinstance(v, int) and not isinstance(v, bool)
     }
-    for name, v in report.get("safety", {}).items():
-        out[f"safety.{name}"] = v
+    for group in ("safety", "guard_refusals"):
+        for name, v in report.get(group, {}).items():
+            out[f"{group}.{name}"] = v
     return out

@@ -44,7 +44,8 @@ SPANS: Dict[str, str] = {
         "one ClusterSim.run_reads call, entry to returned report; stats: "
         "call (sequence number of this sim's calls), rounds, groups, "
         "loss_draw (1 where the runner's rounds draw a chaos plan's loss "
-        "sample, 0 for a plan with no loss rate or no chaos plan)"
+        "sample, 0 for a plan with no loss rate or no chaos plan), split, "
+        "chaos (0 / 1: the call's mode and whether it has a chaos plan)"
     ),
     "raft.run_reads.prepare": (
         "runner cache look-up (schedule compile + make_runner on a miss: "
@@ -57,12 +58,15 @@ SPANS: Dict[str, str] = {
     ),
     "raft.runner.blocks": (
         "the split runner's Python loop of fused_jit dispatches, inside "
-        "dispatch; stats: blocks, tail (rounds left to the tail program)"
+        "dispatch; stats: blocks, tail (rounds left to the tail program), "
+        "chaos (1 where the runner has a chaos plan), blocks_faulted (the "
+        "blocks whose chaos phase has a crash, a cut or a loss rate)"
     ),
     "raft.run_reads.report": (
         "everything dispatched for the report (latency_percentiles, "
         "unfinished_groups under a reconfig plan), the download, "
-        "formatting; closed with the report's integer counts"
+        "formatting; closed with the report's integer counts (a split call "
+        "under a chaos plan: its block counts and guard_refusals.<term> too)"
     ),
     "raft.run_reads.download": (
         "the device_get of the report's vectors, inside report: the host's "
@@ -192,6 +196,18 @@ SCOPES: Dict[str, str] = {
         "its acting leader's commit, in every _runner_body round of a "
         "run_reads scan on a fleet that boots with learners "
         "(workload.LearnerLagCarry); no other fleet's round has it"
+    ),
+    "runner.block_planes": (
+        "a split block under a chaos plan: chaos.schedule_planes of the "
+        "block's first round — the link, crash and (where the plan has a "
+        "rate) loss planes unpacked, finished behind a barrier, for the "
+        "guard and the kernel"
+    ),
+    "runner.guard_refusals": (
+        "runner._guard_refusals, inside runner.general_arm of a split "
+        "block under a chaos plan: the groups each guard term refused in "
+        "a block that did not fuse, one reduce (the report's "
+        "guard_refusals)"
     ),
     "runner.block_guard": (
         "everything a split block computes before its lax.cond: its "

@@ -132,7 +132,8 @@ def test_traced_call_yields_the_span_tree(tmp_path, split):
         if split:
             blocks = by_name["raft.runner.blocks"]
             assert inside(blocks, by_name["raft.run_reads.dispatch"])
-            assert blocks[3] == {"blocks": ROUNDS // 8, "tail": ROUNDS % 8}
+            assert blocks[3] == {"blocks": ROUNDS // 8, "tail": ROUNDS % 8,
+                                 "chaos": 0, "blocks_faulted": 0}
         # The report span is closed with the report's counts, and `call`
         # ties it to its call.
         stats = by_name["raft.run_reads.report"][3]
@@ -169,6 +170,33 @@ def test_run_reads_span_says_whether_its_rounds_draw_the_loss_sample(
     calls = [sp[3] for sp in read_spans(str(tmp_path)) if sp[0] == "raft.run_reads"]
     assert [c["loss_draw"] for c in calls] == [want, want]
     assert [c["call"] for c in calls] == [1, 2]
+
+
+def test_split_call_under_a_chaos_plan_says_so_in_its_spans(tmp_path):
+    """ISSUE 51: `raft.run_reads` carries `split` and `chaos`,
+    `raft.runner.blocks` `chaos` and `blocks_faulted` (the blocks whose
+    chaos phase has a fault: static), and the report span the split run's
+    block counts and the guard's refusals by term."""
+    plan, cplan = client_plan(), crash_plan()
+    s = booted()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        report = s.run_reads(plan, cplan, split=True, split_k=4)
+    finally:
+        jax.profiler.stop_trace()
+    spans = {sp[0]: sp[3] for sp in read_spans(str(tmp_path))}
+    assert spans["raft.run_reads"]["split"] == 1
+    assert spans["raft.run_reads"]["chaos"] == 1
+    # crash_plan: 4 healthy rounds, then a store down to the end.
+    assert spans["raft.runner.blocks"] == {
+        "blocks": ROUNDS // 4, "tail": 0, "chaos": 1, "blocks_faulted": ROUNDS // 4 - 1}
+    stats = spans["raft.run_reads.report"]
+    assert stats["split_blocks"] == ROUNDS // 4
+    assert stats["split_blocks_faulted"] == ROUNDS // 4 - 1
+    assert stats["split_blocks_healthy"] == 1
+    assert stats["split_blocks_healthy_refused"] == report["split_blocks_healthy_refused"]
+    for term in workload.GUARD_TERMS:
+        assert stats[f"guard_refusals.{term}"] == report["guard_refusals"][term]
 
 
 def test_untraced_report_equals_traced(tmp_path):
@@ -330,6 +358,15 @@ def lowered_text():
     ).as_text(debug_info=True)
     # ... as ClusterSim.run_reads calls it for a fleet that boots with
     # learners: their lag is counted beside the read carry.
+    # The split block program under a chaos plan (ISSUE 51): the block's
+    # planes and the guard's refusal counts.
+    xrun = runner_mod.make_runner(
+        cfg, (client, chaos.compile_plan(crash_plan(), G)), split=True, k=8)
+    texts["chaos_block"] = xrun.fused_jit.lower(
+        *args[:10], jnp.int32(0),
+        jnp.zeros((len(workload.GUARD_TERMS),), jnp.int32),
+        *xrun.block_args[0], *xrun.schedule_args,
+    ).as_text(debug_info=True)
     texts["learner_scan"] = xscan.jitted.lower(
         st, sim.init_health(cfg), rst,
         workload.LearnerLagCarry(workload.init_read_carry(G), jnp.int32(0)),
@@ -342,6 +379,8 @@ WHERE = {"round": "plain", "round.linked": "linked", "read_latency": "latency",
          "damped.read_holders": "readindex",
          "runner.chaos_masks": "client_chaos_scan",
          "runner.learner_lag": "learner_scan",
+         "runner.block_planes": "chaos_block",
+         "runner.guard_refusals": "chaos_block",
          **{s: "linked" for s in profiling.SCOPES if s.startswith("linked.")}}
 
 

@@ -94,19 +94,24 @@ def scan_program(with_chaos, P=3, learners=False, **flags):
                         rcar, *run.schedule_args)
 
 
-def block_program(P=3):
+def block_program(P=3, with_chaos=False):
     """The split runner's block program: guard, both arms, the damped
-    round with reads and health in the general arm."""
+    round with reads and health in the general arm; with a chaos plan
+    (ISSUE 51), the block's planes, the refusal counts and the chaos
+    schedule's masks in the general arm's rounds."""
     cfg = damped_cfg(P)
-    run = runner_mod.make_runner(cfg, (client_of(P),), split=True, k=8)
+    scheds = (client_of(P),) + ((chaos_of(P),) if with_chaos else ())
+    run = runner_mod.make_runner(cfg, scheds, split=True, k=8)
     st = sim.init_state(cfg)
     zeros = lambda n: jnp.zeros((n,), jnp.int32)  # noqa: E731
+    counts = (jnp.int32(0), jnp.zeros((len(workload.GUARD_TERMS),), jnp.int32))
     return run.fused_jit, (
         st, sim.init_health(cfg), reconfig.init_reconfig_state(st),
         zeros(chaos.N_CHAOS_STATS), zeros(reconfig.N_RECONFIG_STATS),
         zeros(kernels.N_SAFETY), workload.init_read_carry(G),
         zeros(workload.N_READ_STATS), zeros(workload.N_LAT_BUCKETS),
-        jnp.int32(0), *run.block_args[0], *run.schedule_args,
+        jnp.int32(0), *(counts if with_chaos else ()), *run.block_args[0],
+        *run.schedule_args,
     )
 
 
@@ -118,6 +123,7 @@ PROGRAMS = {
     "client-chaos-scan": (scan_program, (True,)),
     "client-chaos-scan-cq": (functools.partial(scan_program, True, 5, **CQ_ONLY), ()),
     "client-chaos-scan-learners": (functools.partial(scan_program, True, 5, learners=True), ()),
+    "client-chaos-split": (block_program, (3, True)),
 }
 
 
@@ -241,6 +247,7 @@ ROUND_PROGRAMS = {
     "client-chaos-scan": (scan_program, (True,)),
     "client-chaos-scan-cq": PROGRAMS["client-chaos-scan-cq"],
     "client-chaos-scan-learners": PROGRAMS["client-chaos-scan-learners"],
+    "client-chaos-split": PROGRAMS["client-chaos-split"],
 }
 
 
