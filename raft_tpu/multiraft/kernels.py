@@ -126,9 +126,12 @@ here is missing from it or untested under tests/.
                                vs real check-quorum Rafts in
                                tests/test_damping_parity.py
   check_safety_groups      <-> the per-GROUP form of check_safety (same
-                               invariants, same optional args): the
-                               forensics trigger surface — its slot-wise
-                               group sums are asserted EQUAL to
+                               invariants, same optional args, on the
+                               packed core `_safety_flags`): the forensics
+                               trigger surface — both held to a plain
+                               per-group NumPy reference in
+                               tests/test_safety_audit_form.py, its
+                               slot-wise group sums asserted EQUAL to
                                check_safety's counts on fuzzed and
                                trapped states in tests/test_forensics.py
   pack_blackbox_meta /     <-> the packed black-box ring word (role < 4,
@@ -164,6 +167,7 @@ so no x64 dependency.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -259,9 +263,14 @@ def committed_index(
     for an empty config (so joint min() ignores it), exactly the reference's
     empty-config convention (majority.rs:71-75).
 
-    The peer axis is read with P static slices, so a caller's
-    swapaxes(x[P_owner, P, G], 1, 2) folds back to x[:, p, :] and G stays
-    on the lanes: no sort, no gather, no operand whose minor dimension is P.
+    The peer axis is read with P static slices, so no operand's minor
+    dimension is P and G stays on the lanes: no sort, no gather.  A
+    caller's swapaxes(x[P_owner, P, G], 1, 2) is then a LAYOUT of x, not
+    an op: where the compiler can hand that layout to x's producer it
+    costs nothing (wave 4's sim._stage_fold at 1M x 3), where it cannot it
+    is a physical owner-major copy of the plane (wave 5's; the safety
+    audit's, until ISSUE 52 replaced the audit's two networks by a count —
+    `tools/aot_round.py` shows which).
     """
     P = matched.shape[-1]
     return _quorum_of_rows(
@@ -690,6 +699,234 @@ def lease_read(
     return holder, served, index
 
 
+@functools.lru_cache(maxsize=None)
+def _combiner(ops):
+    """(a, b) -> (ops[k](a[k], b[k]) ...), ONE function object per tuple of
+    ops: lax.reduce caches its traced computation by the function's
+    identity, and an eager caller (the CPU parity suites) would otherwise
+    compile every reduction anew on every call."""
+    return lambda a, b: tuple(op(x, y) for op, x, y in zip(ops, a, b))
+
+
+def _reduce_each(operands, ops, axis: int, inits=None):
+    """ONE variadic `lax.reduce` over `axis`: operand k folded by ops[k]
+    from inits[k] (0 where not given).  A reduction is a kernel on the TPU
+    and XLA fuses no reduce into a reduce, so values that leave the same
+    axis leave it together."""
+    return jax.lax.reduce(
+        tuple(operands),
+        tuple(
+            jnp.int32(0 if inits is None else inits[k])
+            for k in range(len(operands))
+        ),
+        _combiner(tuple(ops)),
+        (axis,),
+    )
+
+
+def _pack(planes, width: int) -> jnp.ndarray:
+    """bool planes -> one int32 word, plane k in the `width` bits from
+    k * width.  astype and shifts by Python ints stay int32 under x64
+    (GC007)."""
+    return functools.reduce(
+        jnp.bitwise_or,
+        (p.astype(jnp.int32) << (width * k) for k, p in enumerate(planes)),
+    )
+
+
+def _safety_flags(
+    state: jnp.ndarray,  # gc: int32[P, G]
+    term: jnp.ndarray,  # gc: int32[P, G]
+    commit: jnp.ndarray,  # gc: int32[P, G]
+    last_index: jnp.ndarray,  # gc: int32[P, G]
+    agree: jnp.ndarray,  # gc: int32[P, P, G]
+    prev_commit: jnp.ndarray,  # gc: int32[P, G]
+    voter_mask: Optional[jnp.ndarray],  # gc: bool[P, G]
+    outgoing_mask: Optional[jnp.ndarray],  # gc: bool[P, G]
+    matched: Optional[jnp.ndarray],  # gc: int32[P, P, G]
+    crashed: Optional[jnp.ndarray],  # gc: bool[P, G]
+    prev_voter_mask: Optional[jnp.ndarray],  # gc: bool[P, G]
+    prev_outgoing_mask: Optional[jnp.ndarray],  # gc: bool[P, G]
+    lease_holder: Optional[jnp.ndarray],  # gc: bool[P, G]
+    lease_fire: Optional[jnp.ndarray],  # gc: bool[G]
+) -> Tuple[Optional[jnp.ndarray], ...]:
+    """The audit's nine per-group violation flags in SV_* order, each a
+    bool[G], or None where the caller's arguments leave the slot inactive:
+    the core under _check_safety_packed (which counts them) and
+    check_safety_groups (which hands them on).  The invariants are in
+    check_safety's docstring, the reasons for the form in
+    _check_safety_packed's."""
+    if voter_mask is not None and (outgoing_mask is None or matched is None):
+        raise ValueError(
+            "joint-window checks need voter_mask, outgoing_mask AND "
+            "matched together"
+        )
+    if prev_voter_mask is not None and (
+        voter_mask is None or prev_outgoing_mask is None
+    ):
+        raise ValueError(
+            "the double-change check needs prev AND current masks"
+        )
+    if lease_fire is not None and lease_holder is None:
+        raise ValueError(
+            "the stale-read check needs lease_holder alongside lease_fire"
+        )
+    P = state.shape[0]
+    if P > 0xFF:
+        raise ValueError("the audit counts peers in a byte: n_peers <= 255")
+    # Finished planes: behind the barrier no slot's arithmetic fuses into the
+    # round's producers (None passes through: an empty pytree node).
+    (
+        state, term, commit, last_index, agree, prev_commit, voter_mask,
+        outgoing_mask, matched, crashed, prev_voter_mask,
+        prev_outgoing_mask, lease_holder, lease_fire,
+    ) = jax.lax.optimization_barrier((
+        state, term, commit, last_index, agree, prev_commit, voter_mask,
+        outgoing_mask, matched, crashed, prev_voter_mask,
+        prev_outgoing_mask, lease_holder, lease_fire,
+    ))
+    joint = voter_mask is not None
+
+    # The pairwise [P, P, G] facts, a bit each of one word: any() over both
+    # peer axes follows from a fold over either and the word reduce below,
+    # so under the joint arguments they leave axis 1 WITH the ack count —
+    # one kernel reads `agree` and `matched` together — and alone (the
+    # six-argument call) the major axis, plane-wise on the TPU.
+    off_diag = ~jnp.eye(P, dtype=bool)[:, :, None]
+    is_lead = state == ROLE_LEADER
+    cmin = jnp.minimum(commit[:, None, :], commit[None, :, :])
+    lmin = jnp.minimum(last_index[:, None, :], last_index[None, :, :])
+    pair = _pack(
+        (
+            is_lead[:, None, :]
+            & is_lead[None, :, :]
+            & (term[:, None, :] == term[None, :, :]),
+            cmin > agree,
+            agree > lmin,
+        ),
+        1,
+    )
+    pair = jnp.where(off_diag, pair, 0)
+    if not joint:
+        (pair,) = _reduce_each([pair], [jnp.bitwise_or], 0)
+    else:
+        # Per-owner joint commit bound off each leader's own tracker row
+        # (reference: joint.rs:47-51 min over both majorities), as a COUNT:
+        # commit exceeds the majority()-th largest acked index of a config
+        # (committed_index, zero padding and all) iff the peers whose padded
+        # matched reaches commit number at most half its members.  No sort
+        # network, no owner-major copy of `matched`, no mask rows: one
+        # reduce over the TARGET axis of [P_owner, P_target, G], which the
+        # pair word rides.
+        with profiling.scope("quorum_commit"):
+            c3 = commit[:, None, :]
+            one, two = jnp.int32(1), jnp.int32(2)
+
+            def short(mask):
+                # 2 * acked - member + 1 per target, in {0, 1, 2, 3}; the
+                # select on the broadcast mask between two full-rank values
+                # leaves XLA no [P_target, G] subexpression to hoist into
+                # a kernel of its own.
+                m = mask[None, :, :]
+                acked = jnp.where(
+                    jnp.where(m, matched, 0) >= c3, two, jnp.int32(0)
+                )
+                return jnp.where(m, acked, acked + one)
+
+            acked = short(voter_mask) | (short(outgoing_mask) << 16)
+        # The reduce itself is the audit's, not a quorum commit: it carries
+        # the pair word too, and the kernel takes the reduce's name.
+        pair, acks = _reduce_each(
+            [pair, acked], [jnp.bitwise_or, jnp.add], 1
+        )  # int32[P_owner, G]: a sum <= P iff 2 * acked <= members
+    # Per-peer facts: one bit each of an int32[P, G] word, or-ed over the
+    # peers below; per-peer counts: one byte each of a second word, summed.
+    bits = {
+        "dual": pair & 1 != 0,
+        "diverged": pair & 2 != 0,
+        "regressed": commit < prev_commit,
+        "invalid": (pair & 4 != 0) | (commit > last_index),
+    }
+    counts = {}
+    prev_high = None
+    if joint:
+        bits["outside"] = (state != ROLE_FOLLOWER) & ~(
+            voter_mask | outgoing_mask
+        )
+        alive = ~crashed if crashed is not None else jnp.ones_like(is_lead)
+        # Checked set: every crashed leader (isolation means it cannot
+        # learn, so its commit is its own quorum's work) plus the
+        # max-term alive leaders (a stale lower-term alive leader can
+        # LEARN a settled commit via the propagation approximation).
+        low = jnp.iinfo(jnp.int32).min
+        prev_high, max_alive_term = _reduce_each(
+            [prev_commit, jnp.where(is_lead & alive, term, -1)],
+            [jnp.maximum, jnp.maximum], 0, inits=[low, low],
+        )  # int32[G] each
+        checked = is_lead & (~alive | (term == max_alive_term[None, :]))
+        advanced = checked & (commit > prev_high[None, :])
+        # acks: short of a quorum of a config that HAS members (an empty one
+        # bounds nothing: committed_index's INF) — that is a per-group
+        # fact, and-ed in after the reduce.
+        bits["short_of_voters"] = advanced & ((acks & 0xFFFF) <= P)
+        bits["short_of_outgoing"] = advanced & ((acks >> 16) <= P)
+        bits["has_voters"] = voter_mask
+        bits["has_outgoing"] = outgoing_mask
+    elif lease_fire is not None:
+        prev_high = jnp.max(prev_commit, axis=0)
+    if prev_voter_mask is not None:
+        bits["was_joint"] = prev_outgoing_mask
+        bits["outgoing_moved"] = prev_outgoing_mask ^ outgoing_mask
+        bits["entered_other"] = outgoing_mask ^ prev_voter_mask
+        counts["voters_moved"] = prev_voter_mask ^ voter_mask
+    if lease_holder is not None:
+        counts["holders"] = lease_holder
+        if lease_fire is not None:
+            # prev_high is the fleet's high-water mark at serve time
+            bits["stale"] = lease_holder & (prev_commit < prev_high[None, :])
+
+    # ONE reduce over the peer axis: the bit word or-ed, the count word
+    # summed.
+    words, ops = [_pack(bits.values(), 1)], [jnp.bitwise_or]
+    # `if counts:`, spelled off the arguments (GC003 cannot see a dict's
+    # truth is static)
+    if prev_voter_mask is not None or lease_holder is not None:
+        words.append(_pack(counts.values(), 8))
+        ops.append(jnp.add)
+    folded = _reduce_each(words, ops, 0)  # int32[G] each
+    any_of = {
+        name: (folded[0] >> k) & 1 != 0 for k, name in enumerate(bits)
+    }
+    count_of = {
+        name: (folded[1] >> (8 * k)) & 0xFF for k, name in enumerate(counts)
+    }
+
+    outside = unbacked = double = stale = dual_lease = None
+    if joint:
+        outside = any_of["outside"]
+        unbacked = (any_of["short_of_voters"] & any_of["has_voters"]) | (
+            any_of["short_of_outgoing"] & any_of["has_outgoing"]
+        )
+    if prev_voter_mask is not None:
+        was_j, now_j = any_of["was_joint"], any_of["has_outgoing"]
+        vm_delta = count_of["voters_moved"]
+        enter_bad = (~was_j & now_j) & any_of["entered_other"]
+        leave_bad = (was_j & ~now_j) & (vm_delta > 0)
+        stay_bad = (was_j & now_j) & (
+            (vm_delta > 0) | any_of["outgoing_moved"]
+        )
+        simple_bad = (~was_j & ~now_j) & (vm_delta > 1)
+        double = enter_bad | leave_bad | stay_bad | simple_bad
+    if lease_holder is not None:
+        dual_lease = count_of["holders"] >= 2
+        if lease_fire is not None:
+            stale = lease_fire & any_of["stale"]
+    return (
+        any_of["dual"], any_of["diverged"], any_of["regressed"],
+        any_of["invalid"], outside, unbacked, double, stale, dual_lease,
+    )
+
+
 @profiling.scope("safety_audit")
 def check_safety(
     state: jnp.ndarray,  # gc: int32[P, G]
@@ -770,7 +1007,92 @@ def check_safety(
 
     The chaos/reconfig fuzz harnesses fold these counts into the compiled
     schedule scan every round and assert the run total is zero.
+
+    Form (ISSUE 52): two, chosen by the size of the pairwise planes, which
+    is what decides what bounds the audit on the chip.
+    `_check_safety_packed` — where `agree` is _AUDIT_PACKED_MIN_BYTES or
+    more (36 MB at 1M x 3) the audit is bound by the bytes it reads: written
+    a slot at a time it was 22 kernels and 7% of a round there, packed it
+    is six and 1.8% (+5.6% group-rounds/s).  `_check_safety_by_slot` —
+    below that (10 MB at 100k x 5) six kernels and twenty-two take the same
+    0.22 ms alone, the form has nothing to gain, and every packed variant
+    that was timed moved the compiler's layout of the round AROUND the
+    audit by -15% .. +5% of a cell's rate, none of them inside the 2% bound
+    in both `fleet-100k-r5-stock.outage` and `fleet-100k-r5.serve`: those
+    fleets keep the program they had.  PERF.md section 6, PR 52 has the
+    readings; between 10 and 36 MB nobody has measured.
     """
+    if 4 * agree.size >= _AUDIT_PACKED_MIN_BYTES:  # graftcheck: allow-no-python-branch-on-traced — a SHAPE (trace-time static), not a value
+        return _check_safety_packed(
+            state, term, commit, last_index, agree, prev_commit, voter_mask,
+            outgoing_mask, matched, crashed, prev_voter_mask,
+            prev_outgoing_mask, lease_holder, lease_fire,
+        )
+    return _check_safety_by_slot(
+        state, term, commit, last_index, agree, prev_commit, voter_mask,
+        outgoing_mask, matched, crashed, prev_voter_mask,
+        prev_outgoing_mask, lease_holder, lease_fire,
+    )
+
+
+# int32 bytes of the pairwise [P, P, G] plane from which check_safety packs
+# its slots: between the two sizes that have a chip reading (PERF.md
+# section 6, PR 52), 10 MB (100k x 5, by slot) and 36 MB (1M x 3, packed).
+_AUDIT_PACKED_MIN_BYTES = 1 << 24
+
+
+def _check_safety_packed(
+    state, term, commit, last_index, agree, prev_commit, voter_mask,
+    outgoing_mask, matched, crashed, prev_voter_mask,
+    prev_outgoing_mask, lease_holder, lease_fire,
+):
+    """check_safety where the audit is bound by the bytes it reads.  On the
+    TPU a reduction is a kernel, and XLA fuses no reduce into a reduce.  So
+    (`_safety_flags`): (1) every argument passes ONE optimization_barrier —
+    the audit reads FINISHED planes in kernels of its own, as
+    chaos.fold_learner_lag does (ledger PR 47: 2.87% fused into the round's
+    producers, 0.67% behind a barrier as four reductions, 0.43% as one);
+    (2) the pairwise [P, P, G] facts are bits of one word, the joint commit
+    bound is a count of acknowledgements instead of two k-th-largest
+    networks (`commit > k-th largest` iff `acked(>= commit) <= members //
+    2`), and both leave the target axis in ONE variadic reduce (or, add):
+    `agree` and `matched` are read once, by one kernel; (3) every per-peer
+    fact is a bit, every per-peer count a byte, of two int32[P, G] words
+    that leave the peer axis in ONE variadic reduce (or, add); (4) the
+    per-group logic reads bits of those two rows and the active slots'
+    counts leave G in ONE variadic reduce.  Four reductions for
+    twenty-five; bit-equal to `_check_safety_by_slot` on every int32 state,
+    reachable or not (tests/test_safety_audit_form.py holds both to a plain
+    per-group reference and pins this form)."""
+    flags = _safety_flags(
+        state, term, commit, last_index, agree, prev_commit, voter_mask,
+        outgoing_mask, matched, crashed, prev_voter_mask,
+        prev_outgoing_mask, lease_holder, lease_fire,
+    )
+    active = [f for f in flags if f is not None]
+    # ONE reduce over G for every active slot's count; astype keeps the
+    # counts int32 under x64 (GC007) — they feed an int32 scan accumulator.
+    sums = iter(
+        _reduce_each(
+            [f.astype(jnp.int32) for f in active], [jnp.add] * len(active), 0
+        )
+    )
+    return jnp.stack(
+        [jnp.int32(0) if f is None else next(sums) for f in flags]
+    )
+
+
+def _check_safety_by_slot(
+    state, term, commit, last_index, agree, prev_commit, voter_mask,
+    outgoing_mask, matched, crashed, prev_voter_mask,
+    prev_outgoing_mask, lease_holder, lease_fire,
+):
+    """check_safety a slot at a time — sixteen reductions over the peers,
+    nine sums over G, two quorum networks (under `quorum_commit`) on an
+    owner-major `matched`: the audit as every fleet ran it until ISSUE 52,
+    and as a fleet whose planes are small still does (check_safety says
+    why).  Not to be tidied toward the packed form: at these sizes what a
+    form of the audit costs is what the compiler lays out around it."""
     P = state.shape[0]
     off_diag = ~jnp.eye(P, dtype=bool)[:, :, None]
     is_lead = state == ROLE_LEADER
@@ -918,121 +1240,23 @@ def check_safety_groups(
     the black-box trigger surface, which needs to know WHICH groups
     tripped, not just how many.
 
-    `check_safety` stays the separate, pinned aggregate kernel (its
-    traced graph anchors every flag-off jaxpr budget); this function is
-    deliberately a standalone twin rather than its factored core, and the
-    drift risk that buys is machine-closed by tests/test_forensics.py,
-    which asserts `check_safety_groups(...).sum(axis=-1) ==
-    check_safety(...)` slot-for-slot on fuzzed, joint, leased, and
-    trapped states every round it drives.
+    It is `_safety_flags` — the packed core, at every fleet size: no cell
+    turns the black box on, so no size has a reading to choose by — with
+    its nine rows stacked (an inactive slot a row of False);
+    `_check_safety_packed` counts the same rows.  tests/test_forensics.py
+    still asserts
+    `check_safety_groups(...).sum(axis=-1) == check_safety(...)`
+    slot-for-slot on fuzzed, joint, leased, and trapped states; what holds
+    the shared core to the invariants is the plain per-group reference of
+    tests/test_safety_audit_form.py.
     """
-    P = state.shape[0]
-    G = state.shape[1]
-    off_diag = ~jnp.eye(P, dtype=bool)[:, :, None]
-    is_lead = state == ROLE_LEADER
-    dual = (
-        is_lead[:, None, :]
-        & is_lead[None, :, :]
-        & (term[:, None, :] == term[None, :, :])
-        & off_diag
+    flags = _safety_flags(
+        state, term, commit, last_index, agree, prev_commit, voter_mask,
+        outgoing_mask, matched, crashed, prev_voter_mask,
+        prev_outgoing_mask, lease_holder, lease_fire,
     )
-    cmin = jnp.minimum(commit[:, None, :], commit[None, :, :])
-    diverged = (cmin > agree) & off_diag
-    regressed = commit < prev_commit
-    lmin = jnp.minimum(last_index[:, None, :], last_index[None, :, :])
-    invalid = ((agree > lmin) & off_diag) | (commit > last_index)[:, None, :]
-    zero_g = jnp.zeros((G,), bool)
-    if voter_mask is not None:
-        if outgoing_mask is None or matched is None:
-            raise ValueError(
-                "joint-window checks need voter_mask, outgoing_mask AND "
-                "matched together"
-            )
-        non_follower = state != ROLE_FOLLOWER
-        outside = non_follower & ~(voter_mask | outgoing_mask)
-        g_outside = jnp.any(outside, axis=0)
-        alive = (
-            ~crashed if crashed is not None else jnp.ones_like(is_lead)
-        )
-        lead_alive = is_lead & alive
-        max_alive_term = jnp.max(jnp.where(lead_alive, term, -1), axis=0)
-        checked = is_lead & (~alive | (term == max_alive_term[None, :]))
-        owner_rows = jnp.swapaxes(matched, 1, 2)
-        mci = jnp.minimum(
-            committed_index(
-                owner_rows,
-                jnp.broadcast_to(
-                    jnp.swapaxes(voter_mask, 0, 1)[None, :, :],
-                    owner_rows.shape,
-                ),
-            ),
-            committed_index(
-                owner_rows,
-                jnp.broadcast_to(
-                    jnp.swapaxes(outgoing_mask, 0, 1)[None, :, :],
-                    owner_rows.shape,
-                ),
-            ),
-        )
-        prev_high = jnp.max(prev_commit, axis=0)
-        unbacked = (
-            checked & (commit > prev_high[None, :]) & (commit > mci)
-        )
-        g_unbacked = jnp.any(unbacked, axis=0)
-    else:
-        g_outside = zero_g
-        g_unbacked = zero_g
-    if prev_voter_mask is not None:
-        if voter_mask is None or prev_outgoing_mask is None:
-            raise ValueError(
-                "the double-change check needs prev AND current masks"
-            )
-        was_j = jnp.any(prev_outgoing_mask, axis=0)
-        now_j = jnp.any(outgoing_mask, axis=0)
-        vm_delta = jnp.sum(
-            prev_voter_mask ^ voter_mask, axis=0, dtype=jnp.int32
-        )
-        om_moved = jnp.any(prev_outgoing_mask ^ outgoing_mask, axis=0)
-        enter_bad = (~was_j & now_j) & jnp.any(
-            outgoing_mask ^ prev_voter_mask, axis=0
-        )
-        leave_bad = (was_j & ~now_j) & (vm_delta > 0)
-        stay_bad = (was_j & now_j) & ((vm_delta > 0) | om_moved)
-        simple_bad = (~was_j & ~now_j) & (vm_delta > 1)
-        g_double = enter_bad | leave_bad | stay_bad | simple_bad
-    else:
-        g_double = zero_g
-    if lease_holder is not None:
-        g_dual_lease = (
-            jnp.sum(lease_holder, axis=0, dtype=jnp.int32) >= 2
-        )
-        if lease_fire is not None:
-            fleet_high = jnp.max(prev_commit, axis=0)
-            stale = lease_holder & (prev_commit < fleet_high[None, :])
-            g_stale = lease_fire & jnp.any(stale, axis=0)
-        else:
-            g_stale = zero_g
-    else:
-        if lease_fire is not None:
-            raise ValueError(
-                "the stale-read check needs lease_holder alongside "
-                "lease_fire"
-            )
-        g_dual_lease = zero_g
-        g_stale = zero_g
-    return jnp.stack(
-        [
-            jnp.any(dual, axis=(0, 1)),
-            jnp.any(diverged, axis=(0, 1)),
-            jnp.any(regressed, axis=0),
-            jnp.any(invalid, axis=(0, 1)),
-            g_outside,
-            g_unbacked,
-            g_double,
-            g_stale,
-            g_dual_lease,
-        ]
-    )
+    zero_g = jnp.zeros((state.shape[1],), bool)
+    return jnp.stack([zero_g if f is None else f for f in flags])
 
 
 def apply_confchange(
