@@ -7,7 +7,12 @@ masks, the op protocol, the audits and the folds).  make_runner's
 docstring is the dispatch table and each variant's contract.  The split
 variants are also the ONE place a fused kernel is chosen over the general
 round (``block_run`` / ``fused_block_run``: pallas_step.steady_mask, one
-``lax.cond``, pallas_step.steady_round or a scan of the body).
+``lax.cond``, pallas_step.steady_round or a scan of the body).  A
+workload split call of a few blocks is ONE program, as the scan runners'
+is (``segment_run``, exposed as ``.jitted``: ``block_run`` under an
+unrolled ``lax.scan`` over the tabled block rows); a longer one, and the
+reconfig split runner, dispatch a program a block from a short host loop
+(_SEGMENT_MAX_BLOCKS says why).
 
 The schedule modules (chaos, reconfig, workload) know nothing of this
 one; ``ClusterSim`` and the autopilot call it.
@@ -1001,6 +1006,19 @@ def _guard_refusals(
     return jnp.stack(counts)
 
 
+# A workload split call of at most this many blocks is ONE program
+# (segment_run, its scan over the blocks unrolled); a longer one dispatches
+# a program a block.  On the v5e at 100k x 5 (PERF.md section 6, PR 53) the
+# one program saves the host a fixed 3.6 ms a call.  With a `while` over the
+# blocks it costs a general round 2.9% and a fused block 4.5-5.9%, at every
+# unroll factor tried: the compiler lays the loop-carried fleet state out
+# anew in both arms, and copies every plane the loop only reads once a
+# call.  So a call of a few blocks gains (`serve`: 3) and one of hundreds
+# loses (`load`: 250, -2.6% at best).  Unrolled there is no `while`, at
+# the price of compiling the general round once a block: a few blocks.
+_SEGMENT_MAX_BLOCKS = 4
+
+
 def _make_workload_split(
     cfg: sim_mod.SimConfig,
     client: workload_mod.CompiledClient,
@@ -1072,10 +1090,11 @@ def _make_workload_split(
         )
         return csched, sched, chaos_sched
 
-    def tables_run(starts, *sched_args):
-        """Every block's workload.BlockRows and the write load of the
-        rounds `starts` (one block start per distinct load, below), each
-        as a list of rows."""
+    def stacked_tables(starts, *sched_args):
+        """Every block's workload.BlockRows, stacked [n_blocks, ...] — the
+        segment program's scanned operands — and the write load of the
+        rounds `starts` (one block start per distinct load, below),
+        [len(starts), G]."""
         csched, sched, chaos_sched = _rebuild(sched_args)
         append = (
             sched.append[sched.phase_of_round[starts]]
@@ -1088,7 +1107,12 @@ def _make_workload_split(
             tables = workload_mod.block_tables(csched, k, chaos_sched)
         else:
             tables = workload_mod.block_tables(csched, k)
-        return jax.tree.map(list, (tables, append))
+        return tables, append
+
+    def tables_run(starts, *sched_args):
+        """stacked_tables, each plane as a list of rows: one block's
+        operands of block_run (runner.block_args, below)."""
+        return jax.tree.map(list, stacked_tables(starts, *sched_args))
 
     def block_run(
         st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
@@ -1215,7 +1239,9 @@ def _make_workload_split(
         if chaos_on:
             args = args + (refusals,)
         carry = jax.lax.cond(pred, fast, slow, args)
-        fused = fused + jnp.where(pred, jnp.int32(k * G), jnp.int32(0))
+        # Named: in the segment program this runs once a scan trip.
+        with profiling.scope("runner.block_guard"):
+            fused = fused + jnp.where(pred, jnp.int32(k * G), jnp.int32(0))
         if chaos_on:
             return carry[:9] + (fused, healthy_refused, carry[9])
         return carry + (fused,)
@@ -1236,6 +1262,55 @@ def _make_workload_split(
             r0 + jnp.arange(tail, dtype=jnp.int32),
         )
         return carry + extra
+
+    def fresh_carry(st, hl, rst, rcar):
+        """A call's carry at its first block: the fleet, the read carry
+        and zeroed accumulators — inside the segment program, or one eager
+        fill apiece before a long call's first block."""
+        stats = jnp.zeros((chaos_mod.N_CHAOS_STATS,), jnp.int32)
+        rstats = jnp.zeros((reconfig_mod.N_RECONFIG_STATS,), jnp.int32)
+        safety = jnp.zeros((kernels.N_SAFETY,), jnp.int32)
+        rdstats = jnp.zeros((workload_mod.N_READ_STATS,), jnp.int32)
+        lat_hist = jnp.zeros((workload_mod.N_LAT_BUCKETS,), jnp.int32)
+        carry = (
+            st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
+            jnp.int32(0),  # the fused group-round accumulator
+        )
+        if chaos_on:  # healthy_refused, refusals
+            carry = carry + (
+                jnp.int32(0),
+                jnp.zeros((len(workload_mod.GUARD_TERMS),), jnp.int32),
+            )
+        return carry
+
+    def segment_run(st, hl, rst, rcar, tables, loads, row_of, *sched_args):
+        """A whole short segment, ONE program: the accumulators made here
+        (as _make_workload's run makes them), block_run scanned over the
+        stacked rows — block b's write load is loads[row_of[b]] — then
+        tail_run and the tail audit.  The scan is unrolled: laid end to
+        end the blocks compile as the block program does, where a `while`
+        over them made XLA place the loop-carried fleet state anew in
+        both arms (PERF.md section 6, PR 53)."""
+        carry = fresh_carry(st, hl, rst, rcar)
+
+        def block(carry, xs):
+            rows, load = xs
+            with profiling.scope("runner.block_guard"):
+                append = jax.lax.dynamic_index_in_dim(
+                    loads, load, keepdims=False
+                )
+            return block_run(*carry, rows, append, *sched_args), None
+
+        if n_blocks:
+            carry, _ = jax.lax.scan(
+                block, carry, (tables, row_of), unroll=True
+            )
+        if tail:
+            carry = tail_run(*carry, jnp.int32(n_blocks * k), *sched_args)
+        # Inert here with the no-op schedule, kept for bit-parity with the
+        # unsplit runner.
+        safety = carry[5] + _tail_audit(carry[0], carry[2])
+        return carry[:5] + (safety,) + carry[6:]
 
     donate = (0, 1, 2, 6)
     fused_jit = jax.jit(block_run, donate_argnums=donate)
@@ -1268,34 +1343,42 @@ def _make_workload_split(
         int(np.sum(jax.device_get(tables.faulted))) if chaos_on else 0
     )
 
-    def runner(st, hl, rst, rcar):
-        stats = jnp.zeros((chaos_mod.N_CHAOS_STATS,), jnp.int32)
-        rstats = jnp.zeros((reconfig_mod.N_RECONFIG_STATS,), jnp.int32)
-        safety = jnp.zeros((kernels.N_SAFETY,), jnp.int32)
-        rdstats = jnp.zeros((workload_mod.N_READ_STATS,), jnp.int32)
-        lat_hist = jnp.zeros((workload_mod.N_LAT_BUCKETS,), jnp.int32)
-        carry = (
-            st, hl, rst, stats, rstats, safety, rcar, rdstats, lat_hist,
-            jnp.int32(0),
-        )
-        if chaos_on:
-            carry = carry + (
-                jnp.int32(0),
-                jnp.zeros((len(workload_mod.GUARD_TERMS),), jnp.int32),
-            )
-        with profiling.span(
+    def blocks_span():
+        return profiling.span(
             "raft.runner.blocks", blocks=n_blocks, tail=tail,
             chaos=int(chaos_on), blocks_faulted=blocks_faulted,
-        ):
-            for block in block_args:
-                carry = fused_jit(*carry, *block, *sched_args)
-        if tail_jit is not None:
-            carry = tail_jit(*carry, tail_r0, *sched_args)
-        # Inert here with the no-op schedule, kept for bit-parity with the
-        # unsplit runner.
-        safety = carry[5] + tail_audit_jit(carry[0], carry[2])
-        return carry[:5] + (safety,) + carry[6:]
+        )
 
+    if n_blocks <= _SEGMENT_MAX_BLOCKS:
+        jitted = jax.jit(segment_run, donate_argnums=(0, 1, 2, 3))
+        segment_args = (
+            *jax.jit(stacked_tables)(starts[first], *sched_args),
+            jnp.asarray(row_of, jnp.int32),
+            *sched_args,
+        )
+
+        def runner(st, hl, rst, rcar):
+            with blocks_span():
+                return jitted(st, hl, rst, rcar, *segment_args)
+
+    else:
+        jitted = segment_args = None
+
+        def runner(st, hl, rst, rcar):
+            carry = fresh_carry(st, hl, rst, rcar)
+            with blocks_span():
+                for block in block_args:
+                    carry = fused_jit(*carry, *block, *sched_args)
+            if tail_jit is not None:
+                carry = tail_jit(*carry, tail_r0, *sched_args)
+            # Inert here with the no-op schedule, kept for bit-parity with
+            # the unsplit runner.
+            safety = carry[5] + tail_audit_jit(carry[0], carry[2])
+            return carry[:5] + (safety,) + carry[6:]
+
+    runner.jitted = jitted  # type: ignore[attr-defined]
+    runner.segment_args = segment_args  # type: ignore[attr-defined]
+    runner.tail_jit = tail_jit  # type: ignore[attr-defined]
     runner.fused_jit = fused_jit  # type: ignore[attr-defined]
     runner.block_args = block_args  # type: ignore[attr-defined]
     runner.schedule_args = sched_args  # type: ignore[attr-defined]
@@ -1557,7 +1640,16 @@ def make_runner(
     (reconfig.empty_reconfig_schedule), whose op protocol never moves.
 
     workload split: the workload scan's outputs plus a trailing
-    fused_rounds, bit-identical, run as `k`-round blocks.  A block takes
+    fused_rounds, bit-identical, run as `k`-round blocks.  A call of at
+    most _SEGMENT_MAX_BLOCKS blocks is ONE program (``.jitted``, the
+    segment program: the accumulators made inside it as the workload scan
+    makes them, an unrolled lax.scan of the block program over the
+    stacked block rows, the tail's n_rounds % k general rounds, the tail
+    audit; ``runner()`` is one dispatch of it with ``.segment_args`` =
+    (stacked rows, stacked loads, each block's load row,
+    *schedule_args)); a longer call dispatches the block program once a
+    block from a host loop, then the tail's and the audit's, and has
+    ``.jitted`` None.  A block takes
     the fused kernel when, at run time, the steady invariant holds for
     the horizon (pallas_step.steady_mask, damping conditions included),
     no quorum-round read work touches it (an outstanding read of any mode
@@ -1570,7 +1662,8 @@ def make_runner(
     round: lat_hist[0], issued and served_lease += fires; the read carry
     stays empty; every safety slot zero).  What a block needs of the
     schedule is tabled once, when the runner is built
-    (workload.block_tables; block b's operands are ``.block_args[b]``),
+    (workload.block_tables; block b's operands are ``.block_args[b]``,
+    the segment program scans the same rows stacked),
     so a block's cost does not follow the schedule's length; only the
     general arm reads the planes.  With a chaos plan (ISSUE 51) a block
     is also one chaos phase (tabled: BlockRows.same_chaos_phase): its
@@ -1581,8 +1674,14 @@ def make_runner(
     scan's, and the outputs end (..., fused_rounds, healthy_refused,
     guard_refusals[len(workload.GUARD_TERMS)]): the blocks outside a
     faulted phase that did not fuse, and the groups each guard term
-    refused in them.  A reconfig plan is refused.  Also exposes ``.fused_jit``,
-    ``.n_blocks``, ``.blocks_faulted``.
+    refused in them.  A reconfig plan is refused.  Also exposes
+    ``.n_blocks``, ``.blocks_faulted`` and the programs a long call
+    dispatches — which are also how a test walks ANY segment one block
+    at a time (against the scalar cluster, a block's jaxpr, its names):
+    ``.fused_jit`` (ONE block: (the ten- or twelve-piece carry, block
+    b's rows, its load row, *schedule_args) -> the carry),
+    ``.block_args[b]`` (those rows) and ``.tail_jit`` (the tail's
+    rounds, None where k divides n_rounds).
 
     cadence segment: returns the bare jit (st, hl, rst, stats, rstats,
     safety[, blackbox], cs_rounds, r0, transfer_plane, kick_plane,

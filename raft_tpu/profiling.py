@@ -53,12 +53,18 @@ SPANS: Dict[str, str] = {
         "last call's), init_read_carry, placement"
     ),
     "raft.run_reads.dispatch": (
-        "runner(*args): the eager zero carries, the block loop or the "
-        "scan's dispatch, the tail audit"
+        "runner(*args): the scan runner's ONE program; the split runner's "
+        "segment program (a call of a few blocks: one program too) or its "
+        "eager zero carries, block loop and tail audit (a longer call)"
     ),
     "raft.runner.blocks": (
-        "the split runner's Python loop of fused_jit dispatches, inside "
-        "dispatch; stats: blocks, tail (rounds left to the tail program), "
+        "the split runner's blocks, inside dispatch: ONE dispatch of the "
+        "segment program (runner.jitted: the accumulators' fills, an "
+        "unrolled lax.scan of the block program over the tabled rows, the "
+        "tail's rounds, the tail audit) where the call has at most "
+        "runner._SEGMENT_MAX_BLOCKS blocks (PR 53), else the Python loop of "
+        "fused_jit dispatches; stats: blocks, tail (rounds left after the "
+        "last block), "
         "chaos (1 where the runner has a chaos plan), blocks_faulted (the "
         "blocks whose chaos phase has a crash, a cut or a loss rate)"
     ),
@@ -210,9 +216,11 @@ SCOPES: Dict[str, str] = {
         "guard_refusals)"
     ),
     "runner.block_guard": (
-        "everything a split block computes before its lax.cond: its "
-        "tabled schedule rows unpacked (workload.BlockRows), lease_read, "
-        "steady_mask"
+        "everything a split block computes outside its lax.cond's arms: "
+        "its write-load row picked from the stacked loads (the segment "
+        "program's scan body), its tabled schedule rows unpacked "
+        "(workload.BlockRows), lease_read, steady_mask and, after the cond, "
+        "the fused group-round count"
     ),
     "runner.fused_arm": "the cond's fused branch: kernel + closed-form folds",
     "runner.general_arm": "the cond's fallback: k general rounds",
